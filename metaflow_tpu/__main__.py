@@ -411,7 +411,7 @@ def metrics(flow_run, run_id, datastore, datastore_root, as_json,
 @main.command(
     help="Chip-second accounting for a run: `goodput FLOW/RUN_ID`. "
          "Derives the goodput ledger from persisted telemetry — every "
-         "chip-second bucketed into the pinned taxonomy (productive "
+         "chip-second bucketed into the pinned set of categories (productive "
          "step, compile, input/transfer stall, checkpoint, restore "
          "replay, capacity wait, serve prefill/decode/idle) — "
          "reconciles it against observed chip-time, and names the "
@@ -616,9 +616,11 @@ def serve(flow_run, run_id, step_name, ckpt_step, params_key, config_json,
           max_queue, mesh_spec, attn_impl, prefill_workers,
           prefix_cache_mb, paged, page_tokens, spec_k,
           reload_checkpoint, federate):
+    from . import device
     from .cmd.serve import serve as serve_impl
     from .exception import TpuFlowException
 
+    device.setup_compile_cache()
     if not flow_run and not federate:
         raise click.ClickException(
             "FLOW_RUN is required (or pass --federate URL,URL)")

@@ -14,7 +14,7 @@ import json
 from .. import goodput as goodput_mod
 from .. import telemetry
 
-# categories always rendered in this order (taxonomy order, losses
+# categories always rendered in this order (category order, losses
 # grouped after productive work)
 _RENDER_ORDER = goodput_mod.CATEGORIES + (goodput_mod.UNATTRIBUTED,)
 

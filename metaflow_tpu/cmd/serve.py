@@ -13,7 +13,7 @@ records.
 import json
 import os
 
-from .. import knobs
+from .. import device, knobs
 from ..exception import TpuFlowException
 
 
@@ -79,6 +79,7 @@ def build_engine(params, cfg, slots=8, max_seq_len=None, prefill_chunk=64,
     None, or a MeshSpec factory name ('dp'|'fsdp'|'fsdp_tp')."""
     from ..serving import PagedEngine, SlotEngine
 
+    device.platform()  # a server on a quiet CPU fallback is an error
     mesh = None
     if mesh_spec:
         import jax
@@ -196,6 +197,11 @@ def serve_fleet(flow_run, run_id=None, step_name=None, ckpt_step=None,
     from ..serving import FleetConfig, ServingFleet, \
         SubprocessReplicaSpawner
 
+    # replica workers are processes of this host and inherit this
+    # environment (a rolling reload adds one more for the surge)
+    device.refuse_chip_sharing(
+        int(replicas) + int(prefill_workers),
+        "tpuflow serve --replicas/--prefill-workers")
     flow_name, run_id = _resolve_flow_run(flow_run, run_id)
     replica_args = [
         "--flow", flow_name, "--run-id", str(run_id),
@@ -377,6 +383,7 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
     # telemetry lands under the real run id, next to its training
     # records — never under a synthetic label
     flow_name, run_id = _resolve_flow_run(flow_run, run_id)
+    compiles = device.watch_compiles()
     restored = load_run_checkpoint(flow_name, run_id=run_id,
                                    step_name=step_name,
                                    ckpt_step=ckpt_step)
@@ -406,6 +413,7 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
                            engine.max_seq_len, engine.attn_impl))
     echo("  POST /v1/generate  {\"tokens\": [...], \"max_new_tokens\": N,"
          " \"stream\": true}")
+    echo("  device: %s" % json.dumps(device.describe()))
     if not block:
         server.start()
         return server
@@ -414,3 +422,5 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
     finally:
         telemetry.close_recorder()
     echo("drained — all in-flight requests finished")
+    echo("  compiles: %s  peak_bytes_in_use: %s"
+         % (json.dumps(compiles), device.peak_bytes_in_use()))

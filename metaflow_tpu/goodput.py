@@ -1,4 +1,4 @@
-"""Fleet-wide goodput ledger: account every chip-second in one taxonomy.
+"""Fleet-wide goodput ledger: account every chip-second in one set of categories.
 
 The scheduling objective every later subsystem optimizes ("goodput over
 elastic capacity") needs a measurement substrate first: the subsystems
@@ -10,7 +10,7 @@ stall breakdowns (training/metrics.py), `serve.prefill_chunk` /
 could SUM them. This module derives a per-rank interval ledger from
 those streams and rolls it up into a wall-clock-reconciled breakdown.
 
-Taxonomy (pinned in tests/schema_validate.py::GOODPUT_CATEGORIES):
+Categories (pinned in tests/schema_validate.py::GOODPUT_CATEGORIES):
 
     productive_step     forward+backward compute inside a train step
     compile             XLA trace+compile (whole interval of a step that

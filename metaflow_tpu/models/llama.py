@@ -182,7 +182,8 @@ def _layer(cfg, cos, sin, x, layer_params, mesh=None):
 
             attn = ulysses_attention(q, k, v, mesh, causal=True)
     else:
-        attn = attention(q, k, v, causal=True, impl=cfg.attention_impl)
+        attn = attention(q, k, v, causal=True, impl=cfg.attention_impl,
+                         mesh=mesh)
     # named for remat_policy='attn_out': saving this tensor across the layer
     # checkpoint boundary means the backward pass never re-runs the
     # attention forward (the flash custom_vjp already recomputes its own

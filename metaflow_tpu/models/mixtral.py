@@ -129,7 +129,8 @@ def _layer(cfg, cos, sin, carry, layer_params, mesh=None):
     v = (h @ layer_params["wv"]).reshape(B, S, KV, Hd)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    attn = attention(q, k, v, causal=True, impl=cfg.attention_impl)
+    attn = attention(q, k, v, causal=True, impl=cfg.attention_impl,
+                     mesh=mesh)
     x = x + attn.reshape(B, S, H * Hd) @ layer_params["wo"]
 
     h = rms_norm(x, layer_params["ffn_norm"], cfg.norm_eps)

@@ -12,14 +12,14 @@ Public entry: `attention(q, k, v, causal=..., impl='auto')` with GQA support
 
 import functools
 import math
+import warnings
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+from jax import shard_map
+from jax.experimental import pallas as pl
 
-import os
-
-from .. import knobs
+from .. import device, knobs
 
 # 128 is the MXU tile floor; the defaults are overridable for tuning
 # sweeps (bench) and odd shapes. Combinations where one block size
@@ -43,10 +43,6 @@ def shard_map_novma(fn, mesh, in_specs, out_specs):
     trips the vma checker's dynamic_slice rule; sharding correctness is
     still enforced by the in/out specs. Shared by the sequence-parallel
     attention variants (ring_attention.py, ulysses_attention.py)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
     return shard_map(fn, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False)
 
@@ -135,15 +131,6 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
     lse_ref[0, :, pl.ds(qi * block_q, block_q)] = jnp.broadcast_to(
         (m + jnp.log(l)).reshape(1, -1), (8, block_q)
     )
-
-
-try:  # pallas import is TPU/CPU-interpret capable; keep soft for portability
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    HAS_PALLAS = False
 
 
 def _flash_forward(q, k, v, causal, scale, interpret=False):
@@ -297,59 +284,9 @@ def _flash_backward_pallas(q, k, v, g, out, lse, causal, scale, interpret):
 
 
 def _flash_attention_bwd(causal, scale, interpret, res, g):
-    """Backward dispatch: pallas kernels when available, else the XLA
-    blockwise-recompute fallback (both use the saved LSE, no S×S tensor)."""
     q, k, v, out, lse = res
-    if HAS_PALLAS:
-        return _flash_backward_pallas(q, k, v, g, out, lse, causal, scale,
-                                      interpret)
-    return _flash_attention_bwd_xla(causal, scale, res, g)
-
-
-def _flash_attention_bwd_xla(causal, scale, res, g):
-    """Blockwise recompute backward using the saved LSE (no S×S tensor)."""
-    q, k, v, out, lse = res
-    qf = q.astype(jnp.float32)
-    kf = k.astype(jnp.float32)
-    vf = v.astype(jnp.float32)
-    gf = g.astype(jnp.float32)
-    BH, S, D = q.shape
-    delta = jnp.sum(gf * out.astype(jnp.float32), axis=-1)  # [BH, S]
-
-    block = min(BLOCK_Q, S)
-    nb = S // block
-
-    q_pos_all = jnp.arange(S)
-
-    def scan_q(carry, qb):
-        dk, dv = carry
-        qs = jax.lax.dynamic_slice_in_dim(qf, qb * block, block, axis=1)
-        gs = jax.lax.dynamic_slice_in_dim(gf, qb * block, block, axis=1)
-        lses = jax.lax.dynamic_slice_in_dim(lse, qb * block, block, axis=1)
-        deltas = jax.lax.dynamic_slice_in_dim(delta, qb * block, block, axis=1)
-        s = jnp.einsum("bqd,bkd->bqk", qs * scale, kf,
-                       preferred_element_type=jnp.float32)
-        if causal:
-            qpos = qb * block + q_pos_all[:block]
-            mask = qpos[:, None] >= q_pos_all[None, :]
-            s = jnp.where(mask[None], s, NEG_INF)
-        p = jnp.exp(s - lses[..., None])
-        dp = jnp.einsum("bqd,bkd->bqk", gs, vf,
-                        preferred_element_type=jnp.float32)
-        ds = p * (dp - deltas[..., None]) * scale
-        dq_b = jnp.einsum("bqk,bkd->bqd", ds, kf,
-                          preferred_element_type=jnp.float32)
-        dk = dk + jnp.einsum("bqk,bqd->bkd", ds, qs,
-                             preferred_element_type=jnp.float32)
-        dv = dv + jnp.einsum("bqk,bqd->bkd", p, gs,
-                             preferred_element_type=jnp.float32)
-        return (dk, dv), dq_b
-
-    (dk, dv), dq_blocks = jax.lax.scan(
-        scan_q, (jnp.zeros_like(kf), jnp.zeros_like(vf)), jnp.arange(nb)
-    )
-    dq = dq_blocks.transpose(1, 0, 2, 3).reshape(BH, S, D)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return _flash_backward_pallas(q, k, v, g, out, lse, causal, scale,
+                                  interpret)
 
 
 _flash_attention.defvjp(_flash_attention_fwd, _flash_attention_bwd)
@@ -359,12 +296,7 @@ def flash_attention(q, k, v, causal=True, scale=None, interpret=False):
     """Pallas flash attention; q,k,v: [B, S, H, D] (kv heads may be fewer).
 
     Requires S to be a multiple of the 128 block size (the `attention`
-    dispatcher falls back to the XLA path otherwise)."""
-    if not HAS_PALLAS:
-        raise RuntimeError(
-            "flash_attention requires pallas (jax.experimental.pallas); "
-            "use attention(impl='auto') for an XLA fallback"
-        )
+    dispatcher takes the XLA path otherwise)."""
     B, S, H, D = q.shape
     block_q, block_k = _check_blocks(S)
     k = _broadcast_gqa(k, H)
@@ -383,10 +315,7 @@ def flash_attention(q, k, v, causal=True, scale=None, interpret=False):
 def _sds(shape, dtype, like):
     """ShapeDtypeStruct carrying `like`'s varying-axes (vma) annotation —
     required for pallas_call outputs under shard_map with check_vma."""
-    try:
-        vma = jax.typeof(like).vma
-    except (AttributeError, TypeError):
-        vma = None
+    vma = jax.typeof(like).vma
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -542,16 +471,69 @@ def flash_block_bwd(q, k, v, g, lse, delta, scale, causal_diag,
     return dq, dk, dv
 
 
-def attention(q, k, v, causal=True, scale=None, impl="auto"):
-    """Dispatch: pallas flash on TPU when shapes tile cleanly, XLA otherwise."""
+def auto_impl(aligned, what, shape):
+    """What impl='auto' means, here and in ring_attention: the Pallas
+    kernel on a TPU whenever the shapes tile, XLA attention in a
+    CPU-pinned process (the kernel would only run interpreted there).
+    On a TPU a shape that does not tile takes the XLA path too, and
+    says so once. Any other backend is device.platform()'s error."""
+    if not device.on_tpu():
+        return "xla"
+    if aligned:
+        return "flash"
+    # shown once per message by the default warning filter
+    warnings.warn(
+        "%s: shape %s does not tile for the flash kernel (sequence "
+        "blocks %d/%d, head size a multiple of 128, batch and heads "
+        "dividing the mesh); using XLA attention"
+        % (what, shape, BLOCK_Q, BLOCK_K), RuntimeWarning, stacklevel=3)
+    return "xla"
+
+
+def _flash_partition(mesh, q, k):
+    """How the flash kernel is split over a multi-device mesh: the
+    compiler cannot partition a Mosaic kernel by itself, so it runs
+    under shard_map, batch over the data axes and heads over 'tensor'.
+    Returns the PartitionSpec for [B, S, H, D], None for no mesh or a
+    one-device mesh, False when batch or heads do not divide."""
+    if mesh is None or mesh.size == 1:
+        return None
+    from jax.sharding import PartitionSpec
+
+    batch_axes = tuple(a for a in ("data", "fsdp")
+                       if mesh.shape.get(a, 1) > 1)
+    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+    n_heads = mesh.shape.get("tensor", 1)
+    if q.shape[0] % n_batch or q.shape[2] % n_heads \
+            or k.shape[2] % n_heads:
+        return False
+    return PartitionSpec(batch_axes or None, None,
+                         "tensor" if n_heads > 1 else None, None)
+
+
+def attention(q, k, v, causal=True, scale=None, impl="auto", mesh=None):
+    """Dispatch: pallas flash on TPU when shapes tile cleanly, XLA
+    where they do not, where the process is CPU-pinned, or by name.
+    `mesh`: the mesh the caller's arrays are sharded over, if any —
+    the kernel then runs per shard (see _flash_partition)."""
+    spec = _flash_partition(mesh, q, k)
     if impl == "auto":
         S, D = q.shape[1], q.shape[3]
-        on_tpu = jax.default_backend() == "tpu"
-        aligned = blocks_aligned(S) and D % 128 == 0
-        impl = "flash" if (HAS_PALLAS and on_tpu and aligned) else "xla"
-    if impl == "flash":
-        return flash_attention(q, k, v, causal=causal, scale=scale)
-    if impl == "flash_interpret":
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               interpret=True)
+        impl = auto_impl(
+            blocks_aligned(S) and D % 128 == 0 and spec is not False,
+            "attention", tuple(q.shape))
+    if impl in ("flash", "flash_interpret"):
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal=causal, scale=scale,
+                                   interpret=impl == "flash_interpret")
+
+        if spec is None:
+            return kernel(q, k, v)
+        if spec is False:
+            raise ValueError(
+                "flash attention over mesh %s needs batch %d and heads "
+                "%d/%d to divide its data and tensor axes"
+                % (dict(mesh.shape), q.shape[0], q.shape[2], k.shape[2]))
+        return shard_map_novma(kernel, mesh, (spec, spec, spec), spec)(
+            q, k, v)
     return reference_attention(q, k, v, causal=causal, scale=scale)

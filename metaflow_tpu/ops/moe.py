@@ -75,18 +75,10 @@ def expert_capacity(num_tokens, num_experts, k, capacity_factor):
 
 def _active_mesh():
     """The mesh from an enclosing `with mesh:` block, if any."""
-    try:
-        try:  # jax >= 0.8.2 deprecated the pxla re-export
-            from jax._src.mesh import thread_resources
-        except ImportError:
-            from jax.interpreters.pxla import thread_resources
+    from jax._src.mesh import thread_resources
 
-        mesh = thread_resources.env.physical_mesh
-        if mesh is not None and not mesh.empty:
-            return mesh
-    except Exception:
-        pass
-    return None
+    mesh = thread_resources.env.physical_mesh
+    return None if mesh.empty else mesh
 
 
 def _constrain_expert_axis(x, mesh):

@@ -25,7 +25,6 @@ Use inside shard_map (ring_attention_sharded builds it for a mesh).
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -36,10 +35,10 @@ from .. import knobs
 from .attention import (
     BLOCK_K,
     BLOCK_Q,
-    HAS_PALLAS,
     _broadcast_gqa,
     _fold_heads,
     _unfold_heads,
+    auto_impl,
     blocks_aligned,
     flash_block_bwd,
     flash_block_fwd,
@@ -298,8 +297,7 @@ def _resolve_impl(impl, S_local):
     # same predicate flash_block_fwd/bwd enforce — single source of truth
     aligned = blocks_aligned(S_local)
     if impl == "auto":
-        on_tpu = jax.default_backend() == "tpu"
-        impl = "flash" if (HAS_PALLAS and on_tpu and aligned) else "xla"
+        impl = auto_impl(aligned, "ring_attention", (S_local,))
     if impl in ("flash", "flash_interpret") and not aligned:
         # an explicitly requested flash impl must not silently drop the
         # unaligned tail (grid floor-division would leave rows unwritten)

@@ -17,7 +17,7 @@ import os
 import subprocess
 import sys
 
-from .. import knobs, telemetry, tracing
+from .. import device, knobs, telemetry, tracing
 from ..current import current, Parallel
 from ..decorators import StepDecorator
 from ..exception import TpuFlowException
@@ -163,6 +163,8 @@ class ParallelDecorator(StepDecorator):
 
         num_parallel = int(flow._foreach_num_splits or 1)
         num_parallel = _elastic_gang_size(num_parallel)
+        device.refuse_chip_sharing(
+            num_parallel, "The local gang of step *%s*" % current.step_name)
         run_id = current.run_id
         step_name = current.step_name
         control_task_id = current.task_id

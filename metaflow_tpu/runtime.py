@@ -885,13 +885,11 @@ class NativeRuntime(object):
             if overrides_cli and not isinstance(deco, ParallelDecorator):
                 # decorator rewrites the task CLI (trampoline): honor via exec
                 return False
-        try:
-            import jax._src.xla_bridge as xb
-
-            if getattr(xb, "_backends", None):
-                return False
-        except Exception:
-            pass
+        # the scheduler itself never starts a backend; flow-level user
+        # code that did holds the chip, and its fds must not be forked
+        jax = sys.modules.get("jax")
+        if jax is not None and jax._src.xla_bridge.backends_are_initialized():
+            return False
         return True
 
     def _fork_worker(self, task):

@@ -30,18 +30,12 @@ the tokens a unified replica would (pinned by tests).
 import json
 import struct
 
+import ml_dtypes  # noqa: F401  (registers bfloat16 & friends with numpy)
 import numpy as np
 
 MAGIC = b"TPFKV1\n"
 
 
-def _dtype(name):
-    try:
-        return np.dtype(name)
-    except TypeError:
-        # bfloat16 & friends live in ml_dtypes (always present under jax)
-        import ml_dtypes
-        return np.dtype(getattr(ml_dtypes, name))
 
 
 def encode_handoff(meta, kv):
@@ -67,7 +61,7 @@ def decode_handoff(data):
     off += 4
     header = json.loads(data[off:off + hlen].decode("utf-8"))
     off += hlen
-    dtype = _dtype(header.pop("dtype"))
+    dtype = np.dtype(header.pop("dtype"))
     k_shape = tuple(header.pop("k_shape"))
     v_shape = tuple(header.pop("v_shape"))
     k_bytes = int(np.prod(k_shape)) * dtype.itemsize

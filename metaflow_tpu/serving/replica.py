@@ -185,10 +185,11 @@ def main(argv=None):
               "is required", file=sys.stderr)
         return 2
 
-    from .. import telemetry
+    from .. import device, telemetry
     from .scheduler import Scheduler
     from .server import ServingServer
 
+    device.setup_compile_cache()
     if args.synthetic_config:
         engine = _build_synthetic(args)
         # hermetic fleets have no run of their own, but a harness can

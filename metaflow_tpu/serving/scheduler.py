@@ -868,7 +868,7 @@ class Scheduler(object):
         return {"enabled": self.tenancy.enabled(), "tenants": tenants}
 
     def goodput_stats(self):
-        """Chip-second split in the goodput taxonomy
+        """Chip-second split in the goodput categories
         (metaflow_tpu/goodput.py): device-busy prefill/decode seconds
         plus the scheduler-lifetime remainder as idle."""
         elapsed = max(0.0, time.perf_counter() - self._t_started)

@@ -21,8 +21,10 @@ def initialize_from_current(timeout_ms=60_000):
 
     from .. import telemetry
 
-    if jax.process_count() > 1:
-        return False  # already initialized
+    # is_initialized, not process_count(): the latter starts the backend,
+    # which must not exist before jax.distributed.initialize()
+    if jax.distributed.is_initialized():
+        return False
     # rendezvous cost is a first-class launch metric: a slow rank (or a
     # wedged coordinator) shows up as this timer in `tpuflow metrics`
     with telemetry.timer(
@@ -50,7 +52,7 @@ def initialize_from_env():
 
     from .. import telemetry
 
-    if jax.process_count() > 1:
+    if jax.distributed.is_initialized():
         return False
     with telemetry.timer("distributed.initialize",
                          data={"source": "tpu_metadata"}):

@@ -62,6 +62,7 @@ import struct
 import threading
 import time
 
+import ml_dtypes  # noqa: F401  (registers bfloat16 & friends with numpy)
 import numpy as np
 
 from .. import knobs
@@ -86,13 +87,6 @@ class MPMDTransferTimeout(MPMDTransferError):
     elastic supervisor reap and relaunch the gang."""
 
 
-def _dtype(name):
-    try:
-        return np.dtype(name)
-    except TypeError:
-        # bfloat16 & friends live in ml_dtypes (always present under jax)
-        import ml_dtypes
-        return np.dtype(getattr(ml_dtypes, name))
 
 
 def encode_frame(meta, arr):
@@ -116,7 +110,7 @@ def decode_frame(data):
     off += 4
     header = json.loads(data[off:off + hlen].decode("utf-8"))
     off += hlen
-    dtype = _dtype(header.pop("dtype"))
+    dtype = np.dtype(header.pop("dtype"))
     shape = tuple(header.pop("shape"))
     n = int(np.prod(shape, dtype=np.int64)) if shape else 1
     if len(data) != off + n * dtype.itemsize:

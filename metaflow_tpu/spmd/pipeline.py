@@ -21,18 +21,11 @@ from jax.sharding import PartitionSpec as P
 
 def _as_varying(z, axis_name):
     """Mark z as varying over the pipeline axis inside shard_map — a
-    no-op if it already is, or on jax versions without vma annotations.
-    (zeros_like(params) inherits the params' annotation, hence the check.)"""
-    try:
-        if axis_name in jax.typeof(z).vma:
-            return z
-    except (AttributeError, TypeError):
-        pass
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is None:
-        # older jax: no vma annotations exist, nothing to satisfy
+    no-op if it already is. (zeros_like(params) inherits the params'
+    annotation, hence the check.)"""
+    if axis_name in jax.typeof(z).vma:
         return z
-    return pcast(z, (axis_name,), to="varying")
+    return jax.lax.pcast(z, (axis_name,), to="varying")
 
 
 def _shard_map(fn, mesh, in_specs, out_specs, manual_axes=None):
@@ -44,20 +37,11 @@ def _shard_map(fn, mesh, in_specs, out_specs, manual_axes=None):
     (fsdp/data) or ZeRO param sharding composes with the pipeline without
     the schedule code knowing about it."""
     kwargs = {}
-    partial = (manual_axes is not None
-               and set(manual_axes) != set(mesh.axis_names))
-    try:
-        from jax import shard_map
-
-        if partial:
-            kwargs["axis_names"] = frozenset(manual_axes)
-    except ImportError:  # older jax spells partial-manual mode `auto=`
-        from jax.experimental.shard_map import shard_map
-
-        if partial:
-            kwargs["auto"] = frozenset(mesh.axis_names) - set(manual_axes)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kwargs)
+    if (manual_axes is not None
+            and set(manual_axes) != set(mesh.axis_names)):
+        kwargs["axis_names"] = frozenset(manual_axes)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def pipeline_apply(layer_fn, stage_params, x, mesh, num_microbatches,

@@ -12,7 +12,7 @@ import sys
 import time
 import traceback
 
-from . import knobs, telemetry
+from . import device, knobs, telemetry
 from .current import current
 from .datastore.task_datastore import TaskDataStore
 from .exception import TaskPreempted, TpuFlowException, MetaflowInternalError
@@ -182,6 +182,7 @@ class MetaflowTask(object):
         else:
             raise MetaflowInternalError("run_id and task_id are required")
 
+        device.setup_compile_cache()
         # flight recorder: every record from here on carries this task's
         # full identity (run/step/task/attempt/rank/host) and persists to
         # the run's datastore at finalization — replacing any recorder

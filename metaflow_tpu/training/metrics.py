@@ -49,24 +49,36 @@ TPU_HBM_GBPS = [
 ]
 
 
-def peak_tflops(device_kind):
-    """Published bf16 peak TFLOP/s for a chip kind, or None (CPU/GPU).
+def _chip_row(table, device_kind, what):
+    """The table row a device_kind matches. A CPU has none (None); a TPU
+    that matches none is an error — a utilization against no peak, or
+    against a guessed one, is not a number."""
+    kind = (device_kind or "").lower()
+    row = next((r for r in table if r[0] in kind), None)
+    if row is None and "tpu" in kind:
+        raise ValueError(
+            "no %s recorded for device_kind %r: add its published figure "
+            "to training/metrics.py" % (what, device_kind))
+    return row
 
-    TPUFLOW_PEAK_TFLOPS overrides the table — for chips not yet listed,
-    or to get meaningful MFU numbers out of CPU/GPU dev runs."""
+
+def peak_tflops(device_kind):
+    """Published bf16 peak TFLOP/s for a chip kind; None on the CPU.
+
+    TPUFLOW_PEAK_TFLOPS overrides the table."""
     override = knobs.get_raw("TPUFLOW_PEAK_TFLOPS")
     if override:
         try:
             return float(override)
         except ValueError:
             pass
-    kind = (device_kind or "").lower()
-    return next((tf for sub, tf in TPU_PEAK_TFLOPS if sub in kind), None)
+    row = _chip_row(TPU_PEAK_TFLOPS, device_kind, "bf16 peak TFLOP/s")
+    return row[1] if row else None
 
 
 def hbm_gbps(device_kind):
-    kind = (device_kind or "").lower()
-    return next((bw for sub, bw in TPU_HBM_GBPS if sub in kind), None)
+    row = _chip_row(TPU_HBM_GBPS, device_kind, "HBM GB/s")
+    return row[1] if row else None
 
 
 def flops_per_token_dense(n_params, n_layers, dim, seq):
@@ -365,7 +377,7 @@ class TrainStepTelemetry(object):
             self._profile.stop(self.step_num)
         interval = self._goodput_interval()
         if interval is not None:
-            # per-rank chip-second rollup in the goodput taxonomy
+            # per-rank chip-second rollup in the goodput categories
             # (metaflow_tpu/goodput.py): rides the crash-safe recorder
             # so the ledger CLI can cross-check its derivation against
             # what the rank itself tallied

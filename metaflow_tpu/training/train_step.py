@@ -13,7 +13,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
+from .. import device
 from ..spmd import sanitizer
 from ..spmd import sharding as shd
 
@@ -93,8 +95,6 @@ def reshard_like(tree, like):
     multi-device jit with 'incompatible devices', while an uncommitted
     host array lets jit place them exactly as it placed the originals.
     """
-    from jax.sharding import NamedSharding
-
     def _place(restored, live):
         host = np.asarray(jax.device_get(restored))
         sharding = getattr(live, "sharding", None)
@@ -146,6 +146,32 @@ def check_opt_state(optimizer, state):
                     have_dtype, have_shape))
 
 
+def _opt_state_shardings(optimizer, params, param_shardings, mesh):
+    """Where each leaf of `optimizer.init(params)` goes. A leaf that
+    mirrors a parameter (its path ends in the parameter's, with the
+    parameter's shape: a moment) is placed like the parameter; the rest
+    (factored moments, counters) is small and replicated over the mesh.
+
+    jit does not do this by itself: a moment starts as zeros, which
+    depend on no input, so GSPMD leaves it replicated — a whole copy of
+    every moment on every device — and a leaf that lands on one device
+    comes back from the first step on the mesh, which compiles the
+    whole step a second time."""
+    flat_params = jax.tree_util.tree_leaves_with_path(params)
+    flat_shardings = jax.tree.leaves(param_shardings)
+    replicated = NamedSharding(mesh, PartitionSpec())
+
+    def place(path, leaf):
+        for (ppath, param), sharding in zip(flat_params, flat_shardings):
+            if (tuple(path[-len(ppath):]) == tuple(ppath)
+                    and leaf.shape == param.shape):
+                return sharding
+        return replicated
+
+    return jax.tree_util.tree_map_with_path(
+        place, jax.eval_shape(optimizer.init, params))
+
+
 def make_train_state(rng, cfg, mesh, model, optimizer=None, rules=None,
                      zero=None):
     """Sharded init: params + optimizer state placed per the rule table.
@@ -171,8 +197,11 @@ def make_train_state(rng, cfg, mesh, model, optimizer=None, rules=None,
         params = jax.jit(init, out_shardings=param_shardings)()
         opt_state = jax.jit(
             optimizer.init,
-            # optimizer state mirrors the param tree; let GSPMD propagate
+            out_shardings=_opt_state_shardings(
+                optimizer, params, param_shardings, mesh),
         )(params)
+        step0 = jax.device_put(jnp.zeros((), jnp.int32),
+                               NamedSharding(mesh, PartitionSpec()))
         if use_zero:
             # re-spec each live leaf over the DP axis (base = the sharding
             # GSPMD propagated, so model-parallel axes are kept) and
@@ -181,8 +210,7 @@ def make_train_state(rng, cfg, mesh, model, optimizer=None, rules=None,
             # exceeds the non-zero path's.
             opt_state = jax.device_put(
                 opt_state, shd.zero_tree_shardings(opt_state, mesh))
-    state = {"params": params, "opt_state": opt_state,
-             "step": jnp.zeros((), jnp.int32)}
+    state = {"params": params, "opt_state": opt_state, "step": step0}
     shardings = {
         "params": param_shardings,
         "opt_state": jax.tree.map(lambda x: x.sharding, opt_state),
@@ -193,7 +221,7 @@ def make_train_state(rng, cfg, mesh, model, optimizer=None, rules=None,
 
 def make_train_step(cfg, mesh, model, optimizer=None, loss_fn=None,
                     zero=None, rules=None, opt_specs=None,
-                    timed_update=False):
+                    timed_update=False, state_shardings=None):
     """Build the jitted, donated train step: (state, batch) → (state, metrics).
 
     WARNING: `optimizer` must be the SAME GradientTransformation the state
@@ -222,6 +250,12 @@ def make_train_step(cfg, mesh, model, optimizer=None, loss_fn=None,
     DIAGNOSTIC mode: the fences serialize work the fused step overlaps, so
     never benchmark with it on. training/metrics.py picks the attribute up
     into the per-step telemetry record as `optimizer_update_ms`.
+
+    state_shardings: make_train_state's `shardings`. The step then
+    returns the state placed exactly as it came in: without it GSPMD may
+    re-place a leaf it finds cheaper elsewhere (a factored moment), and a
+    state whose placement changed compiles the step a second time and is
+    not updated in place.
 
     `mesh` shapes the zero schedule's constraints; with zero off the step
     itself is mesh-agnostic (shardings propagate from the state)."""
@@ -300,7 +334,10 @@ def make_train_step(cfg, mesh, model, optimizer=None, loss_fn=None,
             }
             return new_state, {"loss": loss, "grad_norm": grad_norm}
 
-        return jax.jit(step, donate_argnums=(0,))
+        return jax.jit(
+            step, donate_argnums=(0,),
+            out_shardings=(None if state_shardings is None
+                           else (state_shardings, None)))
 
     # diagnostic split: measure the update (optimizer math + zero
     # collectives) separately from the fwd/bwd. Two compiles, two fences.
@@ -369,6 +406,7 @@ def make_trainer(rng, cfg, mesh, model, optimizer=None, rules=None,
     step and the saved `extra` (e.g. the data iterator's resume stamp)
     are available afterwards as `checkpoint.last_restored` — without
     them a resumed run would silently restart its data stream."""
+    device.platform()  # a trainer on a quiet CPU fallback is an error
     optimizer = optimizer or default_optimizer()
     use_zero = shd.zero_enabled(mesh, zero)
     # compile-shaping state: every rank must build the SAME mesh/program
@@ -387,8 +425,6 @@ def make_trainer(rng, cfg, mesh, model, optimizer=None, rules=None,
     # instead of re-deriving from a replicated base
     opt_specs = None
     if use_zero:
-        from jax.sharding import NamedSharding
-
         opt_specs = jax.tree.map(
             lambda s: s.spec if isinstance(s, NamedSharding) else None,
             shardings["opt_state"])
@@ -397,7 +433,8 @@ def make_trainer(rng, cfg, mesh, model, optimizer=None, rules=None,
             opt_specs = None  # non-mesh placements: let trace-time derive
     step = make_train_step(cfg, mesh, model, optimizer=optimizer,
                            loss_fn=loss_fn, zero=use_zero, rules=rules,
-                           opt_specs=opt_specs, timed_update=timed_update)
+                           opt_specs=opt_specs, timed_update=timed_update,
+                           state_shardings=shardings)
     if checkpoint is not None:
         restored = checkpoint.restore(like=state)
         if restored is not None:
@@ -437,8 +474,6 @@ def shard_batch(batch, mesh):
     sequence dim over the 'sequence' axis when present AND divisible (a
     [B, S+1] token array stays batch-sharded; GSPMD reshards the sliced
     [B, S] inputs inside the step)."""
-    from jax.sharding import NamedSharding, PartitionSpec
-
     from ..spmd.mesh import data_axes
 
     sanitizer.journal("collective", "shard_batch", axes=mesh.axis_names,

@@ -5,8 +5,7 @@ preempted mid-epoch, and resumed exactly — model + optimizer moments +
 schedule step + data cursor all restored from one orbax checkpoint, the
 consumed token sequence asserted against an uninterrupted oracle.
 
-(BASELINE.md "Expert-parallel + resume" row; reference intent: exact
-resume via per-task artifact persistence, metaflow/datastore/
+(Reference intent: exact resume via per-task artifact persistence, metaflow/datastore/
 task_datastore.py:880 — here the data cursor must ride the checkpoint.)
 """
 

@@ -1573,15 +1573,15 @@ def validate_manifest(manifest):
 
 # ---------------------------------------------------------------------------
 # Goodput ledger (metaflow_tpu/goodput.py + cmd/goodput.py): the pinned
-# chip-second taxonomy, the ledger document `tpuflow goodput --json`
+# chip-second categories, the ledger document `tpuflow goodput --json`
 # emits / save_ledger persists, the per-rank goodput.interval event, the
 # `tpuflow watch --json` snapshot, and the OpenMetrics metric-name
 # vocabulary the /metrics endpoints expose. additionalProperties: false
 # throughout — a category or metric name the code invents (or renames)
-# fails validation, so dashboards keyed on the taxonomy cannot drift.
+# fails validation, so dashboards keyed on the categories cannot drift.
 # ---------------------------------------------------------------------------
 
-# the chip-second taxonomy, pinned to goodput.CATEGORIES (a test asserts
+# the chip-second categories, pinned to goodput.CATEGORIES (a test asserts
 # they stay equal). `unattributed` is the explicit remainder bucket, a
 # ledger output rather than an attribution category.
 GOODPUT_CATEGORIES = (

@@ -1,5 +1,5 @@
 """Fleet-wide goodput ledger + OpenMetrics export: the pinned
-chip-second taxonomy, cross-subsystem ledger derivation (elastic resize
+chip-second categories, cross-subsystem ledger derivation (elastic resize
 + MPMD stage stall + serving trace reconciling to observed chip-time),
 the `tpuflow goodput` CLI round-trip, the strict OpenMetrics writer/
 parser pair, the pinned metric-name vocabularies, and the /metrics
@@ -31,7 +31,7 @@ def _cross_subsystem_records():
     """The satellite fixture: an elastic 8->4 resize (kill at step 3,
     restore + replay of steps 2-3, a capacity park), an MPMD-style
     transfer stall on every steady step, a checkpoint snapshot, and a
-    serving lane — every taxonomy category is exercised at once.
+    serving lane — every category is exercised at once.
 
     Hand-auditable totals (seconds of chip-time):
       attempt 0: 8 ranks x 4 steps x 10s            = 320
@@ -113,7 +113,7 @@ def _fds(tmp_path, flow="GoodputTest"):
 
 
 class TestDeriveLedger:
-    def test_taxonomy_pinned(self):
+    def test_categories_pinned(self):
         assert goodput.CATEGORIES == sv.GOODPUT_CATEGORIES
         assert goodput.UNATTRIBUTED == "unattributed"
         assert set(goodput.PRODUCTIVE_CATEGORIES) < set(goodput.CATEGORIES)
@@ -417,7 +417,7 @@ class TestMetricFamilies:
                                          sv.OPENMETRICS_RUN_METRICS)
         chip = {l["category"]: v for _n, l, v
                 in parsed["tpuflow_goodput_chip_seconds"]["samples"]}
-        # every taxonomy bucket present, incl. the explicit remainder
+        # every category bucket present, incl. the explicit remainder
         assert set(chip) == set(sv.GOODPUT_ALL_BUCKETS)
         assert sum(chip.values()) \
             == pytest.approx(ledger["observed_chip_s"], rel=1e-3)

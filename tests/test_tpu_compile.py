@@ -1,0 +1,284 @@
+"""Compile the main path's programs for a TPU v5e that is described, not
+attached: what the chip's compiler refuses, it refuses here, at no chip
+time. Nothing runs, so nothing here is a result or a time.
+
+This is the only file that describes the chip. The topology is asked
+for inside the fixture below and nowhere else: one process at a time
+may load the TPU's library, the driver's test workers each import every
+test file, and only the worker given this file may load it.
+"""
+
+import math
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from metaflow_tpu.models import llama
+from metaflow_tpu.ops import gmm as gmm_fn
+from metaflow_tpu.ops.attention import (
+    flash_attention,
+    flash_block_fwd,
+)
+from metaflow_tpu.ops.ring_attention import ring_attention
+from metaflow_tpu.spmd import sharding as shd
+from metaflow_tpu.training import (
+    make_train_step,
+    memory_efficient_optimizer,
+)
+
+HBM_BYTES = 16 * 1000 ** 3
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as ex:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % ex)
+    # such a compile can be written to the persistent cache but not read
+    # back without a chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def on(tree, sharding):
+    """The same shapes, placed: `sharding` is one sharding for every
+    leaf or a tree of them."""
+    if isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(lambda x: sds(x.shape, x.dtype, sharding), tree)
+    return jax.tree.map(lambda x, s: sds(x.shape, x.dtype, s), tree,
+                        sharding)
+
+
+def compiled_with_kernel(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def device_bytes(compiled):
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# kernels alone, real widths
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("seq", [2048, 8192])
+def test_flash_attention_llama3_8b_heads(one_chip, seq, grad):
+    q = sds((1, seq, 32, 128), BF16, one_chip)
+    kv = sds((1, seq, 8, 128), BF16, one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: fwd(*a).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    compiled_with_kernel(fwd_bwd if grad else fwd, q, kv, kv)
+
+
+@pytest.mark.parametrize("diag", [True, False], ids=["diag", "off_diag"])
+def test_flash_block_fwd(one_chip, diag):
+    x = sds((32, 2048, 128), BF16, one_chip)
+    compiled_with_kernel(
+        lambda q, k, v: flash_block_fwd(q, k, v, 1 / math.sqrt(128), diag),
+        x, x, x)
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "grad"])
+@pytest.mark.parametrize(
+    "rows,dim,ffn,groups",
+    [(9216, 4096, 14336, 8), (24576, 2048, 1024, 64)],
+    ids=["mixtral_8x7b", "64x1024"])
+def test_gmm(one_chip, rows, dim, ffn, groups, grad):
+    x = sds((rows, dim), BF16, one_chip)
+    w = sds((groups, dim, ffn), BF16, one_chip)
+    tiles = sds((rows // 128,), jnp.int32, one_chip)
+
+    def fwd(x, w, tile_group, tile_active):
+        return gmm_fn(x, w, tile_group, tile_active=tile_active,
+                      interpret=False)
+
+    def dx_dw(x, w, tile_group, tile_active):
+        return jax.grad(
+            lambda x, w: fwd(x, w, tile_group, tile_active)
+            .astype(jnp.float32).sum(), (0, 1))(x, w)
+
+    compiled_with_kernel(dx_dw if grad else fwd, x, w, tiles, tiles)
+
+
+# ---------------------------------------------------------------------------
+# whole programs at Llama-3-8B widths, from eval_shape
+# ---------------------------------------------------------------------------
+
+SMOKE_LAYERS = 4  # tests/flows/chip_smoke_flow.py's default depth
+
+
+def smoke_cfg(**kw):
+    # 'flash' by name: this process is CPU-pinned, where 'auto' means XLA
+    return llama.LlamaConfig.llama3_8b(
+        n_layers=SMOKE_LAYERS, max_seq_len=2048, attention_impl="flash",
+        **kw)
+
+
+def train_step_args(cfg, mesh, batch, seq):
+    """(jitted step, abstract state, abstract batch) placed on `mesh` the
+    way make_train_state places the live ones."""
+    optimizer = memory_efficient_optimizer(total_steps=10)
+    param_sh = shd.tree_shardings(llama.logical_axes(cfg), mesh)
+    params = on(jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg)), param_sh)
+    # the optimizer state's placement is whatever GSPMD propagates from
+    # the parameters, as in make_train_state
+    # (its counters depend on no parameter and land on the default
+    # device, which here is the CPU: those are replicated instead)
+    replicated = NamedSharding(mesh, P())
+    chips = set(mesh.devices.flat)
+    init = jax.jit(optimizer.init).lower(params).compile()
+    opt_state = on(
+        jax.eval_shape(optimizer.init, params),
+        jax.tree.map(lambda s: s if s.device_set <= chips else replicated,
+                     init.output_shardings))
+    state = {"params": params, "opt_state": opt_state,
+             "step": sds((), jnp.int32, replicated)}
+    data = tuple(a for a in ("data", "fsdp") if a in mesh.axis_names)
+    tokens = sds((batch, seq + 1), jnp.int32,
+                 NamedSharding(mesh, P(data or None)))
+    step = make_train_step(cfg, mesh, llama, optimizer=optimizer)
+    return step, state, {"tokens": tokens}
+
+
+def test_train_step_llama3_8b_widths_one_chip(topo):
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    step, state, batch = train_step_args(smoke_cfg(), mesh, 4, 2048)
+    compiled = step.lower(state, batch).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert device_bytes(compiled) < HBM_BYTES
+
+
+def test_train_step_llama3_8b_widths_fsdp_tp_four_chips(topo):
+    """The compiler cannot partition a Mosaic kernel by itself: on a
+    mesh the flash call has to sit under shard_map (ops/attention.py
+    _flash_partition), or this compile is refused."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("fsdp", "tensor"))
+    step, state, batch = train_step_args(smoke_cfg(), mesh, 4, 2048)
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text or "reduce-scatter" in text
+    assert device_bytes(compiled) < HBM_BYTES
+
+
+def test_ring_flash_attention_four_chips(topo):
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("fsdp", "sequence"))
+    spec = NamedSharding(mesh, P("fsdp", "sequence", None, None))
+    q = sds((1, 8192, 32, 128), BF16, spec)
+    kv = sds((1, 8192, 8, 128), BF16, spec)
+
+    def fwd_bwd(q, k, v):
+        return jax.grad(
+            lambda *a: ring_attention(*a, mesh, impl="flash")
+            .astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+
+    text = compiled_with_kernel(fwd_bwd, q, kv, kv).as_text()
+    assert "collective-permute" in text
+
+
+def test_gmm_ep_mixtral_widths_four_chips(topo, monkeypatch):
+    """Dropless expert-parallel dispatch (all-to-all in, local grouped
+    matmul, all-to-all back) with the experts split four ways."""
+    from metaflow_tpu.ops.moe import moe_ffn
+
+    # this process is CPU-pinned, where the kernel would be interpreted
+    # (`metaflow_tpu.ops.gmm` the attribute is the function, not the module)
+    monkeypatch.setattr(sys.modules["metaflow_tpu.ops.gmm"],
+                        "_default_interpret", lambda: False)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4, 1),
+                ("fsdp", "expert", "tensor"))
+    put = lambda shape, *spec: sds(shape, BF16, NamedSharding(mesh, P(*spec)))
+    x = put((4, 2048, 4096), "fsdp")
+    router = put((4096, 8))
+    w_in = put((8, 4096, 14336), "expert", None, "tensor")
+    w_out = put((8, 14336, 4096), "expert", "tensor", None)
+
+    def fwd_bwd(x, router, w_gate, w_up, w_down):
+        def loss(*a):
+            out, aux = moe_ffn(*a, num_experts_per_tok=2,
+                               dispatch="gmm_ep", mesh=mesh,
+                               ep_buffer_factor=2.0)
+            return out.astype(jnp.float32).sum() + aux
+        return jax.grad(loss, (0, 2, 3, 4))(x, router, w_gate, w_up,
+                                            w_down)
+
+    compiled = compiled_with_kernel(fwd_bwd, x, router, w_in, w_in, w_out)
+    assert "all-to-all" in compiled.as_text()
+    assert device_bytes(compiled) < HBM_BYTES
+
+
+def serve_params(cfg, one_chip):
+    return on(jax.eval_shape(
+        lambda: llama.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+
+
+ENGINE_KW = dict(max_slots=4, max_seq_len=1024, prefill_chunk=64)
+
+
+def test_slot_engine_steps_llama3_8b_widths(one_chip):
+    from metaflow_tpu.serving import SlotEngine
+
+    cfg = smoke_cfg()
+    params = serve_params(cfg, one_chip)
+    engine = SlotEngine(params, cfg, **ENGINE_KW)
+    cache = on(engine._cache, one_chip)
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    decode = engine._decode_greedy_fn.lower(
+        params, cache, i32(4), i32(4), sds((4,), jnp.bool_, one_chip)
+    ).compile()
+    prefill = engine._prefill_fn.lower(
+        params, cache, i32(1, 64), i32(), i32()).compile()
+    assert max(device_bytes(decode), device_bytes(prefill)) < HBM_BYTES
+
+
+def test_paged_engine_steps_llama3_8b_widths(one_chip):
+    from metaflow_tpu.serving import PagedEngine
+
+    cfg = smoke_cfg()
+    params = serve_params(cfg, one_chip)
+    engine = PagedEngine(params, cfg, spec_k=0, **ENGINE_KW)
+    pool = on(engine.pool.kv, one_chip)
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    decode = engine._decode_greedy_fn.lower(
+        params, pool, i32(4), i32(4), sds((4,), jnp.bool_, one_chip),
+        i32(4, engine.n_blocks)).compile()
+    prefill = engine._prefill_fn.lower(
+        params, pool, i32(1, 64), i32(engine.n_blocks), i32()).compile()
+    assert max(device_bytes(decode), device_bytes(prefill)) < HBM_BYTES
