@@ -28,7 +28,6 @@ hierarchical order in ordering.py (seed=None matches plain sequential
 ResumableTokenBatches too). tests/test_data.py pins this.
 """
 
-import time
 
 import numpy as np
 
@@ -268,7 +267,7 @@ class StreamingTokenBatches(object):
                           and self._window_cursor == 0)
             yielded = False
             buf = []
-            t_batch = time.perf_counter()
+            wait = telemetry.timer("data.batch_wait").start()
             pos = self._shard_cursor
             stream = self._reader.stream(order[pos:])
             try:
@@ -292,10 +291,7 @@ class StreamingTokenBatches(object):
                             self._window_cursor = j
                         buf.append(tokens[w * W:(w + 1) * W])
                         if len(buf) == B:
-                            telemetry.emit(
-                                "timer", "data.batch_wait",
-                                ms=(time.perf_counter() - t_batch) * 1000,
-                                ok=True)
+                            wait.stop()
                             batch = np.stack(buf)
                             if self._sanitizer is not None:
                                 self._sanitizer.journal(
@@ -305,16 +301,15 @@ class StreamingTokenBatches(object):
                                    STATE_KEY: self.state()}
                             yielded = True
                             buf = []
-                            t_batch = time.perf_counter()
+                            wait = telemetry.timer(
+                                "data.batch_wait").start()
                     pos += 1
                     self._shard_cursor = pos
                     self._window_cursor = 0
             finally:
                 stream.close()
             if buf and not self._drop_last:
-                telemetry.emit(
-                    "timer", "data.batch_wait",
-                    ms=(time.perf_counter() - t_batch) * 1000, ok=True)
+                wait.stop()
                 batch = np.stack(buf)
                 if self._sanitizer is not None:
                     self._sanitizer.journal("data", "batch", shape=batch,

@@ -106,16 +106,37 @@ def setup_compile_cache():
     reads it when it is imported (a task that never imports JAX pays
     nothing) and child processes inherit it. A CPU-pinned process gets
     no cache of its own accord: XLA:CPU logs an error block for every
-    executable it loads back. Returns the directory."""
+    executable it loads back. Returns the directory.
+
+    The cache's key includes the programs' metadata (scope names, source
+    lines). JAX leaves it out by default, and an executable loaded from
+    the cache keeps the names it was compiled with: a profile would then
+    show the scope names of whichever commit compiled the program first
+    (seen on the chip, PERF.md PR 24: every program hit the parent's
+    entries and the trace held none of this commit's scopes). The price
+    is one recompilation after a change that moves lines."""
+    _configure("jax_compilation_cache_include_metadata_in_key",
+               "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY", "true", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path
     if not cpu_pinned():
-        os.environ["JAX_COMPILATION_CACHE_DIR"] = _CACHE_DIR
-        jax = sys.modules.get("jax")
-        if jax is not None:  # imported before this call: the env was read
-            jax.config.update("jax_compilation_cache_dir", _CACHE_DIR)
+        _configure("jax_compilation_cache_dir",
+                   "JAX_COMPILATION_CACHE_DIR", _CACHE_DIR, _CACHE_DIR)
     return _CACHE_DIR
+
+
+def _configure(option, variable, text, value):
+    """Set a JAX option through its environment variable, unless that is
+    set already, so that JAX reads it when it is imported and child
+    processes inherit it; a JAX imported before this call has read the
+    environment and is told directly."""
+    if os.environ.get(variable):
+        return
+    os.environ[variable] = text
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.config.update(option, value)
 
 
 def watch_compiles():

@@ -12,6 +12,13 @@ The reference framework has no inference engine (it orchestrates user
 frameworks); this is part of the training/serving substrate the TPU
 rebuild provides natively (SURVEY.md §5.7).
 
+Scope names (jax.named_scope: metadata only, stable across recompiles;
+benchmark/span_readings.py sums device time under them): the layer scan
+is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
+`decode_attention`, `attn_out` and `ffn` (ops/moe.py adds `moe_*` inside
+`ffn`). What lies under `decode_layers` and under none of those is the
+scan carrying, slicing and copying the cache.
+
 Sharding: the cache carries the same logical axes as activations
 ([layers, batch, seq, kv_heads, head_dim]) — under a mesh, batch rides
 the data/fsdp axes and kv_heads the tensor axis, so decode parallelizes
@@ -62,6 +69,7 @@ def _mask_positions(q_positions):
     return q_positions[:, None, :, None]
 
 
+@jax.named_scope("decode_attention")
 def _cached_attention(q, cache_k, cache_v, pos):
     """q: [B, T, H, Hd] at absolute positions pos..pos+T-1; cache_k/v:
     [B, Smax, KV, Hd]. Keys at index i are visible to query t iff
@@ -93,6 +101,7 @@ def _default_decode_chunk():
 DECODE_CHUNK = _default_decode_chunk()
 
 
+@jax.named_scope("decode_attention")
 def _streamed_attention(q, pos, chunk, n_chunks, fetch):
     """Online-softmax attention over KV streamed in `chunk`-sized blocks
     (the flash-decode accumulation shared by the contiguous-cache and
@@ -134,6 +143,7 @@ def _streamed_attention(q, pos, chunk, n_chunks, fetch):
     return jnp.swapaxes(out, 1, 2).astype(q.dtype)  # [B, T, H, Hd]
 
 
+@jax.named_scope("decode_attention")
 def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
     """Flash-decode: the same attention reading ONLY the filled prefix.
 
@@ -160,6 +170,7 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
     return _streamed_attention(q, pos, chunk, n_chunks, fetch)
 
 
+@jax.named_scope("attn_qkv")
 def _attn_qkv(cfg, cos, sin, pos, x, lp):
     """The pre-attention half of a block: attn-norm, QKV projections and
     rope at the absolute positions `pos` implies. Shared verbatim by the
@@ -182,7 +193,13 @@ def _block_ffn(cfg, x, attn, lp, mesh=None):
     and the dense (Llama) or MoE (Mixtral) FFN picked off the parameter
     tree. Shared by the contiguous and paged cache paths."""
     B, T, _ = x.shape
-    x = x + attn.reshape(B, T, cfg.n_heads * cfg.head_dim) @ lp["wo"]
+    with jax.named_scope("attn_out"):
+        x = x + attn.reshape(B, T, cfg.n_heads * cfg.head_dim) @ lp["wo"]
+    return _ffn(cfg, x, lp, mesh)
+
+
+@jax.named_scope("ffn")
+def _ffn(cfg, x, lp, mesh):
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
     if "router" in lp:  # Mixtral: token-choice MoE FFN
         from ..ops.moe import moe_ffn
@@ -216,19 +233,20 @@ def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
     lp = layer_params
     q, k, v = _attn_qkv(cfg, cos, sin, pos, x, lp)
 
-    if jnp.ndim(pos) == 0:
-        cache_k = jax.lax.dynamic_update_slice_in_dim(
-            cache_k, k.astype(cache_k.dtype), pos, axis=1)
-        cache_v = jax.lax.dynamic_update_slice_in_dim(
-            cache_v, v.astype(cache_v.dtype), pos, axis=1)
-    else:
-        # per-slot offsets: every batch row writes its T new positions at
-        # its OWN cursor (lowered to a batched scatter)
-        _write = jax.vmap(
-            lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
-                c, u, p, axis=0))
-        cache_k = _write(cache_k, k.astype(cache_k.dtype), pos)
-        cache_v = _write(cache_v, v.astype(cache_v.dtype), pos)
+    with jax.named_scope("kv_cache_update"):
+        if jnp.ndim(pos) == 0:
+            cache_k = jax.lax.dynamic_update_slice_in_dim(
+                cache_k, k.astype(cache_k.dtype), pos, axis=1)
+            cache_v = jax.lax.dynamic_update_slice_in_dim(
+                cache_v, v.astype(cache_v.dtype), pos, axis=1)
+        else:
+            # per-slot offsets: every batch row writes its T new positions
+            # at its OWN cursor (lowered to a batched scatter)
+            _write = jax.vmap(
+                lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
+                    c, u, p, axis=0))
+            cache_k = _write(cache_k, k.astype(cache_k.dtype), pos)
+            cache_v = _write(cache_v, v.astype(cache_v.dtype), pos)
 
     if attn_impl == "chunked":
         attn = _chunked_cached_attention(q, cache_k, cache_v, pos)
@@ -262,9 +280,10 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
                                     mesh=mesh, attn_impl=attn_impl)
         return out, (nk, nv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_fn, x, (params["layers"], cache["k"], cache["v"])
-    )
+    with jax.named_scope("decode_layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_fn, x, (params["layers"], cache["k"], cache["v"])
+        )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)

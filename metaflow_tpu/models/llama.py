@@ -153,6 +153,17 @@ def logical_axes(cfg):
 
 def _layer(cfg, cos, sin, x, layer_params, mesh=None):
     """One transformer block; x: [B, S, D]."""
+    x = _attention_block(cfg, cos, sin, x, layer_params, mesh)
+    with jax.named_scope("ffn"):
+        h = rms_norm(x, layer_params["ffn_norm"], cfg.norm_eps)
+        gate = jax.nn.silu(h @ layer_params["w_gate"])
+        up = h @ layer_params["w_up"]
+        return x + (gate * up) @ layer_params["w_down"]
+
+
+@jax.named_scope("attention")
+def _attention_block(cfg, cos, sin, x, layer_params, mesh):
+    """The attention half of a block, its residual included."""
     B, S, D = x.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -192,13 +203,7 @@ def _layer(cfg, cos, sin, x, layer_params, mesh=None):
     from jax.ad_checkpoint import checkpoint_name
 
     attn = checkpoint_name(attn, "attn_out")
-    x = x + attn.reshape(B, S, H * Hd) @ layer_params["wo"]
-
-    h = rms_norm(x, layer_params["ffn_norm"], cfg.norm_eps)
-    gate = jax.nn.silu(h @ layer_params["w_gate"])
-    up = h @ layer_params["w_up"]
-    x = x + (gate * up) @ layer_params["w_down"]
-    return x
+    return x + attn.reshape(B, S, H * Hd) @ layer_params["wo"]
 
 
 def hidden_states(params, tokens, cfg, mesh=None):
@@ -224,7 +229,8 @@ def hidden_states(params, tokens, cfg, mesh=None):
                 "attn_out"
             )
         layer_fn = jax.checkpoint(layer_fn, policy=policy)
-    x, _ = jax.lax.scan(layer_fn, x, params["layers"])
+    with jax.named_scope("layers"):
+        x, _ = jax.lax.scan(layer_fn, x, params["layers"])
 
     return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
@@ -268,11 +274,14 @@ def loss_fn(params, batch, cfg, mesh=None):
         targets = batch["tokens"][:, 1:]
     else:
         inputs, targets = batch["inputs"], batch["targets"]
-    mask = batch.get("mask")
     x = hidden_states(params, inputs, cfg, mesh=mesh)
+    return _loss_from_hidden(x, params["lm_head"], targets,
+                             batch.get("mask"), cfg.loss_chunk)
 
+
+@jax.named_scope("loss")
+def _loss_from_hidden(x, lm_head, targets, mask, chunk):
     B, S, D = x.shape
-    chunk = cfg.loss_chunk
     if chunk and S % chunk:
         # snap to the largest divisor of S that fits the requested bound so
         # an off-size sequence never silently reverts to full-logit memory
@@ -280,7 +289,7 @@ def loss_fn(params, batch, cfg, mesh=None):
         if chunk < 32:
             chunk = 0  # degenerate chunking would be slower than the memory win
     if not chunk or S == chunk:
-        loss_sum, count = _ce_sums(x, params["lm_head"], targets, mask)
+        loss_sum, count = _ce_sums(x, lm_head, targets, mask)
         return loss_sum / jnp.maximum(count, 1)
 
     n = S // chunk
@@ -291,9 +300,7 @@ def loss_fn(params, batch, cfg, mesh=None):
     @jax.checkpoint
     def body(carry, sl):
         loss_sum, count = carry
-        s, c = _ce_sums(
-            sl["x"], params["lm_head"], sl["t"], sl.get("m")
-        )
+        s, c = _ce_sums(sl["x"], lm_head, sl["t"], sl.get("m"))
         return (loss_sum + s, count + c), None
 
     sl = {"x": xs, "t": ts}

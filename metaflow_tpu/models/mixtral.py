@@ -120,6 +120,15 @@ def logical_axes(cfg):
 
 def _layer(cfg, cos, sin, carry, layer_params, mesh=None):
     x, aux_sum = carry
+    x = _attention_block(cfg, cos, sin, x, layer_params, mesh)
+    with jax.named_scope("ffn"):
+        moe_out, aux = _moe_block(cfg, x, layer_params, mesh)
+    return (x + moe_out, aux_sum + aux), None
+
+
+@jax.named_scope("attention")
+def _attention_block(cfg, cos, sin, x, layer_params, mesh):
+    """The attention half of a block, its residual included."""
     B, S, D = x.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -131,10 +140,12 @@ def _layer(cfg, cos, sin, carry, layer_params, mesh=None):
     k = apply_rope(k, cos, sin)
     attn = attention(q, k, v, causal=True, impl=cfg.attention_impl,
                      mesh=mesh)
-    x = x + attn.reshape(B, S, H * Hd) @ layer_params["wo"]
+    return x + attn.reshape(B, S, H * Hd) @ layer_params["wo"]
 
+
+def _moe_block(cfg, x, layer_params, mesh):
     h = rms_norm(x, layer_params["ffn_norm"], cfg.norm_eps)
-    moe_out, aux = moe_ffn(
+    return moe_ffn(
         h,
         layer_params["router"],
         layer_params["w_gate"],
@@ -149,7 +160,6 @@ def _layer(cfg, cos, sin, carry, layer_params, mesh=None):
         ep_buffer_factor=(cfg.ep_buffer_factor
                           if cfg.moe_dispatch == "gmm_ep" else None),
     )
-    return (x + moe_out, aux_sum + aux), None
 
 
 def forward(params, tokens, cfg, return_aux=False, mesh=None):
@@ -161,9 +171,10 @@ def forward(params, tokens, cfg, return_aux=False, mesh=None):
     layer_fn = lambda carry, lp: _layer(cfg, cos, sin, carry, lp, mesh=mesh)
     if cfg.remat:
         layer_fn = jax.checkpoint(layer_fn)
-    (x, aux), _ = jax.lax.scan(
-        layer_fn, (x, jnp.zeros((), jnp.float32)), params["layers"]
-    )
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(
+            layer_fn, (x, jnp.zeros((), jnp.float32)), params["layers"]
+        )
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"],
@@ -180,10 +191,12 @@ def loss_fn(params, batch, cfg, mesh=None):
     else:
         inputs, targets = batch["inputs"], batch["targets"]
     logits, aux = forward(params, inputs, cfg, return_aux=True, mesh=mesh)
-    logps = jax.nn.log_softmax(logits, axis=-1)
-    token_lp = jnp.take_along_axis(logps, targets[..., None], axis=-1)[..., 0]
-    ce = -jnp.mean(token_lp)
-    return ce + cfg.router_aux_coef * aux
+    with jax.named_scope("loss"):
+        logps = jax.nn.log_softmax(logits, axis=-1)
+        token_lp = jnp.take_along_axis(
+            logps, targets[..., None], axis=-1)[..., 0]
+        ce = -jnp.mean(token_lp)
+        return ce + cfg.router_aux_coef * aux
 
 
 def num_params(params):

@@ -133,6 +133,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
     )
 
 
+@jax.named_scope("flash_attention")
 def _flash_forward(q, k, v, causal, scale, interpret=False):
     """q,k,v: [BH, S, D] (heads folded into batch). Returns (out, lse).
     Block sizes come from the module-level BLOCK_Q/BLOCK_K (env-tunable);
@@ -166,6 +167,7 @@ def _flash_forward(q, k, v, causal, scale, interpret=False):
             jax.ShapeDtypeStruct((BH, 8, S), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[:, 0, :]
 
@@ -272,6 +274,7 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
+@jax.named_scope("flash_attention")
 def _flash_backward_pallas(q, k, v, g, out, lse, causal, scale, interpret):
     """Pallas backward via the shared blockwise kernels (flash_block_bwd):
     dq grid over q blocks, dk/dv grid over k blocks."""
@@ -375,6 +378,7 @@ def _check_blocks(S):
     return block_q, block_k
 
 
+@jax.named_scope("flash_attention")
 def flash_block_fwd(q, k, v, scale, causal_diag, interpret=False):
     """One ring step's unnormalized contribution.
 
@@ -407,10 +411,12 @@ def flash_block_fwd(q, k, v, scale, causal_diag, interpret=False):
             _sds((BH, 8, S), jnp.float32, q),
         ],
         interpret=interpret,
+        name="flash_block_fwd",
     )(q, k, v)
     return acc, m[:, 0, :], l[:, 0, :]
 
 
+@jax.named_scope("flash_attention")
 def flash_block_bwd(q, k, v, g, lse, delta, scale, causal_diag,
                     interpret=False):
     """One ring step's gradient contribution given the GLOBAL lse/delta.
@@ -442,6 +448,7 @@ def flash_block_bwd(q, k, v, g, lse, delta, scale, causal_diag,
         out_specs=pl.BlockSpec((1, block_q, D), lambda b, i: (b, i, 0)),
         out_shape=_sds((BH, S, D), jnp.float32, q),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, g, lse_t, delta_t)
 
     dk, dv = pl.pallas_call(
@@ -467,6 +474,7 @@ def flash_block_bwd(q, k, v, g, lse, delta, scale, causal_diag,
             _sds((BH, S, D), jnp.float32, q),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, g, lse_t, delta_t)
     return dq, dk, dv
 

@@ -40,6 +40,13 @@ Two dispatch strategies, numerically equivalent modulo capacity drops:
     shard-overflow drops only under routing imbalance (the aux loss
     pushes toward balance).
 
+Scope names (jax.named_scope, metadata only): routing is `moe_router`,
+and `sparse`, `dense` and `gmm` mark their gather/bucket step
+`moe_dispatch`, the three expert matmuls `moe_experts` and the weighted
+scatter back `moe_combine`, so a device trace splits the layer the same
+way whichever of them ran (benchmark/span_readings.py). `gmm_ep` marks
+only its router; its collectives are for the PR that measures them.
+
 Capacity semantics are identical in the sparse and dense paths: an
 expert accepts its first ``capacity`` tokens in token order; the rest
 are dropped (their combine weight becomes 0 and the residual stream
@@ -52,6 +59,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("moe_router")
 def top_k_router(logits, num_experts, k, dtype=jnp.float32):
     """logits: [tokens, experts] → (weights [tokens, k], idx [tokens, k]).
 
@@ -135,12 +143,15 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, num_experts_per_tok=2,
         raise ValueError("ep_buffer_factor only applies to dispatch='gmm_ep'")
     tokens = x.reshape(B * S, E)
 
-    router_logits = jnp.einsum(
-        "te,en->tn", tokens.astype(jnp.float32), router_w.astype(jnp.float32)
-    )
-    weights, idx = top_k_router(router_logits, num_experts, k, dtype=x.dtype)
-    one_hot = jax.nn.one_hot(idx, num_experts, dtype=x.dtype)  # [t, k, n]
-    aux = _load_balancing_loss(router_logits, one_hot)
+    with jax.named_scope("moe_router"):
+        router_logits = jnp.einsum(
+            "te,en->tn", tokens.astype(jnp.float32),
+            router_w.astype(jnp.float32)
+        )
+        weights, idx = top_k_router(router_logits, num_experts, k,
+                                    dtype=x.dtype)
+        one_hot = jax.nn.one_hot(idx, num_experts, dtype=x.dtype)  # [t,k,n]
+        aux = _load_balancing_loss(router_logits, one_hot)
 
     if dispatch == "sparse":
         out = _sparse_dispatch_ffn(
@@ -191,36 +202,40 @@ def _sparse_dispatch_ffn(tokens, weights, idx, w_gate, w_up, w_down,
     N = num_experts
     C = expert_capacity(T, N, k, capacity_factor)
 
-    e_flat = idx.reshape(T * k)                      # expert id per slot
-    w_flat = weights.reshape(T * k)                  # combine weight per slot
-    slot_one_hot = jax.nn.one_hot(e_flat, N, dtype=jnp.int32)  # [T*k, N]
-    # 0-based arrival position of each slot within its expert
-    pos = jnp.cumsum(slot_one_hot, axis=0) - 1       # [T*k, N]
-    pos_flat = jnp.take_along_axis(pos, e_flat[:, None], axis=1)[:, 0]
-    keep = pos_flat < C
-    # dropped slots scatter out of range; mode="drop" discards them with
-    # static shapes (positions are unique per expert, so add == set)
-    safe_pos = jnp.where(keep, pos_flat, C)
-    t_flat = jnp.arange(T * k) // k                  # owning token per slot
+    with jax.named_scope("moe_dispatch"):
+        e_flat = idx.reshape(T * k)                  # expert id per slot
+        w_flat = weights.reshape(T * k)              # combine weight per slot
+        slot_one_hot = jax.nn.one_hot(e_flat, N, dtype=jnp.int32)  # [T*k, N]
+        # 0-based arrival position of each slot within its expert
+        pos = jnp.cumsum(slot_one_hot, axis=0) - 1   # [T*k, N]
+        pos_flat = jnp.take_along_axis(pos, e_flat[:, None], axis=1)[:, 0]
+        keep = pos_flat < C
+        # dropped slots scatter out of range; mode="drop" discards them
+        # with static shapes (positions are unique per expert, so add ==
+        # set)
+        safe_pos = jnp.where(keep, pos_flat, C)
+        t_flat = jnp.arange(T * k) // k              # owning token per slot
 
-    x_buf = jnp.zeros((N, C, E), tokens.dtype).at[e_flat, safe_pos].add(
-        tokens[t_flat], mode="drop"
-    )
-    x_buf = _constrain_expert_axis(x_buf, mesh)      # all-to-all boundary in
+        x_buf = jnp.zeros((N, C, E), tokens.dtype).at[e_flat, safe_pos].add(
+            tokens[t_flat], mode="drop"
+        )
+        x_buf = _constrain_expert_axis(x_buf, mesh)  # all-to-all boundary in
 
-    gate = activation(jnp.einsum("nce,nef->ncf", x_buf, w_gate,
-                                 preferred_element_type=jnp.float32))
-    up = jnp.einsum("nce,nef->ncf", x_buf, w_up,
-                    preferred_element_type=jnp.float32)
-    y_buf = jnp.einsum("ncf,nfe->nce", (gate * up).astype(tokens.dtype),
-                       w_down, preferred_element_type=jnp.float32)
-    y_buf = _constrain_expert_axis(y_buf.astype(tokens.dtype), mesh)
+    with jax.named_scope("moe_experts"):
+        gate = activation(jnp.einsum("nce,nef->ncf", x_buf, w_gate,
+                                     preferred_element_type=jnp.float32))
+        up = jnp.einsum("nce,nef->ncf", x_buf, w_up,
+                        preferred_element_type=jnp.float32)
+        y_buf = jnp.einsum("ncf,nfe->nce", (gate * up).astype(tokens.dtype),
+                           w_down, preferred_element_type=jnp.float32)
+        y_buf = _constrain_expert_axis(y_buf.astype(tokens.dtype), mesh)
 
     # combine: gather each slot's expert output back (all-to-all boundary
     # out); out-of-range gathers clamp but are zeroed by the keep mask
-    y_slots = y_buf[e_flat, safe_pos]                # [T*k, E]
-    y_slots = jnp.where(keep[:, None], y_slots, 0) * w_flat[:, None]
-    return y_slots.reshape(T, k, E).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        y_slots = y_buf[e_flat, safe_pos]            # [T*k, E]
+        y_slots = jnp.where(keep[:, None], y_slots, 0) * w_flat[:, None]
+        return y_slots.reshape(T, k, E).sum(axis=1)
 
 
 def _gmm_dispatch_ffn(tokens, weights, idx, w_gate, w_up, w_down,
@@ -232,19 +247,22 @@ def _gmm_dispatch_ffn(tokens, weights, idx, w_gate, w_up, w_down,
     from .gmm import gather_rows, gmm, make_group_layout, scatter_rows
 
     T, E = tokens.shape
-    e_flat = idx.reshape(T * k)
-    w_flat = weights.reshape(T * k)
-    t_flat = jnp.arange(T * k) // k
+    with jax.named_scope("moe_dispatch"):
+        e_flat = idx.reshape(T * k)
+        w_flat = weights.reshape(T * k)
+        t_flat = jnp.arange(T * k) // k
 
-    layout = make_group_layout(e_flat, num_experts)
-    x_pad = scatter_rows(tokens[t_flat], layout)
-    tg, ta = layout["tile_group"], layout["tile_active"]
-    gate = activation(gmm(x_pad, w_gate, tg, tile_active=ta))
-    up = gmm(x_pad, w_up, tg, tile_active=ta)
-    y_pad = gmm((gate * up).astype(tokens.dtype), w_down, tg,
-                tile_active=ta)
-    y_slots = gather_rows(y_pad, layout) * w_flat[:, None]
-    return y_slots.reshape(T, k, E).sum(axis=1)
+        layout = make_group_layout(e_flat, num_experts)
+        x_pad = scatter_rows(tokens[t_flat], layout)
+        tg, ta = layout["tile_group"], layout["tile_active"]
+    with jax.named_scope("moe_experts"):
+        gate = activation(gmm(x_pad, w_gate, tg, tile_active=ta))
+        up = gmm(x_pad, w_up, tg, tile_active=ta)
+        y_pad = gmm((gate * up).astype(tokens.dtype), w_down, tg,
+                    tile_active=ta)
+    with jax.named_scope("moe_combine"):
+        y_slots = gather_rows(y_pad, layout) * w_flat[:, None]
+        return y_slots.reshape(T, k, E).sum(axis=1)
 
 
 def _gmm_ep_dispatch_ffn(x, router_w, w_gate, w_up, w_down, num_experts, k,
@@ -394,29 +412,36 @@ def _dense_dispatch_ffn(tokens, weights, idx, one_hot, w_gate, w_up, w_down,
                         num_experts, k, capacity_factor, activation):
     """Reference oracle: every expert sees every token (one-hot einsums)."""
     T, E = tokens.shape
-    # combine matrix: [tokens, experts], rows sum to 1 over selected experts
-    combine = jnp.einsum("tkn,tk->tn", one_hot, weights)
+    with jax.named_scope("moe_dispatch"):
+        # combine matrix: [tokens, experts], rows sum to 1 over selected
+        # experts
+        combine = jnp.einsum("tkn,tk->tn", one_hot, weights)
 
-    if capacity_factor is not None:
-        C = expert_capacity(T, num_experts, k, capacity_factor)
-        # count capacity from the ROUTING mask (one_hot), not `combine > 0`:
-        # a top-k slot whose softmax weight underflowed to exactly 0 still
-        # occupies a capacity slot in the sparse path, and the oracle must
-        # make identical drop decisions
-        dispatch_mask = jnp.sum(one_hot, axis=1) > 0  # [t, n]
-        # 1-based arrival position in token order
-        position_in_expert = jnp.cumsum(dispatch_mask, axis=0) * dispatch_mask
-        combine = jnp.where(position_in_expert <= C, combine, 0.0)
+        if capacity_factor is not None:
+            C = expert_capacity(T, num_experts, k, capacity_factor)
+            # count capacity from the ROUTING mask (one_hot), not
+            # `combine > 0`: a top-k slot whose softmax weight underflowed
+            # to exactly 0 still occupies a capacity slot in the sparse
+            # path, and the oracle must make identical drop decisions
+            dispatch_mask = jnp.sum(one_hot, axis=1) > 0  # [t, n]
+            # 1-based arrival position in token order
+            position_in_expert = (jnp.cumsum(dispatch_mask, axis=0)
+                                  * dispatch_mask)
+            combine = jnp.where(position_in_expert <= C, combine, 0.0)
 
-    # [n, t, E]: per-expert token batch
-    h = jnp.einsum("te,tn->nte", tokens, combine != 0)
-    gate = activation(jnp.einsum("nte,nef->ntf", h, w_gate,
-                                 preferred_element_type=jnp.float32))
-    up = jnp.einsum("nte,nef->ntf", h, w_up,
-                    preferred_element_type=jnp.float32)
-    expert_out = jnp.einsum("ntf,nfe->nte", (gate * up).astype(tokens.dtype),
-                            w_down, preferred_element_type=jnp.float32)
-    return jnp.einsum("nte,tn->te", expert_out.astype(tokens.dtype), combine)
+        # [n, t, E]: per-expert token batch
+        h = jnp.einsum("te,tn->nte", tokens, combine != 0)
+    with jax.named_scope("moe_experts"):
+        gate = activation(jnp.einsum("nte,nef->ntf", h, w_gate,
+                                     preferred_element_type=jnp.float32))
+        up = jnp.einsum("nte,nef->ntf", h, w_up,
+                        preferred_element_type=jnp.float32)
+        expert_out = jnp.einsum(
+            "ntf,nfe->nte", (gate * up).astype(tokens.dtype), w_down,
+            preferred_element_type=jnp.float32)
+    with jax.named_scope("moe_combine"):
+        return jnp.einsum("nte,tn->te", expert_out.astype(tokens.dtype),
+                          combine)
 
 
 def _load_balancing_loss(router_logits, one_hot):

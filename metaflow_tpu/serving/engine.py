@@ -44,6 +44,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import telemetry
 from ..inference.decode import (
     DECODE_CHUNK,
     bucket_length,
@@ -448,9 +449,10 @@ class SlotEngine(object):
         if bucket > chunk.size:
             chunk = np.concatenate([
                 chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
-        logits, self._cache = self._prefill_fn(
-            self.params, self._cache, jnp.asarray(chunk)[None],
-            jnp.int32(slot), jnp.int32(start))
+        with telemetry.annotate("engine.prefill.dispatch"):
+            logits, self._cache = self._prefill_fn(
+                self.params, self._cache, jnp.asarray(chunk)[None],
+                jnp.int32(slot), jnp.int32(start))
         self._prefill_cursor[slot] = end
         # keep pos at the prefill cursor: a mid-prefill slot rides
         # through fused decode steps as a masked lane whose write lands
@@ -467,7 +469,8 @@ class SlotEngine(object):
             jnp.asarray(self._keys_for(slot)),
             jnp.float32(self._temp[slot]), jnp.int32(self._top_k[slot]),
             jnp.float32(self._top_p[slot]))
-        first = int(first)
+        with telemetry.annotate("engine.first_token.fetch"):
+            first = int(first)   # the host waits for the device here
         self.decoding[slot] = True
         self.pos[slot] = prompt.size
         self._tok[slot] = first
@@ -497,27 +500,30 @@ class SlotEngine(object):
         if not decoding:
             return {}
         if self._dirty:
-            self._d_tok = jnp.asarray(self._tok)
-            self._d_pos = jnp.asarray(self.pos)
-            self._d_mask = jnp.asarray(self.decoding)
-            self._d_temp = jnp.asarray(self._temp)
-            self._d_top_k = jnp.asarray(self._top_k)
-            self._d_top_p = jnp.asarray(self._top_p)
-            self._dirty = False
-        if any(self._temp[i] > 0.0 for i in decoding):
-            for i in decoding:
-                self._keys[i] = self._keys_for(i)
-            out, self._d_tok, self._d_pos, self._cache = \
-                self._decode_sampled_fn(
-                    self.params, self._cache, self._d_tok, self._d_pos,
-                    self._d_mask, jnp.asarray(self._keys), self._d_temp,
-                    self._d_top_k, self._d_top_p)
-        else:
-            out, self._d_tok, self._d_pos, self._cache = \
-                self._decode_greedy_fn(
-                    self.params, self._cache, self._d_tok, self._d_pos,
-                    self._d_mask)
-        out = np.asarray(out)
+            with telemetry.annotate("engine.decode.upload"):
+                self._d_tok = jnp.asarray(self._tok)
+                self._d_pos = jnp.asarray(self.pos)
+                self._d_mask = jnp.asarray(self.decoding)
+                self._d_temp = jnp.asarray(self._temp)
+                self._d_top_k = jnp.asarray(self._top_k)
+                self._d_top_p = jnp.asarray(self._top_p)
+                self._dirty = False
+        with telemetry.annotate("engine.decode.dispatch"):
+            if any(self._temp[i] > 0.0 for i in decoding):
+                for i in decoding:
+                    self._keys[i] = self._keys_for(i)
+                out, self._d_tok, self._d_pos, self._cache = \
+                    self._decode_sampled_fn(
+                        self.params, self._cache, self._d_tok, self._d_pos,
+                        self._d_mask, jnp.asarray(self._keys), self._d_temp,
+                        self._d_top_k, self._d_top_p)
+            else:
+                out, self._d_tok, self._d_pos, self._cache = \
+                    self._decode_greedy_fn(
+                        self.params, self._cache, self._d_tok, self._d_pos,
+                        self._d_mask)
+        with telemetry.annotate("engine.decode.fetch"):
+            out = np.asarray(out)   # the host waits for the device here
         tokens = {}
         for i in decoding:
             tokens[i] = int(out[i])

@@ -57,7 +57,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import knobs
+from .. import knobs, telemetry
 from ..inference.decode import (
     DECODE_CHUNK,
     _attn_qkv,
@@ -258,8 +258,9 @@ def _paged_forward(params, tokens, pool_kv, tables, pos, cfg,
         # paged cache write: token t of row b lands in page pids[b, t]
         # at offset offs[b, t] — one batched scatter per layer, the
         # block-table analogue of the vector-pos dynamic_update_slice
-        pk = pk.at[pids, offs].set(k.astype(pk.dtype))
-        pv = pv.at[pids, offs].set(v.astype(pv.dtype))
+        with jax.named_scope("kv_cache_update"):
+            pk = pk.at[pids, offs].set(k.astype(pk.dtype))
+            pv = pv.at[pids, offs].set(v.astype(pv.dtype))
         if attn_impl == "chunked":
             n_chunks = (jnp.max(pos) + T + page_tokens - 1) // page_tokens
 
@@ -271,15 +272,17 @@ def _paged_forward(params, tokens, pool_kv, tables, pos, cfg,
             attn = _streamed_attention(q, pos, page_tokens, n_chunks,
                                        fetch)
         else:
-            view_k = pk[tables].reshape(B, S, KV, Hd)
-            view_v = pv[tables].reshape(B, S, KV, Hd)
+            with jax.named_scope("decode_attention"):
+                view_k = pk[tables].reshape(B, S, KV, Hd)
+                view_v = pv[tables].reshape(B, S, KV, Hd)
             attn = _cached_attention(q, view_k, view_v, pos)
         out = _block_ffn(cfg, carry, attn, lp, mesh=mesh)
         return out, (pk, pv)
 
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_fn, x, (params["layers"], pool_kv["k"], pool_kv["v"])
-    )
+    with jax.named_scope("decode_layers"):
+        x, (new_k, new_v) = jax.lax.scan(
+            layer_fn, x, (params["layers"], pool_kv["k"], pool_kv["v"])
+        )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
@@ -757,9 +760,10 @@ class PagedEngine(object):
         if bucket > chunk.size:
             chunk = np.concatenate([
                 chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
-        logits, self.pool.kv = self._prefill_fn(
-            self.params, self.pool.kv, jnp.asarray(chunk)[None],
-            jnp.asarray(self.block_tables[slot]), jnp.int32(start))
+        with telemetry.annotate("engine.prefill.dispatch"):
+            logits, self.pool.kv = self._prefill_fn(
+                self.params, self.pool.kv, jnp.asarray(chunk)[None],
+                jnp.asarray(self.block_tables[slot]), jnp.int32(start))
         self._prefill_cursor[slot] = end
         self.pos[slot] = end
         self._dirty = True
@@ -771,7 +775,8 @@ class PagedEngine(object):
             jnp.asarray(self._keys_for(slot)),
             jnp.float32(self._temp[slot]), jnp.int32(self._top_k[slot]),
             jnp.float32(self._top_p[slot]))
-        first = int(first)
+        with telemetry.annotate("engine.first_token.fetch"):
+            first = int(first)
         self.decoding[slot] = True
         self.pos[slot] = prompt.size
         self._tok[slot] = first
@@ -790,14 +795,15 @@ class PagedEngine(object):
 
     def _stage(self):
         if self._dirty:
-            self._d_tok = jnp.asarray(self._tok)
-            self._d_pos = jnp.asarray(self.pos)
-            self._d_mask = jnp.asarray(self.decoding)
-            self._d_tables = jnp.asarray(self.block_tables)
-            self._d_temp = jnp.asarray(self._temp)
-            self._d_top_k = jnp.asarray(self._top_k)
-            self._d_top_p = jnp.asarray(self._top_p)
-            self._dirty = False
+            with telemetry.annotate("engine.decode.upload"):
+                self._d_tok = jnp.asarray(self._tok)
+                self._d_pos = jnp.asarray(self.pos)
+                self._d_mask = jnp.asarray(self.decoding)
+                self._d_tables = jnp.asarray(self.block_tables)
+                self._d_temp = jnp.asarray(self._temp)
+                self._d_top_k = jnp.asarray(self._top_k)
+                self._d_top_p = jnp.asarray(self._top_p)
+                self._dirty = False
 
     def decode_step(self):
         """One fused step over the whole pool. Returns {slot: token}
@@ -811,20 +817,23 @@ class PagedEngine(object):
         if self.spec_k > 0 and not sampled:
             return self._spec_decode_step(decoding)
         self._stage()
-        if sampled:
-            for i in decoding:
-                self._keys[i] = self._keys_for(i)
-            out, self._d_tok, self._d_pos, self.pool.kv = \
-                self._decode_sampled_fn(
-                    self.params, self.pool.kv, self._d_tok, self._d_pos,
-                    self._d_mask, self._d_tables, jnp.asarray(self._keys),
-                    self._d_temp, self._d_top_k, self._d_top_p)
-        else:
-            out, self._d_tok, self._d_pos, self.pool.kv = \
-                self._decode_greedy_fn(
-                    self.params, self.pool.kv, self._d_tok, self._d_pos,
-                    self._d_mask, self._d_tables)
-        out = np.asarray(out)
+        with telemetry.annotate("engine.decode.dispatch"):
+            if sampled:
+                for i in decoding:
+                    self._keys[i] = self._keys_for(i)
+                out, self._d_tok, self._d_pos, self.pool.kv = \
+                    self._decode_sampled_fn(
+                        self.params, self.pool.kv, self._d_tok,
+                        self._d_pos, self._d_mask, self._d_tables,
+                        jnp.asarray(self._keys), self._d_temp,
+                        self._d_top_k, self._d_top_p)
+            else:
+                out, self._d_tok, self._d_pos, self.pool.kv = \
+                    self._decode_greedy_fn(
+                        self.params, self.pool.kv, self._d_tok,
+                        self._d_pos, self._d_mask, self._d_tables)
+        with telemetry.annotate("engine.decode.fetch"):
+            out = np.asarray(out)
         tokens = {}
         for i in decoding:
             tokens[i] = int(out[i])
@@ -851,10 +860,12 @@ class PagedEngine(object):
             drafts[i] = np.asarray(d[:K], np.int32)
         toks = np.concatenate([self._tok[:, None], drafts], axis=1)
         self._stage()
-        out, self.pool.kv = self._spec_fn(
-            self.params, self.pool.kv, jnp.asarray(toks), self._d_pos,
-            self._d_tables)
-        out = np.asarray(out)
+        with telemetry.annotate("engine.decode.dispatch"):
+            out, self.pool.kv = self._spec_fn(
+                self.params, self.pool.kv, jnp.asarray(toks), self._d_pos,
+                self._d_tables)
+        with telemetry.annotate("engine.decode.fetch"):
+            out = np.asarray(out)
         tokens = {}
         for i in decoding:
             remaining = int(self._max_new[i] - self._emitted[i])

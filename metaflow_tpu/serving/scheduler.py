@@ -25,6 +25,13 @@ tests/schema_validate.py::SERVING_EVENT_DATA_SCHEMAS:
 
 plus serve.batch_occupancy + serve.queue_depth gauges and the
 serve.decode_step / serve.prefill_chunk timers.
+
+Every boundary of an iteration is also a span on the profiler's clock
+(telemetry.annotate; recorded while a profiler session is open, a flag
+check otherwise): serve.iteration around step(), inside it serve.reap,
+serve.admit, serve.prefill_chunk and serve.decode_step (the two timers),
+serve.deliver; the engine adds engine.* spans inside the last three
+(docs/observability.md has the table).
 """
 
 import itertools
@@ -208,7 +215,13 @@ class Scheduler(object):
         self.peak_in_flight = 0
         self._occupancy_sum = 0.0
         # goodput accounting: device-busy seconds split prefill/decode;
-        # idle = elapsed - busy (stats()["goodput"], /metrics)
+        # idle = elapsed - busy (stats()["goodput"], /metrics). Both are
+        # the host's time around the engine call. A decode step waits
+        # for its tokens, so busy_decode_s includes whatever the device
+        # still had queued before the step; a prefill chunk returns once
+        # dispatched unless it is its prompt's last (which fetches the
+        # first token), so busy_prefill_s is dispatch time for every
+        # other chunk, not device time (PERF.md, PR 23)
         self.busy_prefill_s = 0.0
         self.busy_decode_s = 0.0
         self._t_started = time.perf_counter()
@@ -619,8 +632,6 @@ class Scheduler(object):
             self._prefill_rr += 1
             slot = slots[self._prefill_rr % len(slots)]
             req = self._slots[slot]
-            t0 = time.perf_counter()
-            consumed, first = self.engine.prefill_step(slot)
             # the chunk's attribution comes from the ENGINE's slot
             # binding (bind_slot_context at admit): device work is
             # stamped by the layer that performed it
@@ -628,13 +639,12 @@ class Scheduler(object):
                    if hasattr(self.engine, "slot_context") else None)
             chunk_data = dict(ctx) if ctx \
                 else self._tdata(req, {"request_id": req.id})
-            chunk_data.update({"slot": slot, "tokens": consumed})
-            chunk_s = time.perf_counter() - t0
-            self.busy_prefill_s += chunk_s
-            telemetry.emit(
-                "timer", "serve.prefill_chunk",
-                ms=chunk_s * 1000, ok=True,
-                data=chunk_data)
+            chunk_data["slot"] = slot
+            with telemetry.timer("serve.prefill_chunk",
+                                 data=chunk_data) as chunk:
+                consumed, first = self.engine.prefill_step(slot)
+                chunk.set(tokens=consumed)
+            self.busy_prefill_s += chunk.seconds
             budget -= consumed
             worked = True
             if first is not None:
@@ -688,14 +698,10 @@ class Scheduler(object):
         active = [r for r in self._slots.values() if r.state == "decode"]
         if not active:
             return False
-        t0 = time.perf_counter()
-        tokens = self.engine.decode_step()
-        step_s = time.perf_counter() - t0
-        self.busy_decode_s += step_s
-        telemetry.emit(
-            "timer", "serve.decode_step",
-            ms=step_s * 1000, ok=True,
-            data={"active": len(tokens)})
+        with telemetry.timer("serve.decode_step") as step:
+            tokens = self.engine.decode_step()
+            step.set(active=len(tokens))
+        self.busy_decode_s += step.seconds
         self.decode_steps += 1
         self._occupancy_sum += self.engine.occupancy()
         telemetry.gauge("serve.batch_occupancy", self.engine.occupancy())
@@ -707,28 +713,36 @@ class Scheduler(object):
             if ss["enabled"]:
                 telemetry.gauge("serve.spec.accept_rate",
                                 ss["accept_rate"])
-        for slot, toks in tokens.items():
-            req = self._slots.get(slot)
-            if req is None:
-                continue
-            # speculative decode emits up to spec_k+1 tokens per slot
-            # per step; eos/length inside the burst stops delivery of
-            # the remainder (the engine over-generated, the stream must
-            # not)
-            for token in (toks if isinstance(toks, list) else [toks]):
-                if req.state != "decode":
-                    break
-                self._deliver(req, token)
+        with telemetry.annotate("serve.deliver") as span:
+            delivered = 0
+            for slot, toks in tokens.items():
+                req = self._slots.get(slot)
+                if req is None:
+                    continue
+                # speculative decode emits up to spec_k+1 tokens per slot
+                # per step; eos/length inside the burst stops delivery of
+                # the remainder (the engine over-generated, the stream
+                # must not)
+                for token in (toks if isinstance(toks, list) else [toks]):
+                    if req.state != "decode":
+                        break
+                    self._deliver(req, token)
+                    delivered += 1
+            span.set_metadata(tokens=delivered)
         return True
 
     # ---------- the loop ----------
 
     def step(self):
         """One scheduler iteration; returns True if any work was done."""
-        self._reap(time.time())
-        admitted = self._admit()
-        prefilled = self._prefill()
-        decoded = self._decode()
+        with telemetry.annotate("serve.iteration", iteration=self.iteration):
+            with telemetry.annotate("serve.reap"):
+                self._reap(time.time())
+            with telemetry.annotate("serve.admit") as span:
+                admitted = self._admit()
+                span.set_metadata(admitted=admitted)
+            prefilled = self._prefill()
+            decoded = self._decode()
         self.iteration += 1
         return bool(admitted or prefilled or decoded)
 

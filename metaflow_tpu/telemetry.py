@@ -22,6 +22,14 @@ Record schema (pinned in tests/schema_validate.py):
      "trace": str,                   # W3C trace id (TRACEPARENT)
      "data": {...}}                  # free-form extras
 
+Profiler spans: `annotate(name, **attrs)` opens a
+`jax.profiler.TraceAnnotation`, and every `timer` opens the same one
+around what it times, so each timed block is also a span on the
+profiler's clock under the name its record has. There is no switch: a
+span is recorded while a profiler session is open (ProfileTrigger below,
+or anyone's `jax.profiler.start_trace`) and costs one flag check when
+none is. A process that has not imported JAX is never made to.
+
 Crash safety: records flush in numbered part files
 (`_telemetry/<step>.<task>.<attempt>.<part>.jsonl`) — a task that dies
 mid-run loses at most the unflushed tail, never already-persisted parts.
@@ -44,7 +52,6 @@ import sys
 import threading
 import time
 import zipfile
-from contextlib import contextmanager
 
 from . import knobs
 
@@ -54,6 +61,100 @@ PROFILE_PREFIX = "_telemetry/profiles"
 HANGS_PREFIX = "_telemetry/hangs"
 
 _current = None
+
+_trace_annotation = None
+
+
+class _NoSpan(object):
+    """What annotate() gives a process without JAX."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+    def set_metadata(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def annotate(name, **attrs):
+    """A span on the profiler's clock: `jax.profiler.TraceAnnotation`
+    with `attrs` as the span's stats (request id, slot, tokens,
+    iteration), and nothing else. Writes no telemetry record. Stats known
+    only inside the block go on with `span.set_metadata(**attrs)`. Where
+    JAX has not been imported the span does nothing: a process without
+    JAX has no profiler session to record into."""
+    global _trace_annotation
+    cls = _trace_annotation
+    if cls is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = getattr(profiler, "TraceAnnotation", None)
+        if cls is None:
+            return _NO_SPAN
+        _trace_annotation = cls
+    return cls(name, **attrs)
+
+
+def _span_attrs(step_num, data):
+    """A timer's step number and the primitive values of its data, as a
+    span's stats."""
+    attrs = {} if step_num is None else {"step_num": int(step_num)}
+    if data:
+        attrs.update((k, v) for k, v in data.items()
+                     if isinstance(v, (str, int, float, bool)))
+    return attrs
+
+
+class _Timer(object):
+    """One timed block: a span on the profiler's clock and, where a
+    recorder is given, its timer record under the same name. The record
+    lands even when the block raises (ok: false) and the exception
+    propagates. GeneratorExit is NOT a failure: it is how a consumer
+    closes a generator-shaped span early (e.g. a single-artifact load).
+    `seconds` holds the block's time once it has ended."""
+
+    __slots__ = ("recorder", "name", "step_num", "data", "seconds", "_t0",
+                 "_span")
+
+    def __init__(self, recorder, name, step_num=None, data=None):
+        self.recorder, self.name = recorder, name
+        self.step_num, self.data = step_num, data
+        self.seconds = None
+
+    def start(self):
+        """For a block that no `with` can hold (a generator's time
+        between two yields): start() ... stop()."""
+        return self.__enter__()
+
+    def stop(self):
+        self.__exit__(None, None, None)
+
+    def __enter__(self):
+        self._span = annotate(self.name,
+                              **_span_attrs(self.step_num, self.data))
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs):
+        """Stats known only inside the block: onto the span and into the
+        record's data."""
+        self._span.set_metadata(**attrs)
+        self.data = dict(self.data or (), **attrs)
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = time.perf_counter() - self._t0
+        self._span.__exit__(exc_type, exc, tb)
+        if self.recorder is not None:
+            self.recorder.emit(
+                "timer", self.name, ms=self.seconds * 1000,
+                ok=exc_type is None or issubclass(exc_type, GeneratorExit),
+                step_num=self.step_num, data=self.data)
+        return False
 
 
 def _rank_from_env():
@@ -152,27 +253,9 @@ class FlightRecorder(object):
             self.flush()
         return rec
 
-    @contextmanager
     def timer(self, name, step_num=None, data=None):
-        """Time a block; the record lands even when the block raises
-        (ok: false) and the exception propagates. GeneratorExit is NOT a
-        failure: it is how a consumer closes a generator-shaped span
-        early (e.g. a single-artifact load)."""
-        start = time.perf_counter()
-        try:
-            yield
-        except GeneratorExit:
-            self.emit("timer", name,
-                      ms=(time.perf_counter() - start) * 1000,
-                      ok=True, step_num=step_num, data=data)
-            raise
-        except BaseException:
-            self.emit("timer", name,
-                      ms=(time.perf_counter() - start) * 1000,
-                      ok=False, step_num=step_num, data=data)
-            raise
-        self.emit("timer", name, ms=(time.perf_counter() - start) * 1000,
-                  ok=True, step_num=step_num, data=data)
+        """Time a block: a timer record and a profiler span (_Timer)."""
+        return _Timer(self, name, step_num=step_num, data=data)
 
     def counter(self, name, inc=1, data=None):
         self.emit("counter", name, inc=inc, data=data)
@@ -314,13 +397,10 @@ def emit(rtype, name, **kwargs):
         _current.emit(rtype, name, **kwargs)
 
 
-@contextmanager
 def timer(name, step_num=None, data=None):
-    if _current is None:
-        yield
-        return
-    with _current.timer(name, step_num=step_num, data=data):
-        yield
+    """Time a block into the current recorder; with no recorder the
+    block is still a span on the profiler's clock."""
+    return _Timer(_current, name, step_num=step_num, data=data)
 
 
 def counter(name, inc=1, data=None):

@@ -1,12 +1,11 @@
 """Tracing: OpenTelemetry with a graceful no-op default.
 
 Reference behavior: metaflow/tracing/ (__init__.py:14-50 no-op shims unless
-deps + an endpoint are configured; spans wrap CLI commands; context
+deps + an endpoint are configured; context
 propagates into subprocesses via env). Enable by setting
 TPUFLOW_OTEL_ENDPOINT (requires opentelemetry-sdk to be installed).
 """
 
-import functools
 import os
 from contextlib import contextmanager
 
@@ -55,7 +54,9 @@ def span(name, attributes=None):
 
     Spans also tee into the run's flight recorder (telemetry.py) as timer
     records when one is active — the `persist.*` spans around datastore
-    ops thereby land in `tpuflow metrics` without double instrumentation.
+    ops thereby land in `tpuflow metrics` without double instrumentation —
+    and, through the same timer, onto the profiler's clock while a
+    profiler session is open.
     Exceptions are recorded on the span (ERROR status) and re-raised,
     never swallowed into a clean span.
     """
@@ -63,9 +64,6 @@ def span(name, attributes=None):
 
     tracer = _init()
     if tracer is None:
-        if telemetry.current_recorder() is None:
-            yield None
-            return
         with telemetry.timer(name, data=_span_data(attributes)):
             yield None
         return
@@ -87,20 +85,6 @@ def _span_data(attributes):
         k: (v if isinstance(v, (str, int, float, bool)) else str(v))
         for k, v in attributes.items()
     }
-
-
-def cli(name):
-    """Decorator wrapping a CLI command in a span (reference: @tracing.cli)."""
-
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with span(name):
-                return fn(*args, **kwargs)
-
-        return wrapped
-
-    return deco
 
 
 def inject_tracing_vars(env):
@@ -143,19 +127,6 @@ def ensure_traceparent(seed):
     value = "00-%s-%s-01" % (digest[:32], digest[32:48])
     os.environ[_TRACEPARENT_VAR] = value
     return value
-
-
-def get_trace_id():
-    tracer = _init()
-    if tracer is None:
-        return ""
-    try:
-        from opentelemetry import trace
-
-        ctx = trace.get_current_span().get_span_context()
-        return format(ctx.trace_id, "032x") if ctx.is_valid else ""
-    except ImportError:
-        return ""
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,8 @@ import threading
 
 import numpy as np
 
+from .. import telemetry
+
 # canonical home: metaflow_tpu/data/ordering.py (shared with the
 # streaming loader); re-exported here for the existing import surface.
 # shard_iterator passes the stamp through host-side (never deviced).
@@ -170,10 +172,13 @@ class ResumableTokenBatches(object):
             order = self._order(self._epoch)
             per_epoch = self.batches_per_epoch
             while self._cursor < per_epoch:
-                idxs = order[self._cursor * B:(self._cursor + 1) * B]
-                rows = [data[i * W:(i + 1) * W] for i in idxs]
-                self._cursor += 1
-                yield {"tokens": np.stack(rows), STATE_KEY: self.state()}
+                with telemetry.annotate("data.next_batch"):
+                    idxs = order[self._cursor * B:(self._cursor + 1) * B]
+                    rows = [data[i * W:(i + 1) * W] for i in idxs]
+                    self._cursor += 1
+                    batch = {"tokens": np.stack(rows),
+                             STATE_KEY: self.state()}
+                yield batch
             self._epoch += 1
             self._cursor = 0
 
@@ -242,7 +247,7 @@ def prefetch(iterator, depth=2):
     thread.start()
     try:
         while True:
-            with lock:
+            with lock, telemetry.annotate("data.next_batch"):
                 while not queue and not done:
                     lock.wait()
                 if queue:

@@ -475,6 +475,8 @@ def instrument_train_step(step_fn, tokens_per_step=None, flops_per_step=None,
     (call `.telemetry.close()` after the loop — or rely on the task
     finalization flush for the buffered records).
     """
+    import jax
+
     tel = TrainStepTelemetry(
         tokens_per_step=tokens_per_step, flops_per_step=flops_per_step,
         cost_analysis=cost_analysis, prefix=prefix,
@@ -493,7 +495,10 @@ def instrument_train_step(step_fn, tokens_per_step=None, flops_per_step=None,
             maybe_chaos_step(tel.step_num)
         started = tel.before_step()
         pre_cache = _cache_size(step_fn)
-        out = step_fn(*args, **kwargs)
+        # cuts a profiler capture (TPUFLOW_PROFILE_STEPS) into steps
+        with jax.profiler.StepTraceAnnotation(
+                "%s.step" % prefix, step_num=tel.step_num):
+            out = step_fn(*args, **kwargs)
         tel.after_step(step_fn, started, pre_cache, args, kwargs)
         return out
 

@@ -277,6 +277,7 @@ def make_train_step(cfg, mesh, model, optimizer=None, loss_fn=None,
         base_specs = shd.tree_specs(
             model.logical_axes(cfg), rules or shd.rules_for_mesh(mesh))
 
+    @jax.named_scope("optimizer_update")
     def apply_update(params, grads, opt_state):
         """(full grads, state) -> (new params, new opt state, grad norm).
 

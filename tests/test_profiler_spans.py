@@ -1,0 +1,301 @@
+"""The program's own marks in a profiler capture: host spans on the
+profiler's clock (telemetry.annotate and every timer) and scope names in
+the compiled programs (jax.named_scope, a Pallas kernel's name).
+
+One profiler session is opened for the whole module (the `captured`
+fixture): a Scheduler on its thread serves a handful of requests, a
+timer runs with a recorder current, a tiny train step runs; the same
+work runs once more with no session open, for the comparison.
+"""
+
+import dataclasses
+import glob
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from metaflow_tpu import telemetry
+from metaflow_tpu.models import llama, mixtral
+from metaflow_tpu.serving import Request, Scheduler, SlotEngine
+from metaflow_tpu.serving.paged import PagedEngine
+from metaflow_tpu.spmd import MeshSpec, create_mesh
+from metaflow_tpu.training import make_trainer, shard_batch
+
+PROMPTS = [list(range(1, 12)), list(range(5, 35)), list(range(3, 22)),
+           list(range(7, 16))]
+DECODE_SCOPES = ("decode_layers", "attn_qkv", "kv_cache_update",
+                 "decode_attention", "attn_out", "ffn")
+MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
+
+
+def _serve(sched):
+    reqs = [sched.submit(Request(p, max_new_tokens=5)) for p in PROMPTS]
+    return reqs, [r.result(timeout=120) for r in reqs]
+
+
+def _events(profile):
+    """[(line key, name, start, end, stats)] of the host plane."""
+    out = []
+    for plane in profile.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for n, line in enumerate(plane.lines):
+            for e in line.events:
+                if e.name.startswith(("serve.", "engine.", "unit.",
+                                      "train.", "data.")):
+                    out.append((n, e.name, e.start_ns,
+                                e.start_ns + e.duration_ns, dict(e.stats)))
+    return out
+
+
+@pytest.fixture(scope="module")
+def captured(tmp_path_factory):
+    from metaflow_tpu.datastore import FlowDataStore, LocalStorage
+
+    tmp = tmp_path_factory.mktemp("spans")
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    engine = SlotEngine(params, cfg, max_slots=2, max_seq_len=64,
+                        prefill_chunk=8)
+    sched = Scheduler(engine).start()
+    mesh = create_mesh(MeshSpec.dp(), devices=jax.devices()[:1])
+    state, step, _ = make_trainer(jax.random.PRNGKey(0), cfg, mesh, llama,
+                                  telemetry=True)
+    batch = shard_batch({"tokens": jax.random.randint(
+        jax.random.PRNGKey(1), (2, 33), 0, cfg.vocab_size)}, mesh)
+    out = {}
+    try:
+        _serve(sched)   # compiles every program
+        state, _ = step(state, batch)
+        saved = jax.tree.map(jnp.copy, state)
+        # ---- no session open ----
+        out["plain_requests"], out["plain_tokens"] = _serve(sched)
+        _, m = step(saved, batch)
+        out["plain_loss"] = float(m["loss"])
+        # ---- one session for the whole module ----
+        fds = FlowDataStore("Spans", LocalStorage, ds_root=str(tmp / "ds"))
+        telemetry.init_recorder(fds, "1", "_serve", "spans-test")
+        jax.profiler.start_trace(str(tmp / "trace"))
+        try:
+            out["requests"], out["tokens"] = _serve(sched)
+            with telemetry.timer("unit.timed", step_num=3,
+                                 data={"k": "v"}) as timed:
+                pass
+            out["timed_s"] = timed.seconds
+            _, m = step(state, batch)
+            out["loss"] = float(m["loss"])
+        finally:
+            jax.profiler.stop_trace()
+            telemetry.close_recorder()
+        out["records"] = telemetry.read_run_records(fds, "1")
+    finally:
+        sched.stop()
+    path = sorted(glob.glob(os.path.join(
+        str(tmp / "trace"), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out["events"] = _events(jax.profiler.ProfileData.from_file(path))
+    return out
+
+
+def _inside(outer, inner):
+    return outer[2] <= inner[2] and inner[3] <= outer[3]
+
+
+class TestSchedulerSpans:
+    def test_iteration_spans_enclose_the_boundaries_on_one_line(
+            self, captured):
+        events = captured["events"]
+        lines = {e[0] for e in events if e[1] == "serve.iteration"}
+        assert len(lines) == 1, "the scheduler's spans lie on one line"
+        line = [e for e in events if e[0] in lines]
+        names = {e[1] for e in line}
+        assert {"serve.iteration", "serve.reap", "serve.admit",
+                "serve.prefill_chunk", "engine.prefill.dispatch",
+                "engine.first_token.fetch", "serve.decode_step",
+                "engine.decode.upload", "engine.decode.dispatch",
+                "engine.decode.fetch", "serve.deliver"} <= names
+        iterations = [e for e in line if e[1] == "serve.iteration"]
+        assert all("iteration" in e[4] for e in iterations)
+
+        def parents(name, of):
+            """Every `name` span lies inside exactly one `of` span."""
+            for e in (x for x in line if x[1] == name):
+                assert sum(_inside(p, e) for p in line
+                           if p[1] == of) == 1, (name, of)
+
+        for name in ("serve.reap", "serve.admit", "serve.prefill_chunk",
+                     "serve.decode_step", "serve.deliver"):
+            parents(name, "serve.iteration")
+        parents("engine.prefill.dispatch", "serve.prefill_chunk")
+        parents("engine.first_token.fetch", "serve.prefill_chunk")
+        for name in ("engine.decode.upload", "engine.decode.dispatch",
+                     "engine.decode.fetch"):
+            parents(name, "serve.decode_step")
+        # deliver is the step's fan-out, after the engine call
+        deliver = [e for e in line if e[1] == "serve.deliver"]
+        steps = [e for e in line if e[1] == "serve.decode_step"]
+        assert len(deliver) == len(steps)
+        assert not any(_inside(s, d) for s in steps for d in deliver)
+        assert all(d[4]["tokens"] >= 1 for d in deliver)
+        assert sum(e[4]["admitted"] for e in line
+                   if e[1] == "serve.admit") == len(PROMPTS)
+
+    def test_every_chunk_names_its_request_and_slot(self, captured):
+        chunks = [e for e in captured["events"]
+                  if e[1] == "serve.prefill_chunk"]
+        assert chunks
+        assert all({"request_id", "slot", "tokens"} <= set(e[4])
+                   for e in chunks)
+        for req in captured["requests"]:
+            mine = [e for e in chunks if e[4]["request_id"] == req.id]
+            assert sum(e[4]["tokens"] for e in mine) == len(req.tokens)
+            assert {e[4]["slot"] for e in mine} == {req.slot}
+
+
+class TestNoSession:
+    def test_same_run_without_a_session_and_same_tokens(self, captured):
+        """Generated tokens are bit-identical with and without a session
+        open; the run with none passed in the fixture."""
+        assert captured["plain_tokens"] == captured["tokens"]
+        assert all(len(t) == 5 for t in captured["tokens"])
+
+    def test_train_loss_identical_with_and_without_a_session(
+            self, captured):
+        assert captured["plain_loss"] == captured["loss"]
+        steps = [e for e in captured["events"] if e[1] == "train.step"]
+        assert steps and "step_num" in steps[-1][4]
+
+    def test_annotate_never_imports_jax(self):
+        code = (
+            "import sys\n"
+            "from metaflow_tpu import telemetry, tracing\n"
+            "with telemetry.annotate('a', n=1) as span:\n"
+            "    span.set_metadata(x=2)\n"
+            "with telemetry.timer('b', step_num=1, data={'k': 1}) as t:\n"
+            "    t.set(tokens=3)\n"
+            "with tracing.span('c', {'step': 1}):\n"
+            "    pass\n"
+            "assert t.seconds >= 0\n"
+            "assert 'jax' not in sys.modules, 'a span imported JAX'\n")
+        env = dict(os.environ, PYTHONPATH=os.pathsep.join(
+            [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
+            + [p for p in os.environ.get("PYTHONPATH", "").split(os.pathsep)
+               if p]))
+        done = subprocess.run([sys.executable, "-c", code], env=env,
+                              capture_output=True, text=True, timeout=120)
+        assert done.returncode == 0, done.stderr
+
+
+class TestTimerIsBoth:
+    def test_record_as_before_and_the_span_as_well(self, captured):
+        recs = [r for r in captured["records"] if r["name"] == "unit.timed"]
+        assert len(recs) == 1
+        rec = recs[0]
+        assert rec["type"] == "timer" and rec["ok"] is True
+        assert rec["step_num"] == 3 and rec["data"] == {"k": "v"}
+        assert rec["ms"] == pytest.approx(captured["timed_s"] * 1e3,
+                                          abs=1e-3)
+        spans = [e for e in captured["events"] if e[1] == "unit.timed"]
+        assert len(spans) == 1
+        assert spans[0][4]["step_num"] == 3 and spans[0][4]["k"] == "v"
+
+    def test_the_scheduler_timers_still_write_their_records(self, captured):
+        names = {r["name"] for r in captured["records"]}
+        assert {"serve.prefill_chunk", "serve.decode_step"} <= names
+        chunk = [r for r in captured["records"]
+                 if r["name"] == "serve.prefill_chunk"][0]
+        assert {"request_id", "slot", "tokens"} <= set(chunk["data"])
+        # the finer spans are spans only: no record, no schema change
+        assert not names & {"serve.iteration", "serve.reap", "serve.admit",
+                            "serve.deliver", "engine.decode.fetch",
+                            "engine.prefill.dispatch"}
+
+
+def _has(text, scope):
+    """A name on some operation's name stack in the lowered text:
+    `"jit(step)/jvp(layers)/while/..."`, `"attn_qkv/dot_general"`."""
+    return re.search(r'["/(]%s["/)]' % re.escape(scope), text) is not None
+
+
+def _engine(model, attn_impl, paged=False):
+    cfg = model.LlamaConfig.tiny() if model is llama \
+        else model.MixtralConfig.tiny()
+    params = jax.eval_shape(
+        lambda: model.init_params(jax.random.PRNGKey(0), cfg))
+    if paged:
+        return PagedEngine(params, cfg, max_slots=2, max_seq_len=64,
+                           prefill_chunk=8, page_tokens=8,
+                           attn_impl=attn_impl)
+    return SlotEngine(params, cfg, max_slots=2, max_seq_len=64,
+                      prefill_chunk=8, attn_impl=attn_impl)
+
+
+def _decode_text(engine):
+    B = engine.max_slots
+    i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
+    args = [engine.params,
+            jax.eval_shape(lambda: engine.pool.kv) if hasattr(engine, "pool")
+            else jax.eval_shape(lambda: engine._cache),
+            i32, i32, jax.ShapeDtypeStruct((B,), jnp.bool_)]
+    if hasattr(engine, "pool"):
+        args.append(jax.eval_shape(lambda: jnp.asarray(engine.block_tables)))
+    return engine._decode_greedy_fn.lower(*args).as_text(debug_info=True)
+
+
+class TestScopeNames:
+    @pytest.mark.parametrize("model,attn_impl,paged", [
+        (llama, "dense", False), (llama, "chunked", False),
+        (mixtral, "dense", False), (mixtral, "chunked", False),
+        (llama, "dense", True), (llama, "chunked", True)])
+    def test_decode_program_holds_every_scope(self, model, attn_impl, paged):
+        text = _decode_text(_engine(model, attn_impl, paged))
+        assert "module @jit__decode_greedy" in text
+        scopes = DECODE_SCOPES + (MOE_SCOPES if model is mixtral else ())
+        for scope in scopes:
+            assert _has(text, scope), scope
+
+    def test_prefill_program_and_pinned_program_names(self):
+        engine = _engine(llama, "dense")
+        cache = jax.eval_shape(lambda: engine._cache)
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+        text = engine._prefill_fn.lower(
+            engine.params, cache, jax.ShapeDtypeStruct((1, 8), jnp.int32),
+            i32, i32).as_text(debug_info=True)
+        assert "module @jit__prefill" in text
+        for scope in DECODE_SCOPES:
+            assert _has(text, scope), scope
+        # the readers find a program's executions by these names
+        for fn, name in ((engine._decode_sampled_fn, "_decode_sampled"),
+                         (engine._decode_greedy_fn, "_decode_greedy"),
+                         (engine._prefill_fn, "_prefill"),
+                         (engine._first_fn, "_first_token")):
+            assert fn.__name__ == name
+
+    @pytest.mark.parametrize("model,scopes", [
+        (llama, ("layers", "attention", "flash_attention", "flash_fwd",
+                 "flash_bwd_dq", "flash_bwd_dkv", "ffn", "loss",
+                 "optimizer_update")),
+        (mixtral, ("layers", "attention", "ffn", "loss", "optimizer_update")
+         + MOE_SCOPES)])
+    def test_train_step_holds_every_scope(self, model, scopes):
+        cfg = model.LlamaConfig.tiny() if model is llama \
+            else model.MixtralConfig.tiny()
+        if model is llama:
+            # the kernel, interpreted: 'auto' is XLA attention on the CPU
+            cfg = dataclasses.replace(cfg, attention_impl="flash_interpret",
+                                      max_seq_len=128)
+        mesh = create_mesh(MeshSpec.dp(),
+                           devices=jax.devices()[:1])
+        state, step, _ = make_trainer(jax.random.PRNGKey(0), cfg, mesh,
+                                      model)
+        seq = 128 if model is llama else 32
+        tokens = jax.ShapeDtypeStruct((2, seq + 1), jnp.int32)
+        text = step.lower(state, {"tokens": tokens}).as_text(
+            debug_info=True)
+        assert "module @jit_step" in text
+        for scope in scopes:
+            assert _has(text, scope), scope

@@ -495,6 +495,9 @@ class TestServingTelemetry:
                 # page-pool events need a paged engine; pinned in
                 # test_paged_serving.py
                 continue
+            if lifecycle.startswith("serve.tenant."):
+                # tenant events need tenancy; pinned in test_tenancy.py
+                continue
             assert lifecycle in names, "missing %s" % lifecycle
         assert "serve.batch_occupancy" in names
         assert "serve.decode_step" in names
