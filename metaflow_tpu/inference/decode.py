@@ -12,6 +12,15 @@ The reference framework has no inference engine (it orchestrates user
 frameworks); this is part of the training/serving substrate the TPU
 rebuild provides natively (SURVEY.md §5.7).
 
+Attention over the cache (`decode_attention`) is grouped-query as
+stored: the G = H // KV query heads that share a KV head are the rows of
+one matrix product against that head's keys and values, in the cache's
+own dtype with float32 accumulation. K and V are never repeated to H
+heads and never widened; with a bfloat16 cache the probabilities of a
+block are rounded to bfloat16 for the product with V, as the training
+kernel rounds them (ops/attention.py). On a v5e the chunked path reads
+its KV chunks at about 70 % of the chip's HBM bandwidth (PERF.md, PR 25).
+
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
 is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
@@ -36,7 +45,7 @@ import jax.numpy as jnp
 from .. import knobs
 from ..models import llama
 from ..ops import rms_norm
-from ..ops.attention import NEG_INF, _broadcast_gqa
+from ..ops.attention import NEG_INF
 from ..ops.rope import apply_rope, rope_frequencies
 
 
@@ -62,11 +71,30 @@ def _query_positions(pos, T):
 
 
 def _mask_positions(q_positions):
-    """[T] or [B, T] query positions -> broadcastable [*, 1, T, 1] for the
-    [B, H, T, S] logits layout."""
+    """[T] or [B, T] query positions -> broadcastable [*, 1, 1, T, 1] for
+    the grouped [B, KV, G, T, S] logits layout (one mask for the whole
+    group of query heads that share a KV head)."""
     if q_positions.ndim == 1:
-        return q_positions[None, None, :, None]
-    return q_positions[:, None, :, None]
+        return q_positions[None, None, None, :, None]
+    return q_positions[:, None, None, :, None]
+
+
+def _group_queries(q, n_kv_heads):
+    """[B, T, H, Hd] -> [B, KV, G, T, Hd]: the G = H // KV query heads
+    that share KV head k (heads k*G .. k*G+G-1, the order jnp.repeat
+    gives) become rows of ONE [G*T, Hd] matrix against that head's keys,
+    so K and V are contracted as stored and never repeated. G = 1 is
+    plain multi-head attention through the same code."""
+    B, T, H, Hd = q.shape
+    q = q.reshape(B, T, n_kv_heads, H // n_kv_heads, Hd)
+    return q.transpose(0, 2, 3, 1, 4)
+
+
+def _ungroup(out, dtype):
+    """[B, KV, G, T, Hd] -> [B, T, H, Hd], the inverse of _group_queries."""
+    B, KV, G, T, Hd = out.shape
+    out = out.transpose(0, 3, 1, 2, 4).reshape(B, T, KV * G, Hd)
+    return out.astype(dtype)
 
 
 @jax.named_scope("decode_attention")
@@ -77,18 +105,22 @@ def _cached_attention(q, cache_k, cache_v, pos):
     pos: traced scalar, or [B] vector for per-slot offsets.
 
     Dense: touches the WHOLE [Smax] cache every step — fine at moderate
-    max_seq, bandwidth-bound for long-context serving (use 'chunked')."""
-    B, T, H, Hd = q.shape
-    k = _broadcast_gqa(cache_k, H)
-    v = _broadcast_gqa(cache_v, H)
+    max_seq, bandwidth-bound for long-context serving (use 'chunked').
+    The same grouped contraction as _streamed_attention: both products
+    batched over (B, KV) on the cache's dtype with float32 accumulation,
+    softmax in float32, probabilities rounded to V's dtype."""
+    T, Hd = q.shape[1], q.shape[3]
     scale = 1.0 / math.sqrt(Hd)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+    qg = _group_queries(q, cache_k.shape[2])
+    logits = jnp.einsum("bkgtd,bskd->bkgts", qg, cache_k,
                         preferred_element_type=jnp.float32) * scale
-    key_idx = jnp.arange(k.shape[1])[None, None, None, :]
+    key_idx = jnp.arange(cache_k.shape[1])
     q_pos = _mask_positions(_query_positions(pos, T))
     logits = jnp.where(key_idx <= q_pos, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+    probs = jax.nn.softmax(logits, axis=-1).astype(cache_v.dtype)
+    out = jnp.einsum("bkgts,bskd->bkgtd", probs, cache_v,
+                     preferred_element_type=jnp.float32)
+    return _ungroup(out, q.dtype)
 
 
 def _default_decode_chunk():
@@ -111,36 +143,42 @@ def _streamed_attention(q, pos, chunk, n_chunks, fetch):
     i-th KV block and the absolute key positions it holds. Keys are
     visible iff key_idx <= q_pos AND key_idx >= i * chunk — the second
     term masks a clamped edge block's re-read of earlier keys (a paged
-    fetch never re-reads, so the term is a no-op there)."""
-    B, T, H, Hd = q.shape
+    fetch never re-reads, so the term is a no-op there).
+
+    A block is contracted as fetched, in the cache's dtype: the query
+    heads of a group are rows of one matrix product per (slot, KV head)
+    (_group_queries), accumulated in float32. Logits, mask, running max
+    and sum and the accumulator are float32; the block's probabilities
+    are rounded to V's dtype for the second product (as the training
+    kernel does, ops/attention.py), the running sum is taken before the
+    rounding."""
+    T, Hd = q.shape[1], q.shape[3]
     scale = 1.0 / math.sqrt(Hd)
-    qf = q.astype(jnp.float32)
     q_pos = _mask_positions(_query_positions(pos, T))
+    # KV heads from a block's shape, without fetching one
+    qg = _group_queries(q, jax.eval_shape(fetch, 0)[0].shape[2])
 
     def body(i, carry):
         m, l, acc = carry
-        k_raw, v_raw, key_pos = fetch(i)
-        k_blk = _broadcast_gqa(k_raw, H)
-        v_blk = _broadcast_gqa(v_raw, H)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qf,
-                            k_blk.astype(jnp.float32)) * scale
-        key_idx = key_pos[None, None, None, :]
-        visible = (key_idx <= q_pos) & (key_idx >= i * chunk)
+        k_blk, v_blk, key_pos = fetch(i)
+        logits = jnp.einsum("bkgtd,bckd->bkgtc", qg, k_blk,
+                            preferred_element_type=jnp.float32) * scale
+        visible = (key_pos <= q_pos) & (key_pos >= i * chunk)
         logits = jnp.where(visible, logits, NEG_INF)
         m_new = jnp.maximum(m, logits.max(axis=-1))
         p = jnp.exp(logits - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1)
         acc_new = acc * corr[..., None] + jnp.einsum(
-            "bhqk,bkhd->bhqd", p, v_blk.astype(jnp.float32))
+            "bkgtc,bckd->bkgtd", p.astype(v_blk.dtype), v_blk,
+            preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((B, H, T), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, H, T), jnp.float32)
-    acc0 = jnp.zeros((B, H, T, Hd), jnp.float32)
+    m0 = jnp.full(qg.shape[:-1], NEG_INF, jnp.float32)
+    l0 = jnp.zeros(qg.shape[:-1], jnp.float32)
+    acc0 = jnp.zeros(qg.shape, jnp.float32)
     _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
-    out = acc / l[..., None]
-    return jnp.swapaxes(out, 1, 2).astype(q.dtype)  # [B, T, H, Hd]
+    return _ungroup(acc / l[..., None], q.dtype)
 
 
 @jax.named_scope("decode_attention")
@@ -151,9 +189,10 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
     (lax.fori_loop with a TRACED trip count ceil((pos+T)/chunk), lowered
     to a while_loop) — per emitted token the HBM traffic is O(filled),
     not O(Smax), which is what long-context serving needs. Numerics
-    match the dense path: same fp32 logits, same masking; the edge
-    chunk's clamped slice re-reads earlier keys, masked out by the
-    `key >= chunk start` term."""
+    follow the dense path: the same grouped products accumulated in
+    float32, the same masking, probabilities rounded to V's dtype per
+    chunk instead of once; the edge chunk's clamped slice re-reads
+    earlier keys, masked out by the `key >= chunk start` term."""
     T = q.shape[1]
     Smax = cache_k.shape[1]
     chunk = min(chunk, Smax)
@@ -330,12 +369,13 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
     (flash-decode: online softmax over only the filled prefix — the
     long-context serving path), or 'auto'. The auto switchover picks
     'chunked' once the KV cache is deeper than 2 * DECODE_CHUNK
-    positions (512 with the default chunk of 256): below that the whole
-    cache fits in two chunks and the dense einsum's single pass beats
-    the online-softmax loop's overhead; above it the chunked path's
-    O(filled) HBM traffic wins. DECODE_CHUNK — and therefore this
-    threshold — is overridable via TPUFLOW_DECODE_CHUNK (read once at
-    import).
+    positions (512 with the default chunk of 256): the dense path reads
+    the whole [Smax] cache every step whatever is filled, the chunked
+    path only the filled prefix, a chunk at a time. Where the two cross
+    has not been measured on a chip: the threshold is a choice, and only
+    the chunked path has a chip time (the module docstring; PERF.md,
+    PR 25). DECODE_CHUNK — and therefore this threshold — is
+    overridable via TPUFLOW_DECODE_CHUNK (read once at import).
 
     prompt_len: None when prompt_tokens is exactly the prompt. A TRACED
     scalar when prompt_tokens is right-PADDED to a longer static shape

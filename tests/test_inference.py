@@ -123,6 +123,84 @@ class TestGenerate:
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(dense),
                                    atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                           ("bfloat16", 2e-2)])
+    @pytest.mark.parametrize("per_slot", [False, True],
+                             ids=["scalar_pos", "per_slot_pos"])
+    @pytest.mark.parametrize("T", [1, 64])
+    @pytest.mark.parametrize("G", [1, 4, 8])
+    def test_grouped_attention_matches_plain_reference(self, G, T, per_slot,
+                                                       dtype, tol):
+        """Both decode attentions contract each KV head against its own
+        group of G query heads on the cache's dtype. The reference here
+        is the plain form they replaced: K and V repeated to H heads,
+        float32 softmax, weighted sum. Smax is no multiple of the chunk,
+        so the deepest slot reads the clamped edge chunk."""
+        from metaflow_tpu.inference.decode import (_cached_attention,
+                                                   _chunked_cached_attention)
+
+        B, KV, Hd, Smax, chunk = 3, 2, 16, 200, 32
+        H = KV * G
+        ks = jax.random.split(jax.random.PRNGKey(G * 100 + T), 3)
+        q = jax.random.normal(ks[0], (B, T, H, Hd)).astype(dtype)
+        ck = jax.random.normal(ks[1], (B, Smax, KV, Hd)).astype(dtype)
+        cv = jax.random.normal(ks[2], (B, Smax, KV, Hd)).astype(dtype)
+        # slots at different depths; the last one fills the cache
+        pos = jnp.asarray([5, 70, Smax - T]) if per_slot else Smax - T
+
+        qf, kf, vf = (np.asarray(x, np.float32) for x in (q, ck, cv))
+        kf, vf = np.repeat(kf, G, axis=2), np.repeat(vf, G, axis=2)
+        logits = np.einsum("bqhd,bkhd->bhqk", qf, kf) / np.sqrt(Hd)
+        q_pos = (np.broadcast_to(np.asarray(pos), (B,))[:, None]
+                 + np.arange(T)[None, :])
+        visible = (np.arange(Smax)[None, None, None, :]
+                   <= q_pos[:, None, :, None])
+        logits = np.where(visible, logits, -np.inf)
+        probs = np.exp(logits - logits.max(-1, keepdims=True))
+        probs /= probs.sum(-1, keepdims=True)
+        want = np.einsum("bhqk,bkhd->bqhd", probs, vf)
+
+        for got in (_chunked_cached_attention(q, ck, cv, pos, chunk=chunk),
+                    _cached_attention(q, ck, cv, pos)):
+            assert got.shape == q.shape and got.dtype == q.dtype
+            np.testing.assert_allclose(np.asarray(got, np.float32), want,
+                                       atol=tol, rtol=tol)
+
+    def test_bf16_decode_step_never_widens_the_kv_chunk(self):
+        """The chunked decode step reads each KV chunk as stored: no
+        float32 value of a chunk's shape, at H heads (K or V repeated for
+        the query heads of a group) or at KV heads (the chunk cast),
+        anywhere in the traced program."""
+        from metaflow_tpu.inference.decode import DECODE_CHUNK
+
+        cfg = llama.LlamaConfig.tiny(dtype="bfloat16")
+        B, Smax = 2, 3 * DECODE_CHUNK
+        params = jax.eval_shape(
+            lambda: llama.init_params(jax.random.PRNGKey(0), cfg))
+        cache = jax.eval_shape(lambda: init_kv_cache(cfg, B, Smax))
+        assert cache["k"].dtype == jnp.bfloat16
+        jaxpr = jax.make_jaxpr(
+            lambda p, t, c, pos: decode_forward(p, t, c, pos, cfg,
+                                                attn_impl="chunked"))(
+            params, jnp.zeros((B, 1), jnp.int32), cache,
+            jnp.zeros((B,), jnp.int32))
+
+        def values(jp):
+            for eqn in jp.eqns:
+                yield from (v.aval for v in eqn.outvars)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from values(sub)
+
+        avals = list(values(jaxpr.jaxpr))
+        chunk_shapes = {(B, DECODE_CHUNK, heads, cfg.head_dim)
+                        for heads in (cfg.n_heads, cfg.n_kv_heads)}
+        # the loop is in there: the chunk as stored
+        assert any(a.shape in chunk_shapes and a.dtype == jnp.bfloat16
+                   for a in avals)
+        widened = [a for a in avals
+                   if a.shape in chunk_shapes and a.dtype == jnp.float32]
+        assert not widened, widened
+
     def test_generate_chunked_matches_dense(self, setup):
         cfg, params, tokens = setup
         dense = generate(params, tokens, cfg, max_new_tokens=6,
