@@ -5,6 +5,8 @@ drivers and metric readers are found by the names the data gives."""
 import json
 import os
 
+from . import families
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
@@ -34,51 +36,16 @@ def load_cell(name, benchmark_path=None):
 
 
 def dims(config):
-    """The sizes the benchmark's own code reads, from the published
-    keys of a configuration file."""
-    d = {
-        "family": config["family"],
-        "dim": config["hidden_size"],
-        "n_layers": config["num_hidden_layers"],
-        "n_heads": config["num_attention_heads"],
-        "n_kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get(
-            "head_dim",
-            config["hidden_size"] // config["num_attention_heads"]),
-        "ffn_dim": config["intermediate_size"],
-        "vocab_size": config["vocab_size"],
-        "rope_theta": float(config["rope_theta"]),
-        "norm_eps": float(config["rms_norm_eps"]),
-        "dtype": config["torch_dtype"],
-    }
-    if config["family"] == "mixtral":
-        d["n_experts"] = config["num_local_experts"]
-        d["experts_per_tok"] = config["num_experts_per_tok"]
-    if d["head_dim"] * d["n_heads"] != d["dim"]:
-        raise ValueError("the program derives head_dim as hidden_size / "
-                         "heads; this configuration states another")
-    if config.get("sliding_window"):
-        raise ValueError("the program has no sliding-window attention")
+    """The sizes the benchmark's own code reads: what the configuration's
+    family (benchmark/families/<family>.py) makes of its published keys,
+    and the family's name."""
+    d = families.load(config["family"]).dims(config)
+    d["family"] = config["family"]
     return d
 
 
 def program_config(config, max_seq_len):
-    """The program's own configuration object for this file: its
-    dataclass fields only, no program file is touched."""
+    """(the program's model module, its own configuration object) for
+    this file, as its family maps it."""
     d = dims(config)
-    common = dict(vocab_size=d["vocab_size"], dim=d["dim"],
-                  n_layers=d["n_layers"], n_heads=d["n_heads"],
-                  n_kv_heads=d["n_kv_heads"], ffn_dim=d["ffn_dim"],
-                  max_seq_len=int(max_seq_len), rope_theta=d["rope_theta"],
-                  norm_eps=d["norm_eps"], dtype=d["dtype"])
-    if d["family"] == "llama":
-        from metaflow_tpu.models import llama
-
-        return llama, llama.LlamaConfig(rope_llama3_scaling=False, **common)
-    if d["family"] == "mixtral":
-        from metaflow_tpu.models import mixtral
-
-        return mixtral, mixtral.MixtralConfig(
-            n_experts=d["n_experts"], experts_per_tok=d["experts_per_tok"],
-            **common)
-    raise ValueError("unknown family %r" % (d["family"],))
+    return families.load(d["family"]).program_config(d, max_seq_len)

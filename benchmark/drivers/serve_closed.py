@@ -76,8 +76,9 @@ def run(ctx):
     failed = sum(r.reason != "length" for r in finished)
     ctx.log("window: %d output tokens in %.3f s, %d requests ended",
             delivered, t1 - t0, len(finished))
-    run_ = serving.layer_readings(finished, {}, before, after)
-    run_.update(kind="serve_closed", trace=reduced, slots=served.slots)
+    run_ = serving.layer_readings(served, finished, {}, before, after,
+                                  (t0, t1))
+    run_.update(kind="serve_closed", trace=reduced)
 
     served.free()
     serving.compare_with_reference(ctx, served, finished)

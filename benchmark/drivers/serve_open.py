@@ -97,7 +97,7 @@ def run(ctx):
         opened["setup_s"] = ctx.setup_seconds(opened["t"])
         slice_.in_thread(opened["t"])
 
-    reqs, due, before, after, _ = drive(
+    reqs, due, before, after, t0 = drive(
         served, todo, offsets, ctx.seconds, opened=on_open)
     compiles_before, setup_s = opened["compiles"], opened["setup_s"]
     reduced = slice_.close(1)
@@ -111,7 +111,8 @@ def run(ctx):
     ttft, itl = serving.tails(reqs, due)
     serving.report_samples(ctx, "ttft_ms", ttft)
     serving.report_samples(ctx, "itl_ms", itl)
-    run_ = serving.layer_readings(reqs, due, before, after)
+    run_ = serving.layer_readings(served, reqs, due, before, after,
+                                  (t0, t0 + ctx.seconds))
     run_.update(kind="serve_open", trace=reduced, ttft_ms=ttft)
 
     served.free()

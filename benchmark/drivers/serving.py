@@ -27,6 +27,7 @@ class Served(object):
         self.ctx = ctx
         s = ctx.config["serving"]
         self.slots, self.max_seq_len = s["slots"], s["max_seq_len"]
+        self.prefill_chunk = s["prefill_chunk"]
         _, cfg = configs.program_config(ctx.config, self.max_seq_len)
         dims = ctx.dims
         self.params = jax.jit(lambda k: weights.init_params(k, dims))(
@@ -35,7 +36,7 @@ class Served(object):
         ctx.log("weights on the device: %d layers", dims["n_layers"])
         self.engine = build_engine(self.params, cfg, slots=self.slots,
                                    max_seq_len=self.max_seq_len,
-                                   prefill_chunk=s["prefill_chunk"])
+                                   prefill_chunk=self.prefill_chunk)
         # the queue never refuses: what waits is measured, not shed
         self.sched = Scheduler(self.engine, max_queue=1 << 20).start()
         self.requests = []
@@ -155,19 +156,33 @@ def compare_with_reference(ctx, served, finished):
                             sum(control) / len(control))
 
 
-def layer_readings(reqs, due, before, after):
-    """What the per-layer readers find in a serving run."""
+def decode_reads(requests, t0, t1):
+    """(tokens the decode steps of [t0, t1) made, cached positions those
+    steps had to read), over all requests: a request's k-th token
+    (k >= 1; its first comes from the prefill) is made by a step that
+    attends to its prompt and the k tokens before it."""
+    made = [len(r.tokens) + k for r in requests
+            for k, at in enumerate(r.token_times) if k and t0 <= at < t1]
+    return len(made), sum(made)
+
+
+def layer_readings(served, reqs, due, before, after, window):
+    """What the per-layer readers find in a serving run; `window` is
+    its two ends on the clock of the requests' timestamps."""
     d = {k: after[k] - before[k] for k in after}
     admitted = [r for r in reqs if r.t_admit is not None]
     first = [r for r in admitted if r.t_first is not None]
-    return {
-        "queue_wait_ms": [(r.t_admit - r.t_submit) * 1e3 for r in admitted],
-        "prefill_ms_per_ktok": [
+    decode_tokens, kv_positions_read = decode_reads(served.requests, *window)
+    return dict(
+        served.ctx.run_sizes(), slots=served.slots,
+        max_seq_len=served.max_seq_len, prefill_chunk=served.prefill_chunk,
+        queue_wait_ms=[(r.t_admit - r.t_submit) * 1e3 for r in admitted],
+        prefill_ms_per_ktok=[
             (r.t_first - r.t_admit) * 1e6 / len(r.tokens) for r in first],
-        "late_ms": [(r.t_submit - due[id(r)]) * 1e3 for r in reqs
-                    if id(r) in due],
-        "counters": d,
-    }
+        late_ms=[(r.t_submit - due[id(r)]) * 1e3 for r in reqs
+                 if id(r) in due],
+        counters=d, decode_tokens=decode_tokens,
+        kv_positions_read=kv_positions_read)
 
 
 def tails(reqs, due):

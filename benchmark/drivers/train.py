@@ -14,7 +14,7 @@ import math
 import time
 import types
 
-from .. import flops, harness, loadgen, peaks, ref_train, weights
+from .. import flops, harness, loadgen, ref_train, weights
 
 
 def first_grad_norms(opt_state, params):
@@ -72,6 +72,7 @@ def run(ctx):
     from .. import configs
 
     t, dims, chips = ctx.traffic, ctx.dims, ctx.cell["chips"]
+    ref_train.training_family(dims)   # or there is nothing to compare with
     seq, batch = t["seq_len"], t["sequences_per_chip"] * chips
     opt = t["optimizer"]
     model, cfg = configs.program_config(ctx.config, seq)
@@ -172,20 +173,17 @@ def run(ctx):
     ctx.check("compilations_in_window", compiled_in_window, 0)
     ctx.check("steps_in_window_short_of_1", max(0, 1 - n), 0)
 
-    peak = peaks.peak(ctx.devices[0].device_kind) \
-        if ctx.devices[0].platform == "tpu" else None
     return {
         "attempted": n, "failed": 0,
         "memory_peak_bytes": memory_peak,
         "end_to_end": {"train_tokens_per_s": tokens_per_s,
                        "setup_s": setup_s},
-        "run": {
-            "kind": "train", "trace": reduced, "chips": chips,
-            "loader_wait_ms": wait_ms, "step_ms": step_ms,
-            "tokens_per_s": tokens_per_s,
-            "flops_per_token": flops.train_flops_per_token(dims, seq),
-            "peak": peak,
-        },
+        "run": dict(
+            ctx.run_sizes(), kind="train", trace=reduced,
+            seq_len=seq, sequences_per_step=batch,
+            loader_wait_ms=wait_ms, step_ms=step_ms,
+            tokens_per_s=tokens_per_s,
+            flops_per_token=flops.train_flops_per_token(dims, seq)),
     }
 
 

@@ -9,7 +9,7 @@ import shutil
 import threading
 import time
 
-from . import configs, trace_reduce
+from . import configs, peaks, trace_reduce
 
 # a fixed place inside the checkout; each traced run replaces the last
 TRACE_DIR = os.path.join(configs.ROOT, ".tpuflow", "bench_trace")
@@ -56,6 +56,17 @@ class Context(object):
     @property
     def correct(self):
         return bool(self.checks) and all(c[3] for c in self.checks)
+
+    def run_sizes(self):
+        """What every driver's `run` carries beside its own readings, so
+        that a reader can turn a time into a share of a roofline
+        (benchmark/kernel_costs.py): the configuration's sizes, the
+        cell's chips, and the device's row of peaks.py (None off the
+        TPU: a share of a peak comes only from a chip run)."""
+        device = self.devices[0]
+        return {"dims": self.dims, "chips": self.cell["chips"],
+                "peak": peaks.peak(device.device_kind)
+                if device.platform == "tpu" else None}
 
     def setup_seconds(self, t_window):
         return t_window - self.t_process - self.excluded_s

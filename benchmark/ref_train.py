@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import reference, weights
+from . import families, weights
 
 F32 = jnp.float32
 EPS = 1e-30
@@ -82,11 +82,22 @@ def _apply(p, mom, g, v, clip, lr, opt):
     return new.astype(p.dtype), m.astype(mom.dtype)
 
 
+def training_family(dims):
+    """The family's module, where it exports the `layer` and `head` that
+    the follower differentiates (one stack of `layers` between `embed`
+    and `final_norm`, `lm_head`); a family without them has no training
+    reference."""
+    family = families.load(dims["family"])
+    if not (hasattr(family, "layer") and hasattr(family, "head")):
+        raise NotImplementedError(
+            "family %r exports no `layer` and `head`: it has no training "
+            "reference" % (dims["family"],))
+    return family
+
+
 class Follower(object):
     def __init__(self, key, dims, opt, lowp=False):
-        if dims.get("n_experts"):
-            raise NotImplementedError("the training reference follows "
-                                      "dense models only")
+        family = training_family(dims)
         self.opt = opt
         stacked = jax.jit(lambda k: weights.init_params(k, dims))(key)
         L = dims["n_layers"]
@@ -104,7 +115,7 @@ class Follower(object):
         d, lp = dims, lowp
 
         def fwd(p, x):
-            return jax.vmap(lambda xs: reference.layer(p, xs, d, lp))(x)
+            return jax.vmap(lambda xs: family.layer(p, xs, d, lp))(x)
 
         def f32(tree):
             return jax.tree.map(lambda a: a.astype(F32), tree)
@@ -131,8 +142,7 @@ class Follower(object):
             return apply_all(p, mom, g, v, clip, lr) + (dx,)
 
         def loss_of(top, x, targets):
-            logits = reference.head(x, top["final_norm"], top["lm_head"],
-                                    d, lp)
+            logits = family.head(x, top["final_norm"], top["lm_head"], d, lp)
             lse = jax.nn.logsumexp(logits, -1)
             picked = jnp.take_along_axis(logits, targets[..., None], -1)[..., 0]
             return jnp.mean(lse - picked)

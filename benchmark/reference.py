@@ -1,10 +1,12 @@
 """The plain reference: the published forward pass in `jax.numpy` and
 float32, at `highest` matmul precision, one layer (and one expert) of
-weights upcast at a time. Written from the published descriptions
-(Mistral-7B: RMS-norm, rotary embedding in the half-split convention,
-grouped-query causal attention, SwiGLU; Mixtral: a router whose top-k
-logits are softmaxed, and a plain loop over every expert). It imports
-nothing of the program.
+weights upcast at a time. Here are the pieces families share, written
+from the published descriptions (RMS-norm, rotary embedding in the
+half-split convention, grouped-query causal attention, SwiGLU, a router
+whose top-k logits are softmaxed over a plain loop over every expert)
+and the comparison of served tokens; a family's module
+(benchmark/families/) puts them together into its forward pass. It
+imports nothing of the program.
 
 `lowp=True` is the control: the same mathematics with both operands of
 every matmul rounded to an 8-bit float (e4m3: three bits of mantissa,
@@ -12,11 +14,11 @@ per-tensor scale), the nearest precision below the bfloat16 the
 configurations state.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from . import families
 
 F32 = jnp.float32
 
@@ -103,43 +105,10 @@ def moe(h, p, top_k, lowp):
     return out
 
 
-def layer(p, x, dims, lowp=False):
-    """One block on one sequence; x: [T, D] float32, p: this layer's
-    weights in the type they are stored in."""
-    T = x.shape[0]
-    H, KV, hd = dims["n_heads"], dims["n_kv_heads"], dims["head_dim"]
-    pos = jnp.arange(T)
-    h = rms_norm(x, p["attn_norm"], dims["norm_eps"])
-    q = rope(mm(h, p["wq"], lowp).reshape(T, H, hd), pos, dims["rope_theta"])
-    k = rope(mm(h, p["wk"], lowp).reshape(T, KV, hd), pos,
-             dims["rope_theta"])
-    v = mm(h, p["wv"], lowp).reshape(T, KV, hd)
-    x = x + mm(attention(q, k, v, lowp), p["wo"], lowp)
-    h = rms_norm(x, p["ffn_norm"], dims["norm_eps"])
-    if "router" in p:
-        return x + moe(h, p, dims["experts_per_tok"], lowp)
-    return x + swiglu(h, p["w_gate"], p["w_up"], p["w_down"], lowp)
-
-
-def head(x, final_norm, lm_head, dims, lowp=False):
-    return mm(rms_norm(x, final_norm, dims["norm_eps"]), lm_head, lowp)
-
-
-@functools.lru_cache(maxsize=None)
-def _jitted(dims_items, lowp):
-    dims = dict(dims_items)
-    return (jax.jit(lambda p, x: layer(p, x, dims, lowp)),
-            jax.jit(lambda x, n, w: head(x, n, w, dims, lowp)))
-
-
 def logits(params, tokens, dims, lowp=False):
-    """Float32 logits [T, vocab] of one sequence of tokens, layer by
-    layer."""
-    layer_fn, head_fn = _jitted(tuple(sorted(dims.items())), lowp)
-    x = params["embed"][jnp.asarray(tokens)].astype(F32)
-    for i in range(dims["n_layers"]):
-        x = layer_fn(jax.tree.map(lambda a: a[i], params["layers"]), x)
-    return head_fn(x, params["final_norm"], params["lm_head"])
+    """Float32 logits [T, vocab] of one sequence of tokens: the plain
+    forward of the configuration's family."""
+    return families.load(dims["family"]).logits(params, tokens, dims, lowp)
 
 
 @jax.jit

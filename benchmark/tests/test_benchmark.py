@@ -2,6 +2,7 @@
 proofs the comparison rests on: the lower-precision control comes out
 as not correct, and so does a run whose timed path is broken."""
 
+import glob
 import json
 import os
 import statistics
@@ -33,7 +34,13 @@ def tiny(name, dtype="float32"):
 
 # ---- the reference against the program's forward pass ----
 
-@pytest.mark.parametrize("name", ["tiny-dense", "tiny-moe"])
+# every tiny configuration in the directory: a new family's is tested by
+# arriving
+TINY = sorted(os.path.basename(p)[:-len(".json")] for p in glob.glob(
+    os.path.join(HERE, "cells", "configs", "*.json")))
+
+
+@pytest.mark.parametrize("name", TINY)
 def test_reference_matches_program_forward(name):
     config = tiny(name)
     dims = configs.dims(config)
@@ -47,17 +54,25 @@ def test_reference_matches_program_forward(name):
     assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
 
 
-def test_weights_any_seed_and_leaf_by_leaf():
-    dims = configs.dims(tiny("tiny-moe", "bfloat16"))
+@pytest.mark.parametrize("name", TINY)
+def test_weights_any_seed_and_leaf_by_leaf(name):
+    dims = configs.dims(tiny(name, "bfloat16"))
     key = weights.seed_key(2 ** 31 + 77)
     tree = jax.jit(lambda k: weights.init_params(k, dims))(key)
-    path = ("layers", "w_gate")
-    again = jax.jit(lambda k: weights.make_leaf(k, dims, path))(key)
-    assert tree["layers"]["w_gate"].dtype == jnp.bfloat16
-    assert bool(jnp.array_equal(tree["layers"]["w_gate"], again))
     other = jax.jit(lambda k: weights.init_params(k, dims))(
         weights.seed_key(77))
-    assert not bool(jnp.array_equal(tree["embed"], other["embed"]))
+    drawn = 0
+    for path, (shape, init) in weights.leaf_specs(dims).items():
+        leaf, theirs = tree, other
+        for part in path:
+            leaf, theirs = leaf[part], theirs[part]
+        again = jax.jit(lambda k: weights.make_leaf(k, dims, path))(key)
+        assert leaf.dtype == jnp.bfloat16 and leaf.shape == tuple(shape)
+        assert bool(jnp.array_equal(leaf, again)), path
+        if init is not None:
+            drawn += 1
+            assert not bool(jnp.array_equal(leaf, theirs)), path
+    assert drawn
 
 
 # ---- traffic is a pure function of the seed ----
@@ -146,17 +161,41 @@ def test_driver_end_to_end(cell, metric):
                            "device"}
 
 
-@pytest.mark.parametrize("cell,metric", [
-    ("tiny.train", "train_step.step_ms"),
-    ("tiny-moe.batch", "scheduler.occupancy_pct.batch"),
+@pytest.mark.parametrize("cell,metric,sizes", [
+    ("tiny.train", "train_step.step_ms", {"seq_len", "sequences_per_step"}),
+    ("tiny.chat", "scheduler.queue_wait_p75_ms",
+     {"slots", "max_seq_len", "prefill_chunk"}),
+    ("tiny-moe.batch", "scheduler.occupancy_pct.batch",
+     {"slots", "max_seq_len", "prefill_chunk"}),
 ])
-def test_traced_run_reports_per_layer_metrics(cell, metric):
+def test_traced_run_reports_per_layer_metrics(cell, metric, sizes,
+                                              monkeypatch):
+    from benchmark import harness
+
+    handed = {}
+    real = harness.read_layer_metrics
+
+    def reading(bench, name, moved, run_):
+        handed.update(run_)
+        return real(bench, name, moved, run_)
+
+    monkeypatch.setattr(harness, "read_layer_metrics", reading)
     result = run_tiny(cell, seconds=6.0, trace=1)
     assert result["correct"], result
     assert result["metrics"][metric]["value"] > 0
     assert "setup_s" not in result["metrics"]
     assert result["device"]["window_s"] > 0
     assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    # what a roofline reader divides by, in every kind of run; off the
+    # TPU there is no row of peaks and so no share of one
+    assert sizes | {"dims", "chips", "peak"} <= set(handed)
+    assert handed["dims"]["family"] and handed["peak"] is None
+    assert not any("_roofline" in name for name in result["metrics"])
+    if "slots" in sizes:
+        steps = handed["counters"]["decode_steps"]
+        assert 0 < handed["decode_tokens"] <= steps * handed["slots"]
+        assert handed["decode_tokens"] < handed["kv_positions_read"] \
+            <= handed["decode_tokens"] * handed["max_seq_len"]
 
 
 def test_training_cell_on_four_virtual_devices():

@@ -213,10 +213,13 @@ def test_every_new_metric_has_its_file_and_the_other_way_round():
     files = {f[:-3] for f in os.listdir(
         os.path.join(configs.HERE, "layer_metrics")) if f.endswith(".py")}
     assert files == set(entries)
+    # PR 24's fourteen; a share of a roofline divides one of them by a
+    # cost (benchmark/kernel_costs.py) and has a file of its own kind
     new = {n for n in files
            if n.startswith(("kernels.", "engine.decode_device_ms.",
                             "engine.prefill_chunk_device_ms.",
-                            "scheduler.host_gap_ms_per_iter."))}
+                            "scheduler.host_gap_ms_per_iter."))
+           and "_roofline" not in n}
     assert len(new) == 14
     for name in new:
         with open(os.path.join(configs.HERE, "layer_metrics",

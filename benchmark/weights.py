@@ -1,39 +1,23 @@
 """Weights from the seed, made on the device in the type they are
 served in. The layout (names, stacked layers) is the program's
-interface; the values are the benchmark's: N(0, 1/fan_in) matrices and
-unit norm weights. Each leaf has a key of its own, so the reference can
-make one leaf again without the rest.
+interface and the family's to state (benchmark/families/); the values
+are the benchmark's: N(0, 1/fan_in) matrices, unit norm weights, and
+what a family draws with an initialiser of its own. Each leaf has a key
+of its own, so the reference can make one leaf again without the rest.
 """
 
 import jax
 import jax.numpy as jnp
 
+from . import families
+
 
 def leaf_specs(dims):
-    """{leaf path: (shape, fan_in or None for a norm weight)}; layer
-    leaves carry the leading layer axis."""
-    L, D, F, V = (dims["n_layers"], dims["dim"], dims["ffn_dim"],
-                  dims["vocab_size"])
-    H, KV, Hd = dims["n_heads"], dims["n_kv_heads"], dims["head_dim"]
-    N = dims.get("n_experts", 0)
-    ex = (N,) if N else ()
-    specs = {
-        ("embed",): ((V, D), D),
-        ("layers", "attn_norm"): ((L, D), None),
-        ("layers", "wq"): ((L, D, H * Hd), D),
-        ("layers", "wk"): ((L, D, KV * Hd), D),
-        ("layers", "wv"): ((L, D, KV * Hd), D),
-        ("layers", "wo"): ((L, H * Hd, D), H * Hd),
-        ("layers", "ffn_norm"): ((L, D), None),
-        ("layers", "w_gate"): ((L,) + ex + (D, F), D),
-        ("layers", "w_up"): ((L,) + ex + (D, F), D),
-        ("layers", "w_down"): ((L,) + ex + (F, D), F),
-        ("final_norm",): ((D,), None),
-        ("lm_head",): ((D, V), D),
-    }
-    if N:
-        specs[("layers", "router")] = ((L, D, N), D)
-    return specs
+    """{leaf path: (shape, init)} of the family's tree; a path may open
+    any number of groups, and layer leaves carry their leading layer
+    axis. `init` is a fan-in (N(0, 1/fan_in)), None (ones), or a
+    function (key, shape) -> float32 array of the family's own."""
+    return families.load(dims["family"]).leaf_specs(dims)
 
 
 def seed_key(seed):
@@ -49,10 +33,12 @@ def make_leaf(key, dims, path):
     exists whole."""
     dtype = jnp.dtype(dims["dtype"])
     specs = leaf_specs(dims)
-    shape, fan_in = specs[path]
-    if fan_in is None:
+    shape, init = specs[path]
+    if init is None:
         return jnp.ones(shape, dtype)
     key = jax.random.fold_in(key, sorted(specs).index(path))
+    if callable(init):
+        return init(key, shape).astype(dtype)
     lead = shape[:-2]
     n = 1
     for s in lead:
@@ -60,7 +46,7 @@ def make_leaf(key, dims, path):
 
     def draw(k):
         return (jax.random.normal(k, shape[-2:], jnp.float32)
-                * (fan_in ** -0.5)).astype(dtype)
+                * (init ** -0.5)).astype(dtype)
 
     if not lead:
         return draw(key)
