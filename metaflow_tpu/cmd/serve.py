@@ -23,13 +23,10 @@ def build_config(restored, config_json=None, model="llama"):
     Priority: --config-json (a file path or inline JSON object of
     LlamaConfig/MixtralConfig field overrides) > a 'cfg'/'config' dict
     the checkpoint itself carries. The named `model` family supplies the
-    dataclass."""
-    if model == "mixtral":
-        from ..models.mixtral import MixtralConfig as config_cls
-    elif model == "llama":
-        from ..models.llama import LlamaConfig as config_cls
-    else:
-        raise TpuFlowException("unknown model family %r" % (model,))
+    dataclass (inference/decode.py's table of families)."""
+    from ..inference.decode import family_config_class
+
+    config_cls = family_config_class(model)
     fields = None
     if config_json:
         if os.path.exists(config_json):
@@ -95,13 +92,10 @@ def build_engine(params, cfg, slots=8, max_seq_len=None, prefill_chunk=64,
                            else factory(min(2, len(jax.devices()))))
         # the rule tree must come from the checkpoint's model family: a
         # Mixtral tree has router/expert axes the Llama table lacks
-        from ..models import llama as llama_mod
-        from ..models import mixtral as mixtral_mod
+        from ..inference.decode import family
 
-        model_mod = (mixtral_mod
-                     if isinstance(cfg, mixtral_mod.MixtralConfig)
-                     else llama_mod)
-        params = shard_tree(params, model_mod.logical_axes(cfg), mesh)
+        params = shard_tree(params, family(cfg).module.logical_axes(cfg),
+                            mesh)
     if paged or knobs.get_bool("TPUFLOW_PAGED"):
         return PagedEngine(params, cfg, max_slots=slots,
                            max_seq_len=max_seq_len,
@@ -117,7 +111,18 @@ def build_prefix_cache(engine, prefix_cache_mb=None):
     """The prefix cache matched to the engine: a zero-copy
     PagedPrefixIndex over the paged engine's own pool, a host-side
     RadixPrefixCache otherwise. Same opt-in contract either way:
-    no byte budget (flag or TPUFLOW_PREFIX_CACHE_MB), no cache."""
+    no byte budget (flag or TPUFLOW_PREFIX_CACHE_MB), no cache. A model
+    that carries recurrent state is refused a cache by name: a KV range
+    is not a prefix of it."""
+    from ..serving.engine import refuse_recurrent
+
+    cache = _prefix_cache_for(engine, prefix_cache_mb)
+    if cache is not None:
+        refuse_recurrent(engine.cfg, "a prefix cache")
+    return cache
+
+
+def _prefix_cache_for(engine, prefix_cache_mb):
     from ..serving import PagedPrefixIndex, RadixPrefixCache
 
     pool = getattr(engine, "pool", None)

@@ -1,4 +1,5 @@
-"""Autoregressive decoding with a KV cache (Llama family).
+"""Autoregressive decoding with a KV cache (and, for a model with
+state-space layers, a recurrent state beside it).
 
 The serving-side counterpart of models/llama.py: prefill runs the prompt
 through the stack once and fills a static-shape KV cache; each decode step
@@ -21,12 +22,29 @@ block are rounded to bfloat16 for the product with V, as the training
 kernel rounds them (ops/attention.py). On a v5e the chunked path reads
 its KV chunks at about 70 % of the chip's HBM bandwidth (PERF.md, PR 25).
 
+Model families: `family(cfg)` is the ONE place a family is picked, a
+table keyed by the config's class (the feed-forward half of a block,
+whether attention rotates its queries and keys, the kinds of layer in
+the stack). A stack of one kind (Llama, Mixtral) is one lax.scan over
+`params["layers"]` with the cache as its scanned input and output. A
+stack of several kinds (Jamba: Mamba-1 layers with an attention layer
+every so many) holds one stack per kind and walks them in the model's
+order (models/jamba.py, `scan_layers`) with the whole cache as the
+loop's carry, each layer reading and writing its own index of it: K and
+V for the attention layers only, and for the Mamba layers the
+convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py).
+Unlike a KV position, a recurrent state has no garbage that is
+overwritten before it is seen: `valid` tells the state-space layers
+which of the new positions are real.
+
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
 is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
 `decode_attention`, `attn_out` and `ffn` (ops/moe.py adds `moe_*` inside
-`ffn`). What lies under `decode_layers` and under none of those is the
-scan carrying, slicing and copying the cache.
+`ffn`); inside a Mamba layer `ssm_in_proj`, `ssm_conv`, `ssm_x_proj`,
+`ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`.
+What lies under `decode_layers` and under none of those is the loop
+carrying, slicing and copying the cache.
 
 Sharding: the cache carries the same logical axes as activations
 ([layers, batch, seq, kv_heads, head_dim]) — under a mesh, batch rides
@@ -35,26 +53,117 @@ with the exact rule table training uses (spmd/sharding.py); XLA keeps the
 per-step all-gathers on ICI.
 """
 
+import collections
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
 
 from .. import knobs
-from ..models import llama
+from ..exception import TpuFlowException
+from ..models import jamba, llama, mixtral
 from ..ops import rms_norm
 from ..ops.attention import NEG_INF
+from ..ops.moe import moe_ffn
 from ..ops.rope import apply_rope, rope_frequencies
+
+# name: what `tpuflow serve --model` takes; module: init_params,
+# logical_axes, forward; ffn: the feed-forward half of a block,
+# (cfg, h, lp, mesh) -> the residual's addend; rope: whether attention
+# rotates q and k. The kinds of layer come from the config
+# (`layer_kinds`; a config without it is attention throughout).
+Family = collections.namedtuple("Family", "name module ffn rope")
+
+
+def _dense_ffn(cfg, h, lp, mesh):
+    return (jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])) @ lp["w_down"]
+
+
+def _moe_ffn(cfg, h, lp, mesh):
+    """Mixtral: token-choice MoE FFN."""
+    dispatch = getattr(cfg, "moe_dispatch", "sparse")
+    if dispatch in ("gmm", "gmm_ep"):
+        # gmm's block-aligned padding is sized for training batches;
+        # a per-token decode step would pad ~8 rows to experts×128.
+        # sparse with no capacity is lossless — identical outputs.
+        dispatch = "sparse"
+    moe_out, _aux = moe_ffn(
+        h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+        num_experts_per_tok=cfg.experts_per_tok,
+        capacity_factor=None,  # decode batches are tiny: lossless
+        dispatch=dispatch,
+        mesh=mesh,
+    )
+    return moe_out
+
+
+FAMILIES = {
+    llama.LlamaConfig: Family("llama", llama, _dense_ffn, True),
+    mixtral.MixtralConfig: Family("mixtral", mixtral, _moe_ffn, True),
+    jamba.JambaConfig: Family("jamba", jamba, _dense_ffn, False),
+}
+
+
+def family(cfg):
+    """The family of a model config: the one place it is picked."""
+    try:
+        return FAMILIES[type(cfg)]
+    except KeyError:
+        raise TpuFlowException(
+            "no model family for a %s (families: %s)" % (
+                type(cfg).__name__,
+                ", ".join(sorted(f.name for f in FAMILIES.values()))))
+
+
+def family_config_class(name):
+    """The config dataclass of the family `--model` names."""
+    for config_cls, fam in FAMILIES.items():
+        if fam.name == name:
+            return config_cls
+    raise TpuFlowException("unknown model family %r (families: %s)" % (
+        name, ", ".join(sorted(f.name for f in FAMILIES.values()))))
+
+
+def layer_kinds(cfg):
+    """The kind of every layer in the model's order: "attention" (K and
+    V cached) or "mamba" (a convolution tail and a state carried)."""
+    return getattr(cfg, "layer_kinds", None) or ("attention",) * cfg.n_layers
+
+
+def is_recurrent(cfg):
+    """Whether some layer carries a state that a KV range does not
+    hold: such a model's prefix is not its cached K and V."""
+    return "mamba" in layer_kinds(cfg)
 
 
 def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
-    """Static [layers, batch, max_seq, kv_heads, head_dim] cache pair."""
+    """The static cache, one tree: `k` and `v`
+    [attention layers, batch, max_seq, kv_heads, head_dim]
+    ([.., kv_heads * head_dim] beside Mamba layers), and where the model
+    has Mamba layers `conv` [mamba layers, batch, d_conv-1, d_inner] (the
+    convolution's tail) and `ssm` [mamba layers, batch, d_state, d_inner]
+    in float32. Every leaf has the batch on axis 1."""
     dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
-    shape = (cfg.n_layers, batch_size, max_seq_len, cfg.n_kv_heads,
-             cfg.head_dim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    kinds = layer_kinds(cfg)
+    shape = (kinds.count("attention"), batch_size, max_seq_len,
+             cfg.n_kv_heads, cfg.head_dim)
+    n_mamba = kinds.count("mamba")
+    if n_mamba:
+        # a stack of several kinds reads and writes its pools a layer at
+        # a time in place (`_decode_layer`, `layer`): heads and head size
+        # are folded into one minor axis, so that a single KV head
+        # (multi-query) leaves no axis of 1 for the chip's tiling to pad
+        # or to lay out anew on the way in and out
+        shape = shape[:3] + (cfg.n_kv_heads * cfg.head_dim,)
+    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if n_mamba:
+        cache["conv"] = jnp.zeros(
+            (n_mamba, batch_size, cfg.mamba_d_conv - 1, cfg.d_inner), dt)
+        cache["ssm"] = jnp.zeros(
+            (n_mamba, batch_size, cfg.mamba_d_state, cfg.d_inner),
+            jnp.float32)
+    return cache
 
 
 def _query_positions(pos, T):
@@ -182,7 +291,8 @@ def _streamed_attention(q, pos, chunk, n_chunks, fetch):
 
 
 @jax.named_scope("decode_attention")
-def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
+def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK,
+                              layer=None):
     """Flash-decode: the same attention reading ONLY the filled prefix.
 
     KV chunks stream through an online-softmax accumulation
@@ -192,9 +302,13 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
     follow the dense path: the same grouped products accumulated in
     float32, the same masking, probabilities rounded to V's dtype per
     chunk instead of once; the edge chunk's clamped slice re-reads
-    earlier keys, masked out by the `key >= chunk start` term."""
+    earlier keys, masked out by the `key >= chunk start` term.
+
+    With `layer` (a traced index) cache_k/v are the pools of every
+    attention layer, [layers, B, Smax, KV * Hd], and the chunks are read
+    out of that layer of them, so the layer's view is never copied."""
     T = q.shape[1]
-    Smax = cache_k.shape[1]
+    Smax = cache_k.shape[1 if layer is None else 2]
     chunk = min(chunk, Smax)
     # traced trip count; with per-slot [B] positions the loop runs to the
     # DEEPEST slot's fill (shallower slots just mask the extra chunks)
@@ -202,8 +316,16 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
 
     def fetch(i):
         start = jnp.minimum(i * chunk, Smax - chunk)
-        k_blk = jax.lax.dynamic_slice_in_dim(cache_k, start, chunk, 1)
-        v_blk = jax.lax.dynamic_slice_in_dim(cache_v, start, chunk, 1)
+        if layer is None:
+            k_blk = jax.lax.dynamic_slice_in_dim(cache_k, start, chunk, 1)
+            v_blk = jax.lax.dynamic_slice_in_dim(cache_v, start, chunk, 1)
+        else:
+            B, Hd = q.shape[0], q.shape[3]
+            at, size = (layer, 0, start, 0), (1, B, chunk, cache_k.shape[3])
+            k_blk = jax.lax.dynamic_slice(cache_k, at, size).reshape(
+                B, chunk, -1, Hd)
+            v_blk = jax.lax.dynamic_slice(cache_v, at, size).reshape(
+                B, chunk, -1, Hd)
         return k_blk, v_blk, start + jnp.arange(chunk)
 
     return _streamed_attention(q, pos, chunk, n_chunks, fetch)
@@ -212,7 +334,8 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK):
 @jax.named_scope("attn_qkv")
 def _attn_qkv(cfg, cos, sin, pos, x, lp):
     """The pre-attention half of a block: attn-norm, QKV projections and
-    rope at the absolute positions `pos` implies. Shared verbatim by the
+    (where the family rotates them) rope at the absolute positions `pos`
+    implies; without rope `cos` and `sin` are None. Shared verbatim by the
     contiguous-cache layer below and the paged-cache layer
     (serving/paged.py) so both paths stay numerically identical."""
     B, T, _ = x.shape
@@ -221,16 +344,17 @@ def _attn_qkv(cfg, cos, sin, pos, x, lp):
     q = (h @ lp["wq"]).reshape(B, T, H, Hd)
     k = (h @ lp["wk"]).reshape(B, T, KV, Hd)
     v = (h @ lp["wv"]).reshape(B, T, KV, Hd)
-    positions = _query_positions(pos, T)
-    q = apply_rope(q, cos, sin, positions=positions)
-    k = apply_rope(k, cos, sin, positions=positions)
+    if cos is not None:
+        positions = _query_positions(pos, T)
+        q = apply_rope(q, cos, sin, positions=positions)
+        k = apply_rope(k, cos, sin, positions=positions)
     return q, k, v
 
 
 def _block_ffn(cfg, x, attn, lp, mesh=None):
     """The post-attention half of a block: output projection, residual,
-    and the dense (Llama) or MoE (Mixtral) FFN picked off the parameter
-    tree. Shared by the contiguous and paged cache paths."""
+    and the family's feed-forward (dense or MoE). Shared by the
+    contiguous and paged cache paths."""
     B, T, _ = x.shape
     with jax.named_scope("attn_out"):
         x = x + attn.reshape(B, T, cfg.n_heads * cfg.head_dim) @ lp["wo"]
@@ -240,40 +364,25 @@ def _block_ffn(cfg, x, attn, lp, mesh=None):
 @jax.named_scope("ffn")
 def _ffn(cfg, x, lp, mesh):
     h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
-    if "router" in lp:  # Mixtral: token-choice MoE FFN
-        from ..ops.moe import moe_ffn
-
-        dispatch = getattr(cfg, "moe_dispatch", "sparse")
-        if dispatch in ("gmm", "gmm_ep"):
-            # gmm's block-aligned padding is sized for training batches;
-            # a per-token decode step would pad ~8 rows to experts×128.
-            # sparse with no capacity is lossless — identical outputs.
-            dispatch = "sparse"
-        moe_out, _aux = moe_ffn(
-            h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            num_experts_per_tok=cfg.experts_per_tok,
-            capacity_factor=None,  # decode batches are tiny: lossless
-            dispatch=dispatch,
-            mesh=mesh,
-        )
-        x = x + moe_out
-    else:
-        gate = jax.nn.silu(h @ lp["w_gate"])
-        up = h @ lp["w_up"]
-        x = x + (gate * up) @ lp["w_down"]
-    return x
+    return x + family(cfg).ffn(cfg, h, lp, mesh)
 
 
 def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
-                  mesh=None, attn_impl="dense"):
-    """One block over T new tokens, reading+extending this layer's cache.
-    Dense (Llama) or MoE (Mixtral) FFN is picked off the parameter tree —
-    the attention/cache half is identical."""
+                  mesh=None, attn_impl="dense", layer=None):
+    """One attention block over T new tokens, reading+extending this
+    layer's cache: [B, Smax, KV, Hd], or with `layer` (a traced index)
+    that layer of the pools [layers, B, Smax, KV * Hd], written and read
+    in place. The feed-forward half is the family's."""
     lp = layer_params
     q, k, v = _attn_qkv(cfg, cos, sin, pos, x, lp)
 
     with jax.named_scope("kv_cache_update"):
-        if jnp.ndim(pos) == 0:
+        if layer is not None:
+            cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos,
+                                   layer)
+            cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos,
+                                   layer)
+        elif jnp.ndim(pos) == 0:
             cache_k = jax.lax.dynamic_update_slice_in_dim(
                 cache_k, k.astype(cache_k.dtype), pos, axis=1)
             cache_v = jax.lax.dynamic_update_slice_in_dim(
@@ -288,45 +397,120 @@ def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
             cache_v = _write(cache_v, v.astype(cache_v.dtype), pos)
 
     if attn_impl == "chunked":
-        attn = _chunked_cached_attention(q, cache_k, cache_v, pos)
+        attn = _chunked_cached_attention(q, cache_k, cache_v, pos,
+                                         layer=layer)
+    elif layer is not None:
+        view = lambda pool: pool[layer].reshape(
+            pool.shape[1:3] + (cfg.n_kv_heads, cfg.head_dim))
+        attn = _cached_attention(q, view(cache_k), view(cache_v), pos)
     else:
         attn = _cached_attention(q, cache_k, cache_v, pos)
     x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
     return x, cache_k, cache_v
 
 
+def _write_layer(pool, new, pos, layer):
+    """new [B, T, KV, Hd] into pool [layers, B, Smax, KV * Hd] at `layer`,
+    every batch row at its own cursor (or all at a scalar `pos`)."""
+    new = new.reshape(new.shape[:2] + (-1,))
+    if jnp.ndim(pos) == 0:
+        return jax.lax.dynamic_update_slice(
+            pool, new[None], (layer, 0, pos, 0))
+    B, T = new.shape[:2]
+    return pool.at[layer, jnp.arange(B)[:, None],
+                   pos[:, None] + jnp.arange(T)[None]].set(
+                       new, mode="promise_in_bounds")
+
+
+def _mamba_layer(cfg, x, lp, conv, state, valid):
+    """One Mamba block over T new tokens from this layer's carried
+    (conv [B, K-1, Di], state [B, N, Di])."""
+    out, conv, state = jamba.mamba_mixer(
+        cfg, lp, rms_norm(x, lp["ssm_norm"], cfg.norm_eps), conv, state,
+        valid)
+    return _ffn(cfg, x + out, lp, None), conv, state
+
+
+def _mixed_layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
+    """A stack of several kinds: the whole cache is the loop's carry and
+    each layer reads and writes its own index of it."""
+    def body(kind, i, carry):
+        x, cache = carry
+        cache = dict(cache)
+        if kind == "attention":
+            x, cache["k"], cache["v"] = _decode_layer(
+                cfg, None, None, pos, x,
+                jamba.layer_at(params["attn_layers"], i), cache["k"],
+                cache["v"], mesh=mesh, attn_impl=attn_impl, layer=i)
+            return x, cache
+        # the layer's tail and state are read out of the pools and written
+        # back under the scope of the op that uses them, so that a scope's
+        # device time holds the pool's traffic that its kernel needs
+        conv_scope = jax.named_scope("ssm_conv")
+        state_scope = jax.named_scope(
+            "ssm_state_update" if x.shape[1] == 1 else "ssm_scan")
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
+        with conv_scope:
+            conv = at(cache["conv"])
+        with state_scope:
+            state = at(cache["ssm"])
+        x, conv, state = _mamba_layer(
+            cfg, x, jamba.layer_at(params["mamba_layers"], i), conv, state,
+            valid)
+        put = jax.lax.dynamic_update_index_in_dim
+        with conv_scope:
+            cache["conv"] = put(cache["conv"], conv, i, 0)
+        with state_scope:
+            cache["ssm"] = put(cache["ssm"], state, i, 0)
+        return x, cache
+
+    return jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
+
+
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
-                   attn_impl="dense"):
+                   attn_impl="dense", valid=None):
     """Forward over T new tokens at absolute position `pos` (a traced
     scalar, or a traced [B] vector when every batch row decodes at its
     own offset — the continuous-batching engine), reading and extending
-    the cache. Works for any model in the Llama family layout (Llama
-    dense FFN, Mixtral MoE FFN).
+    the cache. Works for every family of `FAMILIES`.
 
     tokens: [B, T] (T static: the prompt length for prefill, 1 per decode
-    step). Returns (logits [B, T, vocab] fp32, updated cache)."""
+    step). valid: None (every position is real) or a [B, T] mask whose
+    true positions lead each row: a recurrent state (`conv`, `ssm`)
+    passes through the positions that are not valid. K and V need no
+    mask (what is written there is overwritten before it is seen).
+    Returns (logits [B, T, vocab] fp32, updated cache)."""
     dt = llama.param_dtype(cfg)
-    max_seq = cache["k"].shape[2]
     x = params["embed"][tokens].astype(dt)
-    cos, sin = rope_frequencies(
-        cfg.head_dim, max_seq, cfg.rope_theta, dtype=dt,
-        llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
-    )
+    if is_recurrent(cfg):
+        with jax.named_scope("decode_layers"):
+            x, cache = _mixed_layers(cfg, params, x, cache, pos, valid,
+                                     mesh, attn_impl)
+    else:
+        cos, sin = rope_frequencies(
+            cfg.head_dim, cache["k"].shape[2], cfg.rope_theta, dtype=dt,
+            llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
+        ) if family(cfg).rope else (None, None)
 
-    def layer_fn(carry, inp):
-        lp, ck, cv = inp
-        out, nk, nv = _decode_layer(cfg, cos, sin, pos, carry, lp, ck, cv,
-                                    mesh=mesh, attn_impl=attn_impl)
-        return out, (nk, nv)
+        def layer_fn(carry, inp):
+            lp, ck, cv = inp
+            out, nk, nv = _decode_layer(cfg, cos, sin, pos, carry, lp, ck,
+                                        cv, mesh=mesh, attn_impl=attn_impl)
+            return out, (nk, nv)
 
-    with jax.named_scope("decode_layers"):
-        x, (new_k, new_v) = jax.lax.scan(
-            layer_fn, x, (params["layers"], cache["k"], cache["v"])
-        )
+        with jax.named_scope("decode_layers"):
+            x, (new_k, new_v) = jax.lax.scan(
+                layer_fn, x, (params["layers"], cache["k"], cache["v"])
+            )
+        cache = {"k": new_k, "v": new_v}
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, {"k": new_k, "v": new_v}
+    if "lm_head" in params:
+        logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
+                            preferred_element_type=jnp.float32)
+    else:   # a head tied to the embedding
+        logits = jnp.einsum("btd,vd->btv", x, params["embed"],
+                            preferred_element_type=jnp.float32)
+    return logits, cache
 
 
 def _sample(logits, temperature, rng, top_k=None, top_p=None):
@@ -382,7 +566,8 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
     (the pad-to-bucket serving path): prefill runs over the padded
     length, the first token samples from the logits at prompt_len - 1,
     and decode starts writing at prompt_len — causal masking keeps the
-    pad positions invisible until they are overwritten, so the output is
+    pad positions invisible until they are overwritten (and a recurrent
+    state is held over them, decode_forward's `valid`), so the output is
     token-identical to the unpadded call. Positions [prompt_len, P) of
     the returned array still hold the pad ids (callers slice them out).
     """
@@ -407,8 +592,11 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
         attn_impl = ("chunked" if cache["k"].shape[2] > 2 * DECODE_CHUNK
                      else "dense")
 
+    valid = None if prompt_len is None else jnp.broadcast_to(
+        jnp.arange(P) < prompt_len, (B, P))
     logits, cache = decode_forward(params, prompt_tokens, cache, 0, cfg,
-                                   mesh=mesh, attn_impl=attn_impl)
+                                   mesh=mesh, attn_impl=attn_impl,
+                                   valid=valid)
     if prompt_len is None:
         last = logits[:, -1]
         start_pos = jnp.int32(P)

@@ -1,6 +1,7 @@
 from . import dit
+from . import jamba
 from . import llama
 from . import mixtral
 from . import resnet
 
-__all__ = ["dit", "llama", "mixtral", "resnet"]
+__all__ = ["dit", "jamba", "llama", "mixtral", "resnet"]

@@ -21,6 +21,11 @@ metadata (the original request payload, the first sampled token). Raw
 buffers rather than npz because the KV dtype may be bfloat16
 (ml_dtypes), which numpy's save path does not round-trip reliably.
 
+A model that carries recurrent state (state-space layers) has no such
+handoff yet: its K and V are not the whole of what a prefill leaves, and
+the scheduler refuses its prefill_only / prefilled requests by name
+(engine.refuse_recurrent).
+
 Identity: the handed-off KV is bitwise what the decode replica's own
 prefill would have written, and the decode side resumes the request's
 rng key schedule at cursor 1, so the disaggregated path emits exactly

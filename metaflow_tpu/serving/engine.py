@@ -31,6 +31,21 @@ their own cursor and are overwritten (prefill rewrites the range, decode
 overwrites pad garbage exactly one position before it would become
 visible), so no flag tensor is needed inside the compiled program.
 
+A model with state-space layers (Jamba) keeps a RECURRENT-STATE POOL
+beside the KV pool, in the same cache tree: per Mamba layer and slot the
+convolution's tail and the float32 state (inference/decode.py,
+init_kv_cache). None of the three invariants above holds for a
+recurrence, which has no garbage that is overwritten before it is seen,
+so for such a model: (a) prefill_step tells the program how many of the
+bucket's positions are real, and the state after a chunk is the state
+after its last real token; (b) the fused decode step holds the state of
+every lane whose mask is false; (c) admit zeroes the slot's state (one
+small jitted program, host span `engine.state.reset`); (d) the state
+carries from chunk to chunk of one prompt in the pool. A KV range is not
+a prefix of such a model: seed_prefix, extract_kv, admit_prefilled and
+kv_token_bytes refuse it (`refuse_recurrent`), as do the prefix caches,
+the paged engine and the disaggregated handoff built on them.
+
 Token identity with generate(): same forward, same sampling ops (the
 per-slot sampler reproduces decode._sample row-for-row), same rng policy
 (request_step_keys mirrors generate's split sequence), so a request
@@ -45,11 +60,14 @@ import jax
 import jax.numpy as jnp
 
 from .. import telemetry
+from ..exception import TpuFlowException
 from ..inference.decode import (
     DECODE_CHUNK,
     bucket_length,
     decode_forward,
+    family,
     init_kv_cache,
+    is_recurrent,
 )
 from ..ops.attention import NEG_INF
 
@@ -66,6 +84,18 @@ def request_step_keys(rng, max_new_tokens):
         return np.concatenate(
             [np.asarray(first)[None], np.asarray(rest)], axis=0)
     return np.asarray(first)[None]
+
+
+def refuse_recurrent(cfg, what):
+    """Raise for `what`, which treats a KV range as a prefix, where the
+    model also carries recurrent state: the K and V of the positions
+    before a cut say nothing of a state-space layer's state there."""
+    if is_recurrent(cfg):
+        raise TpuFlowException(
+            "%s is not supported for a %s model: its state-space layers "
+            "carry recurrent state (a convolution tail and a state per "
+            "layer and slot) beside the KV cache, and a KV range is not a "
+            "prefix of it" % (what, family(cfg).name))
 
 
 def sample_slots(logits, keys, temperature, top_k, top_p):
@@ -138,6 +168,7 @@ class SlotEngine(object):
 
         self._cache = init_kv_cache(cfg, self.max_slots, self.max_seq_len,
                                     dtype=cache_dtype)
+        self.recurrent = is_recurrent(cfg)
         B = self.max_slots
         # host-side per-slot state
         self.pos = np.zeros(B, np.int32)          # next cache write index
@@ -161,14 +192,19 @@ class SlotEngine(object):
         self._d_tok = self._d_pos = self._d_mask = None
         self._d_temp = self._d_top_k = self._d_top_p = None
 
-        def _prefill(params, cache, chunk_tokens, slot, start):
+        def _prefill(params, cache, chunk_tokens, slot, start, n_real=None):
+            # n_real: how many of the chunk's positions are the prompt's
+            # (the rest pad it to its bucket); None = all of them. Only a
+            # recurrent state needs it.
             sub = {
                 name: jax.lax.dynamic_slice_in_dim(arr, slot, 1, axis=1)
                 for name, arr in cache.items()
             }
+            valid = None if n_real is None else (
+                jnp.arange(chunk_tokens.shape[1]) < n_real)[None]
             logits, sub = decode_forward(
                 params, chunk_tokens, sub, start, cfg, mesh=mesh,
-                attn_impl=self.attn_impl)
+                attn_impl=self.attn_impl, valid=valid)
             cache = {
                 name: jax.lax.dynamic_update_slice_in_dim(
                     cache[name], sub[name], slot, axis=1)
@@ -188,7 +224,7 @@ class SlotEngine(object):
                             top_k, top_p):
             logits, cache = decode_forward(
                 params, tok[:, None], cache, pos, cfg, mesh=mesh,
-                attn_impl=self.attn_impl)
+                attn_impl=self.attn_impl, valid=mask[:, None])
             nxt = sample_slots(logits[:, 0], keys, temp, top_k, top_p)
             tok, pos = _advance(nxt, tok, pos, mask)
             return nxt, tok, pos, cache
@@ -199,7 +235,7 @@ class SlotEngine(object):
             # tiny forward on CPU; greedy traffic must not pay it
             logits, cache = decode_forward(
                 params, tok[:, None], cache, pos, cfg, mesh=mesh,
-                attn_impl=self.attn_impl)
+                attn_impl=self.attn_impl, valid=mask[:, None])
             nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
             tok, pos = _advance(nxt, tok, pos, mask)
             return nxt, tok, pos, cache
@@ -231,6 +267,17 @@ class SlotEngine(object):
                 cache["v"], (0, slot, 0, 0, 0), (L, 1, T, KV, HD))
             return k[:, 0], v[:, 0]
 
+        def _reset_state(cache, slot):
+            # a new occupant starts from an empty recurrent state; its K
+            # and V need no reset (overwritten before they are seen)
+            cache = dict(cache)
+            for name in ("conv", "ssm"):
+                arr = cache[name]
+                cache[name] = jax.lax.dynamic_update_slice_in_dim(
+                    arr, jnp.zeros(arr.shape[:1] + (1,) + arr.shape[2:],
+                                   arr.dtype), slot, axis=1)
+            return cache
+
         # the cache is donated: the pool's KV state is the single largest
         # buffer and every call replaces it wholesale
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
@@ -240,6 +287,7 @@ class SlotEngine(object):
                                          donate_argnums=(1,))
         self._first_fn = jax.jit(_first_token)
         self._seed_fn = jax.jit(_seed, donate_argnums=(0,))
+        self._reset_state_fn = jax.jit(_reset_state, donate_argnums=(0,))
         # no donation: the pool cache must survive an extraction
         self._extract_fn = jax.jit(_extract, static_argnums=(2,))
 
@@ -272,11 +320,13 @@ class SlotEngine(object):
             "first_token": self._first_fn._cache_size(),
             "seed_prefix": self._seed_fn._cache_size(),
             "extract_kv": self._extract_fn._cache_size(),
+            "reset_state": self._reset_state_fn._cache_size(),
         }
 
     def kv_token_bytes(self):
         """Host bytes one cached token costs (k + v across layers) —
         the unit the prefix-cache byte budget is denominated in."""
+        refuse_recurrent(self.cfg, "kv_token_bytes (a prefix cache's unit)")
         k = self._cache["k"]
         layers, _, _, kv_heads, head_dim = k.shape
         return 2 * layers * kv_heads * head_dim * k.dtype.itemsize
@@ -309,6 +359,10 @@ class SlotEngine(object):
         self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
         self._key_cursor[slot] = 0
         self._dirty = True
+        if self.recurrent:
+            with telemetry.annotate("engine.state.reset", slot=int(slot)):
+                self._cache = self._reset_state_fn(self._cache,
+                                                   jnp.int32(slot))
 
     def bind_slot_context(self, slot, ctx):
         """Attach the occupant's identity/trace context ({"request_id",
@@ -337,6 +391,7 @@ class SlotEngine(object):
         before it becomes visible — by the resumed prefill chunks up to
         the prompt end, and by the decode-step write at pos beyond it —
         the same invariant masked lanes already rely on."""
+        refuse_recurrent(self.cfg, "seed_prefix (prefix-cache reuse)")
         if not self.active[slot] or self.decoding[slot]:
             raise ValueError("slot %d is not prefilling" % slot)
         if int(self._prefill_cursor[slot]) != 0:
@@ -368,6 +423,8 @@ class SlotEngine(object):
         prefix-cache insert / disaggregation handoff read path. The
         device slice uses a power-of-two bucket (static shape, bounded
         compiles) and trims on host."""
+        refuse_recurrent(self.cfg, "extract_kv (prefix-cache insert, "
+                         "disaggregated handoff)")
         if length < 1 or length > self.max_seq_len:
             raise ValueError("length %d out of range" % length)
         bucket = bucket_length(length, minimum=self.min_bucket,
@@ -385,6 +442,8 @@ class SlotEngine(object):
         the same (prompt, knobs, rng), the continued decode emits
         exactly the tokens a local prefill would — the key schedule
         resumes at cursor 1, mirroring prefill_step's final chunk."""
+        refuse_recurrent(self.cfg, "admit_prefilled (disaggregated "
+                         "handoff)")
         self.admit(slot, prompt_tokens, max_new_tokens,
                    temperature=temperature, top_k=top_k, top_p=top_p,
                    rng=rng)
@@ -413,7 +472,8 @@ class SlotEngine(object):
 
     def release(self, slot):
         """Reclaim a slot immediately; the stale cache contents stay and
-        are overwritten by the next occupant's prefill."""
+        are overwritten by the next occupant's prefill (a recurrent
+        state is zeroed when the next occupant is admitted)."""
         self.active[slot] = False
         self._slot_ctx[slot] = None
         self.decoding[slot] = False
@@ -449,10 +509,12 @@ class SlotEngine(object):
         if bucket > chunk.size:
             chunk = np.concatenate([
                 chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
+        args = (jnp.asarray(chunk)[None], jnp.int32(slot), jnp.int32(start))
+        if self.recurrent:   # only a recurrent state asks which are real
+            args += (jnp.int32(end - start),)
         with telemetry.annotate("engine.prefill.dispatch"):
-            logits, self._cache = self._prefill_fn(
-                self.params, self._cache, jnp.asarray(chunk)[None],
-                jnp.int32(slot), jnp.int32(start))
+            logits, self._cache = self._prefill_fn(self.params, self._cache,
+                                                   *args)
         self._prefill_cursor[slot] = end
         # keep pos at the prefill cursor: a mid-prefill slot rides
         # through fused decode steps as a masked lane whose write lands
@@ -488,9 +550,9 @@ class SlotEngine(object):
     def decode_step(self):
         """One fused decode step over the WHOLE pool. Returns a dict
         {slot: token} for slots in the decode state; other slots ride
-        through as masked lanes (their writes are overwritten before
-        becoming visible). Advances pos/key cursors for decoding slots
-        only.
+        through as masked lanes (their KV writes are overwritten before
+        becoming visible, their recurrent state is held). Advances
+        pos/key cursors for decoding slots only.
 
         Steady state stays on device: tok/pos flow out of one jitted call
         and back into the next; only the per-step sampling keys upload

@@ -69,7 +69,7 @@ from ..inference.decode import (
 from ..models import llama
 from ..ops import rms_norm
 from ..ops.rope import rope_frequencies
-from .engine import request_step_keys, sample_slots
+from .engine import refuse_recurrent, request_step_keys, sample_slots
 
 DEFAULT_PAGE_TOKENS = 16
 
@@ -309,6 +309,8 @@ class PagedEngine(object):
         if attn_impl not in ("auto", "dense", "chunked"):
             raise ValueError("attn_impl must be 'auto', 'dense' or "
                              "'chunked', got %r" % (attn_impl,))
+        # pages hold K and V only: no page table for a recurrent state
+        refuse_recurrent(cfg, "the paged engine")
         self.params = params
         self.cfg = cfg
         self.max_slots = int(max_slots)

@@ -42,6 +42,7 @@ from collections import deque
 
 from .. import knobs, telemetry
 from .. import tracing
+from .engine import refuse_recurrent
 from .paged import PageExhaustedError
 from .tenancy import TenancyConfig, TenantQueues, TokenBudgets
 
@@ -181,6 +182,8 @@ class Scheduler(object):
         # optional RadixPrefixCache: admit seeds the longest cached
         # prefix into the slot, prefill resumes at the boundary, and a
         # finished prefill inserts the slot's KV back for the next hit
+        if prefix_cache is not None:
+            refuse_recurrent(engine.cfg, "a prefix cache")
         self.prefix_cache = prefix_cache
         self.prefix_hits = 0
         self.prefix_misses = 0
@@ -241,6 +244,9 @@ class Scheduler(object):
         failing after it reaches a slot)."""
         import queue as _q
 
+        if request.prefill_only or request.prefilled is not None:
+            refuse_recurrent(self.engine.cfg, "disaggregated prefill/decode "
+                             "(the KV handoff of serving/disagg.py)")
         fits = getattr(self.engine, "fits", None)
         if fits is not None and not fits(len(request.tokens),
                                          request.max_new_tokens):

@@ -1,0 +1,455 @@
+"""Jamba (Mamba-1 layers with attention layers among them) through the
+slot engine: parity with the plain reference, and the five properties a
+recurrent-state pool needs that a KV pool got for free: a padded chunk
+leaves the state after its last real token, masked lanes hold their
+state, a new occupant starts from an empty state, the state carries
+from chunk to chunk, and what treats a KV range as a prefix refuses the
+model by name. Tiny sizes, float32 unless said."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import configs, reference
+from benchmark.families import jamba as ref_family
+from metaflow_tpu.cmd.serve import build_config, build_engine, \
+    build_prefix_cache
+from metaflow_tpu.exception import TpuFlowException
+from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
+from metaflow_tpu.inference.decode import family, is_recurrent, layer_kinds
+from metaflow_tpu.models import jamba, llama, mixtral
+from metaflow_tpu.ops import ssm
+from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \
+    Scheduler, SlotEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG = jamba.JambaConfig.tiny()   # hidden 64, 8 layers, attention at 2 and 6
+# the reference's sizes of the same tiny model, from the benchmark's file
+DIMS = dict(configs.dims(dict(configs.read_json(os.path.join(
+    ROOT, "benchmark", "tests", "cells", "configs", "tiny-jamba.json")),
+    torch_dtype="float32")))
+NEW = 8
+LENGTHS = (5, 16, 37, 50)   # a padded chunk, a whole one, 2 + a padded, 3 + 2
+
+
+def prompt(n, salt=0):
+    return ((np.arange(n) * 37 + 11 + 5 * salt) % 255 + 1).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def params():
+    p = jamba.init_params(jax.random.PRNGKey(0), CFG)
+    # a drawn convolution bias, so that an empty tail is not a fixed point
+    p["mamba_layers"]["conv_b"] = 0.5 * jax.random.normal(
+        jax.random.PRNGKey(1), p["mamba_layers"]["conv_b"].shape)
+    return p
+
+
+@pytest.fixture(scope="module")
+def engine(params):
+    """Three slots, chunks of 16; every test leaves its slots released."""
+    return SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
+                      prefill_chunk=16)
+
+
+@pytest.fixture(scope="module")
+def alone(params):
+    """The tokens a prompt emits alone, unpadded, in one lockstep call
+    (one compile a prompt length: the tests share four, LENGTHS)."""
+    run = jax.jit(lambda p, toks: generate(p, toks, CFG, NEW,
+                                           max_seq_len=128))
+
+    def tokens(p):
+        assert len(p) in LENGTHS
+        return np.asarray(run(params, jnp.asarray(p)[None])[0, len(p):]
+                          ).tolist()
+    return tokens
+
+
+def prefill(eng, slot):
+    first = None
+    while first is None:
+        _, first = eng.prefill_step(slot)
+    return first
+
+
+def serve(eng, slot, p, n=NEW):
+    """One request alone in `slot`, to its end; the slot is released."""
+    eng.admit(slot, p, n)
+    out = [prefill(eng, slot)]
+    while len(out) < n:
+        out.append(eng.decode_step()[slot])
+    eng.release(slot)
+    return out
+
+
+# ---- parity with the plain reference ----
+
+def test_forward_matches_the_reference(params):
+    tokens = prompt(48)
+    want = reference.logits(params, tokens, DIMS)
+    got = jamba.forward(params, jnp.asarray(tokens)[None], CFG)[0]
+    # float32 on both sides: rounding only
+    assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("chunks", [(16, 16, 8), (7, 33)])
+def test_chunks_then_steps_through_the_cache_match_the_reference(params,
+                                                                 chunks):
+    """Prefill in chunks, then a token at a time, each row at its own
+    cursor: the logits are the reference's full forward pass. Float32 on
+    both sides, so the tolerance is rounding: 1e-4 of the largest logit."""
+    tokens = np.stack([prompt(48), prompt(48, salt=3)])
+    want = jnp.stack([reference.logits(params, t, DIMS) for t in tokens])
+    cache = init_kv_cache(CFG, 2, 64)
+    run = jax.jit(lambda toks, cache, pos: decode_forward(
+        params, toks, cache, pos, CFG))
+    got, at = [], 0
+    for n in chunks:
+        logits, cache = run(jnp.asarray(tokens[:, at:at + n]), cache, at)
+        got.append(logits)
+        at += n
+    for t in range(at, 48):
+        logits, cache = run(jnp.asarray(tokens[:, t:t + 1]), cache,
+                            jnp.full((2,), t))
+        got.append(logits)
+    got = jnp.concatenate(got, axis=1)
+    assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("dtype,limit", [
+    # rounding only: a served token is the reference's best, or ties it
+    ("float32", 1e-4),
+    # bfloat16 keeps 8 bits: logits of size 2-4 carry errors of a few
+    # hundredths, so a served token may lie that far below the best
+    ("bfloat16", 0.15)])
+def test_engine_serves_the_references_tokens(params, dtype, limit):
+    cfg = jamba.JambaConfig.tiny(dtype=dtype)
+    cast = jax.tree.map(lambda a: a.astype(dtype), params)
+    eng = SlotEngine(cast, cfg, max_slots=2, max_seq_len=128,
+                     prefill_chunk=16)
+    p = prompt(37)
+    served = serve(eng, 1, p, n=12)
+    gaps = reference.served_gaps(cast, p.tolist(), served,
+                                 dict(DIMS, dtype=dtype), pad_to=64)
+    assert gaps.shape == (12,) and float(gaps.max()) <= limit
+    # the comparison sees an altered token
+    wrong = [(served[0] + 1) % 256] + served[1:]
+    assert float(reference.served_gaps(
+        cast, p.tolist(), wrong, dict(DIMS, dtype=dtype),
+        pad_to=64)[0]) > limit
+
+
+# ---- (a) a padded chunk, (d) the state carried from chunk to chunk ----
+
+@pytest.mark.parametrize("n", LENGTHS)
+def test_padded_last_chunk_gives_the_unpadded_tokens(engine, alone, n):
+    assert serve(engine, 0, prompt(n)) == alone(prompt(n))
+
+
+def test_state_after_a_padded_chunk_is_the_state_after_its_last_token(
+        params, engine):
+    p = prompt(37)   # chunks of 16, 16 and 5 padded to 16
+    engine.admit(2, p, NEW)
+    prefill(engine, 2)
+    _, want = decode_forward(params, jnp.asarray(p)[None],
+                             init_kv_cache(CFG, 1, 128), 0, CFG)
+    for name in ("conv", "ssm"):
+        got = engine._cache[name][:, 2]
+        assert float(jnp.abs(got - want[name][:, 0]).max()) < 1e-5, name
+        assert float(jnp.abs(got).max()) > 0
+    engine.release(2)
+
+
+# ---- (b) masked lanes, (c) the state reset ----
+
+def test_a_request_admitted_while_another_decodes(engine, alone):
+    """The second rides through decode steps as a masked lane between
+    its prefill chunks; the first decodes beside the second's chunks."""
+    a, b = prompt(16, salt=1), prompt(50, salt=2)
+    engine.admit(0, a, NEW)
+    out = {0: [prefill(engine, 0)], 1: []}
+    out[0].append(engine.decode_step()[0])
+    engine.admit(1, b, NEW)
+    while not engine.decoding[1]:
+        _, first = engine.prefill_step(1)
+        if first is not None:
+            out[1].append(first)
+        for slot, tok in engine.decode_step().items():
+            if len(out[slot]) < NEW:
+                out[slot].append(tok)
+    while min(len(v) for v in out.values()) < NEW:
+        for slot, tok in engine.decode_step().items():
+            if len(out[slot]) < NEW:
+                out[slot].append(tok)
+    engine.release(0)
+    engine.release(1)
+    assert out[0] == alone(a) and out[1] == alone(b)
+
+
+def test_a_free_slots_state_is_held_through_decode_steps(engine):
+    before = np.asarray(engine._cache["ssm"][:, 2])
+    serve(engine, 0, prompt(16))
+    assert np.array_equal(np.asarray(engine._cache["ssm"][:, 2]), before)
+
+
+def test_a_request_that_reuses_a_released_slot(engine, alone):
+    serve(engine, 1, prompt(50, salt=4))
+    assert float(jnp.abs(engine._cache["ssm"][:, 1]).max()) > 0
+    assert serve(engine, 1, prompt(5, salt=1)) == alone(prompt(5, salt=1))
+
+
+def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
+        params, alone):
+    eng = build_engine(params, CFG, slots=2, max_seq_len=128,
+                       prefill_chunk=16)
+    sched = Scheduler(eng).start()
+    prompts = [prompt(n, salt=n) for n in LENGTHS]   # four over two slots
+    reqs = [sched.submit(Request(p.tolist(), max_new_tokens=NEW,
+                                 temperature=0.0, eos_id=None, rng=0))
+            for p in prompts]
+    got = [r.result(timeout=120) for r in reqs]
+    sched.stop()
+    assert got == [alone(p) for p in prompts]
+    assert eng.compile_counts()["reset_state"] == 1
+
+
+def test_chunk_sizes_16_and_64_agree(params, engine, alone):
+    wide = SlotEngine(params, CFG, max_slots=1, max_seq_len=128,
+                      prefill_chunk=64)
+    for n in (37, 50):
+        p = prompt(n, salt=6)
+        assert serve(wide, 0, p) == serve(engine, 0, p) == alone(p)
+
+
+# ---- (e) what treats a KV range as a prefix refuses the model by name ----
+
+def _kv(n):
+    shape = (2, n, 1, 16)
+    return {"k": np.zeros(shape, np.float32), "v": np.zeros(shape, np.float32)}
+
+
+REFUSALS = {
+    "seed_prefix": lambda e, p: e.seed_prefix(0, _kv(4)),
+    "extract_kv": lambda e, p: e.extract_kv(0, 4),
+    "admit_prefilled": lambda e, p: e.admit_prefilled(
+        0, prompt(8), 1, _kv(8), 4),
+    "kv_token_bytes": lambda e, p: e.kv_token_bytes(),
+    "build_prefix_cache": lambda e, p: build_prefix_cache(e, 1),
+    "scheduler_prefix_cache": lambda e, p: Scheduler(
+        e, prefix_cache=RadixPrefixCache(1 << 20)),
+    "paged_engine": lambda e, p: PagedEngine(p, CFG, max_slots=2,
+                                             max_seq_len=64),
+    "build_engine_paged": lambda e, p: build_engine(
+        p, CFG, slots=2, max_seq_len=64, paged=True),
+    "disagg_prefill_only": lambda e, p: Scheduler(e).submit(
+        Request([1, 2, 3], max_new_tokens=2, prefill_only=True)),
+    "disagg_prefilled": lambda e, p: Scheduler(e).submit(
+        Request([1, 2, 3], max_new_tokens=2,
+                prefilled={"first": 1, "kv": _kv(3)})),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(REFUSALS))
+def test_kv_only_entry_points_refuse_the_model_by_name(params, engine, entry):
+    with pytest.raises(TpuFlowException, match="jamba.*recurrent state"):
+        REFUSALS[entry](engine, params)
+    assert not engine.active.any()
+
+
+def test_no_budget_builds_no_prefix_cache_and_refuses_nothing(engine,
+                                                              monkeypatch):
+    monkeypatch.delenv("TPUFLOW_PREFIX_CACHE_MB", raising=False)
+    assert build_prefix_cache(engine) is None
+
+
+# ---- the family table ----
+
+@pytest.mark.parametrize("cfg,name,recurrent", [
+    (llama.LlamaConfig.tiny(), "llama", False),
+    (mixtral.MixtralConfig.tiny(), "mixtral", False),
+    (CFG, "jamba", True)])
+def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
+    fam = family(cfg)
+    assert fam.name == name and fam.module.__name__.endswith(name)
+    assert is_recurrent(cfg) is recurrent
+    assert len(layer_kinds(cfg)) == cfg.n_layers
+    assert type(build_config({"cfg": {"dim": cfg.dim}}, model=name)) \
+        is type(cfg)
+    cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 32))
+    assert set(cache) == ({"k", "v", "conv", "ssm"} if recurrent
+                          else {"k", "v"})
+    assert all(leaf.shape[1] == 2 for leaf in cache.values())
+    axes = fam.module.logical_axes(cfg)
+    shapes = jax.eval_shape(lambda: fam.module.init_params(
+        jax.random.PRNGKey(0), cfg))
+    assert jax.tree.structure(shapes) == jax.tree.structure(
+        axes, is_leaf=lambda x: isinstance(x, tuple))
+
+
+def test_an_unknown_family_is_refused_by_name():
+    with pytest.raises(TpuFlowException, match="jamba, llama, mixtral"):
+        build_config({"cfg": {}}, model="gpt")
+    with pytest.raises(TpuFlowException, match="no model family"):
+        family(object())
+
+
+def test_the_layer_pattern_comes_from_period_and_offset():
+    big = jamba.JambaConfig()
+    kinds = big.layer_kinds
+    assert [i for i, k in enumerate(kinds) if k == "attention"] == [7, 21]
+    assert jamba.layer_plan(kinds)[:2] == (
+        2, [("mamba", 0, 7), ("attention", 0, 1), ("mamba", 7, 6)])
+    assert jamba.layer_plan(("mamba", "attention", "mamba"))[0] == 1
+    order = []
+    jamba.scan_layers(CFG.layer_kinds,
+                      lambda kind, i, c: order.append(kind) or c, 0)
+    assert order == ["mamba", "attention", "mamba"]   # one body a run
+    n = sum(int(np.prod(s)) for s, _ in jamba.leaf_shapes(big).values())
+    published = configs.read_json(os.path.join(
+        ROOT, "benchmark", "configs", "jamba2-3b-serve.json"))
+    assert n == 3_029_337_472 and ref_family.matmul_params(
+        configs.dims(published)) < n
+    assert configs.program_config(published, 2560)[1] == jamba.JambaConfig(
+        max_seq_len=2560)
+
+
+# ---- Llama and Mixtral emit what they emitted before the family table ----
+
+# recorded on the parent commit (PR 26) by these lines: greedy in slot 0,
+# temperature 0.8 / top-k 20 / top-p 0.9 in slot 1, both decoding together
+BEFORE = {
+    "llama": [[502, 312, 403, 265, 302, 270, 28, 180, 41, 358],
+              [77, 432, 34, 338, 35, 338, 457, 440, 102, 290]],
+    "mixtral": [[145, 134, 455, 145, 184, 399, 427, 147, 501, 402],
+                [21, 386, 501, 203, 470, 475, 149, 341, 473, 67]],
+}
+
+
+@pytest.mark.parametrize("name", sorted(BEFORE))
+def test_llama_and_mixtral_emit_the_tokens_they_emitted_before(name):
+    mod = {"llama": llama, "mixtral": mixtral}[name]
+    cfg = (llama.LlamaConfig if name == "llama"
+           else mixtral.MixtralConfig).tiny()
+    eng = SlotEngine(mod.init_params(jax.random.PRNGKey(0), cfg), cfg,
+                     max_slots=2, max_seq_len=128, prefill_chunk=16)
+    prompts = [((np.arange(n) * 37 + 11) % cfg.vocab_size).astype(np.int32)
+               for n in (21, 40)]
+    for slot, (p, temp) in enumerate(zip(prompts, (0.0, 0.8))):
+        eng.admit(slot, p, 10, temperature=temp, top_k=20, top_p=0.9, rng=7)
+    out = [[prefill(eng, 0)], [prefill(eng, 1)]]
+    for _ in range(9):
+        for slot, tok in eng.decode_step().items():
+            out[slot].append(tok)
+    assert out == BEFORE[name]
+    assert set(eng._cache) == {"k", "v"} and not eng.recurrent
+
+
+# ---- ops/ssm.py ----
+
+def _ssm_inputs(B=2, T=12, Di=8, N=4, seed=0):
+    r = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.float32)
+    return dict(h=f(B, N, Di), u=f(B, T, Di),
+                delta=jax.nn.softplus(f(B, T, Di)), A=-jnp.exp(f(N, Di)),
+                Bm=f(B, T, N), Cm=f(B, T, N), D=f(Di))
+
+
+def test_selective_scan_is_the_recurrence_written_out():
+    x = _ssm_inputs()
+    y, h = ssm.selective_scan(x["h"], x["u"], x["delta"], x["A"], x["Bm"],
+                              x["Cm"], x["D"])
+    want_h, want_y = np.asarray(x["h"], np.float64), []
+    for t in range(12):
+        d, u = np.asarray(x["delta"][:, t]), np.asarray(x["u"][:, t])
+        want_h = np.exp(d[:, None] * np.asarray(x["A"])[None]) * want_h \
+            + (d * u)[:, None] * np.asarray(x["Bm"][:, t])[:, :, None]
+        want_y.append((want_h * np.asarray(x["Cm"][:, t])[:, :, None]).sum(1)
+                      + np.asarray(x["D"]) * u)
+    assert np.allclose(h, want_h, atol=1e-4)
+    assert np.allclose(y, np.stack(want_y, 1), atol=1e-4)
+
+
+@pytest.mark.parametrize("n_valid", [(12, 12), (5, 9), (0, 12), (1, 0)])
+def test_state_and_tail_pass_through_what_is_not_valid(n_valid):
+    x = _ssm_inputs(seed=1)
+    valid = jnp.arange(12)[None] < jnp.asarray(n_valid)[:, None]
+    _, h = ssm.selective_scan(x["h"], x["u"], x["delta"], x["A"], x["Bm"],
+                              x["Cm"], x["D"], valid)
+    w, b = jnp.ones((4, 8)) * 0.25, jnp.zeros(8)
+    tail = x["u"][:, :3] * 2
+    _, new_tail = ssm.causal_conv(x["u"], tail, w, b, valid)
+    for row, n in enumerate(n_valid):
+        one = {k: v[row:row + 1, :n] for k, v in x.items()
+               if k in ("u", "delta", "Bm", "Cm")}
+        _, want = ssm.selective_scan(x["h"][row:row + 1], one["u"],
+                                     one["delta"], x["A"], one["Bm"],
+                                     one["Cm"], x["D"]) if n else (
+                                         None, x["h"][row:row + 1])
+        assert np.allclose(h[row], want[0], atol=1e-6)
+        window = jnp.concatenate([tail[row], x["u"][row, :n]])
+        assert np.array_equal(new_tail[row], window[-3:])
+
+
+def test_one_token_is_a_chunk_of_one_and_conv_chunks_are_the_whole():
+    x = _ssm_inputs(T=1, seed=2)
+    valid = jnp.asarray([[True], [False]])
+    y, h = ssm.selective_step(x["h"], x["u"][:, 0], x["delta"][:, 0], x["A"],
+                              x["Bm"][:, 0], x["Cm"][:, 0], x["D"],
+                              valid[:, 0])
+    y2, h2 = ssm.selective_scan(x["h"], x["u"], x["delta"], x["A"], x["Bm"],
+                                x["Cm"], x["D"], valid)
+    assert np.allclose(y, y2[:, 0]) and np.allclose(h, h2)
+    assert np.array_equal(h[1], x["h"][1]) and not np.allclose(h[0], x["h"][0])
+    u = _ssm_inputs(T=20, seed=3)["u"]
+    w = jnp.asarray(np.random.default_rng(4).normal(size=(4, 8)), jnp.float32)
+    b = jnp.arange(8.0)
+    zero = jnp.zeros((2, 3, 8))
+    whole, _ = ssm.causal_conv(u, zero, w, b)
+    first, tail = ssm.causal_conv(u[:, :13], zero, w, b)
+    tail1 = tail
+    steps = []
+    for t in range(13, 20):   # then a token at a time, one row masked
+        out, tail = ssm.causal_conv(u[:, t:t + 1], tail, w, b, valid)
+        steps.append(out)
+    got = jnp.concatenate([first] + steps, axis=1)
+    assert np.allclose(got[0], whole[0], atol=1e-5)
+    assert np.array_equal(tail[1], tail1[1])
+    # by hand: out_t = b + sum_k w[k] * u[t - 3 + k]
+    assert np.allclose(whole[0, 5], b + sum(w[k] * u[0, 2 + k]
+                                            for k in range(4)), atol=1e-5)
+
+
+# ---- scope names and program names, as the benchmark's readers find them ----
+
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", ("decode_layers", "attn_qkv", "kv_cache_update",
+                "decode_attention", "attn_out", "ffn", "ssm_in_proj",
+                "ssm_conv", "ssm_x_proj", "ssm_state_update",
+                "ssm_out_proj")),
+    ("prefill", ("decode_layers", "attn_qkv", "kv_cache_update",
+                 "decode_attention", "attn_out", "ffn", "ssm_in_proj",
+                 "ssm_conv", "ssm_x_proj", "ssm_scan", "ssm_out_proj"))])
+def test_programs_keep_their_names_and_hold_every_scope(engine, program,
+                                                        scopes):
+    import re
+
+    cache = jax.eval_shape(lambda: engine._cache)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    if program == "decode":
+        lowered = engine._decode_greedy_fn.lower(
+            engine.params, cache, i32(3), i32(3),
+            jax.ShapeDtypeStruct((3,), jnp.bool_))
+    else:   # five arguments, as benchmark/describe_compile.py calls it
+        lowered = engine._prefill_fn.lower(engine.params, cache, i32(1, 16),
+                                           i32(), i32())
+    text = lowered.as_text(debug_info=True)
+    assert "module @jit__%s" % ("decode_greedy" if program == "decode"
+                                else "prefill") in text
+    for scope in scopes:
+        assert re.search(r'["/(]%s["/)]' % scope, text), scope
+    other = "ssm_scan" if program == "decode" else "ssm_state_update"
+    assert not re.search(r'["/(]%s["/)]' % other, text)
