@@ -24,15 +24,19 @@ its KV chunks at about 70 % of the chip's HBM bandwidth (PERF.md, PR 25).
 
 Model families: `family(cfg)` is the ONE place a family is picked, a
 table keyed by the config's class (the feed-forward half of a block,
-whether attention rotates its queries and keys, the kinds of layer in
-the stack). A stack of one kind (Llama, Mixtral) is one lax.scan over
-`params["layers"]` with the cache as its scanned input and output. A
-stack of several kinds (Jamba: Mamba-1 layers with an attention layer
-every so many) holds one stack per kind and walks them in the model's
-order (models/jamba.py, `scan_layers`) with the whole cache as the
-loop's carry, each layer reading and writing its own index of it: K and
-V for the attention layers only, and for the Mamba layers the
-convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py).
+whether attention rotates its queries and keys, where `params` holds
+the stack of each kind of layer). There is ONE layer loop (`_layers`):
+the stacks are walked in the model's order (models/jamba.py,
+`scan_layers`; a stack of one kind, Llama or Mixtral, is its one-run
+case, a stack of several kinds, Jamba's Mamba-1 layers with an attention
+layer every so many, holds one stack per kind). The loop's carry is the
+activations and the WHOLE cache: the pools are never a scanned input or
+output, which would slice every layer out and write every layer back
+into a second buffer. A layer's weights are sliced out of their stack by
+the loop's index (`layer_at`), and the layer writes its new positions
+into, and reads its chunks out of, its own index of the donated pools
+in place: K and V for the attention layers, and for the Mamba layers
+the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py).
 Unlike a KV position, a recurrent state has no garbage that is
 overwritten before it is seen: `valid` tells the state-space layers
 which of the new positions are real.
@@ -43,14 +47,17 @@ is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
 `decode_attention`, `attn_out` and `ffn` (ops/moe.py adds `moe_*` inside
 `ffn`); inside a Mamba layer `ssm_in_proj`, `ssm_conv`, `ssm_x_proj`,
 `ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`.
-What lies under `decode_layers` and under none of those is the loop
-carrying, slicing and copying the cache.
+The pool's write sits under `kv_cache_update`, its chunk reads under
+`decode_attention`, a state pool's read and write-back under the
+`ssm_*` scope that needs them: what lies under `decode_layers` and under
+none of those is the loop's own cost (its counter, the residual
+stream), and anything the compiler still moves without being asked.
 
 Sharding: the cache carries the same logical axes as activations
-([layers, batch, seq, kv_heads, head_dim]) — under a mesh, batch rides
-the data/fsdp axes and kv_heads the tensor axis, so decode parallelizes
-with the exact rule table training uses (spmd/sharding.py); XLA keeps the
-per-step all-gathers on ICI.
+([layers, batch, seq, kv_heads * head_dim], heads major in the folded
+axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
+tensor axis, so decode parallelizes with the exact rule table training
+uses (spmd/sharding.py); XLA keeps the per-step all-gathers on ICI.
 """
 
 import collections
@@ -71,9 +78,10 @@ from ..ops.rope import apply_rope, rope_frequencies
 # name: what `tpuflow serve --model` takes; module: init_params,
 # logical_axes, forward; ffn: the feed-forward half of a block,
 # (cfg, h, lp, mesh) -> the residual's addend; rope: whether attention
-# rotates q and k. The kinds of layer come from the config
-# (`layer_kinds`; a config without it is attention throughout).
-Family = collections.namedtuple("Family", "name module ffn rope")
+# rotates q and k; stacks: kind of layer -> the key of `params` that
+# holds that kind's stacked leaves. The kinds of layer come from the
+# config (`layer_kinds`; a config without it is attention throughout).
+Family = collections.namedtuple("Family", "name module ffn rope stacks")
 
 
 def _dense_ffn(cfg, h, lp, mesh):
@@ -99,9 +107,13 @@ def _moe_ffn(cfg, h, lp, mesh):
 
 
 FAMILIES = {
-    llama.LlamaConfig: Family("llama", llama, _dense_ffn, True),
-    mixtral.MixtralConfig: Family("mixtral", mixtral, _moe_ffn, True),
-    jamba.JambaConfig: Family("jamba", jamba, _dense_ffn, False),
+    llama.LlamaConfig: Family("llama", llama, _dense_ffn, True,
+                              {"attention": "layers"}),
+    mixtral.MixtralConfig: Family("mixtral", mixtral, _moe_ffn, True,
+                                  {"attention": "layers"}),
+    jamba.JambaConfig: Family("jamba", jamba, _dense_ffn, False,
+                              {"attention": "attn_layers",
+                               "mamba": "mamba_layers"}),
 }
 
 
@@ -139,23 +151,22 @@ def is_recurrent(cfg):
 
 def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
     """The static cache, one tree: `k` and `v`
-    [attention layers, batch, max_seq, kv_heads, head_dim]
-    ([.., kv_heads * head_dim] beside Mamba layers), and where the model
-    has Mamba layers `conv` [mamba layers, batch, d_conv-1, d_inner] (the
-    convolution's tail) and `ssm` [mamba layers, batch, d_state, d_inner]
-    in float32. Every leaf has the batch on axis 1."""
+    [attention layers, batch, max_seq, kv_heads * head_dim], and where
+    the model has Mamba layers `conv` [mamba layers, batch, d_conv-1,
+    d_inner] (the convolution's tail) and `ssm` [mamba layers, batch,
+    d_state, d_inner] in float32. Every leaf has the batch on axis 1.
+
+    The pools are read and written a layer at a time in place
+    (`_decode_layer`): heads and head size are folded into one minor
+    axis (heads major), so that the indexed write and the chunk reads
+    meet rows of whole lanes whatever the number of KV heads, and a
+    single KV head (multi-query) leaves no axis of 1 for the chip's
+    tiling to pad or to lay out anew on the way in and out."""
     dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
     kinds = layer_kinds(cfg)
     shape = (kinds.count("attention"), batch_size, max_seq_len,
-             cfg.n_kv_heads, cfg.head_dim)
+             cfg.n_kv_heads * cfg.head_dim)
     n_mamba = kinds.count("mamba")
-    if n_mamba:
-        # a stack of several kinds reads and writes its pools a layer at
-        # a time in place (`_decode_layer`, `layer`): heads and head size
-        # are folded into one minor axis, so that a single KV head
-        # (multi-query) leaves no axis of 1 for the chip's tiling to pad
-        # or to lay out anew on the way in and out
-        shape = shape[:3] + (cfg.n_kv_heads * cfg.head_dim,)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if n_mamba:
         cache["conv"] = jnp.zeros(
@@ -291,9 +302,12 @@ def _streamed_attention(q, pos, chunk, n_chunks, fetch):
 
 
 @jax.named_scope("decode_attention")
-def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK,
-                              layer=None):
-    """Flash-decode: the same attention reading ONLY the filled prefix.
+def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
+                              chunk=DECODE_CHUNK):
+    """Flash-decode: the same attention reading ONLY the filled prefix
+    of layer `layer` (a traced index) of the pools cache_k/v
+    [layers, B, Smax, KV * Hd]; the chunks are read straight out of that
+    layer of them, so the layer's view is never copied.
 
     KV chunks stream through an online-softmax accumulation
     (lax.fori_loop with a TRACED trip count ceil((pos+T)/chunk), lowered
@@ -302,13 +316,9 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK,
     follow the dense path: the same grouped products accumulated in
     float32, the same masking, probabilities rounded to V's dtype per
     chunk instead of once; the edge chunk's clamped slice re-reads
-    earlier keys, masked out by the `key >= chunk start` term.
-
-    With `layer` (a traced index) cache_k/v are the pools of every
-    attention layer, [layers, B, Smax, KV * Hd], and the chunks are read
-    out of that layer of them, so the layer's view is never copied."""
-    T = q.shape[1]
-    Smax = cache_k.shape[1 if layer is None else 2]
+    earlier keys, masked out by the `key >= chunk start` term."""
+    B, T, _, Hd = q.shape
+    Smax = cache_k.shape[2]
     chunk = min(chunk, Smax)
     # traced trip count; with per-slot [B] positions the loop runs to the
     # DEEPEST slot's fill (shallower slots just mask the extra chunks)
@@ -316,16 +326,11 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, chunk=DECODE_CHUNK,
 
     def fetch(i):
         start = jnp.minimum(i * chunk, Smax - chunk)
-        if layer is None:
-            k_blk = jax.lax.dynamic_slice_in_dim(cache_k, start, chunk, 1)
-            v_blk = jax.lax.dynamic_slice_in_dim(cache_v, start, chunk, 1)
-        else:
-            B, Hd = q.shape[0], q.shape[3]
-            at, size = (layer, 0, start, 0), (1, B, chunk, cache_k.shape[3])
-            k_blk = jax.lax.dynamic_slice(cache_k, at, size).reshape(
-                B, chunk, -1, Hd)
-            v_blk = jax.lax.dynamic_slice(cache_v, at, size).reshape(
-                B, chunk, -1, Hd)
+        at, size = (layer, 0, start, 0), (1, B, chunk, cache_k.shape[3])
+        k_blk = jax.lax.dynamic_slice(cache_k, at, size).reshape(
+            B, chunk, -1, Hd)
+        v_blk = jax.lax.dynamic_slice(cache_v, at, size).reshape(
+            B, chunk, -1, Hd)
         return k_blk, v_blk, start + jnp.arange(chunk)
 
     return _streamed_attention(q, pos, chunk, n_chunks, fetch)
@@ -368,43 +373,23 @@ def _ffn(cfg, x, lp, mesh):
 
 
 def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
-                  mesh=None, attn_impl="dense", layer=None):
-    """One attention block over T new tokens, reading+extending this
-    layer's cache: [B, Smax, KV, Hd], or with `layer` (a traced index)
-    that layer of the pools [layers, B, Smax, KV * Hd], written and read
-    in place. The feed-forward half is the family's."""
+                  layer, mesh=None, attn_impl="dense"):
+    """One attention block over T new tokens, reading+extending layer
+    `layer` (a traced index) of the pools [layers, B, Smax, KV * Hd],
+    written and read in place. The feed-forward half is the family's."""
     lp = layer_params
     q, k, v = _attn_qkv(cfg, cos, sin, pos, x, lp)
 
     with jax.named_scope("kv_cache_update"):
-        if layer is not None:
-            cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos,
-                                   layer)
-            cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos,
-                                   layer)
-        elif jnp.ndim(pos) == 0:
-            cache_k = jax.lax.dynamic_update_slice_in_dim(
-                cache_k, k.astype(cache_k.dtype), pos, axis=1)
-            cache_v = jax.lax.dynamic_update_slice_in_dim(
-                cache_v, v.astype(cache_v.dtype), pos, axis=1)
-        else:
-            # per-slot offsets: every batch row writes its T new positions
-            # at its OWN cursor (lowered to a batched scatter)
-            _write = jax.vmap(
-                lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
-                    c, u, p, axis=0))
-            cache_k = _write(cache_k, k.astype(cache_k.dtype), pos)
-            cache_v = _write(cache_v, v.astype(cache_v.dtype), pos)
+        cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos, layer)
+        cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos, layer)
 
     if attn_impl == "chunked":
-        attn = _chunked_cached_attention(q, cache_k, cache_v, pos,
-                                         layer=layer)
-    elif layer is not None:
+        attn = _chunked_cached_attention(q, cache_k, cache_v, pos, layer)
+    else:
         view = lambda pool: pool[layer].reshape(
             pool.shape[1:3] + (cfg.n_kv_heads, cfg.head_dim))
         attn = _cached_attention(q, view(cache_k), view(cache_v), pos)
-    else:
-        attn = _cached_attention(q, cache_k, cache_v, pos)
     x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
     return x, cache_k, cache_v
 
@@ -431,17 +416,25 @@ def _mamba_layer(cfg, x, lp, conv, state, valid):
     return _ffn(cfg, x + out, lp, None), conv, state
 
 
-def _mixed_layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
-    """A stack of several kinds: the whole cache is the loop's carry and
-    each layer reads and writes its own index of it."""
+def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
+    """The layer loop of every family: the activations and the whole
+    cache are its carry, and layer i of a kind reads its weights out of
+    that kind's stack and reads and writes index i of that kind's pools."""
+    fam = family(cfg)
+    cos, sin = rope_frequencies(
+        cfg.head_dim, cache["k"].shape[2], cfg.rope_theta,
+        dtype=llama.param_dtype(cfg),
+        llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
+    ) if fam.rope else (None, None)
+
     def body(kind, i, carry):
         x, cache = carry
         cache = dict(cache)
+        lp = jamba.layer_at(params[fam.stacks[kind]], i)
         if kind == "attention":
             x, cache["k"], cache["v"] = _decode_layer(
-                cfg, None, None, pos, x,
-                jamba.layer_at(params["attn_layers"], i), cache["k"],
-                cache["v"], mesh=mesh, attn_impl=attn_impl, layer=i)
+                cfg, cos, sin, pos, x, lp, cache["k"], cache["v"], i,
+                mesh=mesh, attn_impl=attn_impl)
             return x, cache
         # the layer's tail and state are read out of the pools and written
         # back under the scope of the op that uses them, so that a scope's
@@ -454,9 +447,7 @@ def _mixed_layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
             conv = at(cache["conv"])
         with state_scope:
             state = at(cache["ssm"])
-        x, conv, state = _mamba_layer(
-            cfg, x, jamba.layer_at(params["mamba_layers"], i), conv, state,
-            valid)
+        x, conv, state = _mamba_layer(cfg, x, lp, conv, state, valid)
         put = jax.lax.dynamic_update_index_in_dim
         with conv_scope:
             cache["conv"] = put(cache["conv"], conv, i, 0)
@@ -464,7 +455,8 @@ def _mixed_layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
             cache["ssm"] = put(cache["ssm"], state, i, 0)
         return x, cache
 
-    return jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
+    with jax.named_scope("decode_layers"):
+        return jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
 
 
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
@@ -480,29 +472,8 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
     passes through the positions that are not valid. K and V need no
     mask (what is written there is overwritten before it is seen).
     Returns (logits [B, T, vocab] fp32, updated cache)."""
-    dt = llama.param_dtype(cfg)
-    x = params["embed"][tokens].astype(dt)
-    if is_recurrent(cfg):
-        with jax.named_scope("decode_layers"):
-            x, cache = _mixed_layers(cfg, params, x, cache, pos, valid,
-                                     mesh, attn_impl)
-    else:
-        cos, sin = rope_frequencies(
-            cfg.head_dim, cache["k"].shape[2], cfg.rope_theta, dtype=dt,
-            llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
-        ) if family(cfg).rope else (None, None)
-
-        def layer_fn(carry, inp):
-            lp, ck, cv = inp
-            out, nk, nv = _decode_layer(cfg, cos, sin, pos, carry, lp, ck,
-                                        cv, mesh=mesh, attn_impl=attn_impl)
-            return out, (nk, nv)
-
-        with jax.named_scope("decode_layers"):
-            x, (new_k, new_v) = jax.lax.scan(
-                layer_fn, x, (params["layers"], cache["k"], cache["v"])
-            )
-        cache = {"k": new_k, "v": new_v}
+    x = params["embed"][tokens].astype(llama.param_dtype(cfg))
+    x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if "lm_head" in params:
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],

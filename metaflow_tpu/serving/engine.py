@@ -9,9 +9,11 @@ but every device step is one of a FIXED set of jitted programs, so the
 compiled-program residency that TPUs reward is preserved.
 
 Layout: a pool of B slots shares one static
-[layers, B, max_seq, kv_heads, head_dim] KV cache. Each slot holds at
-most one in-flight request and carries host-side state (pos, sampling
-knobs, per-token rng keys). Three compiled programs cover everything:
+[layers, B, max_seq, kv_heads * head_dim] KV cache (init_kv_cache), the
+carry of decode_forward's layer loop, donated and updated in place.
+Each slot holds at most one in-flight request and carries host-side
+state (pos, sampling knobs, per-token rng keys). Three compiled programs
+cover everything:
 
   - prefill: write one PROMPT CHUNK of one slot into the cache
     (single-slot cache view via dynamic_slice on the batch axis; chunk
@@ -249,23 +251,23 @@ class SlotEngine(object):
         def _seed(cache, k, v, slot):
             # write a [layers, T, kv_heads, head_dim] KV range into one
             # slot's cache view starting at position 0; slot is TRACED
-            # so compiles are bounded by the T bucket, not the pool size
-            cache_k = jax.lax.dynamic_update_slice(
-                cache["k"], k[:, None], (0, slot, 0, 0, 0))
-            cache_v = jax.lax.dynamic_update_slice(
-                cache["v"], v[:, None], (0, slot, 0, 0, 0))
-            return {"k": cache_k, "v": cache_v}
+            # so compiles are bounded by the T bucket, not the pool size.
+            # The pools fold heads and head size into one axis
+            # (init_kv_cache); the host's contract keeps them apart
+            fold = lambda a: a.reshape(a.shape[0], 1, a.shape[1], -1)
+            return {name: jax.lax.dynamic_update_slice(
+                        cache[name], fold(new), (0, slot, 0, 0))
+                    for name, new in (("k", k), ("v", v))}
 
         def _extract(cache, slot, T):
             # read the first T positions of one slot's view; T is STATIC
             # (callers pass a power-of-two bucket and trim on host)
-            L = cache["k"].shape[0]
-            KV, HD = cache["k"].shape[3], cache["k"].shape[4]
-            k = jax.lax.dynamic_slice(
-                cache["k"], (0, slot, 0, 0, 0), (L, 1, T, KV, HD))
-            v = jax.lax.dynamic_slice(
-                cache["v"], (0, slot, 0, 0, 0), (L, 1, T, KV, HD))
-            return k[:, 0], v[:, 0]
+            L, width = cache["k"].shape[0], cache["k"].shape[3]
+            return tuple(
+                jax.lax.dynamic_slice(
+                    cache[name], (0, slot, 0, 0), (L, 1, T, width)
+                ).reshape(L, T, cfg.n_kv_heads, cfg.head_dim)
+                for name in ("k", "v"))
 
         def _reset_state(cache, slot):
             # a new occupant starts from an empty recurrent state; its K
@@ -328,8 +330,8 @@ class SlotEngine(object):
         the unit the prefix-cache byte budget is denominated in."""
         refuse_recurrent(self.cfg, "kv_token_bytes (a prefix cache's unit)")
         k = self._cache["k"]
-        layers, _, _, kv_heads, head_dim = k.shape
-        return 2 * layers * kv_heads * head_dim * k.dtype.itemsize
+        layers, _, _, width = k.shape   # width: kv_heads * head_dim
+        return 2 * layers * width * k.dtype.itemsize
 
     # ---------- slot lifecycle ----------
 

@@ -12,7 +12,7 @@ from metaflow_tpu.inference import (
     init_kv_cache,
     make_generator,
 )
-from metaflow_tpu.models import llama
+from metaflow_tpu.models import llama, mixtral
 from metaflow_tpu.spmd import MeshSpec, create_mesh, shard_tree
 from metaflow_tpu.training import shard_batch
 
@@ -24,6 +24,15 @@ def setup():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0,
                                 cfg.vocab_size)
     return cfg, params, tokens
+
+
+def _as_layer(c, layer, layers=3):
+    """A layer's cache [B, S, KV, Hd] as index `layer` of a pool
+    [layers, B, S, KV * Hd] (init_kv_cache's layout) whose other layers
+    hold something else."""
+    folded = c.reshape(c.shape[:2] + (-1,))
+    return jnp.stack([folded if i == layer else jnp.flip(folded, 1) + 1
+                      for i in range(layers)])
 
 
 class TestDecodeEquivalence:
@@ -119,7 +128,11 @@ class TestGenerate:
         ck = jax.random.normal(ks[1], (B, Smax, KV, Hd))
         cv = jax.random.normal(ks[2], (B, Smax, KV, Hd))
         dense = _cached_attention(q, ck, cv, pos)
-        chunked = _chunked_cached_attention(q, ck, cv, pos, chunk=32)
+        # the chunks are read out of layer 1 of the pools, by a traced index
+        chunked = jax.jit(
+            lambda q, pk, pv, layer: _chunked_cached_attention(
+                q, pk, pv, pos, layer, chunk=32))(
+            q, _as_layer(ck, 1), _as_layer(cv, 1), 1)
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(dense),
                                    atol=2e-5, rtol=2e-5)
 
@@ -160,7 +173,10 @@ class TestGenerate:
         probs /= probs.sum(-1, keepdims=True)
         want = np.einsum("bhqk,bkhd->bqhd", probs, vf)
 
-        for got in (_chunked_cached_attention(q, ck, cv, pos, chunk=chunk),
+        # the chunked path reads its chunks out of layer 2 of the pools
+        for got in (_chunked_cached_attention(q, _as_layer(ck, 2),
+                                              _as_layer(cv, 2), pos,
+                                              jnp.int32(2), chunk=chunk),
                     _cached_attention(q, ck, cv, pos)):
             assert got.shape == q.shape and got.dtype == q.dtype
             np.testing.assert_allclose(np.asarray(got, np.float32), want,
@@ -329,6 +345,170 @@ class TestMixtralDecode:
                                     cfg.vocab_size)
         out = generate(params, tokens, cfg, max_new_tokens=4)
         assert out.shape == (2, 12)
+
+
+def _tiny(name, dtype="float32"):
+    mod, cls = {"llama": (llama, llama.LlamaConfig),
+                "mixtral": (mixtral, mixtral.MixtralConfig)}[name]
+    cfg = cls.tiny(dtype=dtype)
+    return cfg, mod.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _layer_by_layer(params, tokens, caches, pos, cfg, attn_impl):
+    """decode_forward as it was before the pool became the loop's carry,
+    kept here as the plain reference: a Python loop over layers, each on
+    its OWN cache [B, Smax, KV, Hd], written with
+    dynamic_update_slice_in_dim (scalar position) or a vmapped per-slot
+    write, and handed back layer by layer."""
+    from metaflow_tpu.inference import decode as D
+    from metaflow_tpu.ops import rms_norm
+    from metaflow_tpu.ops.rope import rope_frequencies
+
+    dt = jnp.dtype(cfg.dtype)
+    x = params["embed"][tokens].astype(dt)
+    Smax = caches[0][0].shape[1]
+    cos, sin = rope_frequencies(cfg.head_dim, Smax, cfg.rope_theta, dtype=dt,
+                                llama3_scaling=False)
+    if jnp.ndim(pos) == 0:
+        write = lambda c, u: jax.lax.dynamic_update_slice_in_dim(
+            c, u, pos, axis=1)
+    else:
+        write = lambda c, u: jax.vmap(
+            lambda c, u, p: jax.lax.dynamic_update_slice_in_dim(
+                c, u, p, axis=0))(c, u, pos)
+    fold = lambda c: c.reshape(c.shape[:2] + (-1,))[None]
+    out = []
+    for i, (ck, cv) in enumerate(caches):
+        lp = jax.tree.map(lambda a: a[i], params["layers"])
+        q, k, v = D._attn_qkv(cfg, cos, sin, pos, x, lp)
+        ck, cv = write(ck, k.astype(ck.dtype)), write(cv, v.astype(cv.dtype))
+        if attn_impl == "chunked":   # a pool of this one layer
+            attn = D._chunked_cached_attention(q, fold(ck), fold(cv), pos, 0)
+        else:
+            attn = D._cached_attention(q, ck, cv, pos)
+        x = D._block_ffn(cfg, x, attn, lp)
+        out.append((ck, cv))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("btd,dv->btv", x, params["lm_head"],
+                      preferred_element_type=jnp.float32), out
+
+
+class TestPoolCarriedThroughTheLoop:
+    """A stack of one kind carries its KV pool through the layer loop and
+    updates it in place (PR 28), as the stack of several kinds does."""
+
+    @pytest.mark.parametrize("dtype,tol", [("float32", 2e-5),
+                                           ("bfloat16", 3e-2)])
+    @pytest.mark.parametrize("per_slot", [False, True],
+                             ids=["scalar_pos", "per_slot_pos"])
+    @pytest.mark.parametrize("T", [1, 64])
+    @pytest.mark.parametrize("attn_impl", ["dense", "chunked"])
+    @pytest.mark.parametrize("name", ["llama", "mixtral"])
+    def test_decode_forward_is_the_layer_by_layer_form(self, name, attn_impl,
+                                                       T, per_slot, dtype,
+                                                       tol):
+        """Logits to the dtype's tolerance and the returned pool equal
+        element for element (in bfloat16 XLA:CPU rounds a fused loop body
+        and the unrolled layers differently: there the new positions
+        agree to the tolerance and every other element exactly). Smax is
+        no multiple of the decode chunk, so the deepest slot reads the
+        clamped edge chunk."""
+        from metaflow_tpu.inference.decode import DECODE_CHUNK
+
+        cfg, params = _tiny(name, dtype)
+        B, Smax = 3, 2 * DECODE_CHUNK + 40
+        pos = jnp.asarray([5, 300, Smax - T]) if per_slot \
+            else jnp.int32(Smax - T)
+        tokens = jax.random.randint(jax.random.PRNGKey(2), (B, T), 0,
+                                    cfg.vocab_size)
+        # a pool that earlier chunks and steps have filled
+        empty = init_kv_cache(cfg, B, Smax)
+        assert empty["k"].shape == (cfg.n_layers, B, Smax,
+                                    cfg.n_kv_heads * cfg.head_dim)
+        cache = {n: jax.random.normal(jax.random.PRNGKey(i), a.shape,
+                                      jnp.float32).astype(a.dtype)
+                 for i, (n, a) in enumerate(sorted(empty.items()))}
+        heads = lambda a: a.reshape(B, Smax, cfg.n_kv_heads, cfg.head_dim)
+        want_logits, want = jax.jit(
+            lambda p, t, c, pos: _layer_by_layer(p, t, c, pos, cfg,
+                                                 attn_impl))(
+            params, tokens, [(heads(cache["k"][i]), heads(cache["v"][i]))
+                             for i in range(cfg.n_layers)], pos)
+        logits, got = jax.jit(
+            lambda p, t, c, pos: decode_forward(p, t, c, pos, cfg,
+                                                attn_impl=attn_impl))(
+            params, tokens, cache, pos)
+
+        assert set(got) == {"k", "v"} and got["k"].dtype == jnp.dtype(dtype)
+        at = np.broadcast_to(np.asarray(pos), (B,))[:, None] + np.arange(T)
+        new = np.zeros((B, Smax), bool)
+        new[np.arange(B)[:, None], at] = True
+        new_tol = 0 if dtype == "float32" else tol
+        for i, layer in enumerate(want):
+            for n, w in zip(("k", "v"), layer):
+                g = np.asarray(heads(got[n][i]), np.float32)
+                w = np.asarray(w, np.float32)
+                np.testing.assert_array_equal(g[~new], w[~new])
+                np.testing.assert_allclose(g[new], w[new], atol=new_tol,
+                                           rtol=new_tol)
+        logits, want_logits = np.asarray(logits), np.asarray(want_logits)
+        if (name, dtype) == ("mixtral", "bfloat16"):
+            # a router near a tie picks another expert under another
+            # rounding: such a token's whole row moves, and is left out
+            off = np.abs(logits - want_logits).max(-1) > 0.1
+            assert off.mean() <= 0.02, off.mean()
+            logits, want_logits = logits[~off], want_logits[~off]
+        np.testing.assert_allclose(logits, want_logits, atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("name", ["llama", "mixtral"])
+    def test_no_scan_takes_the_pool_in_or_hands_it_out(self, name):
+        """In the slot engine's decode step the pool is a loop's carry:
+        no scan has an array of the pool's shape among its scanned inputs
+        (which slices every layer out) or outputs (which writes every
+        layer back into a second buffer), and the compiled step's
+        temporaries are smaller than one pool."""
+        from metaflow_tpu.inference.decode import DECODE_CHUNK
+        from metaflow_tpu.serving import SlotEngine
+
+        # float32: XLA:CPU widens a bfloat16 pool whole, which the chip
+        # does not (benchmark/describe_compile.py counts that)
+        cfg, params = _tiny(name)
+        eng = SlotEngine(params, cfg, max_slots=4,
+                         max_seq_len=16 * DECODE_CHUNK, prefill_chunk=16)
+        assert eng.attn_impl == "chunked"
+        cache = jax.eval_shape(lambda: eng._cache)
+        pool, B = cache["k"], eng.max_slots
+        i32 = jax.ShapeDtypeStruct((B,), jnp.int32)
+        args = (params, cache, i32, i32,
+                jax.ShapeDtypeStruct((B,), jnp.bool_))
+
+        def loops(jp):
+            for eqn in jp.eqns:
+                if eqn.primitive.name in ("scan", "while"):
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from loops(sub)
+
+        is_pool = lambda v: (v.aval.shape, v.aval.dtype) == (pool.shape,
+                                                             pool.dtype)
+        carried = 0
+        for eqn in loops(jax.make_jaxpr(eng._decode_greedy_fn)(*args).jaxpr):
+            if eqn.primitive.name == "scan":
+                n_fixed = eqn.params["num_consts"] + eqn.params["num_carry"]
+                scanned = (eqn.invars[n_fixed:]
+                           + eqn.outvars[eqn.params["num_carry"]:])
+                assert not [v for v in scanned if is_pool(v)], eqn
+                carry = eqn.invars[eqn.params["num_consts"]:n_fixed]
+            else:
+                carry = eqn.invars[eqn.params["cond_nconsts"]
+                                   + eqn.params["body_nconsts"]:]
+            carried += sum(map(is_pool, carry))
+        assert carried >= 2   # K and V ride the layer loop
+
+        stats = eng._decode_greedy_fn.lower(*args).compile().memory_analysis()
+        if stats is not None:
+            one_pool = int(np.prod(pool.shape)) * pool.dtype.itemsize
+            assert stats.temp_size_in_bytes < one_pool, stats
 
 
 class TestShardedDecode:
