@@ -16,8 +16,7 @@ if the flow ran in the client.
 
 The reference has no equivalent (its runtime pays the cold start every
 run); this is a TPU-first addition in the spirit of its fast-launch work
-(metaflow_profile timings). Measured by bench.py BENCH_MODE=launch with
-BENCH_DAEMON=1.
+(metaflow_profile timings). Not measured on the chip.
 
 Caveat (dev tool, by design): the fork inherits the daemon's module
 cache, so edits to *framework* code need a daemon restart; the flow file

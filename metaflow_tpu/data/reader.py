@@ -66,7 +66,7 @@ class ShardReader(object):
     `stream(shard_ids)` yields (shard_id, token_array) in the GIVEN
     order; up to `readahead_bytes` of further shards are in flight or
     ready at any time. `stats` accumulates fetch/retry/occupancy/wait
-    figures across streams (the data bench reads them)."""
+    figures across streams (tests read them)."""
 
     def __init__(self, flow_datastore, manifest, max_workers=None,
                  readahead_bytes=None, verify=True):
@@ -83,7 +83,7 @@ class ShardReader(object):
                       "occupancy_samples": 0}
         # fetches/retries/bytes are bumped from pool worker threads;
         # += on a dict entry is a read-modify-write that loses updates
-        # without a lock (the bench and tests read exact counts)
+        # without a lock (tests read exact counts)
         self._stats_lock = threading.Lock()
 
     # ---------- blob fetch (worker threads) ----------

@@ -276,8 +276,6 @@ _k("TPUFLOW_PREFIX_CACHE_MB", "float", 0.0, "MB", "serving",
    "prefix KV cache budget (0 = disabled)")
 _k("TPUFLOW_SERVE_LATENCY_WINDOW", "int", 1024, "count", "serving",
    "latency percentile reservoir size")
-_k("TPUFLOW_SERVE_STEP_DELAY_MS", "float", 0.0, "ms", "serving",
-   "injected per-decode-step delay for tests/chaos")
 _k("TPUFLOW_TRACE_REQUESTS", "bool", True, "", "serving",
    "per-request spans in the serving scheduler")
 

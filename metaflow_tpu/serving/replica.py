@@ -11,7 +11,7 @@ Two ways to get weights:
                             off the run's datastore through
                             inference/loading.load_run_checkpoint, same
                             as `tpuflow serve` without --replicas.
-  --synthetic-config JSON   hermetic path for benches/tests: params are
+  --synthetic-config JSON   hermetic path for tests: params are
                             initialized from PRNGKey(--synthetic-seed),
                             a pure function of (seed, config), so every
                             replica of a fleet materializes IDENTICAL
@@ -22,12 +22,9 @@ the first real request never pays a compile), the replica atomically
 writes {"pid", "host", "port"} to --port-file. The supervisor waits on
 that file, then health-checks /healthz.
 
-TPUFLOW_SERVE_STEP_DELAY_MS (or --step-delay-ms) adds a fixed sleep to
-every engine device call. This emulates a device-bound step for the
-hermetic fleet bench: on a CPU host all replicas share the cores, so
-real compute cannot scale with replica count — a TPU fleet gives each
-replica its own chip. The sleep yields the GIL and the core, making
-per-replica throughput device-bound the way production is. Default 0.
+--step-delay-ms adds a fixed sleep to every engine device call: a test
+affordance (tests/test_fleet.py) that holds requests in flight long
+enough for a replica to be killed under them. It measures nothing.
 """
 
 import argparse
@@ -40,8 +37,8 @@ from .. import knobs
 
 
 def _add_step_delay(engine, delay_s):
-    """Emulated device time: each prefill chunk / fused decode step
-    holds its slot for `delay_s` wall seconds (GIL released)."""
+    """Each prefill chunk / fused decode step holds its slot for
+    `delay_s` more wall seconds (GIL released)."""
     real_decode = engine.decode_step
     real_prefill = engine.prefill_step
 
@@ -168,7 +165,7 @@ def build_parser():
     p.add_argument("--mesh", default=None)
     p.add_argument("--attn-impl", default="auto")
     p.add_argument("--no-warmup", action="store_true")
-    p.add_argument("--step-delay-ms", type=float, default=None)
+    p.add_argument("--step-delay-ms", type=float, default=0.0)
     p.add_argument("--role", default="unified",
                    choices=("unified", "prefill", "decode"))
     p.add_argument("--prefix-cache-mb", type=int, default=None)
@@ -206,11 +203,8 @@ def main(argv=None):
                                 args.replica_index)
     if not args.no_warmup:
         _warm(engine)
-    delay_ms = args.step_delay_ms
-    if delay_ms is None:
-        delay_ms = knobs.get_float("TPUFLOW_SERVE_STEP_DELAY_MS")
-    if delay_ms > 0:
-        _add_step_delay(engine, delay_ms / 1000.0)
+    if args.step_delay_ms > 0:
+        _add_step_delay(engine, args.step_delay_ms / 1000.0)
 
     from ..cmd.serve import build_prefix_cache
 

@@ -40,8 +40,8 @@ Transport: `StageTransport` runs a background sender thread (serialize +
 wire latency off the critical path) and a background receiver thread
 (prefetch into a bounded queue) per ring, so the send/recv of microbatch
 k+1 overlaps the compute of microbatch k. `double_buffer=False` degrades
-to the synchronous send-then-compute baseline the BENCH_MODE=mpmd gate
-measures against. Every recv carries a BOUNDED deadline
+to the synchronous send-then-compute form, whose losses and gradients
+the double-buffered one must equal bit for bit. Every recv carries a BOUNDED deadline
 (TPUFLOW_MPMD_RECV_TIMEOUT_S), and sends get their own generous deadline
 (TPUFLOW_MPMD_SEND_TIMEOUT_S, default = the recv deadline — backpressure
 from a peer mid-compile is normal and must NOT look like death): a peer
@@ -243,8 +243,7 @@ class StageTransport(object):
     background sender thread, and a background receiver thread prefetches
     inbound frames into a bounded queue — send/recv of microbatch k+1
     overlaps compute of microbatch k. False: every send and recv runs
-    inline (the synchronous send-then-compute baseline BENCH_MODE=mpmd
-    measures overlap against).
+    inline (the synchronous send-then-compute form).
 
     Wall-clock spent BLOCKED on the transport (inline send, queue put on
     a full buffer, recv wait) accumulates as transfer-stall time; the

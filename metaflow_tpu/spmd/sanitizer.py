@@ -31,8 +31,7 @@ flight-recorder pattern PyTorch/NCCL stacks ship for this failure class.
 
 The journal entries are plain strings, hashing is host-side, and no jax
 import happens here: a disabled sanitizer costs one attribute load per
-hook. Measured overhead with TPUFLOW_SANITIZE=1 is gated ≤3% by
-``BENCH_MODE=sanitize``.
+hook.
 
 Env vars:
     TPUFLOW_SANITIZE=1            enable journaling + barrier checks

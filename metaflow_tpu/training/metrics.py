@@ -28,7 +28,9 @@ from .. import telemetry
 
 # bf16 peak TFLOP/s per chip, from published TPU specs (substring-matched
 # against jax Device.device_kind so "TPU v5 lite" and "TPU v5e" both hit).
-# Single source of truth: bench.py imports these.
+# The library's own table, for step telemetry (`StepTelemetry`) and
+# `chip_smoke.py`; the benchmark keeps its peaks in `benchmark/peaks.py`,
+# which the library may not import.
 TPU_PEAK_TFLOPS = [
     ("v5p", 459.0),
     ("v5e", 197.0),
@@ -38,7 +40,7 @@ TPU_PEAK_TFLOPS = [
     ("v3", 123.0),
 ]
 
-# HBM bandwidth GB/s per chip, same sources (bench roofline)
+# HBM bandwidth GB/s per chip, same sources
 TPU_HBM_GBPS = [
     ("v5p", 2765.0),
     ("v5e", 819.0),
@@ -83,8 +85,8 @@ def hbm_gbps(device_kind):
 
 def flops_per_token_dense(n_params, n_layers, dim, seq):
     """Train-step FLOPs/token for a dense transformer (fwd+bwd = 3x fwd):
-    6*N + 12*L*D*S, the PaLM appendix-B convention (see bench.py _mfu for
-    the honesty caveats about counting embedding params)."""
+    6*N + 12*L*D*S, the PaLM appendix-B convention (a caller that passes
+    every parameter, the embedding table included, overstates it a little)."""
     return 6.0 * n_params + 12.0 * n_layers * dim * seq
 
 
@@ -460,7 +462,7 @@ def instrument_train_step(step_fn, tokens_per_step=None, flops_per_step=None,
 
     The wrapper adds only host-side bookkeeping (no device syncs): two
     perf_counter reads, a cache-size probe, and one buffered record per
-    step — the BENCH_MODE=telemetry bench pins the overhead at ≤2%.
+    step.
 
     tokens_per_step: GLOBAL tokens consumed per step (batch*seq) — enables
         tokens/sec on every record.

@@ -1,5 +1,5 @@
 """Shim: the fake GCS server moved into the package (devtools) so the
-devstack can ship it; tests and bench.py keep this import/exec path."""
+devstack can ship it; tests keep this import/exec path."""
 
 import os
 import sys

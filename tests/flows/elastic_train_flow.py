@@ -9,7 +9,7 @@ The `end` step replays the whole run single-process from scratch and
 asserts the distributed, twice-resized run produced the EXACT same loss
 trajectory and token order — the ROADMAP item 5 gate.
 
-Driven by tests/test_elastic.py (and BENCH_MODE=elastic) via env:
+Driven by tests/test_zelastic_e2e.py via env:
 
     ELASTIC_FLOW_RANKS   gang size             (default 8)
     ELASTIC_FLOW_STEPS   total train steps     (default 40)
@@ -18,6 +18,7 @@ Driven by tests/test_elastic.py (and BENCH_MODE=elastic) via env:
     TPUFLOW_CAPACITY_ORACLE  e.g. "scripted:4,4,4,8" (see elastic/oracle.py)
 """
 
+import contextlib
 import os
 import time
 
@@ -91,6 +92,13 @@ class ElasticTrainFlow(FlowSpec):
         # fast-forward through rank 0's in-flight saves (it would skip
         # its own scheduled chaos kill, among other things). Each save
         # stamps its attempt; loads skip same-attempt saves.
+        # a notice is honoured only at a boundary THIS attempt has
+        # written: the restore and the first step ride one shield, so a
+        # grow notice that finds a shrunk gang still starting up waits
+        # until that gang has a step of its own in the record (the
+        # supervisor's head start is a clock, this is a step)
+        first_boundary = contextlib.ExitStack()
+        first_boundary.enter_context(current.preemption.shield())
         restored = None
         for s in reversed(ckpt.list()):
             state = ckpt.load(step=s)
@@ -134,7 +142,9 @@ class ElasticTrainFlow(FlowSpec):
                              "history": history},
                             step=i)
                 time.sleep(self.step_sleep)
+            first_boundary.close()
             i += 1
+        first_boundary.close()
         self.final_w = w
         self.history = history if rank == 0 else None
         self.next(self.join)

@@ -12,7 +12,7 @@ Unlike elastic_train_flow, the train step here runs through the REAL
 progress beats, and the adaptive hang deadline all ride the production
 path rather than hand-rolled calls.
 
-Driven by tests/test_zhang_e2e.py (and BENCH_MODE=hang) via env:
+Driven by tests/test_zhang_e2e.py via env:
 
     HANG_FLOW_RANKS     gang size             (default 4)
     HANG_FLOW_STEPS     total train steps     (default 8)

@@ -1,4 +1,4 @@
-"""North-star path (BASELINE.json): `num_parallel` gang step training a
+"""North-star path: `num_parallel` gang step training a
 Llama model with jax.distributed — each rank is one process of a multi-host
 JAX program; the mesh spans all ranks' devices (SURVEY.md §2.9)."""
 

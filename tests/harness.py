@@ -6,6 +6,7 @@ combination, executed through the actual CLI, and checked via the client
 API. This multiplies coverage across the DSL/scheduler/datastore layers.
 """
 
+import contextlib
 import os
 
 GRAPHS = {
@@ -403,3 +404,77 @@ def generate_flow(graph, flow_name, fail_step=None, specs=()):
     lines.append("if __name__ == '__main__':")
     lines.append("    %s()" % flow_name)
     return "\n".join(lines)
+
+
+
+@contextlib.contextmanager
+def _client_env(extra):
+    saved = {k: os.environ.get(k) for k in extra}
+    os.environ.update(extra)
+    try:
+        yield
+    finally:
+        for k, v in saved.items():
+            if v is None:
+                os.environ.pop(k, None)
+            else:
+                os.environ[k] = v
+
+
+def _check_run(flow_name, graph, tpuflow_root, client_env):
+    """Client-side checker: every step ran with the expected cardinality,
+    read back through the same providers the flow wrote through."""
+    os.environ["TPUFLOW_DATASTORE_SYSROOT_LOCAL"] = tpuflow_root
+    with _client_env(client_env):
+        from metaflow_tpu import client
+
+        client.namespace(None)
+        run = client.Flow(flow_name).latest_run
+        assert run.successful
+        expected = expected_task_counts(graph)
+        for step_name, count in expected.items():
+            tasks = list(run[step_name].tasks())
+            assert len(tasks) == count, (
+                "%s/%s: expected %d tasks, found %d"
+                % (flow_name, step_name, count, len(tasks))
+            )
+        # the end task saw every step that executed (unchosen switch
+        # branches never run)
+        trace = run.data.trace
+        assert set(trace) == {n for n, c in expected.items() if c > 0}, trace
+
+
+# The graphs x contexts product (reference: test/README.md runs every graph
+# under every valid context) is spread over three test files by graph, so
+# that `--dist loadfile` can give them to three workers; between them the
+# groups hold every graph (test_harness_graphs_simple.py checks it).
+GRAPH_GROUPS = {
+    "simple": ("branch", "foreach", "linear", "switch"),
+    "nested": ("branch_of_foreach", "nested_foreach", "recursive"),
+    "gang": ("foreach_gang", "gang"),
+}
+
+
+def matrix(group):
+    return [(g, c) for g in GRAPH_GROUPS[group] for c in sorted(CONTEXTS)]
+
+
+def run_generated_flow(graph_name, context_name, run_flow, tpuflow_root,
+                       tmp_path):
+    """One cell of the matrix: generate the flow, run it through the CLI
+    under the context, check it through the client."""
+    graph = GRAPHS[graph_name]
+    flow_name = "Gen%s%sFlow" % (
+        graph_name.title().replace("_", ""),
+        context_name.title().replace("_", ""),
+    )
+    src = generate_flow(graph, flow_name)
+    flow_file = str(tmp_path / ("%s.py" % flow_name))
+    with open(flow_file, "w") as f:
+        f.write(src)
+
+    with ActiveContext(context_name, tpuflow_root) as ctx:
+        proc = run_flow(flow_file, *(ctx.args + ["run"]), env_extra=ctx.env,
+                        prefix=ctx.prefix)
+        assert "TRACE:" in proc.stdout
+        _check_run(flow_name, graph, tpuflow_root, ctx.client_env)

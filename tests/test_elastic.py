@@ -6,8 +6,7 @@ admissible-size selection + SPMD pre-relaunch validation, grow-notice
 delivery, the chaos harness's once-only seeded kill schedules, the
 preemption-marker freshness satellites, and the streaming loader's
 epoch-boundary re-slice. The end-to-end shrink/grow scenarios (real
-gangs, real SIGTERMs, the goodput bench gate) live in
-tests/test_zelastic_e2e.py.
+gangs, real SIGTERMs) live in tests/test_zelastic_e2e.py.
 """
 
 import json

@@ -305,7 +305,10 @@ class TestFleetRouter:
         dead = [h for h in fleet.handles if h.state == "dead"][0]
         live = [h for h in fleet.handles if h.state == "ready"][0]
         # resurrect the dead handle's routing entry but point it at a
-        # closed port: the relay fails instantly
+        # closed port: the relay fails instantly. The corpse must look
+        # alive to the monitor too, or its next pass (every 50 ms)
+        # declares it dead again before the request is routed
+        dead.proc._rc = None
         dead.state = "ready"
         fleet.config.failover = False
         # force the pick to the corpse
@@ -320,6 +323,7 @@ class TestFleetRouter:
         finally:
             fleet.config.failover = True
             dead.state = "dead"
+            dead.proc._rc = -9
             with fleet._lock:
                 live.inflight = max(0, live.inflight - 10)
 
@@ -364,7 +368,22 @@ class TestFleetRouter:
         from metaflow_tpu import telemetry
         from metaflow_tpu.cmd.metrics import aggregate
 
-        _fleet, _servers, fds = fleet_env
+        fleet, _servers, fds = fleet_env
+
+        def deaths():
+            telemetry.flush()
+            return sum(r["name"] == "fleet.replica.dead"
+                       for r in telemetry.read_run_records(fds, "1"))
+
+        # the tests above wait for a replica's state, not for its record:
+        # kill the survivor here and wait until the record has landed
+        before = deaths()
+        live = [h for h in fleet.handles if h.state == "ready"][0]
+        assert fleet.kill_replica(live.index)
+        deadline = time.time() + 30
+        while deaths() == before and time.time() < deadline:
+            time.sleep(0.05)
+        assert deaths() == before + 1
         telemetry.close_recorder()
         records = telemetry.read_run_records(fds, "1")
         fleet_recs = [r for r in records
@@ -376,9 +395,12 @@ class TestFleetRouter:
         names = {r["name"] for r in fleet_recs}
         for lifecycle in FLEET_EVENT_DATA_SCHEMAS:
             if lifecycle in ("chaos.replica_kill", "fleet.scale_out",
-                             "fleet.scale_in", "fleet.rollout"):
-                # no chaos injector here, and the autoscaler/rollout
-                # events are exercised by test_disagg_fleet.py
+                             "fleet.scale_in", "fleet.rollout",
+                             "fleet.cache_route.hit"):
+                # no chaos injector here; the autoscaler/rollout events
+                # are exercised by test_disagg_fleet.py; these replicas
+                # hold no prefix cache, so no dispatch can find one warm:
+                # test_tenancy.py causes a hit and validates it
                 continue
             assert lifecycle in names, "missing %s" % lifecycle
         assert "fleet.replicas_ready" in names
@@ -430,8 +452,8 @@ class TestFleetChaosE2E:
             "--synthetic-config", cfg_json, "--synthetic-seed", "7",
             "--slots", "2", "--max-seq-len", "96",
             "--prefill-chunk", "16", "--max-queue", "32",
-            # emulated device time: keeps requests in flight long
-            # enough that the kill lands mid-generation
+            # keeps requests in flight long enough that the kill
+            # lands mid-generation
             "--step-delay-ms", "30",
         ]
         schedule = chaos.KillSchedule.parse("3:1")  # dispatch 3 kills r1

@@ -269,8 +269,7 @@ class TestFlowLevelGS:
 class TestThroughput:
     """Timing measurements (loopback fake server: measures the client
     engine's overhead ceiling, not network). Floors are deliberately low —
-    this is a regression tripwire, not a benchmark claim; bench.py
-    BENCH_MODE=gsop records the real numbers."""
+    this is a regression tripwire, not a benchmark claim."""
 
     def test_get_many_throughput(self, gcs, tmp_path):
         # the floor assumes client and server can run concurrently; with a

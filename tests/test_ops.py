@@ -580,7 +580,7 @@ class TestGroupedMatmul:
 
     def test_gmm_bwd_check_fires_under_grad(self):
         """custom_vjp routes jax.grad through _gmm_fwd, not the primal —
-        the fail-fast must fire there too (ADVICE round 5)."""
+        the fail-fast must fire there too."""
         from metaflow_tpu.ops.gmm import gmm
 
         x = jnp.ones((128, 192), jnp.float32)

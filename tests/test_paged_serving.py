@@ -343,6 +343,33 @@ class TestExhaustionBackpressure:
             == small.pool.usable_pages * PTOK
 
 
+class TestInFlightAtEqualKVBytes:
+    def test_short_requests_pack_past_the_slot_count(self, setup):
+        """A pool of exactly the slot engine's KV footprint (slots x
+        max_seq_len tokens) under a burst of short requests: the slot
+        engine's ceiling is `slots` by construction (a slot reserves a
+        whole max_seq_len row), the paged engine reserves
+        ceil(need / page) pages a request and holds more lanes in the
+        same bytes."""
+        cfg, params = setup
+        slots, max_seq_len = 4, 128
+        eng = PagedEngine(params, cfg, max_slots=2 * slots,
+                          max_seq_len=max_seq_len, prefill_chunk=32,
+                          page_tokens=PTOK, spec_k=0,
+                          total_pages=slots * (max_seq_len // PTOK) + 1)
+        assert eng.pool.usable_pages * PTOK == slots * max_seq_len
+        rng = np.random.default_rng(5)
+        sched = Scheduler(eng, max_queue=4 * slots + 1)
+        reqs = [sched.submit(Request(
+            rng.integers(1, cfg.vocab_size, PTOK).tolist(),
+            max_new_tokens=8, rng=i)) for i in range(4 * slots)]
+        sched.run_until_idle(100_000)
+        assert all(len(r.generated) == 8 for r in reqs)
+        # a request is 2 pages, the pool 32: the lanes run out first
+        assert sched.stats()["peak_in_flight"] == 2 * slots
+        _assert_pool_free(eng)
+
+
 class TestPagedSharedTelemetry:
     def test_page_shared_event_and_schema(self, setup, tmp_path):
         """serve.kv.page_shared rides every zero-copy attach and every

@@ -285,6 +285,31 @@ class TestPrefixTokenIdentity:
         assert cache.pinned_nodes() == 0
 
 
+    def test_shared_system_prompt_skips_nine_tenths_of_prefill(
+            self, engine):
+        """One cold request seeds a 72-token system prompt; sixteen more
+        share it and differ in a 4-token tail: each starts its prefill at
+        the match boundary, counted by the scheduler's own counters over
+        the requests after the seed."""
+        rng = np.random.default_rng(0)
+        system = rng.integers(1, 200, 72).tolist()
+        sched = Scheduler(engine, max_queue=32,
+                          prefix_cache=RadixPrefixCache(64 << 20))
+        sched.submit(Request(system + [207, 208, 209, 210],
+                             max_new_tokens=4))
+        sched.run_until_idle(50_000)
+        hit0, prompt0 = sched.prefix_hit_tokens, sched.prefix_prompt_tokens
+        assert (hit0, prompt0) == (0, 76)
+        reqs = [sched.submit(Request(
+            system + [211 + i] + rng.integers(1, 200, 3).tolist(),
+            max_new_tokens=4, rng=i)) for i in range(16)]
+        sched.run_until_idle(50_000)
+        assert all(len(r.generated) == 4 for r in reqs)
+        assert sched.prefix_prompt_tokens - prompt0 == 16 * 76
+        assert sched.prefix_hit_tokens - hit0 == 16 * 72
+        assert sched.prefix_cache.pinned_nodes() == 0
+
+
 # ---------------------------------------------------------------------------
 # Cancellation mid-prefill releases the prefix pin (no leaked refs)
 # ---------------------------------------------------------------------------

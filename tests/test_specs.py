@@ -20,11 +20,12 @@ from harness import (
     ActiveContext,
     CONTEXTS,
     GRAPHS,
+    _check_run,
+    _client_env,
     expected_task_counts,
     generate_flow,
 )
 from specs import ADDITIVE_SPECS, SOLO_SPECS
-from test_harness import _check_run, _client_env
 
 # deterministic context rotation: every context is exercised across the
 # graph set without multiplying runtime by |contexts|

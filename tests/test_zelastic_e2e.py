@@ -6,19 +6,15 @@ mid-training, driven by the chaos harness) reaches the same loss
 trajectory as an uninterrupted run and is token-exact on data order; a
 follow-on grow-back (4 -> 8) continues without repeating or skipping a
 token. Plus: repeated-kill resilience, checkpoint restore onto a
-SMALLER mesh (the model-state half of a resize), the pinned elastic
-telemetry surface from a live run, and the BENCH_MODE=elastic goodput
-gate (elastic vs fixed-size retry under the same capacity hole).
+SMALLER mesh (the model-state half of a resize), and the pinned elastic
+telemetry surface from a live run.
 """
 
-import json
 import os
 import re
-import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -28,7 +24,6 @@ from metaflow_tpu.datastore import FlowDataStore, LocalStorage
 from schema_validate import validate_elastic_record
 
 FLOWS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "flows")
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _run_records(tpuflow_root, run_id):
@@ -48,7 +43,10 @@ class TestShrinkGrowE2E:
                                           tmp_path):
         """8 ranks; rank 2 reclaimed at step 3; capacity oracle admits 4
         -> supervisor shrinks; when the script reports 8 again the gang
-        grows back at the next checkpoint boundary. The flow's own `end`
+        grows back at the next checkpoint boundary. The script is indexed
+        by consultation, and the flow honours a notice only at a boundary
+        the attempt has itself written, so the 4-rank gang always leaves
+        steps in the record however late it starts. The flow's own `end`
         step asserts the loss trajectory and token order are EXACTLY the
         uninterrupted run's."""
         proc = run_flow(
@@ -215,32 +213,3 @@ class TestReshardOntoSmallerMesh:
             assert shd.zero_spec(
                 jax.sharding.PartitionSpec(), x.shape, mesh4) \
                 == x.sharding.spec
-
-
-class TestElasticBenchGate:
-    def test_goodput_vs_fixed_size_retry(self, tmp_path):
-        """BENCH_MODE=elastic: under one kill and a scripted capacity
-        hole, resize-and-continue must deliver >= 1.5x the goodput of
-        fixed-size retry (which parks until capacity returns)."""
-        env = dict(os.environ)
-        env.update({
-            "BENCH_MODE": "elastic",
-            "BENCH_HISTORY": "0",  # hermetic: no BENCH_HISTORY.jsonl write
-            "JAX_PLATFORMS": "cpu",
-            "PYTHONPATH": REPO,
-            # trimmed scenario for CI: 4 ranks, one kill, 8s hole
-            "BENCH_ELASTIC_RANKS": "4",
-            "BENCH_ELASTIC_STEPS": "22",
-            "BENCH_ELASTIC_SLEEP": "0.05",
-            "BENCH_ELASTIC_HOLE_S": "8",
-        })
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env, capture_output=True, text=True, timeout=600)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        result = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert result["metric"] == "elastic_goodput_ratio"
-        assert result["value"] >= 1.5, result
-        subs = {s["metric"]: s for s in result.get("submetrics", [])}
-        assert subs["elastic_goodput_steps_per_s"]["value"] > \
-            subs["fixed_goodput_steps_per_s"]["value"]

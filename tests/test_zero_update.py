@@ -2,8 +2,7 @@
 training/train_step.py): spec-transform units, loss-trajectory parity
 sharded vs replicated, checkpoint round-trips across DP sizes and the
 zero on/off switch, the optimizer/opt-state guard, the sanitizer's
-pinned zero.* collective vocabulary, the split memory gauges, the
-BENCH_MODE=zero memory gate, and the fused-config sweep harness.
+pinned zero.* collective vocabulary and the split memory gauges.
 
 Parity tolerances (measured on the 8-device CPU mesh, documented in
 docs/training.md): losses zero-on vs zero-off drift <= ~1e-6 over a few
@@ -12,9 +11,7 @@ one step after a restore drifts <= ~1.3e-6 per param element (host-numpy
 restore changes reduction layouts, amplified by adamw's early-warmup
 normalization) — asserted at atol=5e-6 for margin."""
 
-import json
 import os
-import subprocess
 import sys
 
 import numpy as np
@@ -44,7 +41,6 @@ from metaflow_tpu.training.metrics import _tree_device_bytes
 
 import schema_validate
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 LOSS_ATOL = 2e-6     # zero-on vs zero-off loss drift (measured ~1e-6)
 RESTORE_ATOL = 5e-6  # params one step after a restore (measured ~1.3e-6)
@@ -389,37 +385,6 @@ class TestZeroMetrics:
         assert step_fn.telemetry.report()["optimizer_update_ms"] > 0
 
 
-class TestZeroBenchGate:
-    def test_opt_state_hbm_ratio_gate(self):
-        """BENCH_MODE=zero: per-replica optimizer-state HBM with the
-        sharded update must be >= 0.75*dp times smaller than replicated
-        (the ~1/N drop), with loss parity along for the ride. Trimmed
-        knobs keep this inside the tier-1 budget."""
-        env = dict(os.environ)
-        env.update({
-            "BENCH_MODE": "zero",
-            "BENCH_HISTORY": "0",   # hermetic: no BENCH_HISTORY.jsonl
-            "BENCH_ZERO_STEPS": "2",
-            "BENCH_ZERO_HLO": "0",  # skip the two extra AOT compiles
-            "JAX_PLATFORMS": "cpu",
-            "PYTHONPATH": REPO,
-        })
-        proc = subprocess.run(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            env=env, capture_output=True, text=True, timeout=600)
-        assert proc.returncode == 0, proc.stderr[-2000:]
-        result = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert result["metric"] == "zero_opt_state_hbm_ratio"
-        extra = result["extra"]
-        assert result["value"] >= extra["gate"], result
-        assert (extra["zero_opt_state_bytes_per_device"]
-                < extra["replicated_opt_state_bytes_per_device"])
-        assert extra["loss_parity_max_abs_diff"] <= 1e-4, extra
-        subs = {s["metric"]: s for s in result.get("submetrics", [])}
-        # the ROADMAP MFU acceptance: modeled update-ratio >= 1.3x
-        assert subs["zero_mfu_estimate_ratio"]["value"] >= 1.3, subs
-
-
 class TestZeroTrainFlow:
     def test_flow_runs_clean(self, run_flow, flows_dir):
         """The docs/training.md demo flow: replicated-vs-sharded parity,
@@ -430,52 +395,3 @@ class TestZeroTrainFlow:
         out = proc.stdout + proc.stderr
         assert "zero run ok" in out, out
         assert "opt_state_ratio=8.00" in out, out
-
-
-class TestSweepHarness:
-    SWEEP = os.path.join(REPO, "scripts", "sweep_fused.py")
-
-    def test_dry_run_grid_composition(self):
-        proc = subprocess.run(
-            [sys.executable, self.SWEEP, "--dry-run", "--quick"],
-            capture_output=True, text=True, timeout=60)
-        assert proc.returncode == 0, proc.stderr
-        lines = proc.stdout.strip().splitlines()
-        plans = [json.loads(l) for l in lines if l.startswith("{")]
-        # quick train grid: 1 remat x 1 chunk x 2 opts x 2 zero = 4
-        assert len(plans) == 4
-        for p in plans:
-            assert p["mode"] == "train"
-            assert set(p["knobs"]) == {"BENCH_REMAT_POLICY",
-                                       "BENCH_LOSS_CHUNK", "BENCH_OPT",
-                                       "TPUFLOW_ZERO"}
-        assert {p["knobs"]["TPUFLOW_ZERO"] for p in plans} == {"0", "1"}
-
-    def test_stub_bench_ledger_and_best_pick(self, tmp_path):
-        """A stub bench (value depends on the knobs) exercises the real
-        subprocess plumbing: every grid point lands in the ledger with
-        its knobs, and the best-config report picks the max."""
-        stub = tmp_path / "stub_bench.py"
-        stub.write_text(
-            "import json, os\n"
-            "value = 100.0 + 50.0 * int(os.environ['TPUFLOW_ZERO'])\n"
-            "assert os.environ['BENCH_HISTORY'] == '0'\n"
-            "print(json.dumps({'metric': 'tokens_per_sec',"
-            " 'value': value,"
-            " 'extra': {'device_kind': 'stub-cpu'}}))\n")
-        out = tmp_path / "sweep.jsonl"
-        proc = subprocess.run(
-            [sys.executable, self.SWEEP, "--quick",
-             "--bench", str(stub), "--out", str(out)],
-            capture_output=True, text=True, timeout=120)
-        assert proc.returncode == 0, proc.stderr
-        rows = [json.loads(l) for l in out.read_text().splitlines()]
-        assert len(rows) == 4
-        for row in rows:
-            assert row["device_kind"] == "stub-cpu"
-            assert row["metric"] == "tokens_per_sec"
-            assert row["knobs"]["TPUFLOW_ZERO"] in ("0", "1")
-        best = max(rows, key=lambda r: r["value"])
-        assert best["knobs"]["TPUFLOW_ZERO"] == "1"
-        assert "best[stub-cpu] tokens_per_sec=150.0" in proc.stdout
-        assert "TPUFLOW_ZERO=1" in proc.stdout
