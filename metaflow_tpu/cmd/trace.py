@@ -30,8 +30,10 @@ from .. import telemetry
 # event families that belong to a request's tree
 _REQUEST_PREFIXES = ("serve.request.", "fleet.request.")
 
-# serve.prefill_chunk timers carry request_id too: they become the
-# chunk-level child slices of the prefill phase
+# a serve.prefill_chunk timer is one prefill program: it names the
+# request of every row it carried (request_ids, with row_tokens and,
+# where traced, spans beside it) and becomes a chunk-level child slice
+# of the prefill phase of each
 _CHUNK_TIMER = "serve.prefill_chunk"
 
 # MPMD pipeline stages stamp a per-stage child span of the run
@@ -42,6 +44,15 @@ _MPMD_TRANSFER = "mpmd.transfer"
 
 def _data(rec):
     return rec.get("data") or {}
+
+
+def _chunk_row(rec, request_id):
+    """(span, tokens) of the row a prefill program carried for
+    `request_id`."""
+    d = _data(rec)
+    row = d["request_ids"].index(request_id)
+    spans = d.get("spans")
+    return (spans[row] if spans else None) or None, d["row_tokens"][row]
 
 
 def build_request_traces(records):
@@ -61,18 +72,21 @@ def build_request_traces(records):
         is_chunk = name == _CHUNK_TIMER
         if not (name.startswith(_REQUEST_PREFIXES) or is_chunk):
             continue
-        rid = _data(rec).get("request_id")
-        if rid is None:
-            continue
-        tree = trees.get(rid)
-        if tree is None:
-            tree = trees[rid] = {
-                "request_id": rid, "trace": None, "root_span": None,
-                "events": [], "attempts": [], "shed": None,
-            }
-            order.append(rid)
-        tree["events"].append(rec)
         d = _data(rec)
+        # a chunk belongs to every request its program carried
+        rids = (d.get("request_ids") or ()) if is_chunk \
+            else [r for r in (d.get("request_id"),) if r is not None]
+        for rid in rids:
+            tree = trees.get(rid)
+            if tree is None:
+                tree = trees[rid] = {
+                    "request_id": rid, "trace": None, "root_span": None,
+                    "events": [], "attempts": [], "shed": None,
+                }
+                order.append(rid)
+            tree["events"].append(rec)
+        if is_chunk or not rids:
+            continue
         if d.get("trace") and not tree["trace"]:
             tree["trace"] = d["trace"]
         if name == "fleet.request.dispatch":
@@ -116,7 +130,9 @@ def _attach_events(tree):
         if name == "fleet.request.shed":
             tree["shed"] = rec
             continue
-        att = _attempt_for(tree, d.get("span"))
+        span = (_chunk_row(rec, tree["request_id"])[0]
+                if name == _CHUNK_TIMER else d.get("span"))
+        att = _attempt_for(tree, span)
         att["events"].append(rec)
         if not tree["root_span"] and not name.startswith("fleet.") \
                 and d.get("span"):
@@ -304,11 +320,12 @@ def perfetto_export(trees):
             out.append(_slice("attempt %s" % (att["dispatch"] or 1),
                               _us(start, t0), (end - start) * 1e6,
                               pid, tid, args))
-            out.extend(_phase_slices(att, a_evts, t0, pid, tid))
+            out.extend(_phase_slices(att, a_evts, t0, pid, tid,
+                                     tree["request_id"]))
     return {"traceEvents": out, "displayTimeUnit": "ms"}
 
 
-def _phase_slices(att, a_evts, t0, pid, tid):
+def _phase_slices(att, a_evts, t0, pid, tid, request_id):
     """queue / prefill / decode sub-slices + instants for one attempt."""
     out = []
 
@@ -330,7 +347,8 @@ def _phase_slices(att, a_evts, t0, pid, tid):
             out.append(_slice(
                 "prefill_chunk",
                 _us(rec["ts"], t0) - rec["ms"] * 1000, rec["ms"] * 1000,
-                pid, tid, {"tokens": _data(rec).get("tokens")}))
+                pid, tid,
+                {"tokens": _chunk_row(rec, request_id)[1]}))
     if first_tok and fin:
         out.append(_slice("decode", _us(first_tok["ts"], t0),
                           (fin["ts"] - first_tok["ts"]) * 1e6, pid, tid,
@@ -418,7 +436,8 @@ def render_tree(tree, echo=print):
         for rec in att["events"]:
             name = rec.get("name", "").split(".")[-1]
             if rec.get("name") == _CHUNK_TIMER:
-                name = "prefill_chunk(%s tok)" % _data(rec).get("tokens")
+                name = "prefill_chunk(%s tok)" % _chunk_row(
+                    rec, tree["request_id"])[1]
             echo("    +%8.1fms  %s" % ((rec["ts"] - t_base) * 1000, name))
     decomp = ttft_decomposition(tree)
     if decomp:

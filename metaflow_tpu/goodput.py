@@ -633,6 +633,13 @@ def scheduler_metric_families(stats):
     fams.append(Family("tpuflow_serve_decode_steps", "counter",
                        "Batched decode steps executed")
                 .add(stats["decode_steps"]))
+    fams.append(
+        Family("tpuflow_serve_prefill", "counter",
+               "Prefill programs run, the rows (slots) they carried and "
+               "the prompt tokens in those rows")
+        .add(stats["prefill_programs"], {"count": "programs"})
+        .add(stats["prefill_rows"], {"count": "rows"})
+        .add(stats["prefill_tokens"], {"count": "tokens"}))
     fams.append(Family("tpuflow_serve_iterations", "counter",
                        "Scheduler loop iterations")
                 .add(stats["iterations"]))

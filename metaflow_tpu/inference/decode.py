@@ -39,7 +39,12 @@ in place: K and V for the attention layers, and for the Mamba layers
 the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py).
 Unlike a KV position, a recurrent state has no garbage that is
 overwritten before it is seen: `valid` tells the state-space layers
-which of the new positions are real.
+which of the new positions are real. The batch is the whole pool (a
+decode step) or, with `slots`, a few distinct rows of it (the slot
+engine's prefill program): K and V of those rows are then written in
+the pool by index and a layer's views of them read out of it, and the
+rows' small recurrent state is cut out of its pools before the loop
+and put back after it; no other row is touched and no pool is copied.
 
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
@@ -373,38 +378,79 @@ def _ffn(cfg, x, lp, mesh):
 
 
 def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
-                  layer, mesh=None, attn_impl="dense"):
+                  layer, mesh=None, attn_impl="dense", slots=None):
     """One attention block over T new tokens, reading+extending layer
     `layer` (a traced index) of the pools [layers, B, Smax, KV * Hd],
-    written and read in place. The feed-forward half is the family's."""
+    written and read in place: every row of the pool, or with `slots`
+    the rows it names. The feed-forward half is the family's."""
     lp = layer_params
     q, k, v = _attn_qkv(cfg, cos, sin, pos, x, lp)
 
     with jax.named_scope("kv_cache_update"):
-        cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos, layer)
-        cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos, layer)
+        cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos, layer,
+                               slots)
+        cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos, layer,
+                               slots)
 
+    read_k, read_v, at = cache_k, cache_v, layer
+    if slots is not None:
+        # the rows' views of this layer, cut out once a layer (a pool of
+        # one layer that holds the batch): a few MB a row, where a chunk
+        # loop that read two slots' chunks out of the whole pool made the
+        # compiler lay the pool out anew in every layer (PERF.md, PR 30)
+        with jax.named_scope("decode_attention"):
+            read_k = _slot_rows(cache_k, slots, layer)
+            read_v = _slot_rows(cache_v, slots, layer)
+        at = 0
     if attn_impl == "chunked":
-        attn = _chunked_cached_attention(q, cache_k, cache_v, pos, layer)
+        attn = _chunked_cached_attention(q, read_k, read_v, pos, at)
     else:
-        view = lambda pool: pool[layer].reshape(
+        view = lambda pool: pool[at].reshape(
             pool.shape[1:3] + (cfg.n_kv_heads, cfg.head_dim))
-        attn = _cached_attention(q, view(cache_k), view(cache_v), pos)
+        attn = _cached_attention(q, view(read_k), view(read_v), pos)
     x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
     return x, cache_k, cache_v
 
 
-def _write_layer(pool, new, pos, layer):
+def _write_layer(pool, new, pos, layer, slots=None):
     """new [B, T, KV, Hd] into pool [layers, B, Smax, KV * Hd] at `layer`,
-    every batch row at its own cursor (or all at a scalar `pos`)."""
+    every batch row at its own cursor (or all at a scalar `pos`); with
+    `slots` ([B] distinct), row b of `new` into row slots[b] of the pool.
+
+    A row of a prefill program is padded to the program's width, so its
+    last positions may lie past the pool's edge: those land on the last
+    position, which is past the row's cursor like every padded position
+    and so overwritten before it is seen (a clamped block write would
+    shift the real positions instead)."""
     new = new.reshape(new.shape[:2] + (-1,))
     if jnp.ndim(pos) == 0:
         return jax.lax.dynamic_update_slice(
             pool, new[None], (layer, 0, pos, 0))
     B, T = new.shape[:2]
-    return pool.at[layer, jnp.arange(B)[:, None],
-                   pos[:, None] + jnp.arange(T)[None]].set(
-                       new, mode="promise_in_bounds")
+    rows = jnp.arange(B) if slots is None else slots
+    at = jnp.minimum(pos[:, None] + jnp.arange(T)[None], pool.shape[2] - 1)
+    return pool.at[layer, rows[:, None], at].set(
+        new, mode="promise_in_bounds")
+
+
+def _slot_rows(pool, slots, layer=None):
+    """Rows `slots` ([R], traced) of a pool [layers, B, ...], each cut
+    out of its own slot, of every layer or of `layer` alone: a pool
+    [layers or 1, R, ...] that holds just those rows."""
+    size = (pool.shape[0] if layer is None else 1, 1) + pool.shape[2:]
+    rest = (0,) * (pool.ndim - 2)
+    return jnp.concatenate([
+        jax.lax.dynamic_slice(
+            pool, (0 if layer is None else layer, slots[r]) + rest, size)
+        for r in range(slots.shape[0])], axis=1)
+
+
+def _put_slot_rows(pool, rows, slots):
+    """`_slot_rows(pool, slots)` back into the pool, in place."""
+    for r in range(slots.shape[0]):
+        pool = jax.lax.dynamic_update_slice_in_dim(
+            pool, rows[:, r:r + 1], slots[r], axis=1)
+    return pool
 
 
 def _mamba_layer(cfg, x, lp, conv, state, valid):
@@ -416,10 +462,11 @@ def _mamba_layer(cfg, x, lp, conv, state, valid):
     return _ffn(cfg, x + out, lp, None), conv, state
 
 
-def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
+def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
     """The layer loop of every family: the activations and the whole
     cache are its carry, and layer i of a kind reads its weights out of
-    that kind's stack and reads and writes index i of that kind's pools."""
+    that kind's stack and reads and writes index i of that kind's pools
+    (of the rows `slots` names, where the batch is not the whole pool)."""
     fam = family(cfg)
     cos, sin = rope_frequencies(
         cfg.head_dim, cache["k"].shape[2], cfg.rope_theta,
@@ -434,7 +481,7 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
         if kind == "attention":
             x, cache["k"], cache["v"] = _decode_layer(
                 cfg, cos, sin, pos, x, lp, cache["k"], cache["v"], i,
-                mesh=mesh, attn_impl=attn_impl)
+                mesh=mesh, attn_impl=attn_impl, slots=slots)
             return x, cache
         # the layer's tail and state are read out of the pools and written
         # back under the scope of the op that uses them, so that a scope's
@@ -455,12 +502,26 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl):
             cache["ssm"] = put(cache["ssm"], state, i, 0)
         return x, cache
 
+    # K and V are read and written in their pools, rows `slots` of them.
+    # A recurrent state is small (a few MB a slot over all layers) and a
+    # slot's convolution tail lies inside a tile of the chip's layout,
+    # where one row cannot be written in place (compiled for the chip, the
+    # whole pool was laid out anew on the way in and out): the rows'
+    # tails and states of all layers are cut out once, carried through
+    # the loop as pools that hold just the batch, and put back after it
+    pools = {} if slots is None else {
+        name: cache[name] for name in ("conv", "ssm") if name in cache}
     with jax.named_scope("decode_layers"):
-        return jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
+        cache = dict(cache, **{name: _slot_rows(pool, slots)
+                               for name, pool in pools.items()})
+        x, cache = jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
+        for name, pool in pools.items():
+            cache[name] = _put_slot_rows(pool, cache[name], slots)
+    return x, cache
 
 
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
-                   attn_impl="dense", valid=None):
+                   attn_impl="dense", valid=None, slots=None):
     """Forward over T new tokens at absolute position `pos` (a traced
     scalar, or a traced [B] vector when every batch row decodes at its
     own offset — the continuous-batching engine), reading and extending
@@ -471,9 +532,14 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
     true positions lead each row: a recurrent state (`conv`, `ssm`)
     passes through the positions that are not valid. K and V need no
     mask (what is written there is overwritten before it is seen).
+    slots: None (row b of the batch is row b of the cache) or a traced
+    [B] vector of DISTINCT rows of a cache that holds more: row b of the
+    batch reads and extends row slots[b] in place and no other row is
+    touched (the slot engine's prefill program; `pos` is then a vector).
     Returns (logits [B, T, vocab] fp32, updated cache)."""
     x = params["embed"][tokens].astype(llama.param_dtype(cfg))
-    x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl)
+    x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl,
+                       slots)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     if "lm_head" in params:
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],

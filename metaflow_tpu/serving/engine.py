@@ -15,16 +15,26 @@ Each slot holds at most one in-flight request and carries host-side
 state (pos, sampling knobs, per-token rng keys). Three compiled programs
 cover everything:
 
-  - prefill: write one PROMPT CHUNK of one slot into the cache
-    (single-slot cache view via dynamic_slice on the batch axis; chunk
-    padded to a power-of-two bucket, so compiles are bounded by
-    log2(prefill_chunk) regardless of prompt-length diversity)
+  - prefill: ONE execution an iteration writes the next prompt tokens of
+    up to `rows` slots into the cache. tokens [R, W], slots [R],
+    start [R], n_real [R]: row r is slot slots[r]'s next tokens from its
+    cursor, padded to the width W. The rows are distinct slots (a Mamba
+    state and causal attention make a slot's second row depend on its
+    first); a lone prefilling slot gets one wider row instead. K and V
+    are written into, and read out of, the pool in place at the rows'
+    slots (inference/decode.py, `slots`); no slot's view is cut out and
+    written back. The shapes are a bounded set, `prefill_shapes(budget)`
+    ([1, chunk], [1, 2 chunk], [2, chunk] with the scheduler's default
+    budget of two chunks: the weights are read once whatever the width,
+    so there are no narrower buckets), and `warm_prefill` compiles all
+    of them before a request is admitted
   - decode: advance ALL slots one token in one fused call — per-slot
     positions (vector-pos decode_forward), per-slot dynamic_update_slice
     cache writes, per-slot slot-masked sampling (greedy/temperature/
     top-k/top-p as traced per-slot arrays, so one program serves every
     sampling-config mix)
-  - first-token: sample the token the final prefill chunk's logits imply
+  - first-token: sample, for every row of a prefill program that ends
+    its prompt, the token its logits imply; fetched in one wait
 
 Slots never wait for each other: a finished slot is released and can be
 refilled while its neighbors keep decoding. Free/prefilling slots ride
@@ -38,12 +48,13 @@ beside the KV pool, in the same cache tree: per Mamba layer and slot the
 convolution's tail and the float32 state (inference/decode.py,
 init_kv_cache). None of the three invariants above holds for a
 recurrence, which has no garbage that is overwritten before it is seen,
-so for such a model: (a) prefill_step tells the program how many of the
-bucket's positions are real, and the state after a chunk is the state
-after its last real token; (b) the fused decode step holds the state of
-every lane whose mask is false; (c) admit zeroes the slot's state (one
-small jitted program, host span `engine.state.reset`); (d) the state
-carries from chunk to chunk of one prompt in the pool. A KV range is not
+so for such a model: (a) the prefill program is told how many of each
+row's positions are real, and the state after a row is the state after
+its last real token (a row with none holds the state); (b) the fused
+decode step holds the state of every lane whose mask is false; (c)
+admit zeroes the slot's state (one small jitted program, host span
+`engine.state.reset`); (d) the state carries from chunk to chunk of one
+prompt in the pool. A KV range is not
 a prefix of such a model: seed_prefix, extract_kv, admit_prefilled and
 kv_token_bytes refuse it (`refuse_recurrent`), as do the prefix caches,
 the paged engine and the disaggregated handoff built on them.
@@ -194,25 +205,21 @@ class SlotEngine(object):
         self._d_tok = self._d_pos = self._d_mask = None
         self._d_temp = self._d_top_k = self._d_top_p = None
 
-        def _prefill(params, cache, chunk_tokens, slot, start, n_real=None):
-            # n_real: how many of the chunk's positions are the prompt's
-            # (the rest pad it to its bucket); None = all of them. Only a
-            # recurrent state needs it.
-            sub = {
-                name: jax.lax.dynamic_slice_in_dim(arr, slot, 1, axis=1)
-                for name, arr in cache.items()
-            }
+        def _prefill(params, cache, tokens, slots, start, n_real=None):
+            # tokens [R, W]: row r is the next tokens of slot slots[r]
+            # (distinct slots) from its position start[r], of which the
+            # first n_real[r] are the prompt's and the rest pad the row
+            # to the program's width (None = all are real; only a
+            # recurrent state asks). A lone scalar slot and start are a
+            # program of one row. The pools are read and written in
+            # place at those rows; no slot's view is cut out of them.
+            slots, start = jnp.reshape(slots, (-1,)), jnp.reshape(start, (-1,))
             valid = None if n_real is None else (
-                jnp.arange(chunk_tokens.shape[1]) < n_real)[None]
-            logits, sub = decode_forward(
-                params, chunk_tokens, sub, start, cfg, mesh=mesh,
-                attn_impl=self.attn_impl, valid=valid)
-            cache = {
-                name: jax.lax.dynamic_update_slice_in_dim(
-                    cache[name], sub[name], slot, axis=1)
-                for name in cache
-            }
-            return logits, cache
+                jnp.arange(tokens.shape[1])[None]
+                < jnp.reshape(n_real, (-1, 1)))
+            return decode_forward(
+                params, tokens, cache, start, cfg, mesh=mesh,
+                attn_impl=self.attn_impl, valid=valid, slots=slots)
 
         def _advance(nxt, tok, pos, mask):
             # decoding lanes take the new token and move their cursor;
@@ -242,11 +249,11 @@ class SlotEngine(object):
             tok, pos = _advance(nxt, tok, pos, mask)
             return nxt, tok, pos, cache
 
-        def _first_token(logits, idx, key, temp, top_k, top_p):
-            last = jax.lax.dynamic_index_in_dim(logits, idx, axis=1,
-                                                keepdims=False)
-            return sample_slots(last, key[None], temp[None], top_k[None],
-                                top_p[None])[0]
+        def _first_token(logits, idx, keys, temp, top_k, top_p):
+            # row r's token off position idx[r] of a prefill program's
+            # logits [R, W, vocab], every row with its own key and knobs
+            last = logits[jnp.arange(logits.shape[0]), idx]
+            return sample_slots(last, keys, temp, top_k, top_p)
 
         def _seed(cache, k, v, slot):
             # write a [layers, T, kv_heads, head_dim] KV range into one
@@ -314,7 +321,8 @@ class SlotEngine(object):
 
     def compile_counts(self):
         """jit cache entries per program — each decode variant must stay
-        at <= 1, prefill at <= number of chunk buckets."""
+        at <= 1, prefill and first_token at the programs of
+        `prefill_shapes` (all compiled by `warm_prefill`)."""
         return {
             "prefill": self._prefill_fn._cache_size(),
             "decode_greedy": self._decode_greedy_fn._cache_size(),
@@ -337,8 +345,8 @@ class SlotEngine(object):
 
     def admit(self, slot, prompt_tokens, max_new_tokens, temperature=0.0,
               top_k=None, top_p=None, rng=0):
-        """Bind a request to a free slot; prefill starts on the next
-        prefill_step calls. prompt_tokens: 1-D int sequence."""
+        """Bind a request to a free slot; prefill starts with the next
+        `prefill` that plans the slot. prompt_tokens: 1-D int sequence."""
         if self.active[slot]:
             raise ValueError("slot %d is busy" % slot)
         prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
@@ -384,7 +392,7 @@ class SlotEngine(object):
         head_dim], "v": ...}, host arrays) into the slot's cache view at
         positions [0, T) and move the prefill cursor to T, so chunked
         prefill resumes at the match boundary. Must run after admit(),
-        before the first prefill_step; T must be < the slot's prompt
+        before the slot's first prefill; T must be < the slot's prompt
         length (at least one token has to prefill so final-chunk logits
         exist for first-token sampling).
 
@@ -443,7 +451,8 @@ class SlotEngine(object):
         first sampled token, and enter the decode state directly. With
         the same (prompt, knobs, rng), the continued decode emits
         exactly the tokens a local prefill would — the key schedule
-        resumes at cursor 1, mirroring prefill_step's final chunk."""
+        resumes at cursor 1, as after a row of `prefill` that ends its
+        prompt."""
         refuse_recurrent(self.cfg, "admit_prefilled (disaggregated "
                          "handoff)")
         self.admit(slot, prompt_tokens, max_new_tokens,
@@ -489,58 +498,113 @@ class SlotEngine(object):
 
     # ---------- device work ----------
 
-    def prefill_step(self, slot):
-        """Write the next prompt chunk of `slot` into the cache.
+    def prefill_shapes(self, budget):
+        """The (rows, width) of every prefill program that a scheduler
+        with `budget` prompt tokens an iteration can call: up to
+        k = budget // prefill_chunk rows (at least one, at most the
+        pool's slots), r rows at every width of 1 .. k // r chunks, so
+        that rows x width never passes k chunks. Three programs with the
+        default budget of two chunks: [1, chunk], [1, 2 chunk],
+        [2, chunk]."""
+        k = max(1, int(budget) // self.prefill_chunk)
+        return [(rows, chunks * self.prefill_chunk)
+                for rows in range(1, min(k, self.max_slots) + 1)
+                for chunks in range(1, k // rows + 1)]
 
-        Returns (tokens_consumed, first_token_or_None): first_token is
-        the request's first sampled token, produced when the final chunk
-        lands (chunked prefill — long prompts spread over several calls
-        so decode steps for other slots interleave between them)."""
-        if not self.active[slot] or self.decoding[slot]:
-            raise ValueError("slot %d is not prefilling" % slot)
-        prompt = self._prompt[slot]
-        start = int(self._prefill_cursor[slot])
-        end = min(start + self.prefill_chunk, prompt.size)
-        chunk = prompt[start:end]
-        # cap the pad bucket at the cache edge: a bucketed write spilling
-        # past max_seq would be CLAMPED by dynamic_update_slice and
-        # silently rewrite earlier live positions
-        bucket = bucket_length(
-            chunk.size, minimum=self.min_bucket,
-            maximum=min(self.prefill_chunk, self.max_seq_len - start))
-        if bucket > chunk.size:
-            chunk = np.concatenate([
-                chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
-        args = (jnp.asarray(chunk)[None], jnp.int32(slot), jnp.int32(start))
-        if self.recurrent:   # only a recurrent state asks which are real
-            args += (jnp.int32(end - start),)
+    def warm_prefill(self, budget):
+        """Compile every program of `prefill_shapes(budget)`, and the
+        first-token program of each, by running it on rows that hold
+        nothing real, so that no request's prefill is the first call of
+        a shape. Safe with requests in flight: a row with nothing real
+        writes only past its slot's cursor (overwritten before it is
+        seen) and holds a recurrent state."""
+        for rows, width in self.prefill_shapes(budget):
+            slots = np.arange(rows, dtype=np.int32)
+            none = np.zeros(rows, np.int32)
+            logits, self._cache = self._prefill_fn(
+                self.params, self._cache,
+                jnp.asarray(np.full((rows, width), self.pad_id, np.int32)),
+                jnp.asarray(slots), jnp.asarray(self.pos[slots]),
+                jnp.asarray(none))
+            self._first_fn(
+                logits, jnp.asarray(none),
+                jnp.asarray(np.zeros((rows, 2), np.uint32)),
+                jnp.asarray(self._temp[slots]),
+                jnp.asarray(self._top_k[slots]),
+                jnp.asarray(self._top_p[slots]))
+
+    def prefill(self, plan):
+        """Run one iteration's prefill, ONE execution of the prefill
+        program: `plan` is [(slot, most_tokens), ...] over distinct
+        prefilling slots, and row r of the program carries the next
+        min(most_tokens, what is left) prompt tokens of its slot, padded
+        to the program's width (the longest row's tokens, to a whole
+        number of chunks).
+
+        Returns [(tokens_consumed, first_token_or_None), ...] in the
+        plan's order: first_token is the request's first sampled token,
+        from this program's logits, where the row ends its prompt (a
+        long prompt spreads over several iterations, so that decode
+        steps for the other slots interleave). The first tokens of all
+        rows are fetched in one wait."""
+        slots = np.asarray([slot for slot, _ in plan], np.int32)
+        if len(set(slots.tolist())) != len(plan) or not len(plan):
+            # a Mamba state and causal attention make a slot's second row
+            # depend on its first: one row a slot and program
+            raise ValueError("a prefill program takes distinct slots, "
+                             "got %r" % (slots.tolist(),))
+        for slot in slots:
+            if not self.active[slot] or self.decoding[slot]:
+                raise ValueError("slot %d is not prefilling" % slot)
+        start = self._prefill_cursor[slots]
+        sizes = np.asarray([self._prompt[s].size for s in slots])
+        n_real = np.minimum(np.asarray([most for _, most in plan]),
+                            sizes - start).astype(np.int32)
+        width = -(-int(n_real.max()) // self.prefill_chunk) \
+            * self.prefill_chunk
+        tokens = np.full((len(plan), width), self.pad_id, np.int32)
+        for r, slot in enumerate(slots):
+            tokens[r, :n_real[r]] = \
+                self._prompt[slot][start[r]:start[r] + n_real[r]]
         with telemetry.annotate("engine.prefill.dispatch"):
-            logits, self._cache = self._prefill_fn(self.params, self._cache,
-                                                   *args)
-        self._prefill_cursor[slot] = end
+            logits, self._cache = self._prefill_fn(
+                self.params, self._cache, jnp.asarray(tokens),
+                jnp.asarray(slots), jnp.asarray(start), jnp.asarray(n_real))
+        end = start + n_real
+        self._prefill_cursor[slots] = end
         # keep pos at the prefill cursor: a mid-prefill slot rides
         # through fused decode steps as a masked lane whose write lands
         # at pos — it must fall where the NEXT chunk overwrites it, not
         # on already-written positions
-        self.pos[slot] = end
+        self.pos[slots] = end
         self._dirty = True
-        consumed = end - start
-        if end < prompt.size:
-            return consumed, None
-        # final chunk: the first generated token comes off these logits
-        first = self._first_fn(
-            logits, jnp.int32(prompt.size - 1 - start),
-            jnp.asarray(self._keys_for(slot)),
-            jnp.float32(self._temp[slot]), jnp.int32(self._top_k[slot]),
-            jnp.float32(self._top_p[slot]))
-        with telemetry.annotate("engine.first_token.fetch"):
-            first = int(first)   # the host waits for the device here
-        self.decoding[slot] = True
-        self.pos[slot] = prompt.size
-        self._tok[slot] = first
-        self._key_cursor[slot] += 1
-        self._dirty = True
-        return consumed, first
+        ends = end == sizes
+        first = None
+        if ends.any():
+            # the rows that do not end sample too, greedily, and are not
+            # read: one program whatever the rows that end
+            first = self._first_fn(
+                logits, jnp.asarray(n_real - 1),
+                jnp.asarray(np.stack([
+                    self._keys_for(s) if e else np.zeros(2, np.uint32)
+                    for s, e in zip(slots, ends)])),
+                jnp.asarray(np.where(ends, self._temp[slots], 0.0),
+                            jnp.float32),
+                jnp.asarray(self._top_k[slots]),
+                jnp.asarray(self._top_p[slots]))
+            with telemetry.annotate("engine.first_token.fetch"):
+                first = np.asarray(first)   # the host waits here, once
+            done = slots[ends]
+            self.decoding[done] = True
+            self._tok[done] = first[ends]
+            self._key_cursor[done] += 1
+        return [(int(n), int(first[r]) if ends[r] else None)
+                for r, n in enumerate(n_real)]
+
+    def prefill_step(self, slot):
+        """The one-row case of `prefill`: the next chunk of `slot`.
+        Returns (tokens_consumed, first_token_or_None)."""
+        return self.prefill([(slot, self.prefill_chunk)])[0]
 
     def _keys_for(self, slot):
         keys = self._step_keys[slot]

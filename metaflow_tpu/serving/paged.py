@@ -292,7 +292,7 @@ def _paged_forward(params, tokens, pool_kv, tables, pos, cfg,
 class PagedEngine(object):
     """SlotEngine-compatible engine over a paged KV pool.
 
-    Same API surface the scheduler drives (admit/prefill_step/
+    Same API surface the scheduler drives (admit/prefill/
     decode_step/release/seed_prefix/extract_kv/admit_prefilled), plus
     the paged extensions: can_admit/fits (reservation capacity),
     seed_pages (zero-copy prefix attach), slot_prefix_pages (prefix
@@ -741,10 +741,28 @@ class PagedEngine(object):
 
     # ---------- device work ----------
 
+    def warm_prefill(self, budget):
+        """SlotEngine.warm_prefill's place in the scheduler's contract:
+        this engine's chunk programs (one slot, power-of-two buckets)
+        compile on first use, as they always have."""
+
+    def prefill(self, plan):
+        """SlotEngine.prefill's contract, [(slot, most_tokens), ...] ->
+        [(tokens_consumed, first_token_or_None), ...], answered with
+        this engine's own program: one slot and one chunk an execution,
+        so a row of several chunks is several executions."""
+        out = []
+        for slot, most in plan:
+            consumed, first = 0, None
+            while consumed < most and first is None:
+                n, first = self.prefill_step(slot)
+                consumed += n
+            out.append((consumed, first))
+        return out
+
     def prefill_step(self, slot):
         """Write the next prompt chunk of `slot` through its block
-        table. Same contract as SlotEngine.prefill_step: returns
-        (tokens_consumed, first_token_or_None)."""
+        table: returns (tokens_consumed, first_token_or_None)."""
         if not self.active[slot] or self.decoding[slot]:
             raise ValueError("slot %d is not prefilling" % slot)
         prompt = self._prompt[slot]

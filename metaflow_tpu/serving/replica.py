@@ -37,23 +37,23 @@ from .. import knobs
 
 
 def _add_step_delay(engine, delay_s):
-    """Each prefill chunk / fused decode step holds its slot for
+    """Each prefill program / fused decode step holds its slots for
     `delay_s` more wall seconds (GIL released)."""
     real_decode = engine.decode_step
-    real_prefill = engine.prefill_step
+    real_prefill = engine.prefill
 
     def decode_step():
         out = real_decode()
         time.sleep(delay_s)
         return out
 
-    def prefill_step(slot):
-        out = real_prefill(slot)
+    def prefill(plan):
+        out = real_prefill(plan)
         time.sleep(delay_s)
         return out
 
     engine.decode_step = decode_step
-    engine.prefill_step = prefill_step
+    engine.prefill = prefill
 
 
 def _warm(engine):

@@ -10,9 +10,14 @@ One scheduler iteration (`step()`):
 
   1. reap  — cancelled/deadline-expired requests free their slot NOW
   2. admit — free slots refill from the queue head (FIFO)
-  3. prefill — up to `prefill_budget` prompt tokens, round-robin over
-     prefilling slots; a slot whose final chunk lands emits its first
-     token (TTFT) and joins the decode set
+  3. prefill — up to `prefill_budget` prompt tokens in ONE engine
+     program (engine.prefill): the budget's k whole chunks go to up to
+     k prefilling slots, a row each, round-robin, or to a lone slot as
+     one row of k chunks (`_prefill_plan`); every slot whose prompt ends
+     in the program emits its first token (TTFT) and joins the decode
+     set. The program's shapes are compiled when the scheduler is built
+     (engine.warm_prefill); prefill_programs / prefill_rows /
+     prefill_tokens count how often it engages
   4. decode — ONE fused jitted step advances every decoding slot; eos /
      max_new_tokens finishes a request and releases its slot immediately
      (the next iteration's admit refills it — no lockstep)
@@ -29,9 +34,10 @@ serve.decode_step / serve.prefill_chunk timers.
 Every boundary of an iteration is also a span on the profiler's clock
 (telemetry.annotate; recorded while a profiler session is open, a flag
 check otherwise): serve.iteration around step(), inside it serve.reap,
-serve.admit, serve.prefill_chunk and serve.decode_step (the two timers),
-serve.deliver; the engine adds engine.* spans inside the last three
-(docs/observability.md has the table).
+serve.admit, serve.prefill_chunk (one a prefill program: rows, tokens,
+and request_ids, slots, row_tokens a row) and serve.decode_step (the two
+timers), serve.deliver; the engine adds engine.* spans inside the last
+three (docs/observability.md has the table).
 """
 
 import itertools
@@ -198,6 +204,9 @@ class Scheduler(object):
         if self.prefill_budget < 1:
             raise ValueError("prefill_budget must be >= 1, got %d"
                              % self.prefill_budget)
+        # every prefill program this budget can call is compiled now,
+        # before a request is admitted (engine.prefill_shapes)
+        engine.warm_prefill(self.prefill_budget)
         self._queue = TenantQueues(self.tenancy)
         self._slots = {}          # slot index -> Request
         self._cond = threading.Condition()
@@ -215,16 +224,22 @@ class Scheduler(object):
         self.served = 0
         self.cancelled_count = 0
         self.decode_steps = 0
+        # how often one prefill program engages: calls of engine.prefill
+        # (one device program each on the slot engine), the rows they
+        # carried and the prompt tokens in those rows
+        self.prefill_programs = 0
+        self.prefill_rows = 0
+        self.prefill_tokens = 0
         self.peak_in_flight = 0
         self._occupancy_sum = 0.0
         # goodput accounting: device-busy seconds split prefill/decode;
         # idle = elapsed - busy (stats()["goodput"], /metrics). Both are
         # the host's time around the engine call. A decode step waits
         # for its tokens, so busy_decode_s includes whatever the device
-        # still had queued before the step; a prefill chunk returns once
-        # dispatched unless it is its prompt's last (which fetches the
-        # first token), so busy_prefill_s is dispatch time for every
-        # other chunk, not device time (PERF.md, PR 23)
+        # still had queued before the step; a prefill program returns
+        # once dispatched unless a row ends its prompt (which fetches the
+        # first tokens), so busy_prefill_s is dispatch time for every
+        # other program, not device time (PERF.md, PR 23)
         self.busy_prefill_s = 0.0
         self.busy_decode_s = 0.0
         self._t_started = time.perf_counter()
@@ -626,36 +641,54 @@ class Scheduler(object):
             "request_id": req.id, "matched_tokens": handle.length,
             "prompt_tokens": len(req.tokens)}))
 
+    def _prefill_plan(self):
+        """Which prefilling slots get how many of this iteration's
+        `prefill_budget` tokens: [(slot, most_tokens), ...] for ONE
+        engine program. The budget holds k whole chunks (at least one);
+        up to k slots get a row each, taken round-robin so that one long
+        prompt cannot starve the others, and each row may take the
+        k // rows chunks that keep rows x width inside the budget: a
+        lone prefilling slot gets one row of the whole budget."""
+        slots = [s for s, r in sorted(self._slots.items())
+                 if r.state == "prefill"]
+        if not slots:
+            return []
+        chunk = self.engine.prefill_chunk
+        k = max(1, self.prefill_budget // chunk)
+        rows = min(k, len(slots))
+        at = self._prefill_rr % len(slots)
+        self._prefill_rr = at + rows
+        return [(slots[(at + j) % len(slots)], k // rows * chunk)
+                for j in range(rows)]
+
     def _prefill(self):
-        budget = self.prefill_budget
-        worked = False
-        while budget > 0:
-            slots = [s for s, r in sorted(self._slots.items())
-                     if r.state == "prefill"]
-            if not slots:
-                break
-            # round-robin so one long prompt cannot starve the others
-            self._prefill_rr += 1
-            slot = slots[self._prefill_rr % len(slots)]
-            req = self._slots[slot]
-            # the chunk's attribution comes from the ENGINE's slot
-            # binding (bind_slot_context at admit): device work is
-            # stamped by the layer that performed it
-            ctx = (self.engine.slot_context(slot)
-                   if hasattr(self.engine, "slot_context") else None)
-            chunk_data = dict(ctx) if ctx \
-                else self._tdata(req, {"request_id": req.id})
-            chunk_data["slot"] = slot
-            with telemetry.timer("serve.prefill_chunk",
-                                 data=chunk_data) as chunk:
-                consumed, first = self.engine.prefill_step(slot)
-                chunk.set(tokens=consumed)
-            self.busy_prefill_s += chunk.seconds
-            budget -= consumed
-            worked = True
+        plan = self._prefill_plan()
+        if not plan:
+            return False
+        slots = [slot for slot, _ in plan]
+        reqs = [self._slots[slot] for slot in slots]
+        # the program's attribution comes from the ENGINE's slot binding
+        # (bind_slot_context at admit): device work is stamped by the
+        # layer that performed it
+        ctxs = [self.engine.slot_context(slot)
+                or self._tdata(req, {"request_id": req.id})
+                for slot, req in zip(slots, reqs)]
+        data = {"rows": len(plan), "slots": slots,
+                "request_ids": [c["request_id"] for c in ctxs]}
+        if any(c.get("span") for c in ctxs):
+            data["spans"] = [c.get("span") or "" for c in ctxs]
+        with telemetry.timer("serve.prefill_chunk", data=data) as chunk:
+            results = self.engine.prefill(plan)
+            consumed = [n for n, _ in results]
+            chunk.set(tokens=sum(consumed), row_tokens=consumed)
+        self.busy_prefill_s += chunk.seconds
+        self.prefill_programs += 1
+        self.prefill_rows += len(plan)
+        self.prefill_tokens += sum(consumed)
+        for req, slot, (_, first) in zip(reqs, slots, results):
             if first is not None:
                 self._prefill_done(req, slot, first)
-        return worked
+        return True
 
     def _prefill_done(self, req, slot, first):
         """The final prefill chunk landed: populate the prefix cache,
@@ -839,6 +872,9 @@ class Scheduler(object):
             "served": self.served,
             "cancelled": self.cancelled_count,
             "decode_steps": self.decode_steps,
+            "prefill_programs": self.prefill_programs,
+            "prefill_rows": self.prefill_rows,
+            "prefill_tokens": self.prefill_tokens,
             "iterations": self.iteration,
             "draining": self._draining,
             # rolling-window tail latency (the SLO monitor's poll surface)

@@ -101,11 +101,16 @@ def annotate(name, **attrs):
 
 def _span_attrs(step_num, data):
     """A timer's step number and the primitive values of its data, as a
-    span's stats."""
+    span's stats; a list of primitives (one value a row of a prefill
+    program) goes on joined by "|" (a comma would end the stat: the
+    profiler packs a span's stats as `name#k=v,k=v#`)."""
     attrs = {} if step_num is None else {"step_num": int(step_num)}
-    if data:
-        attrs.update((k, v) for k, v in data.items()
-                     if isinstance(v, (str, int, float, bool)))
+    for k, v in (data or {}).items():
+        if isinstance(v, (str, int, float, bool)):
+            attrs[k] = v
+        elif isinstance(v, (list, tuple)) and all(
+                isinstance(x, (str, int, float, bool)) for x in v):
+            attrs[k] = "|".join(str(x) for x in v)
     return attrs
 
 
@@ -143,7 +148,7 @@ class _Timer(object):
     def set(self, **attrs):
         """Stats known only inside the block: onto the span and into the
         record's data."""
-        self._span.set_metadata(**attrs)
+        self._span.set_metadata(**_span_attrs(None, attrs))
         self.data = dict(self.data or (), **attrs)
 
     def __exit__(self, exc_type, exc, tb):

@@ -1812,6 +1812,7 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_max_context_tokens": "gauge",
     "tpuflow_serve_requests": "counter",
     "tpuflow_serve_decode_steps": "counter",
+    "tpuflow_serve_prefill": "counter",
     "tpuflow_serve_iterations": "counter",
     "tpuflow_serve_ttft_ms": "summary",
     "tpuflow_serve_itl_ms": "summary",

@@ -316,7 +316,8 @@ def _scheduler_stats():
     return {
         "queue_depth": 2, "in_flight": 3, "slots": 4, "occupancy": 0.75,
         "mean_batch_occupancy": 0.6, "served": 11, "cancelled": 1,
-        "decode_steps": 40, "iterations": 55, "draining": False,
+        "decode_steps": 40, "prefill_programs": 9, "prefill_rows": 14,
+        "prefill_tokens": 500, "iterations": 55, "draining": False,
         "p50_ttft_ms": 12.0, "p99_ttft_ms": 30.0,
         "p50_itl_ms": 3.0, "p99_itl_ms": 8.0,
         "peak_in_flight": 4, "max_context_tokens": 96,
@@ -509,6 +510,9 @@ class TestReplicaMetricsEndpoint:
             == stats["served"]
         assert only("tpuflow_serve_decode_steps") \
             == stats["decode_steps"]
+        for count in ("programs", "rows", "tokens"):
+            assert only("tpuflow_serve_prefill", count=count) \
+                == stats["prefill_" + count]
         assert only("tpuflow_serve_ttft_ms", quantile="0.99") \
             == pytest.approx(stats["p99_ttft_ms"] or 0.0)
         # the serve-side goodput tally rides the same stats dict

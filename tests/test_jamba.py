@@ -225,6 +225,102 @@ def test_chunk_sizes_16_and_64_agree(params, engine, alone):
         assert serve(wide, 0, p) == serve(engine, 0, p) == alone(p)
 
 
+# ---- one prefill program an iteration: rows of several slots (PR 30) ----
+
+@pytest.mark.parametrize("lengths,rows", [
+    # two slots a program: 16 ends on the chunk's edge, 37 mid-chunk
+    ((37, 16), [(16, 16), (21,)]),
+    # a lone slot takes rows of 2 x chunk, then what is left
+    ((50,), [(32,), (18,)]),
+    # three admitted in one iteration, two rows a program, round robin
+    ((5, 16, 37), [(5, 16), (32,), (5,)]),
+])
+def test_uneven_prompts_admitted_together_emit_what_they_emit_alone(
+        params, alone, lengths, rows):
+    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
+                     prefill_chunk=16)
+    sched = Scheduler(eng)
+    plans, real = [], eng.prefill
+    eng.prefill = lambda plan: plans.append(real(plan)) or plans[-1]
+    prompts = [prompt(n, salt=n) for n in lengths]
+    reqs = [sched.submit(Request(p.tolist(), max_new_tokens=NEW))
+            for p in prompts]
+    sched.run_until_idle(10_000)
+    assert [r.generated for r in reqs] == [alone(p) for p in prompts]
+    assert [tuple(n for n, _ in plan) for plan in plans] == rows
+    assert sched.prefill_programs == len(rows)
+    assert sched.prefill_tokens == sum(lengths)
+
+
+def test_state_after_rows_of_several_slots_is_the_one_slot_paths(params,
+                                                                 engine):
+    """Two prompts prefilled as rows of one program (a padded row beside
+    a whole one, then a lone wider row): each slot's tail, state, K and
+    V are those of the prompt run alone through the cache."""
+    prompts = {0: prompt(37, salt=1), 2: prompt(50, salt=2)}
+    for slot, p in prompts.items():
+        engine.admit(slot, p, NEW)
+    for _ in range(2):
+        assert engine.prefill([(2, 16), (0, 16)]) == [(16, None)] * 2
+    (n0, first0), (n2, _) = engine.prefill([(0, 16), (2, 16)])
+    assert (n0, n2) == (5, 16) and first0 is not None       # 0 ends
+    assert engine.prefill([(2, 32)])[0][0] == 2             # 2 ends
+    assert engine.decoding[0] and engine.decoding[2]
+    for slot, p in prompts.items():
+        _, want = decode_forward(params, jnp.asarray(p)[None],
+                                 init_kv_cache(CFG, 1, 128), 0, CFG)
+        for name in ("conv", "ssm"):
+            got = engine._cache[name][:, slot]
+            assert float(jnp.abs(got - want[name][:, 0]).max()) < 1e-5, name
+            assert float(jnp.abs(got).max()) > 0
+        for name in ("k", "v"):
+            got = engine._cache[name][:, slot, :len(p)]
+            assert float(jnp.abs(
+                got - want[name][:, 0, :len(p)]).max()) < 1e-5, name
+        engine.release(slot)
+
+
+def test_rows_with_nothing_real_leave_every_slot_as_it_was(engine):
+    """What compiles the programs before a request: rows that hold
+    nothing real. With requests in flight every slot's tail and state,
+    and the K and V a slot can see, stay bit for bit."""
+    engine.admit(0, prompt(37, salt=3), NEW)   # mid-prefill, 16 of 37 in
+    engine.prefill_step(0)
+    engine.admit(1, prompt(16, salt=4), NEW)   # decoding
+    prefill(engine, 1)
+    engine.decode_step()
+    seen = {slot: int(engine.pos[slot]) for slot in range(3)}
+    before = jax.tree.map(np.asarray, engine._cache)
+    engine.warm_prefill(2 * engine.prefill_chunk)
+    after = jax.tree.map(np.asarray, engine._cache)
+    for name in ("conv", "ssm"):
+        assert np.array_equal(after[name], before[name]), name
+    for name in ("k", "v"):
+        for slot, n in seen.items():
+            assert np.array_equal(after[name][:, slot, :n],
+                                  before[name][:, slot, :n]), (name, slot)
+    assert engine.prefill_shapes(2 * engine.prefill_chunk) == [
+        (1, 16), (1, 32), (2, 16)]
+    engine.release(0)
+    engine.release(1)
+
+
+def test_twenty_prompt_lengths_compile_nothing(params):
+    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
+                     prefill_chunk=16)
+    sched = Scheduler(eng)
+    built = eng.compile_counts()
+    assert built["prefill"] == built["first_token"] == 3
+    lengths = [1, 2, 5, 15, 16, 17, 20, 31, 32, 33, 40, 47, 48, 49, 63, 64,
+               65, 80, 96, 100]
+    reqs = [sched.submit(Request(prompt(n, salt=n).tolist(),
+                                 max_new_tokens=2)) for n in lengths]
+    sched.run_until_idle(10_000)
+    assert all(r.reason == "length" for r in reqs)
+    assert eng.compile_counts() == dict(
+        built, decode_greedy=1, reset_state=1)
+
+
 # ---- (e) what treats a KV range as a prefix refuses the model by name ----
 
 def _kv(n):

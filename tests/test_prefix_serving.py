@@ -201,6 +201,37 @@ class TestEngineKVRoundtrip:
         assert first2 == first
         engine.release(slot2)
 
+    def test_a_seeded_slot_rides_a_program_beside_an_unseeded_one(
+            self, engine):
+        """The rows of one prefill program start where each slot's
+        cursor is: a slot seeded from a cached range and a slot that
+        starts at 0 give the first tokens they give alone."""
+        prompts = [list(range(3, 43)), list(range(7, 30))]
+        alone, kv = [], None
+        for p in prompts:
+            slot = engine.free_slots()[0]
+            engine.admit(slot, p, 4)
+            first = None
+            while first is None:
+                _, first = engine.prefill_step(slot)
+            alone.append(first)
+            kv = kv or engine.extract_kv(slot, 17)
+            engine.release(slot)
+        a, b = engine.free_slots()[:2]
+        engine.admit(a, prompts[0], 4)
+        engine.seed_prefix(a, kv)
+        engine.admit(b, prompts[1], 4)
+        consumed, firsts = [0, 0], [None, None]
+        while None in firsts:
+            plan = [(s, 16) for s, f in zip((a, b), firsts) if f is None]
+            rows = [i for i, f in enumerate(firsts) if f is None]
+            for i, (n, first) in zip(rows, engine.prefill(plan)):
+                consumed[i] += n
+                firsts[i] = first
+        assert consumed == [40 - 17, 23] and firsts == alone
+        engine.release(a)
+        engine.release(b)
+
     def test_seed_rejects_full_prompt_and_started_slots(self, engine):
         prompt = list(range(5, 25))
         slot = engine.free_slots()[0]

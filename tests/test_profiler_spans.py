@@ -145,15 +145,25 @@ class TestSchedulerSpans:
                    if e[1] == "serve.admit") == len(PROMPTS)
 
     def test_every_chunk_names_its_request_and_slot(self, captured):
+        """One span a prefill program: it names the request, slot and
+        tokens of every row it carried (a value a row, joined by "|")."""
         chunks = [e for e in captured["events"]
                   if e[1] == "serve.prefill_chunk"]
         assert chunks
-        assert all({"request_id", "slot", "tokens"} <= set(e[4])
-                   for e in chunks)
+        assert all({"request_ids", "slots", "row_tokens", "rows",
+                    "tokens"} <= set(e[4]) for e in chunks)
+        rows = []   # (request id, slot, tokens) of every row
+        for e in chunks:
+            mine = list(zip(e[4]["request_ids"].split("|"),
+                            map(int, str(e[4]["slots"]).split("|")),
+                            map(int, str(e[4]["row_tokens"]).split("|"))))
+            assert len(mine) == e[4]["rows"]
+            assert sum(r[2] for r in mine) == e[4]["tokens"]
+            rows += mine
         for req in captured["requests"]:
-            mine = [e for e in chunks if e[4]["request_id"] == req.id]
-            assert sum(e[4]["tokens"] for e in mine) == len(req.tokens)
-            assert {e[4]["slot"] for e in mine} == {req.slot}
+            mine = [r for r in rows if r[0] == req.id]
+            assert sum(r[2] for r in mine) == len(req.tokens)
+            assert {r[1] for r in mine} == {req.slot}
 
 
 class TestNoSession:
@@ -208,7 +218,9 @@ class TestTimerIsBoth:
         assert {"serve.prefill_chunk", "serve.decode_step"} <= names
         chunk = [r for r in captured["records"]
                  if r["name"] == "serve.prefill_chunk"][0]
-        assert {"request_id", "slot", "tokens"} <= set(chunk["data"])
+        assert {"request_ids", "slots", "row_tokens", "rows",
+                "tokens"} <= set(chunk["data"])
+        assert len(chunk["data"]["request_ids"]) == chunk["data"]["rows"]
         # the finer spans are spans only: no record, no schema change
         assert not names & {"serve.iteration", "serve.reap", "serve.admit",
                             "serve.deliver", "engine.decode.fetch",

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from metaflow_tpu.inference import generate
-from metaflow_tpu.models import llama
+from metaflow_tpu.models import llama, mixtral
 from metaflow_tpu.serving import (
     CapacityError,
     QueueFullError,
@@ -138,6 +138,146 @@ class TestTokenIdentity:
         assert counts["decode_sampled"] <= 1
         # prefill chunk buckets: powers of two up to prefill_chunk
         assert counts["prefill"] <= 3
+
+
+# ---- one prefill program an iteration (PR 30) ----
+
+CHUNK = 16          # prefill_chunk of the engines below; the budget is 2
+FAMILIES = {"llama": (llama, llama.LlamaConfig),
+            "mixtral": (mixtral, mixtral.MixtralConfig)}
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def family_engine(request):
+    """(cfg, params, engine, programs) of a tiny model of each family:
+    three slots, chunks of 16, float32; `programs` counts the
+    executions of the engine's prefill program."""
+    mod, config = FAMILIES[request.param]
+    cfg = config.tiny()
+    params = mod.init_params(jax.random.PRNGKey(0), cfg)
+    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
+                     prefill_chunk=CHUNK)
+    programs = _count_calls(eng, "_prefill_fn")
+    return cfg, params, eng, programs
+
+
+def _count_calls(obj, name):
+    """Wrap obj.<name> so that calls[0] counts its calls."""
+    real, calls = getattr(obj, name), [0]
+
+    def counted(*args, **kw):
+        calls[0] += 1
+        return real(*args, **kw)
+
+    counted._cache_size = real._cache_size
+    setattr(obj, name, counted)
+    return calls
+
+
+def _prompts(cfg, lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, cfg.vocab_size, n).tolist() for n in lengths]
+
+
+class TestOnePrefillProgramAnIteration:
+    @pytest.mark.parametrize("lengths,rows", [
+        # two slots a program; 21 ends mid-chunk (5 real of 16) beside a
+        # full row of the other
+        ((21, 40), [(16, 16), (5, 16), (8,)]),
+        # a lone slot takes a row of 2 x chunk, then what is left
+        ((70,), [(32,), (32,), (6,)]),
+        # three admitted in one iteration, two rows a program, round
+        # robin; 32 and 16 end exactly on a chunk's edge
+        ((32, 16, 9), [(16, 16), (16, 9)]),
+    ])
+    def test_uneven_prompts_admitted_together_emit_generates_tokens(
+            self, family_engine, lengths, rows):
+        cfg, params, eng, programs = family_engine
+        sched = Scheduler(eng)
+        plans = []
+        real = eng.prefill
+        eng.prefill = lambda plan: plans.append(real(plan)) or plans[-1]
+        try:
+            reqs = [sched.submit(Request(p, max_new_tokens=6, rng=i))
+                    for i, p in enumerate(_prompts(cfg, lengths))]
+            sched.run_until_idle(10_000)
+        finally:
+            eng.prefill = real
+        for req in reqs:
+            assert req.generated == _ref_tokens(params, cfg, req)
+        # the tokens each program's rows consumed, program by program
+        assert [tuple(n for n, _ in plan) for plan in plans] == rows
+
+    def test_at_most_the_budget_and_one_program_an_iteration(
+            self, family_engine):
+        cfg, params, eng, programs = family_engine
+        sched = Scheduler(eng)
+        lengths = (90, 3, 40, 17, 64, 33)
+        reqs = [sched.submit(Request(p, max_new_tokens=3, rng=i))
+                for i, p in enumerate(_prompts(cfg, lengths, seed=1))]
+        seen, ran_before = [], programs[0]
+        while sched.pending():
+            before = (programs[0], sched.prefill_tokens)
+            sched.step()
+            ran, tokens = (programs[0] - before[0],
+                           sched.prefill_tokens - before[1])
+            assert ran <= 1 and tokens <= sched.prefill_budget
+            assert (ran == 0) == (tokens == 0)
+            seen.append(tokens)
+        # the budget is used whole where prompts are long enough
+        assert max(seen) == sched.prefill_budget == 2 * CHUNK
+        stats = sched.stats()
+        assert stats["prefill_programs"] == programs[0] - ran_before > 0
+        assert stats["prefill_tokens"] == sum(lengths)
+        assert stats["prefill_programs"] <= stats["prefill_rows"] \
+            <= 2 * stats["prefill_programs"]
+        assert all(r.reason == "length" for r in reqs)
+
+    def test_a_long_prompt_cannot_starve_a_short_one(self, family_engine):
+        cfg, params, eng, _ = family_engine
+        sched = Scheduler(eng)
+        long_a, long_b, short = [
+            sched.submit(Request(p, max_new_tokens=2, rng=i))
+            for i, p in enumerate(_prompts(cfg, (100, 100, 9), seed=2))]
+        for _ in range(2):   # three prefilling slots, two rows a program
+            sched.step()
+        assert short.generated and not long_a.generated \
+            and not long_b.generated
+        sched.run_until_idle(10_000)
+
+    def test_no_slot_twice_in_a_program(self, family_engine):
+        cfg, params, eng, programs = family_engine
+        eng.admit(0, list(range(1, 40)), 2)
+        before = programs[0]
+        with pytest.raises(ValueError, match="distinct slots"):
+            eng.prefill([(0, CHUNK), (0, CHUNK)])
+        with pytest.raises(ValueError, match="not prefilling"):
+            eng.prefill([(0, CHUNK), (1, CHUNK)])
+        assert programs[0] == before and eng._prefill_cursor[0] == 0
+        eng.release(0)
+
+    def test_twenty_prompt_lengths_compile_nothing(self, family_engine):
+        """Every prefill shape is compiled when the scheduler is built,
+        none by a request."""
+        cfg, params, eng, _ = family_engine
+        sched = Scheduler(eng)
+        built = eng.compile_counts()
+        assert eng.prefill_shapes(sched.prefill_budget) == [
+            (1, CHUNK), (1, 2 * CHUNK), (2, CHUNK)]
+        assert built["prefill"] == built["first_token"] == 3
+        lengths = [1, 2, 5, 15, 16, 17, 20, 31, 32, 33, 40, 47, 48, 49,
+                   63, 64, 65, 80, 96, 100]
+        reqs = [sched.submit(Request(p, max_new_tokens=2, rng=i))
+                for i, p in enumerate(_prompts(cfg, lengths, seed=3))]
+        sched.run_until_idle(10_000)
+        assert all(r.reason == "length" for r in reqs)
+        after = eng.compile_counts()
+        assert (after["prefill"], after["first_token"]) == (3, 3)
+        assert after["decode_greedy"] == 1 and after["decode_sampled"] == 0
+        # a scheduler with a wider budget asks for its own, bounded, set
+        assert eng.prefill_shapes(4 * CHUNK) == [
+            (1, 16), (1, 32), (1, 48), (1, 64), (2, 16), (2, 32), (3, 16)]
+        assert eng.prefill_shapes(1) == [(1, CHUNK)]
 
 
 class TestContinuousBatching:

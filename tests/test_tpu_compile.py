@@ -268,6 +268,40 @@ def test_slot_engine_steps_llama3_8b_widths(one_chip):
     assert max(device_bytes(decode), device_bytes(prefill)) < HBM_BYTES
 
 
+@pytest.mark.parametrize("family", ["llama", "jamba"])
+def test_no_prefill_shape_holds_a_second_pool(one_chip, family):
+    """Every shape of the one prefill program an iteration, rows of
+    several slots among them, reads and writes the pools in place: its
+    temporaries stay far under the smallest pool. (Two slots' chunks read
+    out of the whole pool inside the chunk loop made the chip's compiler
+    lay the K and V pools out anew in every layer, and a convolution
+    tail written one row at a time made it copy that pool in and out:
+    3.4e9 and 1.4e8 B of temporaries at the benchmark's sizes, PR 30.)"""
+    from metaflow_tpu.models import jamba
+    from metaflow_tpu.serving import SlotEngine
+
+    if family == "llama":
+        cfg, mod = smoke_cfg(), llama
+    else:   # eight layers: one attention layer among seven Mamba layers
+        cfg, mod = jamba.JambaConfig.jamba2_3b(n_layers=8), jamba
+    params = on(jax.eval_shape(
+        lambda: mod.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    engine = SlotEngine(params, cfg, max_slots=32, max_seq_len=1024,
+                        prefill_chunk=64)
+    cache = on(engine._cache, one_chip)
+    pool = min(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(cache))
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    shapes = engine.prefill_shapes(2 * 64)
+    assert shapes == [(1, 64), (1, 128), (2, 64)]
+    for rows, width in shapes:
+        compiled = engine._prefill_fn.lower(
+            params, cache, i32(rows, width), i32(rows), i32(rows),
+            i32(rows)).compile()
+        temporaries = compiled.memory_analysis().temp_size_in_bytes
+        assert temporaries < pool / 4, (rows, width, temporaries, pool)
+
+
 def test_paged_engine_steps_llama3_8b_widths(one_chip):
     from metaflow_tpu.serving import PagedEngine
 

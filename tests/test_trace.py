@@ -109,9 +109,9 @@ def _run_traced_requests(setup, tmp_path, n_requests=6, prefill_sleep=0.02):
                             prefill_chunk=16)
         # slow prefill so TTFT is dominated by spans the decomposition
         # measures (at tiny-model speed, emission jitter would swamp it)
-        real_prefill = engine.prefill_step
-        engine.prefill_step = \
-            lambda slot: (time.sleep(prefill_sleep), real_prefill(slot))[1]
+        real_prefill = engine.prefill
+        engine.prefill = \
+            lambda plan: (time.sleep(prefill_sleep), real_prefill(plan))[1]
         sched = Scheduler(engine, max_queue=n_requests + 1)
         for i in range(n_requests):
             req = Request(list(range(1, 6 + i)), max_new_tokens=3, rng=i,
@@ -158,6 +158,31 @@ class TestTraceAssembly:
             # measured TTFT to 5% (the slowed prefill keeps emission
             # jitter small beside the spans)
             assert d["err_pct"] <= 5.0, d
+
+    def test_a_chunk_belongs_to_every_request_it_carried(self, setup,
+                                                         tmp_path):
+        """One serve.prefill_chunk record a prefill program: with two
+        slots some carry two requests, and each request's tree holds
+        the chunks that carried it, its own rows adding up to its
+        prompt, under its own span."""
+        records = _run_traced_requests(setup, tmp_path)
+        chunks = [r for r in records if r["name"] == "serve.prefill_chunk"]
+        assert any(r["data"]["rows"] == 2 for r in chunks)
+        for rec in chunks:
+            d = rec["data"]
+            assert len(d["request_ids"]) == len(d["slots"]) \
+                == len(d["row_tokens"]) == len(d["spans"]) == d["rows"]
+            assert sum(d["row_tokens"]) == d["tokens"]
+        for i, tree in enumerate(build_request_traces(records)):
+            att = tree["attempts"][0]
+            mine = [r for r in att["events"]
+                    if r["name"] == "serve.prefill_chunk"]
+            rows = [r["data"]["request_ids"].index(tree["request_id"])
+                    for r in mine]
+            assert sum(r["data"]["row_tokens"][row]
+                       for r, row in zip(mine, rows)) == 5 + i
+            assert {r["data"]["spans"][row]
+                    for r, row in zip(mine, rows)} == {att["span"]}
 
     def test_perfetto_export_validates_and_covers_phases(self, setup,
                                                          tmp_path):
