@@ -653,6 +653,13 @@ def scheduler_metric_families(stats):
     itl.add(stats["p50_itl_ms"] or 0.0, {"quantile": "0.5"})
     itl.add(stats["p99_itl_ms"] or 0.0, {"quantile": "0.99"})
     fams.append(itl)
+    state = stats.get("state_pool") or {}
+    gauge("tpuflow_serve_state_pool_bytes", state.get("bytes", 0),
+          "Device bytes of the recurrent-state pools (0: the model "
+          "carries no recurrent state)")
+    gauge("tpuflow_serve_state_pool_bytes_per_slot",
+          state.get("bytes_per_slot", 0),
+          "Recurrent-state bytes one slot holds, whatever its position")
     prefix = stats.get("prefix_cache") or {}
     if prefix.get("enabled"):
         fams.append(

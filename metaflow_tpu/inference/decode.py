@@ -36,29 +36,40 @@ into a second buffer. A layer's weights are sliced out of their stack by
 the loop's index (`layer_at`), and the layer writes its new positions
 into, and reads its chunks out of, its own index of the donated pools
 in place: K and V for the attention layers, and for the Mamba layers
-the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py).
-Unlike a KV position, a recurrent state has no garbage that is
-overwritten before it is seen: `valid` tells the state-space layers
-which of the new positions are real. The batch is the whole pool (a
-decode step) or, with `slots`, a few distinct rows of it (the slot
+the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py),
+for the power-retention layers (Brumby: every layer, so the cache holds
+no K and V at all) the float32 state `ret_s` and its normaliser `ret_z`
+(ops/retention.py). Which pools a kind of layer carries, their shape a
+slot and their dtype are that kind's declaration (`POOLS`), and
+`init_kv_cache`, this loop, the engine's reset of a new occupant and
+`is_recurrent` read it. Unlike a KV position, a recurrent state has no
+garbage that is overwritten before it is seen: `valid` tells those
+layers which of the new positions are real. The batch is the whole pool
+(a decode step) or, with `slots`, a few distinct rows of it (the slot
 engine's prefill program): K and V of those rows are then written in
-the pool by index and a layer's views of them read out of it, and the
-rows' small recurrent state is cut out of its pools before the loop
-and put back after it; no other row is touched and no pool is copied.
+the pool by index and a layer's views of them read out of it; a small
+recurrent state (Mamba's: 0.3 MB a layer and slot) is cut out of its
+pools before the loop and put back after it, a large one (power
+retention's: 34 MB a layer and slot) is read and written in its pool by
+row index a layer at a time, and its one-token update touches only the
+lanes that decode; no other row is touched and no pool is copied.
 
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
 is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
 `decode_attention`, `attn_out` and `ffn` (ops/moe.py adds `moe_*` inside
 `ffn`); inside a Mamba layer `ssm_in_proj`, `ssm_conv`, `ssm_x_proj`,
-`ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`.
-The pool's write sits under `kv_cache_update`, its chunk reads under
-`decode_attention`, a state pool's read and write-back under the
-`ssm_*` scope that needs them: what lies under `decode_layers` and under
-none of those is the loop's own cost (its counter, the residual
-stream), and anything the compiler still moves without being asked.
+`ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`;
+inside a retention layer `retention_qkvg` (the projections, the head
+norms, rope and the gate), `retention_chunk` (a chunk) or
+`retention_update` (one token), `retention_out`. The pool's write sits
+under `kv_cache_update`, its chunk reads under `decode_attention`, a
+state pool's read and write-back under the `ssm_*` or `retention_*`
+scope that needs them: what lies under `decode_layers` and under none of
+those is the loop's own cost (its counter, the residual stream), and
+anything the compiler still moves without being asked.
 
-Sharding: the cache carries the same logical axes as activations
+Sharding: the KV cache carries the same logical axes as activations
 ([layers, batch, seq, kv_heads * head_dim], heads major in the folded
 axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
 tensor axis, so decode parallelizes with the exact rule table training
@@ -74,8 +85,8 @@ import jax.numpy as jnp
 
 from .. import knobs
 from ..exception import TpuFlowException
-from ..models import jamba, llama, mixtral
-from ..ops import rms_norm
+from ..models import brumby, jamba, llama, mixtral
+from ..ops import retention, rms_norm
 from ..ops.attention import NEG_INF
 from ..ops.moe import moe_ffn
 from ..ops.rope import apply_rope, rope_frequencies
@@ -119,6 +130,39 @@ FAMILIES = {
     jamba.JambaConfig: Family("jamba", jamba, _dense_ffn, False,
                               {"attention": "attn_layers",
                                "mamba": "mamba_layers"}),
+    brumby.BrumbyConfig: Family("brumby", brumby, _dense_ffn, True,
+                                {"retention": "layers"}),
+}
+
+# A pool a kind of layer carries. shape: (cfg, max_seq_len) -> its shape
+# a slot (the pool is [layers of the kind, slots] + that); dtype: None
+# for the cache's; recurrent: what it holds is carried from position to
+# position (nothing there is overwritten before it is seen, so it is
+# masked by `valid`, zeroed for a new occupant, and no KV range stands
+# for it); view: a prefill program cuts its rows out once and puts them
+# back (small states), else a layer reads and writes its rows in place.
+Pool = collections.namedtuple("Pool", "shape dtype recurrent view")
+
+_kv_pool = Pool(lambda cfg, seq: (seq, cfg.n_kv_heads * cfg.head_dim),
+                None, False, False)
+POOLS = {
+    "attention": {"k": _kv_pool, "v": _kv_pool},
+    "mamba": {
+        "conv": Pool(lambda cfg, seq: (cfg.mamba_d_conv - 1, cfg.d_inner),
+                     None, True, True),
+        "ssm": Pool(lambda cfg, seq: (cfg.mamba_d_state, cfg.d_inner),
+                    jnp.float32, True, True),
+    },
+    # [KV, Hd, D] with D = 8,320 at a head size of 128 (the 8,256 products
+    # of a symmetric square and 64 zeros: whole lanes, ops/retention.py)
+    "retention": {
+        "ret_s": Pool(lambda cfg, seq: (
+            cfg.n_kv_heads, cfg.head_dim, retention.state_dim(cfg.head_dim)),
+            jnp.float32, True, False),
+        "ret_z": Pool(lambda cfg, seq: (
+            cfg.n_kv_heads, retention.state_dim(cfg.head_dim)),
+            jnp.float32, True, False),
+    },
 }
 
 
@@ -143,23 +187,42 @@ def family_config_class(name):
 
 
 def layer_kinds(cfg):
-    """The kind of every layer in the model's order: "attention" (K and
-    V cached) or "mamba" (a convolution tail and a state carried)."""
+    """The kind of every layer in the model's order, a key of `POOLS`:
+    "attention" (K and V cached), "mamba" (a convolution tail and a
+    state carried) or "retention" (a state and its normaliser carried)."""
     return getattr(cfg, "layer_kinds", None) or ("attention",) * cfg.n_layers
+
+
+def cache_pools(cfg):
+    """{pool name: (its Pool, how many layers carry it)} of the model's
+    cache, from what each kind of layer present declares."""
+    kinds = layer_kinds(cfg)
+    return {name: (pool, kinds.count(kind))
+            for kind in sorted(set(kinds))
+            for name, pool in POOLS[kind].items()}
+
+
+def recurrent_pools(cfg):
+    """The names of the pools that hold recurrent state."""
+    return sorted(name for name, (pool, _) in cache_pools(cfg).items()
+                  if pool.recurrent)
 
 
 def is_recurrent(cfg):
     """Whether some layer carries a state that a KV range does not
     hold: such a model's prefix is not its cached K and V."""
-    return "mamba" in layer_kinds(cfg)
+    return bool(recurrent_pools(cfg))
 
 
 def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
-    """The static cache, one tree: `k` and `v`
-    [attention layers, batch, max_seq, kv_heads * head_dim], and where
-    the model has Mamba layers `conv` [mamba layers, batch, d_conv-1,
-    d_inner] (the convolution's tail) and `ssm` [mamba layers, batch,
-    d_state, d_inner] in float32. Every leaf has the batch on axis 1.
+    """The static cache, one tree of the pools the model's kinds of
+    layer declare (`POOLS`), each [layers of the kind, batch] + its shape
+    a slot: `k` and `v` [attention layers, batch, max_seq, kv_heads *
+    head_dim]; for Mamba layers `conv` [.., d_conv-1, d_inner] (the
+    convolution's tail) and `ssm` [.., d_state, d_inner] in float32; for
+    retention layers `ret_s` [.., kv_heads, head_dim, D] and `ret_z`
+    [.., kv_heads, D] in float32. A stack with no attention layer has no
+    `k` and `v`. Every leaf has the batch on axis 1.
 
     The pools are read and written a layer at a time in place
     (`_decode_layer`): heads and head size are folded into one minor
@@ -168,18 +231,10 @@ def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
     single KV head (multi-query) leaves no axis of 1 for the chip's
     tiling to pad or to lay out anew on the way in and out."""
     dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
-    kinds = layer_kinds(cfg)
-    shape = (kinds.count("attention"), batch_size, max_seq_len,
-             cfg.n_kv_heads * cfg.head_dim)
-    n_mamba = kinds.count("mamba")
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if n_mamba:
-        cache["conv"] = jnp.zeros(
-            (n_mamba, batch_size, cfg.mamba_d_conv - 1, cfg.d_inner), dt)
-        cache["ssm"] = jnp.zeros(
-            (n_mamba, batch_size, cfg.mamba_d_state, cfg.d_inner),
-            jnp.float32)
-    return cache
+    return {name: jnp.zeros(
+                (layers, batch_size) + pool.shape(cfg, max_seq_len),
+                pool.dtype or dt)
+            for name, (pool, layers) in cache_pools(cfg).items()}
 
 
 def _query_positions(pos, T):
@@ -343,17 +398,26 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
 
 @jax.named_scope("attn_qkv")
 def _attn_qkv(cfg, cos, sin, pos, x, lp):
-    """The pre-attention half of a block: attn-norm, QKV projections and
-    (where the family rotates them) rope at the absolute positions `pos`
-    implies; without rope `cos` and `sin` are None. Shared verbatim by the
+    """The pre-attention half of a block: attn-norm, QKV projections,
+    the q and k head norms where the layer has them, and (where the
+    family rotates them) rope at the absolute positions `pos` implies;
+    without rope `cos` and `sin` are None. Shared verbatim by the
     contiguous-cache layer below and the paged-cache layer
     (serving/paged.py) so both paths stay numerically identical."""
-    B, T, _ = x.shape
+    return _project_qkv(cfg, cos, sin, pos,
+                        rms_norm(x, lp["attn_norm"], cfg.norm_eps), lp)
+
+
+def _project_qkv(cfg, cos, sin, pos, h, lp):
+    """q, k and v of the normed input h [B, T, dim]."""
+    B, T, _ = h.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q = (h @ lp["wq"]).reshape(B, T, H, Hd)
     k = (h @ lp["wk"]).reshape(B, T, KV, Hd)
     v = (h @ lp["wv"]).reshape(B, T, KV, Hd)
+    if "q_norm" in lp:   # an RMS norm a head, one weight a head size
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     if cos is not None:
         positions = _query_positions(pos, T)
         q = apply_rope(q, cos, sin, positions=positions)
@@ -445,12 +509,29 @@ def _slot_rows(pool, slots, layer=None):
         for r in range(slots.shape[0])], axis=1)
 
 
-def _put_slot_rows(pool, rows, slots):
-    """`_slot_rows(pool, slots)` back into the pool, in place."""
+def _put_slot_rows(pool, rows, slots, layer=None):
+    """`_slot_rows(pool, slots, layer)` back into the pool, in place."""
+    rest = (0,) * (pool.ndim - 2)
     for r in range(slots.shape[0]):
-        pool = jax.lax.dynamic_update_slice_in_dim(
-            pool, rows[:, r:r + 1], slots[r], axis=1)
+        pool = jax.lax.dynamic_update_slice(
+            pool, rows[:, r:r + 1],
+            (0 if layer is None else layer, slots[r]) + rest)
     return pool
+
+
+def _layer_rows(pool, layer, slots):
+    """Layer `layer` of a pool [layers, B, ...]: the whole batch, or the
+    rows `slots` names, [R, ...]."""
+    if slots is None:
+        return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+    return _slot_rows(pool, slots, layer)[0]
+
+
+def _put_layer_rows(pool, rows, layer, slots):
+    """`_layer_rows(pool, layer, slots)` back into the pool, in place."""
+    if slots is None:
+        return jax.lax.dynamic_update_index_in_dim(pool, rows, layer, 0)
+    return _put_slot_rows(pool, rows[None], slots, layer)
 
 
 def _mamba_layer(cfg, x, lp, conv, state, valid):
@@ -462,15 +543,51 @@ def _mamba_layer(cfg, x, lp, conv, state, valid):
     return _ffn(cfg, x + out, lp, None), conv, state
 
 
+def _retention_layer(cfg, cos, sin, pos, x, lp, cache, layer, valid, slots):
+    """One power-retention block over T new tokens, reading and writing
+    layer `layer` (a traced index) of the state pools `ret_s`
+    [layers, B, KV, Hd, D] and `ret_z` in place: one token updates the
+    lanes that are valid and reads no other (ops/retention.py,
+    `update_pool`); a chunk reads its rows' state out of the pool by
+    index (every row, or the rows `slots` names) and writes it back to
+    the same rows, this layer's 34 MB a row and no more."""
+    B, T, _ = x.shape
+    with jax.named_scope("retention_qkvg"):
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = _project_qkv(cfg, cos, sin, pos, h, lp)
+        log_g = brumby.log_gate(lp, h)
+    pool_s, pool_z = cache["ret_s"], cache["ret_z"]
+    if T == 1:
+        with jax.named_scope("retention_update"):
+            y, pool_s, pool_z = retention.update_pool(
+                pool_s, pool_z, layer, q[:, 0], k[:, 0], v[:, 0],
+                log_g[:, 0], cfg.retention_eps,
+                None if valid is None else valid[:, 0])
+    else:
+        with jax.named_scope("retention_chunk"):
+            y, S, z = retention.chunk(
+                _layer_rows(pool_s, layer, slots),
+                _layer_rows(pool_z, layer, slots),
+                q, k, v, log_g, cfg.retention_eps, valid)
+            pool_s = _put_layer_rows(pool_s, S, layer, slots)
+            pool_z = _put_layer_rows(pool_z, z, layer, slots)
+    with jax.named_scope("retention_out"):
+        x = x + y.reshape(B, T, -1).astype(x.dtype) @ lp["wo"]
+    return _ffn(cfg, x, lp, None), dict(cache, ret_s=pool_s, ret_z=pool_z)
+
+
 def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
     """The layer loop of every family: the activations and the whole
     cache are its carry, and layer i of a kind reads its weights out of
     that kind's stack and reads and writes index i of that kind's pools
     (of the rows `slots` names, where the batch is not the whole pool)."""
     fam = family(cfg)
+    # rope's table is as long as the KV pool is deep; a stack that caches
+    # no K and V is bound by the config's positions alone
     cos, sin = rope_frequencies(
-        cfg.head_dim, cache["k"].shape[2], cfg.rope_theta,
-        dtype=llama.param_dtype(cfg),
+        cfg.head_dim,
+        cache["k"].shape[2] if "k" in cache else cfg.max_seq_len,
+        cfg.rope_theta, dtype=llama.param_dtype(cfg),
         llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
     ) if fam.rope else (None, None)
 
@@ -483,6 +600,9 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
                 cfg, cos, sin, pos, x, lp, cache["k"], cache["v"], i,
                 mesh=mesh, attn_impl=attn_impl, slots=slots)
             return x, cache
+        if kind == "retention":
+            return _retention_layer(cfg, cos, sin, pos, x, lp, cache, i,
+                                    valid, slots)
         # the layer's tail and state are read out of the pools and written
         # back under the scope of the op that uses them, so that a scope's
         # device time holds the pool's traffic that its kernel needs
@@ -502,15 +622,18 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
             cache["ssm"] = put(cache["ssm"], state, i, 0)
         return x, cache
 
-    # K and V are read and written in their pools, rows `slots` of them.
-    # A recurrent state is small (a few MB a slot over all layers) and a
-    # slot's convolution tail lies inside a tile of the chip's layout,
-    # where one row cannot be written in place (compiled for the chip, the
-    # whole pool was laid out anew on the way in and out): the rows'
-    # tails and states of all layers are cut out once, carried through
-    # the loop as pools that hold just the batch, and put back after it
+    # K and V are read and written in their pools, rows `slots` of them,
+    # and so is a large recurrent state (power retention's, 34 MB a layer
+    # and slot: a layer's rows by index). A small one is a `view` pool
+    # (Mamba's: 8.5 MB a slot over all 26 layers, and a slot's convolution
+    # tail lies inside a tile of the chip's layout, where one row cannot
+    # be written in place: compiled for the chip, the whole pool was laid
+    # out anew on the way in and out): the rows' part of all its layers
+    # is cut out once, carried through the loop as a pool that holds just
+    # the batch, and put back after it
     pools = {} if slots is None else {
-        name: cache[name] for name in ("conv", "ssm") if name in cache}
+        name: cache[name] for name, (pool, _) in cache_pools(cfg).items()
+        if pool.view}
     with jax.named_scope("decode_layers"):
         cache = dict(cache, **{name: _slot_rows(pool, slots)
                                for name, pool in pools.items()})
@@ -529,7 +652,7 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
 
     tokens: [B, T] (T static: the prompt length for prefill, 1 per decode
     step). valid: None (every position is real) or a [B, T] mask whose
-    true positions lead each row: a recurrent state (`conv`, `ssm`)
+    true positions lead each row: a recurrent state (`recurrent_pools`)
     passes through the positions that are not valid. K and V need no
     mask (what is written there is overwritten before it is seen).
     slots: None (row b of the batch is row b of the cache) or a traced
@@ -620,13 +743,19 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
             "the KV cache cannot hold the generation" %
             (max_seq_len, P, max_new_tokens))
     cache = init_kv_cache(cfg, B, max_seq_len or total)
+    if "k" not in cache and total > cfg.max_seq_len:
+        # nothing is cached by position: rope's table is all that ends
+        raise ValueError(
+            "prompt_len (%d) + max_new_tokens (%d) passes the config's "
+            "max_seq_len (%d), where rope's table ends"
+            % (P, max_new_tokens, cfg.max_seq_len))
     if attn_impl not in ("auto", "dense", "chunked"):
         # a typo'd impl must not silently select dense (and then be
         # recorded verbatim in benchmark results)
         raise ValueError("attn_impl must be 'auto', 'dense' or "
                          "'chunked', got %r" % (attn_impl,))
     if attn_impl == "auto":
-        attn_impl = ("chunked" if cache["k"].shape[2] > 2 * DECODE_CHUNK
+        attn_impl = ("chunked" if (max_seq_len or total) > 2 * DECODE_CHUNK
                      else "dense")
 
     valid = None if prompt_len is None else jnp.broadcast_to(

@@ -18,9 +18,9 @@ cover everything:
   - prefill: ONE execution an iteration writes the next prompt tokens of
     up to `rows` slots into the cache. tokens [R, W], slots [R],
     start [R], n_real [R]: row r is slot slots[r]'s next tokens from its
-    cursor, padded to the width W. The rows are distinct slots (a Mamba
-    state and causal attention make a slot's second row depend on its
-    first); a lone prefilling slot gets one wider row instead. K and V
+    cursor, padded to the width W. The rows are distinct slots (a
+    recurrent state and causal attention make a slot's second row depend
+    on its first); a lone prefilling slot gets one wider row instead. K and V
     are written into, and read out of, the pool in place at the rows'
     slots (inference/decode.py, `slots`); no slot's view is cut out and
     written back. The shapes are a bounded set, `prefill_shapes(budget)`
@@ -43,10 +43,15 @@ their own cursor and are overwritten (prefill rewrites the range, decode
 overwrites pad garbage exactly one position before it would become
 visible), so no flag tensor is needed inside the compiled program.
 
-A model with state-space layers (Jamba) keeps a RECURRENT-STATE POOL
-beside the KV pool, in the same cache tree: per Mamba layer and slot the
-convolution's tail and the float32 state (inference/decode.py,
-init_kv_cache). None of the three invariants above holds for a
+A model with recurrent layers keeps a RECURRENT-STATE POOL in the same
+cache tree, whatever pools its kinds of layer declare
+(inference/decode.py, `POOLS`): per Mamba layer and slot the
+convolution's tail and the float32 state (Jamba, beside the KV pool of
+its attention layers); per power-retention layer and slot the float32
+state and its normaliser (Brumby: 34 MB a layer and slot, and NO KV pool,
+since no layer caches K and V: a slot then costs the same at position 10
+and at 30,000, and `max_seq_len` bounds rope's table only). None of the
+three invariants above holds for a
 recurrence, which has no garbage that is overwritten before it is seen,
 so for such a model: (a) the prefill program is told how many of each
 row's positions are real, and the state after a row is the state after
@@ -76,11 +81,14 @@ from .. import telemetry
 from ..exception import TpuFlowException
 from ..inference.decode import (
     DECODE_CHUNK,
+    POOLS,
     bucket_length,
     decode_forward,
     family,
     init_kv_cache,
     is_recurrent,
+    layer_kinds,
+    recurrent_pools,
 )
 from ..ops.attention import NEG_INF
 
@@ -102,13 +110,17 @@ def request_step_keys(rng, max_new_tokens):
 def refuse_recurrent(cfg, what):
     """Raise for `what`, which treats a KV range as a prefix, where the
     model also carries recurrent state: the K and V of the positions
-    before a cut say nothing of a state-space layer's state there."""
+    before a cut say nothing of a recurrent layer's state there."""
     if is_recurrent(cfg):
         raise TpuFlowException(
-            "%s is not supported for a %s model: its state-space layers "
-            "carry recurrent state (a convolution tail and a state per "
-            "layer and slot) beside the KV cache, and a KV range is not a "
-            "prefix of it" % (what, family(cfg).name))
+            "%s is not supported for a %s model: its %s layers carry "
+            "recurrent state (the pools %s, per layer and slot) that no KV "
+            "range holds, and a KV range is not a prefix of it" % (
+                what, family(cfg).name,
+                " and ".join(kind for kind in sorted(set(layer_kinds(cfg)))
+                             if any(p.recurrent
+                                    for p in POOLS[kind].values())),
+                ", ".join(recurrent_pools(cfg))))
 
 
 def sample_slots(logits, keys, temperature, top_k, top_p):
@@ -181,7 +193,14 @@ class SlotEngine(object):
 
         self._cache = init_kv_cache(cfg, self.max_slots, self.max_seq_len,
                                     dtype=cache_dtype)
-        self.recurrent = is_recurrent(cfg)
+        if "k" not in self._cache and self.max_seq_len > cfg.max_seq_len:
+            # no pool is as deep as max_seq_len: rope's table, which is
+            # the config's, is all that bounds a position
+            raise ValueError(
+                "max_seq_len %d passes the config's %d, where rope's table "
+                "ends" % (self.max_seq_len, cfg.max_seq_len))
+        self._recurrent_pools = recurrent_pools(cfg)
+        self.recurrent = bool(self._recurrent_pools)
         B = self.max_slots
         # host-side per-slot state
         self.pos = np.zeros(B, np.int32)          # next cache write index
@@ -280,7 +299,7 @@ class SlotEngine(object):
             # a new occupant starts from an empty recurrent state; its K
             # and V need no reset (overwritten before they are seen)
             cache = dict(cache)
-            for name in ("conv", "ssm"):
+            for name in self._recurrent_pools:
                 arr = cache[name]
                 cache[name] = jax.lax.dynamic_update_slice_in_dim(
                     arr, jnp.zeros(arr.shape[:1] + (1,) + arr.shape[2:],
@@ -311,13 +330,25 @@ class SlotEngine(object):
     def fits(self, prompt_len, max_new_tokens):
         """Could this request EVER be admitted? False is a permanent
         413 at submit time (the scheduler's admission capacity check),
-        not backpressure."""
+        not backpressure. A slot holds `max_seq_len` positions: the KV
+        pool's depth, or where no layer caches K and V (a slot's state
+        is then the same size at every position) the length of rope's
+        table."""
         return prompt_len + max_new_tokens <= self.max_seq_len
 
     def max_context_tokens(self):
         """The largest prompt+max_new any request may carry — the
         scalar the fleet router sheds oversized dispatches against."""
         return self.max_seq_len
+
+    def state_pool_stats(self):
+        """The recurrent-state pools' bytes on the device, in all and a
+        slot (0 for a model that carries none): what a slot costs
+        whatever its position, beside the KV pool's bytes a position."""
+        total = sum(self._cache[name].nbytes
+                    for name in self._recurrent_pools)
+        return {"bytes": int(total),
+                "bytes_per_slot": int(total // self.max_slots)}
 
     def compile_counts(self):
         """jit cache entries per program — each decode variant must stay
@@ -549,8 +580,8 @@ class SlotEngine(object):
         rows are fetched in one wait."""
         slots = np.asarray([slot for slot, _ in plan], np.int32)
         if len(set(slots.tolist())) != len(plan) or not len(plan):
-            # a Mamba state and causal attention make a slot's second row
-            # depend on its first: one row a slot and program
+            # a recurrent state and causal attention make a slot's second
+            # row depend on its first: one row a slot and program
             raise ValueError("a prefill program takes distinct slots, "
                              "got %r" % (slots.tolist(),))
         for slot in slots:

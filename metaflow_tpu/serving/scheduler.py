@@ -886,6 +886,7 @@ class Scheduler(object):
             "max_context_tokens": self.max_context_tokens(),
             "prefix_cache": self.prefix_stats(),
             "kv_pages": self.kv_pages_stats(),
+            "state_pool": self.state_pool_stats(),
             "speculative": (self.engine.spec_stats() if self._paged
                             else {"enabled": False}),
             "goodput": self.goodput_stats(),
@@ -935,6 +936,13 @@ class Scheduler(object):
             "serve_idle_s": round(max(0.0, elapsed - busy), 3),
             "elapsed_s": round(elapsed, 3),
         }
+
+    def state_pool_stats(self):
+        """The engine's recurrent-state pools (bytes, bytes a slot);
+        zeros for an engine that keeps none."""
+        stats = getattr(self.engine, "state_pool_stats", None)
+        return stats() if stats is not None else {
+            "bytes": 0, "bytes_per_slot": 0}
 
     def kv_pages_stats(self):
         """Page-pool health for /v1/stats and /healthz; {"enabled":

@@ -1,10 +1,14 @@
-"""Jamba (Mamba-1 layers with attention layers among them) through the
-slot engine: parity with the plain reference, and the five properties a
-recurrent-state pool needs that a KV pool got for free: a padded chunk
-leaves the state after its last real token, masked lanes hold their
-state, a new occupant starts from an empty state, the state carries
-from chunk to chunk, and what treats a KV range as a prefix refuses the
-model by name. Tiny sizes, float32 unless said."""
+"""The families that carry recurrent state through the slot engine,
+Jamba (Mamba-1 layers with attention layers among them) and Brumby
+(power-retention layers alone: a state pool and no KV pool): parity with
+the plain reference, and the five properties a recurrent-state pool
+needs that a KV pool got for free: a padded chunk leaves the state after
+its last real token, masked lanes hold their state, a new occupant
+starts from an empty state, the state carries from chunk to chunk, and
+what treats a KV range as a prefix refuses the model by name. Every such
+case runs for both families (`fam`); what only Jamba has (the layer
+pattern, ops/ssm.py) follows, and what only Brumby has is
+tests/test_brumby.py. Tiny sizes, float32 unless said."""
 
 import os
 
@@ -15,11 +19,13 @@ import pytest
 
 from benchmark import configs, reference
 from benchmark.families import jamba as ref_family
+from metaflow_tpu.models import brumby
 from metaflow_tpu.cmd.serve import build_config, build_engine, \
     build_prefix_cache
 from metaflow_tpu.exception import TpuFlowException
 from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
-from metaflow_tpu.inference.decode import family, is_recurrent, layer_kinds
+from metaflow_tpu.inference.decode import family, is_recurrent, \
+    layer_kinds, recurrent_pools
 from metaflow_tpu.models import jamba, llama, mixtral
 from metaflow_tpu.ops import ssm
 from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \
@@ -27,11 +33,36 @@ from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CFG = jamba.JambaConfig.tiny()   # hidden 64, 8 layers, attention at 2 and 6
-# the reference's sizes of the same tiny model, from the benchmark's file
-DIMS = dict(configs.dims(dict(configs.read_json(os.path.join(
-    ROOT, "benchmark", "tests", "cells", "configs", "tiny-jamba.json")),
-    torch_dtype="float32")))
 NEW = 8
+
+
+class Fam(object):
+    """One recurrent family at its tiny size: the program's module and
+    config, and the reference's sizes of the same model, from the
+    benchmark's file."""
+
+    def __init__(self, name, module, cfg_class):
+        self.name, self.module, self.tiny = name, module, cfg_class.tiny
+        self.cfg = cfg_class.tiny()
+        self.dims = dict(configs.dims(dict(configs.read_json(os.path.join(
+            ROOT, "benchmark", "tests", "cells", "configs",
+            "tiny-%s.json" % name)), torch_dtype="float32")))
+        self.state = recurrent_pools(self.cfg)   # the pools' names
+        self.kv = "attention" in layer_kinds(self.cfg)
+
+    def init_params(self):
+        p = self.module.init_params(jax.random.PRNGKey(0), self.cfg)
+        if self.name == "jamba":
+            # a drawn convolution bias, so that an empty tail is not a
+            # fixed point
+            p["mamba_layers"]["conv_b"] = 0.5 * jax.random.normal(
+                jax.random.PRNGKey(1), p["mamba_layers"]["conv_b"].shape)
+        return p
+
+
+FAMS = {"jamba": Fam("jamba", jamba, jamba.JambaConfig),
+        "brumby": Fam("brumby", brumby, brumby.BrumbyConfig)}
+DIMS = FAMS["jamba"].dims
 LENGTHS = (5, 16, 37, 50)   # a padded chunk, a whole one, 2 + a padded, 3 + 2
 
 
@@ -39,27 +70,28 @@ def prompt(n, salt=0):
     return ((np.arange(n) * 37 + 11 + 5 * salt) % 255 + 1).astype(np.int32)
 
 
-@pytest.fixture(scope="module")
-def params():
-    p = jamba.init_params(jax.random.PRNGKey(0), CFG)
-    # a drawn convolution bias, so that an empty tail is not a fixed point
-    p["mamba_layers"]["conv_b"] = 0.5 * jax.random.normal(
-        jax.random.PRNGKey(1), p["mamba_layers"]["conv_b"].shape)
-    return p
+@pytest.fixture(scope="module", params=sorted(FAMS))
+def fam(request):
+    return FAMS[request.param]
 
 
 @pytest.fixture(scope="module")
-def engine(params):
+def params(fam):
+    return fam.init_params()
+
+
+@pytest.fixture(scope="module")
+def engine(fam, params):
     """Three slots, chunks of 16; every test leaves its slots released."""
-    return SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
+    return SlotEngine(params, fam.cfg, max_slots=3, max_seq_len=128,
                       prefill_chunk=16)
 
 
 @pytest.fixture(scope="module")
-def alone(params):
+def alone(fam, params):
     """The tokens a prompt emits alone, unpadded, in one lockstep call
     (one compile a prompt length: the tests share four, LENGTHS)."""
-    run = jax.jit(lambda p, toks: generate(p, toks, CFG, NEW,
+    run = jax.jit(lambda p, toks: generate(p, toks, fam.cfg, NEW,
                                            max_seq_len=128))
 
     def tokens(p):
@@ -67,6 +99,13 @@ def alone(params):
         return np.asarray(run(params, jnp.asarray(p)[None])[0, len(p):]
                           ).tolist()
     return tokens
+
+
+def close(got, want):
+    """Float32 rounding: 1e-5 of the largest entry (a retention state
+    sums tens of positions and holds entries of tens), or of one."""
+    return float(jnp.abs(got - want).max()) < 1e-5 * max(
+        1.0, float(jnp.abs(want).max()))
 
 
 def prefill(eng, slot):
@@ -88,25 +127,25 @@ def serve(eng, slot, p, n=NEW):
 
 # ---- parity with the plain reference ----
 
-def test_forward_matches_the_reference(params):
+def test_forward_matches_the_reference(fam, params):
     tokens = prompt(48)
-    want = reference.logits(params, tokens, DIMS)
-    got = jamba.forward(params, jnp.asarray(tokens)[None], CFG)[0]
+    want = reference.logits(params, tokens, fam.dims)
+    got = fam.module.forward(params, jnp.asarray(tokens)[None], fam.cfg)[0]
     # float32 on both sides: rounding only
     assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
 
 
 @pytest.mark.parametrize("chunks", [(16, 16, 8), (7, 33)])
-def test_chunks_then_steps_through_the_cache_match_the_reference(params,
-                                                                 chunks):
+def test_chunks_then_steps_through_the_cache_match_the_reference(
+        fam, params, chunks):
     """Prefill in chunks, then a token at a time, each row at its own
     cursor: the logits are the reference's full forward pass. Float32 on
     both sides, so the tolerance is rounding: 1e-4 of the largest logit."""
     tokens = np.stack([prompt(48), prompt(48, salt=3)])
-    want = jnp.stack([reference.logits(params, t, DIMS) for t in tokens])
-    cache = init_kv_cache(CFG, 2, 64)
+    want = jnp.stack([reference.logits(params, t, fam.dims) for t in tokens])
+    cache = init_kv_cache(fam.cfg, 2, 64)
     run = jax.jit(lambda toks, cache, pos: decode_forward(
-        params, toks, cache, pos, CFG))
+        params, toks, cache, pos, fam.cfg))
     got, at = [], 0
     for n in chunks:
         logits, cache = run(jnp.asarray(tokens[:, at:at + n]), cache, at)
@@ -126,20 +165,20 @@ def test_chunks_then_steps_through_the_cache_match_the_reference(params,
     # bfloat16 keeps 8 bits: logits of size 2-4 carry errors of a few
     # hundredths, so a served token may lie that far below the best
     ("bfloat16", 0.15)])
-def test_engine_serves_the_references_tokens(params, dtype, limit):
-    cfg = jamba.JambaConfig.tiny(dtype=dtype)
+def test_engine_serves_the_references_tokens(fam, params, dtype, limit):
+    cfg = fam.tiny(dtype=dtype)
     cast = jax.tree.map(lambda a: a.astype(dtype), params)
     eng = SlotEngine(cast, cfg, max_slots=2, max_seq_len=128,
                      prefill_chunk=16)
     p = prompt(37)
     served = serve(eng, 1, p, n=12)
     gaps = reference.served_gaps(cast, p.tolist(), served,
-                                 dict(DIMS, dtype=dtype), pad_to=64)
+                                 dict(fam.dims, dtype=dtype), pad_to=64)
     assert gaps.shape == (12,) and float(gaps.max()) <= limit
     # the comparison sees an altered token
     wrong = [(served[0] + 1) % 256] + served[1:]
     assert float(reference.served_gaps(
-        cast, p.tolist(), wrong, dict(DIMS, dtype=dtype),
+        cast, p.tolist(), wrong, dict(fam.dims, dtype=dtype),
         pad_to=64)[0]) > limit
 
 
@@ -151,15 +190,15 @@ def test_padded_last_chunk_gives_the_unpadded_tokens(engine, alone, n):
 
 
 def test_state_after_a_padded_chunk_is_the_state_after_its_last_token(
-        params, engine):
+        fam, params, engine):
     p = prompt(37)   # chunks of 16, 16 and 5 padded to 16
     engine.admit(2, p, NEW)
     prefill(engine, 2)
     _, want = decode_forward(params, jnp.asarray(p)[None],
-                             init_kv_cache(CFG, 1, 128), 0, CFG)
-    for name in ("conv", "ssm"):
+                             init_kv_cache(fam.cfg, 1, 128), 0, fam.cfg)
+    for name in fam.state:
         got = engine._cache[name][:, 2]
-        assert float(jnp.abs(got - want[name][:, 0]).max()) < 1e-5, name
+        assert close(got, want[name][:, 0]), name
         assert float(jnp.abs(got).max()) > 0
     engine.release(2)
 
@@ -190,21 +229,27 @@ def test_a_request_admitted_while_another_decodes(engine, alone):
     assert out[0] == alone(a) and out[1] == alone(b)
 
 
-def test_a_free_slots_state_is_held_through_decode_steps(engine):
-    before = np.asarray(engine._cache["ssm"][:, 2])
+def test_a_free_slots_state_is_held_through_decode_steps(fam, engine):
+    serve(engine, 2, prompt(16, salt=7))   # what a released slot leaves
+    before = {name: np.asarray(engine._cache[name][:, 2])
+              for name in fam.state}
     serve(engine, 0, prompt(16))
-    assert np.array_equal(np.asarray(engine._cache["ssm"][:, 2]), before)
+    for name in fam.state:
+        assert np.abs(before[name]).max() > 0
+        assert np.array_equal(np.asarray(engine._cache[name][:, 2]),
+                              before[name]), name
 
 
-def test_a_request_that_reuses_a_released_slot(engine, alone):
+def test_a_request_that_reuses_a_released_slot(fam, engine, alone):
     serve(engine, 1, prompt(50, salt=4))
-    assert float(jnp.abs(engine._cache["ssm"][:, 1]).max()) > 0
+    for name in fam.state:
+        assert float(jnp.abs(engine._cache[name][:, 1]).max()) > 0
     assert serve(engine, 1, prompt(5, salt=1)) == alone(prompt(5, salt=1))
 
 
 def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
-        params, alone):
-    eng = build_engine(params, CFG, slots=2, max_seq_len=128,
+        fam, params, alone):
+    eng = build_engine(params, fam.cfg, slots=2, max_seq_len=128,
                        prefill_chunk=16)
     sched = Scheduler(eng).start()
     prompts = [prompt(n, salt=n) for n in LENGTHS]   # four over two slots
@@ -217,8 +262,8 @@ def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
     assert eng.compile_counts()["reset_state"] == 1
 
 
-def test_chunk_sizes_16_and_64_agree(params, engine, alone):
-    wide = SlotEngine(params, CFG, max_slots=1, max_seq_len=128,
+def test_chunk_sizes_16_and_64_agree(fam, params, engine, alone):
+    wide = SlotEngine(params, fam.cfg, max_slots=1, max_seq_len=128,
                       prefill_chunk=64)
     for n in (37, 50):
         p = prompt(n, salt=6)
@@ -236,12 +281,12 @@ def test_chunk_sizes_16_and_64_agree(params, engine, alone):
     ((5, 16, 37), [(5, 16), (32,), (5,)]),
 ])
 def test_uneven_prompts_admitted_together_emit_what_they_emit_alone(
-        params, alone, lengths, rows):
-    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
-                     prefill_chunk=16)
+        engine, alone, monkeypatch, lengths, rows):
+    eng = engine   # three slots, chunks of 16, every slot released
     sched = Scheduler(eng)
     plans, real = [], eng.prefill
-    eng.prefill = lambda plan: plans.append(real(plan)) or plans[-1]
+    monkeypatch.setattr(
+        eng, "prefill", lambda plan: plans.append(real(plan)) or plans[-1])
     prompts = [prompt(n, salt=n) for n in lengths]
     reqs = [sched.submit(Request(p.tolist(), max_new_tokens=NEW))
             for p in prompts]
@@ -252,11 +297,12 @@ def test_uneven_prompts_admitted_together_emit_what_they_emit_alone(
     assert sched.prefill_tokens == sum(lengths)
 
 
-def test_state_after_rows_of_several_slots_is_the_one_slot_paths(params,
-                                                                 engine):
+def test_state_after_rows_of_several_slots_is_the_one_slot_paths(
+        fam, params, engine):
     """Two prompts prefilled as rows of one program (a padded row beside
-    a whole one, then a lone wider row): each slot's tail, state, K and
-    V are those of the prompt run alone through the cache."""
+    a whole one, then a lone wider row): each slot's recurrent state, and
+    its K and V where the family caches them, are those of the prompt
+    run alone through the cache."""
     prompts = {0: prompt(37, salt=1), 2: prompt(50, salt=2)}
     for slot, p in prompts.items():
         engine.admit(slot, p, NEW)
@@ -268,21 +314,21 @@ def test_state_after_rows_of_several_slots_is_the_one_slot_paths(params,
     assert engine.decoding[0] and engine.decoding[2]
     for slot, p in prompts.items():
         _, want = decode_forward(params, jnp.asarray(p)[None],
-                                 init_kv_cache(CFG, 1, 128), 0, CFG)
-        for name in ("conv", "ssm"):
+                                 init_kv_cache(fam.cfg, 1, 128), 0, fam.cfg)
+        for name in fam.state:
             got = engine._cache[name][:, slot]
-            assert float(jnp.abs(got - want[name][:, 0]).max()) < 1e-5, name
+            assert close(got, want[name][:, 0]), name
             assert float(jnp.abs(got).max()) > 0
-        for name in ("k", "v"):
+        for name in ("k", "v") if fam.kv else ():
             got = engine._cache[name][:, slot, :len(p)]
             assert float(jnp.abs(
                 got - want[name][:, 0, :len(p)]).max()) < 1e-5, name
         engine.release(slot)
 
 
-def test_rows_with_nothing_real_leave_every_slot_as_it_was(engine):
+def test_rows_with_nothing_real_leave_every_slot_as_it_was(fam, engine):
     """What compiles the programs before a request: rows that hold
-    nothing real. With requests in flight every slot's tail and state,
+    nothing real. With requests in flight every slot's recurrent state,
     and the K and V a slot can see, stay bit for bit."""
     engine.admit(0, prompt(37, salt=3), NEW)   # mid-prefill, 16 of 37 in
     engine.prefill_step(0)
@@ -293,9 +339,9 @@ def test_rows_with_nothing_real_leave_every_slot_as_it_was(engine):
     before = jax.tree.map(np.asarray, engine._cache)
     engine.warm_prefill(2 * engine.prefill_chunk)
     after = jax.tree.map(np.asarray, engine._cache)
-    for name in ("conv", "ssm"):
+    for name in fam.state:
         assert np.array_equal(after[name], before[name]), name
-    for name in ("k", "v"):
+    for name in ("k", "v") if fam.kv else ():
         for slot, n in seen.items():
             assert np.array_equal(after[name][:, slot, :n],
                                   before[name][:, slot, :n]), (name, slot)
@@ -305,8 +351,8 @@ def test_rows_with_nothing_real_leave_every_slot_as_it_was(engine):
     engine.release(1)
 
 
-def test_twenty_prompt_lengths_compile_nothing(params):
-    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=128,
+def test_twenty_prompt_lengths_compile_nothing(fam, params):
+    eng = SlotEngine(params, fam.cfg, max_slots=3, max_seq_len=128,
                      prefill_chunk=16)
     sched = Scheduler(eng)
     built = eng.compile_counts()
@@ -324,7 +370,7 @@ def test_twenty_prompt_lengths_compile_nothing(params):
 # ---- (e) what treats a KV range as a prefix refuses the model by name ----
 
 def _kv(n):
-    shape = (2, n, 1, 16)
+    shape = (2, n, 1, 16)   # refused before any shape is read
     return {"k": np.zeros(shape, np.float32), "v": np.zeros(shape, np.float32)}
 
 
@@ -337,10 +383,10 @@ REFUSALS = {
     "build_prefix_cache": lambda e, p: build_prefix_cache(e, 1),
     "scheduler_prefix_cache": lambda e, p: Scheduler(
         e, prefix_cache=RadixPrefixCache(1 << 20)),
-    "paged_engine": lambda e, p: PagedEngine(p, CFG, max_slots=2,
+    "paged_engine": lambda e, p: PagedEngine(p, e.cfg, max_slots=2,
                                              max_seq_len=64),
     "build_engine_paged": lambda e, p: build_engine(
-        p, CFG, slots=2, max_seq_len=64, paged=True),
+        p, e.cfg, slots=2, max_seq_len=64, paged=True),
     "disagg_prefill_only": lambda e, p: Scheduler(e).submit(
         Request([1, 2, 3], max_new_tokens=2, prefill_only=True)),
     "disagg_prefilled": lambda e, p: Scheduler(e).submit(
@@ -350,8 +396,10 @@ REFUSALS = {
 
 
 @pytest.mark.parametrize("entry", sorted(REFUSALS))
-def test_kv_only_entry_points_refuse_the_model_by_name(params, engine, entry):
-    with pytest.raises(TpuFlowException, match="jamba.*recurrent state"):
+def test_kv_only_entry_points_refuse_the_model_by_name(fam, params, engine,
+                                                       entry):
+    with pytest.raises(TpuFlowException,
+                       match="%s.*recurrent state" % fam.name):
         REFUSALS[entry](engine, params)
     assert not engine.active.any()
 
@@ -367,7 +415,8 @@ def test_no_budget_builds_no_prefix_cache_and_refuses_nothing(engine,
 @pytest.mark.parametrize("cfg,name,recurrent", [
     (llama.LlamaConfig.tiny(), "llama", False),
     (mixtral.MixtralConfig.tiny(), "mixtral", False),
-    (CFG, "jamba", True)])
+    (CFG, "jamba", True),
+    (brumby.BrumbyConfig.tiny(), "brumby", True)])
 def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
     fam = family(cfg)
     assert fam.name == name and fam.module.__name__.endswith(name)
@@ -376,8 +425,10 @@ def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
     assert type(build_config({"cfg": {"dim": cfg.dim}}, model=name)) \
         is type(cfg)
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 32))
-    assert set(cache) == ({"k", "v", "conv", "ssm"} if recurrent
-                          else {"k", "v"})
+    assert set(cache) == {"llama": {"k", "v"}, "mixtral": {"k", "v"},
+                          "jamba": {"k", "v", "conv", "ssm"},
+                          "brumby": {"ret_s", "ret_z"}}[name]
+    assert set(recurrent_pools(cfg)) == set(cache) - {"k", "v"}
     assert all(leaf.shape[1] == 2 for leaf in cache.values())
     axes = fam.module.logical_axes(cfg)
     shapes = jax.eval_shape(lambda: fam.module.init_params(
@@ -387,7 +438,8 @@ def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
 
 
 def test_an_unknown_family_is_refused_by_name():
-    with pytest.raises(TpuFlowException, match="jamba, llama, mixtral"):
+    with pytest.raises(TpuFlowException,
+                       match="brumby, jamba, llama, mixtral"):
         build_config({"cfg": {}}, model="gpt")
     with pytest.raises(TpuFlowException, match="no model family"):
         family(object())
@@ -521,17 +573,23 @@ def test_one_token_is_a_chunk_of_one_and_conv_chunks_are_the_whole():
 
 # ---- scope names and program names, as the benchmark's readers find them ----
 
-@pytest.mark.parametrize("program,scopes", [
-    ("decode", ("decode_layers", "attn_qkv", "kv_cache_update",
-                "decode_attention", "attn_out", "ffn", "ssm_in_proj",
-                "ssm_conv", "ssm_x_proj", "ssm_state_update",
-                "ssm_out_proj")),
-    ("prefill", ("decode_layers", "attn_qkv", "kv_cache_update",
-                 "decode_attention", "attn_out", "ffn", "ssm_in_proj",
-                 "ssm_conv", "ssm_x_proj", "ssm_scan", "ssm_out_proj"))])
-def test_programs_keep_their_names_and_hold_every_scope(engine, program,
-                                                        scopes):
+SCOPES = {
+    "jamba": (("decode_layers", "attn_qkv", "kv_cache_update",
+               "decode_attention", "attn_out", "ffn", "ssm_in_proj",
+               "ssm_conv", "ssm_x_proj", "ssm_out_proj"),
+              {"decode": "ssm_state_update", "prefill": "ssm_scan"}),
+    "brumby": (("decode_layers", "retention_qkvg", "retention_out", "ffn"),
+               {"decode": "retention_update", "prefill": "retention_chunk"}),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_programs_keep_their_names_and_hold_every_scope(fam, engine,
+                                                        program):
     import re
+
+    scopes, by_program = SCOPES[fam.name]
+    scopes += (by_program[program],)
 
     cache = jax.eval_shape(lambda: engine._cache)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -547,5 +605,5 @@ def test_programs_keep_their_names_and_hold_every_scope(engine, program,
                                 else "prefill") in text
     for scope in scopes:
         assert re.search(r'["/(]%s["/)]' % scope, text), scope
-    other = "ssm_scan" if program == "decode" else "ssm_state_update"
+    other = by_program["prefill" if program == "decode" else "decode"]
     assert not re.search(r'["/(]%s["/)]' % other, text)

@@ -302,6 +302,44 @@ def test_no_prefill_shape_holds_a_second_pool(one_chip, family):
         assert temporaries < pool / 4, (rows, width, temporaries, pool)
 
 
+def test_brumby_programs_hold_no_second_state_pool(one_chip):
+    """The benchmark's Brumby configuration (8 layers at published
+    widths, 20 slots: a float32 state pool of 5.5e9 B, no KV pool): the
+    decode step holds the Pallas kernel that updates the decoding lanes'
+    state in place, and neither it nor a prefill program, which reads and
+    writes its rows' state in the pool by index, holds as much as ONE
+    layer of that pool among its temporaries; the whole program stays
+    under the chip's 16.0e9 B. Of the three prefill shapes the widest row
+    and the two rows are compiled here ([1, 64] is [1, 128]'s program at
+    half the width: `benchmark/describe_compile.py` compiles that one, and
+    a compile of this model costs this file seven seconds of tier-1's
+    limit)."""
+    from metaflow_tpu.models import brumby
+    from metaflow_tpu.serving import SlotEngine
+
+    cfg = brumby.BrumbyConfig.brumby_14b(n_layers=8, max_seq_len=4096)
+    params = on(jax.eval_shape(
+        lambda: brumby.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    engine = SlotEngine(params, cfg, max_slots=20, max_seq_len=4096,
+                        prefill_chunk=64)
+    assert set(engine._cache) == {"ret_s", "ret_z"}
+    cache = on(engine._cache, one_chip)
+    layer = math.prod(cache["ret_s"].shape[1:]) * 4
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    decode = engine._decode_greedy_fn.lower(
+        params, cache, i32(20), i32(20), sds((20,), jnp.bool_, one_chip)
+    ).compile()
+    assert "tpu_custom_call" in decode.as_text()
+    programs = [decode] + [
+        engine._prefill_fn.lower(params, cache, i32(rows, width), i32(rows),
+                                 i32(rows), i32(rows)).compile()
+        for rows, width in engine.prefill_shapes(2 * 64)[1:]]
+    assert engine.prefill_shapes(2 * 64)[1:] == [(1, 128), (2, 64)]
+    for compiled in programs:
+        assert compiled.memory_analysis().temp_size_in_bytes < layer
+        assert device_bytes(compiled) < HBM_BYTES
+
+
 def test_paged_engine_steps_llama3_8b_widths(one_chip):
     from metaflow_tpu.serving import PagedEngine
 
