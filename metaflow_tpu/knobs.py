@@ -188,10 +188,6 @@ _k("TPUFLOW_ZERO", "bool", False, "", "training",
    "ZeRO-style optimizer-state sharding over the data axis")
 
 # --- ops -------------------------------------------------------------------
-_k("TPUFLOW_FLASH_BLOCK_Q", "int", 128, "", "ops",
-   "flash-attention query block size")
-_k("TPUFLOW_FLASH_BLOCK_K", "int", 128, "", "ops",
-   "flash-attention key/value block size")
 _k("TPUFLOW_GMM_BLOCK_S", "int", 128, "", "ops",
    "grouped matmul block size along tokens")
 _k("TPUFLOW_GMM_BLOCK_F", "int", 128, "", "ops",

@@ -33,8 +33,7 @@ from jax.sharding import PartitionSpec as P
 from .. import knobs
 
 from .attention import (
-    BLOCK_K,
-    BLOCK_Q,
+    MIN_BLOCK,
     _broadcast_gqa,
     _fold_heads,
     _unfold_heads,
@@ -291,11 +290,11 @@ def _ring_attention_local_flash(q, k, v, axis_name, causal=True, scale=None,
 # ---------------------------------------------------------------------------
 
 
-def _resolve_impl(impl, S_local):
+def _resolve_impl(impl, S_local, D, dtype):
     if impl == "auto":
         impl = knobs.get_str("TPUFLOW_RING_IMPL")
     # same predicate flash_block_fwd/bwd enforce — single source of truth
-    aligned = blocks_aligned(S_local)
+    aligned = blocks_aligned(S_local, D, dtype)
     if impl == "auto":
         impl = auto_impl(aligned, "ring_attention", (S_local,))
     if impl in ("flash", "flash_interpret") and not aligned:
@@ -303,10 +302,8 @@ def _resolve_impl(impl, S_local):
         # unaligned tail (grid floor-division would leave rows unwritten)
         raise ValueError(
             "ring flash attention needs the per-device sequence shard "
-            "(%d) to be a multiple of both block sizes (q=%d, k=%d via "
-            "TPUFLOW_FLASH_BLOCK_Q/K), with one block dividing the "
-            "other; use impl='xla' or pad the sequence"
-            % (S_local, min(BLOCK_Q, S_local), min(BLOCK_K, S_local))
+            "(%d) to be a multiple of the kernels' %d-row tile floor; use "
+            "impl='xla' or pad the sequence" % (S_local, MIN_BLOCK)
         )
     return impl
 
@@ -318,7 +315,7 @@ def ring_attention_sharded(mesh, axis_name="sequence", causal=True,
 
     impl: 'auto' | 'flash' | 'flash_interpret' | 'xla' (or env
     TPUFLOW_RING_IMPL). 'flash' needs the per-device sequence shard to be
-    a multiple of the pallas block size (BLOCK_Q, 128).
+    a multiple of the pallas tile floor (MIN_BLOCK, 128).
     """
     from .attention import shard_map_novma
 
@@ -327,7 +324,7 @@ def ring_attention_sharded(mesh, axis_name="sequence", causal=True,
 
     def dispatch(q, k, v):
         S_local = q.shape[1]
-        chosen = _resolve_impl(impl, S_local)
+        chosen = _resolve_impl(impl, S_local, q.shape[3], q.dtype)
         if chosen in ("flash", "flash_interpret"):
             return _ring_attention_local_flash(
                 q, k, v, axis_name, causal=causal, scale=scale,

@@ -16,6 +16,15 @@ from metaflow_tpu.ops import (
     rms_norm,
     rope_frequencies,
 )
+from metaflow_tpu.ops.attention import (
+    KERNELS,
+    _flash_forward,
+    _fold_heads,
+    _unfold_heads,
+    blocks_aligned,
+    flash_block_bwd,
+    flash_tiles,
+)
 from metaflow_tpu.spmd import MeshSpec, create_mesh
 
 
@@ -60,6 +69,62 @@ class TestFlashAttention:
         ref = reference_attention(q, k, v, causal=False)
         fl = flash_attention(q, k, v, causal=False, interpret=True)
         np.testing.assert_allclose(ref, fl, atol=2e-5, rtol=2e-4)
+
+
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    @pytest.mark.parametrize(
+        "block_q,block_k", [(128, 256), (256, 128), (128, 512), (512, 128)])
+    def test_tile_boundaries(self, block_q, block_k, causal):
+        """Unequal tiles over a sequence of several: the first tile that
+        touches the diagonal is not the last, in each of the three
+        kernels; forward and the three gradients against the reference."""
+        BH, S, D = 2, 512, 64
+        scale = 1.0 / np.sqrt(D)
+        blocks = (block_q, block_k)
+        q, k, v, g = (x[0].transpose(1, 0, 2) for x in
+                      _qkv(B=1, S=S, H=BH, D=D, seed=3)
+                      + _qkv(B=1, S=S, H=BH, D=D, seed=4)[:1])
+
+        def ref(q, k, v):
+            return _fold_heads(reference_attention(
+                *(_unfold_heads(x, 1, BH) for x in (q, k, v)),
+                causal=causal))
+
+        want, vjp = jax.vjp(ref, q, k, v)
+        out, lse = _flash_forward(
+            q, k, v, causal, scale, interpret=True, blocks=blocks)
+        np.testing.assert_allclose(out, want, atol=2e-5, rtol=2e-4)
+        delta = jnp.sum(g * out, axis=-1)
+        got = flash_block_bwd(
+            q, k, v, g, lse, delta, scale, causal, interpret=True,
+            blocks=(blocks, blocks))
+        for a, b in zip(got, vjp(g)):
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-3)
+
+    @pytest.mark.parametrize(
+        "S", [8, 64, 96, 128, 256, 384, 512, 1024, 1536, 2048, 4096, 8192,
+              32768])
+    def test_tiles_of_every_length_keep_the_contract(self, S):
+        """What flash_tiles answers tiles every sequence length the tests
+        and the cells use, for each kernel, head size and dtype."""
+        for D in (64, 128, 256):
+            for dtype in (jnp.bfloat16, jnp.float32):
+                assert blocks_aligned(S, D, dtype)
+                for kernel in KERNELS:
+                    for causal in (True, False):
+                        bq, bk = flash_tiles(
+                            S, D, dtype, causal, kernel)
+                        assert S % bq == 0 and S % bk == 0
+                        assert bq % bk == 0 or bk % bq == 0
+                        assert min(bq, bk) >= min(S, 128)
+                        assert bq * bk * 4 <= 4 * 2 ** 20
+
+    def test_length_that_does_not_tile_is_refused(self):
+        assert not blocks_aligned(192)
+        q = jnp.zeros((1, 192, 1, 128), jnp.float32)
+        with pytest.raises(ValueError, match="divisible"):
+            flash_attention(q, q, q, interpret=True)
 
 
 class TestRingAttention:

@@ -91,10 +91,15 @@ def device_bytes(compiled):
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
-@pytest.mark.parametrize("seq", [2048, 8192])
-def test_flash_attention_llama3_8b_heads(one_chip, seq, grad):
-    q = sds((1, seq, 32, 128), BF16, one_chip)
-    kv = sds((1, seq, 8, 128), BF16, one_chip)
+@pytest.mark.parametrize(
+    "batch,seq", [(1, 2048), (1, 8192), (2, 4096)],
+    ids=["2048", "8192", "mistral-7b.train-4k"])
+def test_flash_attention_llama3_8b_heads(one_chip, batch, seq, grad):
+    """With the tiles flash_tiles answers: one that overflows fast memory
+    or that Mosaic refuses is found here. The third case is the training
+    cell's own shape."""
+    q = sds((batch, seq, 32, 128), BF16, one_chip)
+    kv = sds((batch, seq, 8, 128), BF16, one_chip)
 
     def fwd(q, k, v):
         return flash_attention(q, k, v, causal=True)
