@@ -660,6 +660,17 @@ def scheduler_metric_families(stats):
     gauge("tpuflow_serve_state_pool_bytes_per_slot",
           state.get("bytes_per_slot", 0),
           "Recurrent-state bytes one slot holds, whatever its position")
+    pools = stats.get("cache_pools") or {}
+    for field, help_text in (
+            ("bytes", "Device bytes of the cache's pools by what they hold: "
+             "global (K and V as deep as max_seq_len), ring (K and V of "
+             "window layers), state (recurrent state)"),
+            ("bytes_per_slot", "The same, one slot's share")):
+        fam = Family("tpuflow_serve_cache_pool_" + field, "gauge", help_text)
+        for kind in sorted(pools):
+            fam.add(pools[kind][field], {"kind": kind})
+        if pools:
+            fams.append(fam)
     prefix = stats.get("prefix_cache") or {}
     if prefix.get("enabled"):
         fams.append(

@@ -39,10 +39,16 @@ in place: K and V for the attention layers, and for the Mamba layers
 the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py),
 for the power-retention layers (Brumby: every layer, so the cache holds
 no K and V at all) the float32 state `ret_s` and its normaliser `ret_z`
-(ops/retention.py). Which pools a kind of layer carries, their shape a
-slot and their dtype are that kind's declaration (`POOLS`), and
-`init_kv_cache`, this loop, the engine's reset of a new occupant and
-`is_recurrent` read it. Unlike a KV position, a recurrent state has no
+(ops/retention.py); for window layers (Phi-4-mini-flash) K and V of
+the last positions in a ring, `win_k` and `win_v`, as deep as the window
+and the widest row one program writes; `k` and `v` may also be ONE
+layer's, which the layers after it read again and never write, and a
+gated memory unit has no pool at all: what it reads rides the loop's
+carry. Which pools a kind of layer carries, their shape a slot and their
+dtype are that kind's declaration (`POOLS`; an attention kind also says
+which pools it reads, whether it writes them and whether its queries see
+a window, `ATTENTION`), and `init_kv_cache`, this loop, the engine's
+reset of a new occupant and `is_recurrent` read it. Unlike a KV position, a recurrent state has no
 garbage that is overwritten before it is seen: `valid` tells those
 layers which of the new positions are real. The batch is the whole pool
 (a decode step) or, with `slots`, a few distinct rows of it (the slot
@@ -62,16 +68,22 @@ is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
 `ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`;
 inside a retention layer `retention_qkvg` (the projections, the head
 norms, rope and the gate), `retention_chunk` (a chunk) or
-`retention_update` (one token), `retention_out`. The pool's write sits
-under `kv_cache_update`, its chunk reads under `decode_attention`, a
-state pool's read and write-back under the `ssm_*` or `retention_*`
-scope that needs them: what lies under `decode_layers` and under none of
+`retention_update` (one token), `retention_out`; where a model has them,
+`window_attention` (a window layer's ring reads and attention) and
+`cross_attention` (a layer's read of another layer's K and V) beside
+`decode_attention`, `diff_combine` (a differential pair's lambda,
+subtraction and sub-norm), `gmu` (a gated memory unit), and in a prefill
+program whose last layers see one position a row `cross_decoder` around
+those. The pool's write sits
+under `kv_cache_update`, its chunk reads under the attention kind's
+scope, a state pool's read and write-back under the `ssm_*` or
+`retention_*` scope that needs them: what lies under `decode_layers` and under none of
 those is the loop's own cost (its counter, the residual stream), and
 anything the compiler still moves without being asked.
 
-Sharding: the KV cache carries the same logical axes as activations
-([layers, batch, seq, kv_heads * head_dim], heads major in the folded
-axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
+Sharding: a KV pool carries the same logical axes as activations
+([layers of its kind, batch, seq or a ring's depth, kv_heads * head_dim],
+heads major in the folded axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
 tensor axis, so decode parallelizes with the exact rule table training
 uses (spmd/sharding.py); XLA keeps the per-step all-gathers on ICI.
 """
@@ -85,8 +97,8 @@ import jax.numpy as jnp
 
 from .. import knobs
 from ..exception import TpuFlowException
-from ..models import brumby, jamba, llama, mixtral
-from ..ops import retention, rms_norm
+from ..models import brumby, jamba, llama, mixtral, phi4flash
+from ..ops import diff_attention, layer_norm, retention, rms_norm
 from ..ops.attention import NEG_INF
 from ..ops.moe import moe_ffn
 from ..ops.rope import apply_rope, rope_frequencies
@@ -132,37 +144,91 @@ FAMILIES = {
                                "mamba": "mamba_layers"}),
     brumby.BrumbyConfig: Family("brumby", brumby, _dense_ffn, True,
                                 {"retention": "layers"}),
+    phi4flash.Phi4FlashConfig: Family(
+        "phi4flash", phi4flash, _dense_ffn, False,
+        {kind: kind + "_layers"
+         for kind in ("mamba", "window", "full", "cross", "gmu")}),
 }
 
-# A pool a kind of layer carries. shape: (cfg, max_seq_len) -> its shape
-# a slot (the pool is [layers of the kind, slots] + that); dtype: None
-# for the cache's; recurrent: what it holds is carried from position to
-# position (nothing there is overwritten before it is seen, so it is
-# masked by `valid`, zeroed for a new occupant, and no KV range stands
-# for it); view: a prefill program cuts its rows out once and puts them
-# back (small states), else a layer reads and writes its rows in place.
+# A pool a kind of layer carries. shape: (cfg, max_seq_len, widest row=None)
+# -> its shape a slot (the pool is [layers of the kind, slots] + that);
+# dtype: None for the cache's; recurrent: what it holds is carried from
+# position to position (nothing there is overwritten before it is seen,
+# so it is masked by `valid`, zeroed for a new occupant, and no KV range
+# stands for it); view: a prefill program cuts its rows out once and puts
+# them back (small states, and K and V of one layer that several read),
+# else a layer reads and writes its rows in place.
 Pool = collections.namedtuple("Pool", "shape dtype recurrent view")
 
-_kv_pool = Pool(lambda cfg, seq: (seq, cfg.n_kv_heads * cfg.head_dim),
+
+def _kv_width(cfg):
+    """K and V of a position, heads folded: V's heads may be fewer and
+    wider than K's (`v_head_dim`), never of another width in all."""
+    return cfg.n_kv_heads * cfg.head_dim
+
+
+def _v_head_dim(cfg):
+    """How wide a head of V is: a key head's size, or what the config
+    says (differential attention: a pair's two key heads share one value
+    head of twice the size)."""
+    return getattr(cfg, "v_head_dim", cfg.head_dim)
+
+
+def _ring_depth(cfg, seq, row):
+    """How deep a window layer's pool is: the window and the widest row
+    one program writes (`_write_layer` has the derivation), or without a
+    bound on the row the whole sequence, where nothing ever wraps."""
+    return seq if row is None else cfg.sliding_window + row
+
+
+_kv_pool = Pool(lambda cfg, seq, row=None: (seq, _kv_width(cfg)),
                 None, False, False)
+_ring_pool = Pool(
+    lambda cfg, seq, row=None: (_ring_depth(cfg, seq, row), _kv_width(cfg)),
+    None, False, False)
+# one layer's K and V that the layers after it read again: a row's view
+# of it (a few MB at 4,096 positions) is cut out once a program, not once
+# a reading layer
+_shared_kv_pool = _kv_pool._replace(view=True)
 POOLS = {
     "attention": {"k": _kv_pool, "v": _kv_pool},
+    "window": {"win_k": _ring_pool, "win_v": _ring_pool},
+    "full": {"k": _shared_kv_pool, "v": _shared_kv_pool},
+    "cross": {},   # reads the full layer's k and v, writes nothing
+    "gmu": {},     # reads the memory the layer loop carries, nothing else
     "mamba": {
-        "conv": Pool(lambda cfg, seq: (cfg.mamba_d_conv - 1, cfg.d_inner),
-                     None, True, True),
-        "ssm": Pool(lambda cfg, seq: (cfg.mamba_d_state, cfg.d_inner),
-                    jnp.float32, True, True),
+        "conv": Pool(
+            lambda cfg, seq, row=None: (cfg.mamba_d_conv - 1, cfg.d_inner),
+            None, True, True),
+        "ssm": Pool(
+            lambda cfg, seq, row=None: (cfg.mamba_d_state, cfg.d_inner),
+            jnp.float32, True, True),
     },
     # [KV, Hd, D] with D = 8,320 at a head size of 128 (the 8,256 products
     # of a symmetric square and 64 zeros: whole lanes, ops/retention.py)
     "retention": {
-        "ret_s": Pool(lambda cfg, seq: (
+        "ret_s": Pool(lambda cfg, seq, row=None: (
             cfg.n_kv_heads, cfg.head_dim, retention.state_dim(cfg.head_dim)),
             jnp.float32, True, False),
-        "ret_z": Pool(lambda cfg, seq: (
+        "ret_z": Pool(lambda cfg, seq, row=None: (
             cfg.n_kv_heads, retention.state_dim(cfg.head_dim)),
             jnp.float32, True, False),
     },
+}
+
+# An attention kind of layer. k, v: the pools it reads; writes: whether
+# it first writes its own K and V of the new positions there, at its own
+# index (a layer that does not projects no K and V and reads index 0 of
+# another kind's pool: one layer's, read by every such layer); window:
+# whether a query sees only itself and the `cfg.sliding_window` - 1
+# positions before it (its pool is then a ring); scope: what the reads
+# and the attention stand under.
+Attention = collections.namedtuple("Attention", "k v writes window scope")
+ATTENTION = {
+    "attention": Attention("k", "v", True, False, "decode_attention"),
+    "window": Attention("win_k", "win_v", True, True, "window_attention"),
+    "full": Attention("k", "v", True, False, "decode_attention"),
+    "cross": Attention("k", "v", False, False, "cross_attention"),
 }
 
 
@@ -189,7 +255,11 @@ def family_config_class(name):
 def layer_kinds(cfg):
     """The kind of every layer in the model's order, a key of `POOLS`:
     "attention" (K and V cached), "mamba" (a convolution tail and a
-    state carried) or "retention" (a state and its normaliser carried)."""
+    state carried), "retention" (a state and its normaliser carried),
+    "window" (K and V of the last positions in a ring), "full" (K and V
+    cached, for itself and the layers after it), "cross" (another
+    layer's K and V read again) or "gmu" (an earlier layer's output of
+    the same program, nothing cached)."""
     return getattr(cfg, "layer_kinds", None) or ("attention",) * cfg.n_layers
 
 
@@ -208,21 +278,34 @@ def recurrent_pools(cfg):
                   if pool.recurrent)
 
 
+def ring_pools(cfg):
+    """The names of the pools that are rings: K and V of the kinds of
+    layer whose queries see a window."""
+    return sorted(name for kind in set(layer_kinds(cfg))
+                  if kind in ATTENTION and ATTENTION[kind].window
+                  for name in (ATTENTION[kind].k, ATTENTION[kind].v))
+
+
 def is_recurrent(cfg):
     """Whether some layer carries a state that a KV range does not
     hold: such a model's prefix is not its cached K and V."""
     return bool(recurrent_pools(cfg))
 
 
-def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
+def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None, row=None):
     """The static cache, one tree of the pools the model's kinds of
     layer declare (`POOLS`), each [layers of the kind, batch] + its shape
     a slot: `k` and `v` [attention layers, batch, max_seq, kv_heads *
-    head_dim]; for Mamba layers `conv` [.., d_conv-1, d_inner] (the
-    convolution's tail) and `ssm` [.., d_state, d_inner] in float32; for
-    retention layers `ret_s` [.., kv_heads, head_dim, D] and `ret_z`
-    [.., kv_heads, D] in float32. A stack with no attention layer has no
-    `k` and `v`. Every leaf has the batch on axis 1.
+    head_dim] (for a model with ONE full-attention layer that others
+    read again, that layer's alone); for window layers `win_k` and
+    `win_v` [.., sliding_window + row, kv_heads * head_dim], a ring
+    (`row`: the most positions one program writes into a row; None: no
+    bound, and the pool is max_seq deep); for Mamba layers `conv` [..,
+    d_conv-1, d_inner] (the convolution's tail) and `ssm` [.., d_state,
+    d_inner] in float32; for retention layers `ret_s` [.., kv_heads,
+    head_dim, D] and `ret_z` [.., kv_heads, D] in float32. A stack with
+    no attention layer has no `k` and `v`. Every leaf has the batch on
+    axis 1.
 
     The pools are read and written a layer at a time in place
     (`_decode_layer`): heads and head size are folded into one minor
@@ -232,7 +315,7 @@ def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None):
     tiling to pad or to lay out anew on the way in and out."""
     dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
     return {name: jnp.zeros(
-                (layers, batch_size) + pool.shape(cfg, max_seq_len),
+                (layers, batch_size) + pool.shape(cfg, max_seq_len, row),
                 pool.dtype or dt)
             for name, (pool, layers) in cache_pools(cfg).items()}
 
@@ -277,30 +360,70 @@ def _ungroup(out, dtype):
     return out.astype(dtype)
 
 
-@jax.named_scope("decode_attention")
-def _cached_attention(q, cache_k, cache_v, pos):
-    """q: [B, T, H, Hd] at absolute positions pos..pos+T-1; cache_k/v:
-    [B, Smax, KV, Hd]. Keys at index i are visible to query t iff
-    i <= pos + t (unfilled cache slots fall outside by construction).
-    pos: traced scalar, or [B] vector for per-slot offsets.
+def _visible(key_idx, q_pos, window=None, ring=None):
+    """Which keys a query sees. key_idx: [S] indices into a layer's
+    pool; q_pos: the queries' absolute positions, `_mask_positions`'
+    shape. Causal: index i holds position i, seen iff i <= q. With
+    `window`, only where it also lies after q - window. With `ring`
+    (the pool's depth R; position p is held at index p % R), index r
+    holds, as far as query q is concerned, the one position of (q - R, q]
+    that falls on it, q - (q - r) % R: a later one cannot be meant, an
+    earlier one has been overwritten. What was never written (a
+    position before 0: a new occupant's ring still holds the last one's
+    keys) is not seen either."""
+    if ring is None:
+        key_pos = key_idx
+        seen = key_pos <= q_pos
+    else:
+        key_pos = q_pos - (q_pos - key_idx) % ring
+        seen = key_pos >= 0
+    if window is not None:
+        seen &= key_pos > q_pos - window
+    return seen
 
-    Dense: touches the WHOLE [Smax] cache every step — fine at moderate
+
+def _value_groups(a, kv_heads):
+    """[B, KV, G, ...] -> [B, kv_heads, KV // kv_heads * G, ...]: the
+    groups of consecutive key heads that share one value head, side by
+    side over it (differential attention: a pair's two score maps read
+    one value head; `ops/diff_attention.py`). The same array where K and
+    V have as many heads."""
+    if a.shape[1] == kv_heads:
+        return a
+    return a.reshape((a.shape[0], kv_heads, -1) + a.shape[3:])
+
+
+def _cached_attention(q, cache_k, cache_v, pos, window=None, ring=False,
+                      scope="decode_attention", dtype=None):
+    """q: [B, T, H, Hd] at absolute positions pos..pos+T-1; cache_k:
+    [B, S, KV, Hd], cache_v: [B, S, KV or fewer, Dv]. Keys at index i
+    are visible to query t iff i <= pos + t (unfilled cache slots fall
+    outside by construction), and as `_visible` says of a window and,
+    with `ring`, of a pool that is a window layer's ring. pos: traced
+    scalar, or [B] vector for per-slot offsets.
+    Returns [B, T, H, Dv] in `dtype` (None: q's), under `scope`.
+
+    Dense: touches the WHOLE [S] cache every step — fine at moderate
     max_seq, bandwidth-bound for long-context serving (use 'chunked').
     The same grouped contraction as _streamed_attention: both products
     batched over (B, KV) on the cache's dtype with float32 accumulation,
     softmax in float32, probabilities rounded to V's dtype."""
-    T, Hd = q.shape[1], q.shape[3]
-    scale = 1.0 / math.sqrt(Hd)
-    qg = _group_queries(q, cache_k.shape[2])
-    logits = jnp.einsum("bkgtd,bskd->bkgts", qg, cache_k,
-                        preferred_element_type=jnp.float32) * scale
-    key_idx = jnp.arange(cache_k.shape[1])
-    q_pos = _mask_positions(_query_positions(pos, T))
-    logits = jnp.where(key_idx <= q_pos, logits, NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(cache_v.dtype)
-    out = jnp.einsum("bkgts,bskd->bkgtd", probs, cache_v,
-                     preferred_element_type=jnp.float32)
-    return _ungroup(out, q.dtype)
+    with jax.named_scope(scope):
+        T, Hd = q.shape[1], q.shape[3]
+        scale = 1.0 / math.sqrt(Hd)
+        qg = _group_queries(q, cache_k.shape[2])
+        logits = jnp.einsum("bkgtd,bskd->bkgts", qg, cache_k,
+                            preferred_element_type=jnp.float32) * scale
+        key_idx = jnp.arange(cache_k.shape[1])
+        q_pos = _mask_positions(_query_positions(pos, T))
+        seen = _visible(key_idx, q_pos, window,
+                        cache_k.shape[1] if ring else None)
+        logits = jnp.where(seen, logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(cache_v.dtype)
+        out = jnp.einsum("bkgts,bskd->bkgtd",
+                         _value_groups(probs, cache_v.shape[2]), cache_v,
+                         preferred_element_type=jnp.float32)
+        return _ungroup(out, dtype or q.dtype)
 
 
 def _default_decode_chunk():
@@ -313,66 +436,81 @@ def _default_decode_chunk():
 DECODE_CHUNK = _default_decode_chunk()
 
 
-@jax.named_scope("decode_attention")
-def _streamed_attention(q, pos, chunk, n_chunks, fetch):
+def _streamed_attention(q, pos, chunk, n_chunks, fetch, window=None,
+                        ring=None, scope="decode_attention", dtype=None):
     """Online-softmax attention over KV streamed in `chunk`-sized blocks
     (the flash-decode accumulation shared by the contiguous-cache and
-    paged-cache paths; only HOW a block is fetched differs).
+    paged-cache paths; only HOW a block is fetched differs), under
+    `scope`.
 
-    fetch(i) -> (k_blk [B, chunk, KV, Hd], v_blk, key_idx [chunk]): the
-    i-th KV block and the absolute key positions it holds. Keys are
-    visible iff key_idx <= q_pos AND key_idx >= i * chunk — the second
-    term masks a clamped edge block's re-read of earlier keys (a paged
-    fetch never re-reads, so the term is a no-op there).
+    fetch(i) -> (k_blk [B, chunk, KV, Hd], v_blk [B, chunk, KV or fewer,
+    Dv], key_idx [chunk]): the i-th KV block and the indices of the pool
+    it holds (a position's own, or in a ring its place). Keys are
+    visible as `_visible` says (causal; a window; a ring's positions)
+    AND where key_idx >= i * chunk — that term masks a clamped edge
+    block's re-read of earlier keys (a paged fetch never re-reads, so the
+    term is a no-op there).
 
     A block is contracted as fetched, in the cache's dtype: the query
     heads of a group are rows of one matrix product per (slot, KV head)
-    (_group_queries), accumulated in float32. Logits, mask, running max
-    and sum and the accumulator are float32; the block's probabilities
-    are rounded to V's dtype for the second product (as the training
-    kernel does, ops/attention.py), the running sum is taken before the
-    rounding."""
-    T, Hd = q.shape[1], q.shape[3]
-    scale = 1.0 / math.sqrt(Hd)
-    q_pos = _mask_positions(_query_positions(pos, T))
-    # KV heads from a block's shape, without fetching one
-    qg = _group_queries(q, jax.eval_shape(fetch, 0)[0].shape[2])
+    (_group_queries), accumulated in float32. Where V has fewer heads
+    than K (differential attention: a pair's two key heads over one
+    value head of twice the size), the score maps of the key heads that
+    share a value head are rows of ONE product with it
+    (`_value_groups`), so each K and V chunk is read once for both maps.
+    Logits, mask, running max and sum and the accumulator are float32;
+    the block's probabilities are rounded to V's dtype for the second
+    product (as the training kernel does, ops/attention.py), the running
+    sum is taken before the rounding. Returns [B, T, H, Dv] in `dtype`
+    (None: q's)."""
+    with jax.named_scope(scope):
+        T, Hd = q.shape[1], q.shape[3]
+        scale = 1.0 / math.sqrt(Hd)
+        q_pos = _mask_positions(_query_positions(pos, T))
+        # heads from a block's shape, without fetching one
+        k_shape, v_shape, _ = jax.eval_shape(fetch, 0)
+        qg = _group_queries(q, k_shape.shape[2])
+        over_v = lambda a: _value_groups(a, v_shape.shape[2])
 
-    def body(i, carry):
-        m, l, acc = carry
-        k_blk, v_blk, key_pos = fetch(i)
-        logits = jnp.einsum("bkgtd,bckd->bkgtc", qg, k_blk,
-                            preferred_element_type=jnp.float32) * scale
-        visible = (key_pos <= q_pos) & (key_pos >= i * chunk)
-        logits = jnp.where(visible, logits, NEG_INF)
-        m_new = jnp.maximum(m, logits.max(axis=-1))
-        p = jnp.exp(logits - m_new[..., None])
-        corr = jnp.exp(m - m_new)
-        l_new = l * corr + p.sum(axis=-1)
-        acc_new = acc * corr[..., None] + jnp.einsum(
-            "bkgtc,bckd->bkgtd", p.astype(v_blk.dtype), v_blk,
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        def body(i, carry):
+            m, l, acc = carry
+            k_blk, v_blk, key_idx = fetch(i)
+            logits = jnp.einsum("bkgtd,bckd->bkgtc", qg, k_blk,
+                                preferred_element_type=jnp.float32) * scale
+            visible = _visible(key_idx, q_pos, window, ring) \
+                & (key_idx >= i * chunk)
+            logits = jnp.where(visible, logits, NEG_INF)
+            m_new = jnp.maximum(m, logits.max(axis=-1))
+            p = jnp.exp(logits - m_new[..., None])
+            corr = jnp.exp(m - m_new)
+            l_new = l * corr + p.sum(axis=-1)
+            acc_new = acc * over_v(corr)[..., None] + jnp.einsum(
+                "bkgtc,bckd->bkgtd", over_v(p.astype(v_blk.dtype)), v_blk,
+                preferred_element_type=jnp.float32)
+            return m_new, l_new, acc_new
 
-    m0 = jnp.full(qg.shape[:-1], NEG_INF, jnp.float32)
-    l0 = jnp.zeros(qg.shape[:-1], jnp.float32)
-    acc0 = jnp.zeros(qg.shape, jnp.float32)
-    _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
-    return _ungroup(acc / l[..., None], q.dtype)
+        m0 = jnp.full(qg.shape[:-1], NEG_INF, jnp.float32)
+        l0 = jnp.zeros(qg.shape[:-1], jnp.float32)
+        acc0 = jnp.zeros(over_v(l0).shape + v_shape.shape[3:], jnp.float32)
+        _, l, acc = jax.lax.fori_loop(0, n_chunks, body, (m0, l0, acc0))
+        return _ungroup(acc / over_v(l)[..., None], dtype or q.dtype)
 
 
-@jax.named_scope("decode_attention")
 def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
-                              chunk=DECODE_CHUNK):
+                              chunk=DECODE_CHUNK, window=None, ring=False,
+                              v_head_dim=None, **kw):
     """Flash-decode: the same attention reading ONLY the filled prefix
     of layer `layer` (a traced index) of the pools cache_k/v
-    [layers, B, Smax, KV * Hd]; the chunks are read straight out of that
-    layer of them, so the layer's view is never copied.
+    [layers, B, S, KV * Hd]; the chunks are read straight out of that
+    layer of them, so the layer's view is never copied. V's heads are
+    `v_head_dim` wide (None: as wide as K's). With `ring` the pool is a
+    window layer's ring: once a slot has passed its depth every index
+    holds a position, and all of it is read.
 
     KV chunks stream through an online-softmax accumulation
     (lax.fori_loop with a TRACED trip count ceil((pos+T)/chunk), lowered
     to a while_loop) — per emitted token the HBM traffic is O(filled),
-    not O(Smax), which is what long-context serving needs. Numerics
+    not O(S), which is what long-context serving needs. Numerics
     follow the dense path: the same grouped products accumulated in
     float32, the same masking, probabilities rounded to V's dtype per
     chunk instead of once; the edge chunk's clamped slice re-reads
@@ -382,7 +520,10 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
     chunk = min(chunk, Smax)
     # traced trip count; with per-slot [B] positions the loop runs to the
     # DEEPEST slot's fill (shallower slots just mask the extra chunks)
-    n_chunks = (jnp.max(jnp.asarray(pos)) + T + chunk - 1) // chunk
+    filled = jnp.max(jnp.asarray(pos)) + T
+    if ring:
+        filled = jnp.minimum(filled, Smax)
+    n_chunks = (filled + chunk - 1) // chunk
 
     def fetch(i):
         start = jnp.minimum(i * chunk, Smax - chunk)
@@ -390,10 +531,25 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
         k_blk = jax.lax.dynamic_slice(cache_k, at, size).reshape(
             B, chunk, -1, Hd)
         v_blk = jax.lax.dynamic_slice(cache_v, at, size).reshape(
-            B, chunk, -1, Hd)
+            B, chunk, -1, v_head_dim or Hd)
         return k_blk, v_blk, start + jnp.arange(chunk)
 
-    return _streamed_attention(q, pos, chunk, n_chunks, fetch)
+    return _streamed_attention(q, pos, chunk, n_chunks, fetch, window=window,
+                               ring=Smax if ring else None, **kw)
+
+
+def _norm(cfg, x, lp, name):
+    """The norm `name` of a layer's (or the model's) leaves: an RMS norm,
+    or where a bias `<name>_b` stands beside the weight a LayerNorm."""
+    if name + "_b" in lp:
+        return layer_norm(x, lp[name], lp[name + "_b"], cfg.norm_eps)
+    return rms_norm(x, lp[name], cfg.norm_eps)
+
+
+def _linear(h, lp, w, b):
+    """h @ lp[w], plus the bias lp[b] where the leaves hold one."""
+    out = h @ lp[w]
+    return out + lp[b] if b in lp else out
 
 
 @jax.named_scope("attn_qkv")
@@ -404,17 +560,23 @@ def _attn_qkv(cfg, cos, sin, pos, x, lp):
     without rope `cos` and `sin` are None. Shared verbatim by the
     contiguous-cache layer below and the paged-cache layer
     (serving/paged.py) so both paths stay numerically identical."""
-    return _project_qkv(cfg, cos, sin, pos,
-                        rms_norm(x, lp["attn_norm"], cfg.norm_eps), lp)
+    return _project_qkv(cfg, cos, sin, pos, _norm(cfg, x, lp, "attn_norm"),
+                        lp)
 
 
 def _project_qkv(cfg, cos, sin, pos, h, lp):
-    """q, k and v of the normed input h [B, T, dim]."""
+    """q, k and v of the normed input h [B, T, dim]: what the layer's
+    leaves hold. k and v are None for a layer that projects none (it
+    reads another layer's); v's heads are `v_head_dim` wide where the
+    config says so; a differential layer (it holds the lambda vectors)
+    gets its query heads in `diff_attention.pair_major`'s order."""
     B, T, _ = h.shape
     H, KV, Hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = (h @ lp["wq"]).reshape(B, T, H, Hd)
-    k = (h @ lp["wk"]).reshape(B, T, KV, Hd)
-    v = (h @ lp["wv"]).reshape(B, T, KV, Hd)
+    q = _linear(h, lp, "wq", "bq").reshape(B, T, H, Hd)
+    k = v = None
+    if "wk" in lp:
+        k = _linear(h, lp, "wk", "bk").reshape(B, T, KV, Hd)
+        v = _linear(h, lp, "wv", "bv").reshape(B, T, -1, _v_head_dim(cfg))
     if "q_norm" in lp:   # an RMS norm a head, one weight a head size
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
@@ -422,6 +584,8 @@ def _project_qkv(cfg, cos, sin, pos, h, lp):
         positions = _query_positions(pos, T)
         q = apply_rope(q, cos, sin, positions=positions)
         k = apply_rope(k, cos, sin, positions=positions)
+    if "lambda_q1" in lp:
+        q = diff_attention.pair_major(q, KV)
     return q, k, v
 
 
@@ -431,53 +595,84 @@ def _block_ffn(cfg, x, attn, lp, mesh=None):
     contiguous and paged cache paths."""
     B, T, _ = x.shape
     with jax.named_scope("attn_out"):
-        x = x + attn.reshape(B, T, cfg.n_heads * cfg.head_dim) @ lp["wo"]
+        x = x + _linear(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp,
+                        "wo", "bo")
     return _ffn(cfg, x, lp, mesh)
 
 
 @jax.named_scope("ffn")
 def _ffn(cfg, x, lp, mesh):
-    h = rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+    h = _norm(cfg, x, lp, "ffn_norm")
     return x + family(cfg).ffn(cfg, h, lp, mesh)
 
 
-def _decode_layer(cfg, cos, sin, pos, x, layer_params, cache_k, cache_v,
-                  layer, mesh=None, attn_impl="dense", slots=None):
-    """One attention block over T new tokens, reading+extending layer
-    `layer` (a traced index) of the pools [layers, B, Smax, KV * Hd],
-    written and read in place: every row of the pool, or with `slots`
-    the rows it names. The feed-forward half is the family's."""
-    lp = layer_params
+def _at(a, last):
+    """Position last[b] of row b of a [B, T, ...]: [B, 1, ...]."""
+    return a[jnp.arange(a.shape[0]), last][:, None]
+
+
+def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
+                  mesh=None, attn_impl="dense", slots=None, last=None):
+    """One attention block of `kind` (`ATTENTION`) over T new tokens,
+    reading and (a kind that writes) extending layer `layer` (a traced
+    index) of its pools [layers, B, S, KV * Hd], written and read in
+    place: every row of the pool, or with `slots` the rows it names (a
+    pool the loop holds as a view holds just the batch already). The
+    feed-forward half is the family's. With `last` ([B]) K and V of all
+    T positions are written and the rest of the block, from the queries
+    on, runs for position last[b] of each row alone: x comes back
+    [B, 1, dim]. Returns (x, cache)."""
+    lp, a = layer_params, ATTENTION[kind]
+    cache_k, cache_v = cache[a.k], cache[a.v]
+    if cache_pools(cfg)[a.k][0].view:
+        slots = None
     q, k, v = _attn_qkv(cfg, cos, sin, pos, x, lp)
 
-    with jax.named_scope("kv_cache_update"):
-        cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos, layer,
-                               slots)
-        cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos, layer,
-                               slots)
+    if a.writes:
+        with jax.named_scope("kv_cache_update"):
+            cache_k = _write_layer(cache_k, k.astype(cache_k.dtype), pos,
+                                   layer, slots, ring=a.window)
+            cache_v = _write_layer(cache_v, v.astype(cache_v.dtype), pos,
+                                   layer, slots, ring=a.window)
+        cache = dict(cache, **{a.k: cache_k, a.v: cache_v})
+    if last is not None:
+        x, q, pos = _at(x, last), _at(q, last), pos + last
 
-    read_k, read_v, at = cache_k, cache_v, layer
+    # a layer that writes none reads the ONE layer its pools hold
+    read_k, read_v, at = cache_k, cache_v, layer if a.writes else 0
     if slots is not None:
         # the rows' views of this layer, cut out once a layer (a pool of
         # one layer that holds the batch): a few MB a row, where a chunk
         # loop that read two slots' chunks out of the whole pool made the
         # compiler lay the pool out anew in every layer (PERF.md, PR 30)
-        with jax.named_scope("decode_attention"):
-            read_k = _slot_rows(cache_k, slots, layer)
-            read_v = _slot_rows(cache_v, slots, layer)
+        with jax.named_scope(a.scope):
+            read_k = _slot_rows(cache_k, slots, at)
+            read_v = _slot_rows(cache_v, slots, at)
         at = 0
+    differential = "lambda_q1" in lp
+    # a differential layer's two maps are subtracted before anything
+    # rounds them to the model's dtype
+    kw = dict(window=cfg.sliding_window if a.window else None, ring=a.window,
+              scope=a.scope, dtype=jnp.float32 if differential else None)
     if attn_impl == "chunked":
-        attn = _chunked_cached_attention(q, read_k, read_v, pos, at)
+        attn = _chunked_cached_attention(
+            q, read_k, read_v, pos, at, v_head_dim=_v_head_dim(cfg), **kw)
     else:
-        view = lambda pool: pool[at].reshape(
-            pool.shape[1:3] + (cfg.n_kv_heads, cfg.head_dim))
-        attn = _cached_attention(q, view(read_k), view(read_v), pos)
+        view = lambda pool, hd: pool[at].reshape(pool.shape[1:3] + (-1, hd))
+        attn = _cached_attention(
+            q, view(read_k, cfg.head_dim), view(read_v, _v_head_dim(cfg)),
+            pos, **kw)
+    if differential:
+        with jax.named_scope("diff_combine"):
+            lam0 = jnp.asarray(cfg.lambda_init[kind], jnp.float32)[layer]
+            attn = diff_attention.combine(attn, cfg.n_kv_heads, lp, lam0,
+                                          cfg.norm_eps, x.dtype)
     x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
-    return x, cache_k, cache_v
+    return x, cache
 
 
-def _write_layer(pool, new, pos, layer, slots=None):
-    """new [B, T, KV, Hd] into pool [layers, B, Smax, KV * Hd] at `layer`,
+def _write_layer(pool, new, pos, layer, slots=None, ring=False):
+    """new [B, T, KV, Hd] into pool [layers, B, S, KV * Hd] at `layer`,
     every batch row at its own cursor (or all at a scalar `pos`); with
     `slots` ([B] distinct), row b of `new` into row slots[b] of the pool.
 
@@ -485,14 +680,33 @@ def _write_layer(pool, new, pos, layer, slots=None):
     last positions may lie past the pool's edge: those land on the last
     position, which is past the row's cursor like every padded position
     and so overwritten before it is seen (a clamped block write would
-    shift the real positions instead)."""
+    shift the real positions instead).
+
+    With `ring` the pool is a window layer's, S = window + the widest
+    row a program writes, and position p lands on index p % S. The
+    engine's invariant, "garbage is overwritten before it is seen",
+    holds there too. A program that writes positions c .. c + T - 1 of a
+    row (T <= S - window) overwrites what stood at c + t - S, and the
+    earliest position any query from c on still sees is c - window + 1 >
+    c + t - S: nothing a live query needs is lost, whether position c + t
+    is real or pads the row. What a padded position (or a masked lane's
+    write at its cursor c) leaves at index (c' + x) % S, x < T, for the
+    row's next cursor c', a later query q >= c' takes for position c' +
+    x - S (`_visible`: the one position of (q - S, q] on that index)
+    until position c' + x itself is written over it, and c' + x - S <= q
+    - window: outside the window. So a ring needs no mask on its writes
+    and no reset for a new occupant, whose queries at q < S take every
+    index past q for a position before 0."""
     new = new.reshape(new.shape[:2] + (-1,))
-    if jnp.ndim(pos) == 0:
-        return jax.lax.dynamic_update_slice(
-            pool, new[None], (layer, 0, pos, 0))
     B, T = new.shape[:2]
+    if jnp.ndim(pos) == 0:
+        if not ring:
+            return jax.lax.dynamic_update_slice(
+                pool, new[None], (layer, 0, pos, 0))
+        pos = jnp.full((B,), pos)
     rows = jnp.arange(B) if slots is None else slots
-    at = jnp.minimum(pos[:, None] + jnp.arange(T)[None], pool.shape[2] - 1)
+    at = pos[:, None] + jnp.arange(T)[None]
+    at = at % pool.shape[2] if ring else jnp.minimum(at, pool.shape[2] - 1)
     return pool.at[layer, rows[:, None], at].set(
         new, mode="promise_in_bounds")
 
@@ -536,11 +750,20 @@ def _put_layer_rows(pool, rows, layer, slots):
 
 def _mamba_layer(cfg, x, lp, conv, state, valid):
     """One Mamba block over T new tokens from this layer's carried
-    (conv [B, K-1, Di], state [B, N, Di])."""
-    out, conv, state = jamba.mamba_mixer(
-        cfg, lp, rms_norm(x, lp["ssm_norm"], cfg.norm_eps), conv, state,
-        valid)
-    return _ffn(cfg, x + out, lp, None), conv, state
+    (conv [B, K-1, Di], state [B, N, Di]); the last of the four returned
+    is the recurrence's output before its gate, [B, T, Di] float32."""
+    out, conv, state, y = jamba.mamba_mixer(
+        cfg, lp, _norm(cfg, x, lp, "ssm_norm"), conv, state, valid)
+    return _ffn(cfg, x + out, lp, None), conv, state, y
+
+
+def _gmu_layer(cfg, x, lp, memory):
+    """One gated-memory-unit block: out = (silu(h W1) * m) W2 with m
+    the memory at the same positions, [B, T, Di]."""
+    with jax.named_scope("gmu"):
+        out = phi4flash.gated_memory_unit(
+            lp, _norm(cfg, x, lp, "gmu_norm"), memory)
+    return _ffn(cfg, x + out, lp, None)
 
 
 def _retention_layer(cfg, cos, sin, pos, x, lp, cache, layer, valid, slots):
@@ -576,12 +799,19 @@ def _retention_layer(cfg, cos, sin, pos, x, lp, cache, layer, valid, slots):
     return _ffn(cfg, x, lp, None), dict(cache, ret_s=pool_s, ret_z=pool_z)
 
 
-def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
-    """The layer loop of every family: the activations and the whole
-    cache are its carry, and layer i of a kind reads its weights out of
-    that kind's stack and reads and writes index i of that kind's pools
-    (of the rows `slots` names, where the batch is not the whole pool)."""
+def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
+            last=None):
+    """The layer loop of every family: the activations, the whole cache
+    and (a model with gated memory units) the memory are its carry, and
+    layer i of a kind reads its weights out of that kind's stack and
+    reads and writes index i of that kind's pools (of the rows `slots`
+    names, where the batch is not the whole pool). With `last` the layers
+    before the config's `tail_layer` run over every position, that layer
+    writes K and V of every position, and from its queries on the loop
+    runs for position last[b] of each row alone (scope `cross_decoder`):
+    x comes back [B, 1, dim]."""
     fam = family(cfg)
+    kinds = layer_kinds(cfg)
     # rope's table is as long as the KV pool is deep; a stack that caches
     # no K and V is bound by the config's positions alone
     cos, sin = rope_frequencies(
@@ -591,36 +821,53 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
         llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
     ) if fam.rope else (None, None)
 
-    def body(kind, i, carry):
-        x, cache = carry
-        cache = dict(cache)
-        lp = jamba.layer_at(params[fam.stacks[kind]], i)
-        if kind == "attention":
-            x, cache["k"], cache["v"] = _decode_layer(
-                cfg, cos, sin, pos, x, lp, cache["k"], cache["v"], i,
-                mesh=mesh, attn_impl=attn_impl, slots=slots)
-            return x, cache
-        if kind == "retention":
-            return _retention_layer(cfg, cos, sin, pos, x, lp, cache, i,
-                                    valid, slots)
-        # the layer's tail and state are read out of the pools and written
-        # back under the scope of the op that uses them, so that a scope's
-        # device time holds the pool's traffic that its kernel needs
-        conv_scope = jax.named_scope("ssm_conv")
-        state_scope = jax.named_scope(
-            "ssm_state_update" if x.shape[1] == 1 else "ssm_scan")
-        at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False)
-        with conv_scope:
-            conv = at(cache["conv"])
-        with state_scope:
-            state = at(cache["ssm"])
-        x, conv, state = _mamba_layer(cfg, x, lp, conv, state, valid)
-        put = jax.lax.dynamic_update_index_in_dim
-        with conv_scope:
-            cache["conv"] = put(cache["conv"], conv, i, 0)
-        with state_scope:
-            cache["ssm"] = put(cache["ssm"], state, i, 0)
-        return x, cache
+    def layers(pos, valid, last=None):
+        """The loop's body for new tokens at `pos`, of which `valid`
+        are real; with `last`, the body of the layer that narrows."""
+
+        def body(kind, i, carry):
+            x, cache, memory = carry
+            lp = jamba.layer_at(params[fam.stacks[kind]], i)
+            if kind in ATTENTION:
+                x, cache = _decode_layer(
+                    cfg, kind, cos, sin, pos, x, lp, cache, i, mesh=mesh,
+                    attn_impl=attn_impl, slots=slots, last=last)
+                if last is not None and memory is not None:
+                    memory = _at(memory, last)
+                return x, cache, memory
+            if kind == "retention":
+                return _retention_layer(cfg, cos, sin, pos, x, lp, cache, i,
+                                        valid, slots) + (memory,)
+            if kind == "gmu":
+                return _gmu_layer(cfg, x, lp, memory), cache, memory
+            # the layer's tail and state are read out of the pools and
+            # written back under the scope of the op that uses them, so
+            # that a scope's device time holds the pool's traffic that its
+            # kernel needs
+            cache = dict(cache)
+            conv_scope = jax.named_scope("ssm_conv")
+            state_scope = jax.named_scope(
+                "ssm_state_update" if x.shape[1] == 1 else "ssm_scan")
+            at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
+                                                        keepdims=False)
+            with conv_scope:
+                conv = at(cache["conv"])
+            with state_scope:
+                state = at(cache["ssm"])
+            x, conv, state, y = _mamba_layer(cfg, x, lp, conv, state, valid)
+            put = jax.lax.dynamic_update_index_in_dim
+            with conv_scope:
+                cache["conv"] = put(cache["conv"], conv, i, 0)
+            with state_scope:
+                cache["ssm"] = put(cache["ssm"], state, i, 0)
+            if memory is not None:
+                # the config marks which Mamba layer's output the gated
+                # memory units after it read
+                memory = jnp.where(i == cfg.memory_layer,
+                                   y.astype(memory.dtype), memory)
+            return x, cache, memory
+
+        return body
 
     # K and V are read and written in their pools, rows `slots` of them,
     # and so is a large recurrent state (power retention's, 34 MB a layer
@@ -630,21 +877,38 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots):
     # be written in place: compiled for the chip, the whole pool was laid
     # out anew on the way in and out): the rows' part of all its layers
     # is cut out once, carried through the loop as a pool that holds just
-    # the batch, and put back after it
+    # the batch, and put back after it. So is ONE layer's K and V that
+    # several layers read (10 MB a row at 4,096 positions): cut out once,
+    # not once a reading layer
     pools = {} if slots is None else {
         name: cache[name] for name, (pool, _) in cache_pools(cfg).items()
         if pool.view}
+    memory = jnp.zeros(x.shape[:2] + (cfg.d_inner,), x.dtype) \
+        if "gmu" in kinds else None
     with jax.named_scope("decode_layers"):
         cache = dict(cache, **{name: _slot_rows(pool, slots)
                                for name, pool in pools.items()})
-        x, cache = jamba.scan_layers(layer_kinds(cfg), body, (x, cache))
+        carry = (x, cache, memory)
+        if last is None:
+            carry = jamba.scan_layers(kinds, layers(pos, valid), carry)
+        else:
+            cut = cfg.tail_layer
+            before = collections.Counter(kinds[:cut + 1])
+            carry = jamba.scan_layers(kinds[:cut], layers(pos, valid), carry)
+            with jax.named_scope("cross_decoder"):
+                carry = layers(pos, valid, last)(
+                    kinds[cut], before[kinds[cut]] - 1, carry)
+                carry = jamba.scan_layers(
+                    kinds[cut + 1:], layers(pos + last, None), carry,
+                    start=before)
+        x, cache, _ = carry
         for name, pool in pools.items():
             cache[name] = _put_slot_rows(pool, cache[name], slots)
     return x, cache
 
 
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
-                   attn_impl="dense", valid=None, slots=None):
+                   attn_impl="dense", valid=None, slots=None, last=None):
     """Forward over T new tokens at absolute position `pos` (a traced
     scalar, or a traced [B] vector when every batch row decodes at its
     own offset — the continuous-batching engine), reading and extending
@@ -659,11 +923,16 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
     [B] vector of DISTINCT rows of a cache that holds more: row b of the
     batch reads and extends row slots[b] in place and no other row is
     touched (the slot engine's prefill program; `pos` is then a vector).
-    Returns (logits [B, T, vocab] fp32, updated cache)."""
+    last: None, or for a model whose config marks a `tail_layer` a [B]
+    vector: only position last[b] of row b is read, so the layers from
+    the tail layer's attention on, and the head, run for that position
+    alone (the cache is extended by all T all the same).
+    Returns (logits [B, T, vocab] fp32, or [B, 1, vocab] with `last`;
+    updated cache)."""
     x = params["embed"][tokens].astype(llama.param_dtype(cfg))
     x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl,
-                       slots)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+                       slots, last)
+    x = _norm(cfg, x, params, "final_norm")
     if "lm_head" in params:
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
                             preferred_element_type=jnp.float32)

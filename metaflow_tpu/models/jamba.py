@@ -12,8 +12,9 @@ The same pure-pytree design as models/llama.py, with TWO stacks, one per
 kind of layer: `attn_layers` (the Llama block's leaves) and
 `mamba_layers`, each stacked on a leading layer axis in the order its
 layers occur. `scan_layers` walks them in the model's order with one
-compiled body per run of a kind inside a period, so compile time does
-not grow with depth.
+compiled body per run of a kind inside a period (`layer_plan`: this
+model's pattern is one period twice over, its one-segment case), so
+compile time does not grow with depth.
 
 Leaves are stored as published except where the chip's tiling wants
 the channels minor: `conv_w` is [d_conv, d_inner] (`conv_w[k]`
@@ -21,6 +22,7 @@ multiplies the input d_conv-1-k positions back; published
 [d_inner, 1, d_conv]). `A_log` stays [d_inner, d_state].
 """
 
+import functools
 from dataclasses import dataclass, replace
 
 import jax
@@ -196,45 +198,76 @@ def logical_axes(cfg):
 
 # ---- walking a stack of several kinds ----
 
+@functools.lru_cache(maxsize=None)
 def layer_plan(kinds):
-    """(repeats, runs) for a pattern of layer kinds: the shortest period
-    that repeats to the whole pattern, cut into runs of one kind,
-    [(kind, index of the run's first layer among the period's layers of
-    that kind, length)]. Jamba2-3B: 2 x [mamba x 7, attention, mamba x 6]."""
+    """The pattern of layer kinds (a tuple) as a list of repeated segments,
+    [(repeats, runs, layers of each kind a period)]: a segment is a period
+    that repeats `repeats` times, cut into runs of one kind, [(kind, index
+    of the run's first layer among the period's layers of that kind,
+    length)]. Of all ways to cut the pattern the one with the fewest runs
+    (each is one traced body), then the fewest segments, then the
+    shortest periods. A pattern that is one period over and over is one
+    segment (Jamba2-3B: 2 x [mamba x 7, attention, mamba x 6]; a stack of
+    one kind: n x [that kind]); one with no period of its own is several
+    (Phi-4-mini-flash: 8 x [mamba, window], [mamba, full], 7 x [gmu,
+    cross])."""
     n = len(kinds)
-    period = next(p for p in range(1, n + 1)
-                  if n % p == 0 and kinds[:p] * (n // p) == kinds)
-    runs, seen = [], {}
-    for kind in kinds[:period]:
-        if runs and runs[-1][0] == kind:
-            runs[-1][2] += 1
-        else:
-            runs.append([kind, seen.get(kind, 0), 1])
-        seen[kind] = seen.get(kind, 0) + 1
-    return n // period, [tuple(r) for r in runs], seen
 
-
-def scan_layers(kinds, body, carry):
-    """Run `body(kind, i, carry) -> carry` for every layer in order; `i`
-    is the layer's (traced) index within the stack of its kind. One
-    traced body per run of the plan, whatever the depth."""
-    repeats, runs, per_period = layer_plan(tuple(kinds))
-
-    def period(r, carry):
-        for kind, first, length in runs:
-            base = r * per_period[kind] + first
-            if length == 1:
-                carry = body(kind, base, carry)
+    def runs_of(period):
+        runs, seen = [], {}
+        for kind in period:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
             else:
-                carry = jax.lax.fori_loop(
-                    0, length,
-                    lambda j, c, kind=kind, base=base: body(kind, base + j, c),
-                    carry)
-        return carry
+                runs.append([kind, seen.get(kind, 0), 1])
+            seen[kind] = seen.get(kind, 0) + 1
+        return [tuple(r) for r in runs], seen
 
-    if repeats == 1:
-        return period(0, carry)
-    return jax.lax.fori_loop(0, repeats, period, carry)
+    # best[s]: (runs, segments, periods' lengths) and the plan of kinds[s:]
+    best = {n: ((0, 0, 0), [])}
+    for s in range(n - 1, -1, -1):
+        for p in range(1, n - s + 1):
+            runs, seen = runs_of(kinds[s:s + p])
+            r = 1
+            while True:
+                cost, plan = best[s + r * p]
+                cost = (cost[0] + len(runs), cost[1] + 1, cost[2] + p)
+                if s not in best or cost < best[s][0]:
+                    best[s] = (cost, [(r, runs, seen)] + plan)
+                if kinds[s + r * p:s + (r + 1) * p] != kinds[s:s + p]:
+                    break
+                r += 1
+    return best[0][1]
+
+
+def scan_layers(kinds, body, carry, start=None):
+    """Run `body(kind, i, carry) -> carry` for every layer in order; `i`
+    is the layer's (traced) index within the stack of its kind, counted
+    from `start[kind]` (a part of a model's layers: how many of each
+    kind came before it). One traced body per run of the plan, whatever
+    the depth."""
+    first_of = dict(start or {})
+    for repeats, runs, per_period in layer_plan(tuple(kinds)):
+
+        def period(r, carry, runs=runs, per_period=per_period,
+                   first_of=dict(first_of)):
+            for kind, first, length in runs:
+                base = r * per_period[kind] + (first_of.get(kind, 0) + first)
+                if length == 1:
+                    carry = body(kind, base, carry)
+                else:
+                    carry = jax.lax.fori_loop(
+                        0, length,
+                        lambda j, c, kind=kind, base=base: body(
+                            kind, base + j, c),
+                        carry)
+            return carry
+
+        carry = (period(0, carry) if repeats == 1
+                 else jax.lax.fori_loop(0, repeats, period, carry))
+        for kind, count in per_period.items():
+            first_of[kind] = first_of.get(kind, 0) + repeats * count
+    return carry
 
 
 def layer_at(stack, i):
@@ -250,8 +283,12 @@ def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
     """The Mamba-1 mixer over T new positions of normed input x
     [B, T, D], continuing from (conv_tail [B, K-1, Di], h [B, N, Di]
     float32). Matmuls in the model's dtype; convolution, softplus and
-    the recurrence in float32. Returns (out [B, T, D], conv tail, h)
-    after the last valid position (ops/ssm.py)."""
+    the recurrence in float32. The inner RMS norms on dt, B and C are
+    Jamba's addition: applied where the layer's leaves hold them.
+    Returns (out [B, T, D], conv tail, h, y): tail and state after the
+    last valid position (ops/ssm.py), and the recurrence's output y
+    [B, T, Di] in float32 before the gate, which a model with gated
+    memory units hands on."""
     T = x.shape[1]
     Di, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
     with jax.named_scope("ssm_in_proj"):
@@ -263,9 +300,11 @@ def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
         u = jax.nn.silu(conv).astype(x.dtype)
     with jax.named_scope("ssm_x_proj"):
         dbc = u @ lp["x_proj"]
-        dt = rms_norm(dbc[..., :R], lp["dt_norm"], cfg.norm_eps)
-        Bm = rms_norm(dbc[..., R:R + N], lp["b_norm"], cfg.norm_eps)
-        Cm = rms_norm(dbc[..., R + N:], lp["c_norm"], cfg.norm_eps)
+        inner = (lambda a, name: rms_norm(a, lp[name], cfg.norm_eps)) \
+            if "dt_norm" in lp else (lambda a, name: a)
+        dt = inner(dbc[..., :R], "dt_norm")
+        Bm = inner(dbc[..., R:R + N], "b_norm")
+        Cm = inner(dbc[..., R + N:], "c_norm")
         delta = jax.nn.softplus((dt @ lp["dt_proj"]).astype(jnp.float32)
                                 + lp["dt_bias"].astype(jnp.float32))
         A = -jnp.exp(lp["A_log"].astype(jnp.float32)).T
@@ -280,7 +319,7 @@ def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
             y, h = ssm.selective_scan(h, u, delta, A, Bm, Cm, lp["D"], valid)
     with jax.named_scope("ssm_out_proj"):
         gated = (y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype)
-        return gated @ lp["out_proj"], conv_tail, h
+        return gated @ lp["out_proj"], conv_tail, h, y
 
 
 @jax.named_scope("ffn")
@@ -306,7 +345,7 @@ def _mamba_layer(cfg, x, lp):
     B = x.shape[0]
     tail = jnp.zeros((B, cfg.mamba_d_conv - 1, cfg.d_inner), x.dtype)
     h0 = jnp.zeros((B, cfg.mamba_d_state, cfg.d_inner), jnp.float32)
-    out, _, _ = mamba_mixer(
+    out, _, _, _ = mamba_mixer(
         cfg, lp, rms_norm(x, lp["ssm_norm"], cfg.norm_eps), tail, h0)
     return x + out
 

@@ -53,9 +53,11 @@ def shard_map_novma(fn, mesh, in_specs, out_specs):
                      out_specs=out_specs, check_vma=False)
 
 
-def reference_attention(q, k, v, causal=True, scale=None):
+def reference_attention(q, k, v, causal=True, scale=None, window=None):
     """XLA attention: [B, S, H, D] layout. Materializes S×S scores — fine for
-    moderate sequence lengths; XLA fuses mask+softmax into the matmuls."""
+    moderate sequence lengths; XLA fuses mask+softmax into the matmuls.
+    `window` (causal only): a query sees itself and the window - 1
+    positions before it. V's heads may be fewer and wider than K's."""
     B, Sq, H, D = q.shape
     k = _broadcast_gqa(k, H)
     v = _broadcast_gqa(v, H)
@@ -66,6 +68,8 @@ def reference_attention(q, k, v, causal=True, scale=None):
     if causal:
         Sk = k.shape[1]
         mask = jnp.tril(jnp.ones((Sq, Sk), dtype=bool), k=Sk - Sq)
+        if window is not None:
+            mask = jnp.triu(mask, k=Sk - Sq - window + 1)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -601,11 +605,20 @@ def _flash_partition(mesh, q, k):
                          "tensor" if n_heads > 1 else None, None)
 
 
-def attention(q, k, v, causal=True, scale=None, impl="auto", mesh=None):
+def attention(q, k, v, causal=True, scale=None, impl="auto", mesh=None,
+              window=None):
     """Dispatch: pallas flash on TPU when shapes tile cleanly, XLA
     where they do not, where the process is CPU-pinned, or by name.
     `mesh`: the mesh the caller's arrays are sharded over, if any —
-    the kernel then runs per shard (see _flash_partition)."""
+    the kernel then runs per shard (see _flash_partition). `window`: a
+    query sees itself and the window - 1 positions before it; the flash
+    kernels have no window, so it is the XLA path's alone (ROADMAP M5)."""
+    if window is not None:
+        if impl not in ("auto", "xla") or not causal:
+            raise ValueError("a window needs causal attention on the XLA "
+                             "path, got impl=%r causal=%r" % (impl, causal))
+        return reference_attention(q, k, v, causal=True, scale=scale,
+                                   window=window)
     spec = _flash_partition(mesh, q, k)
     if impl == "auto":
         S, D = q.shape[1], q.shape[3]

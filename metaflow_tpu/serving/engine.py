@@ -8,9 +8,14 @@ Orca/vLLM-lineage fix, shaped for TPUs: scheduling happens in Python,
 but every device step is one of a FIXED set of jitted programs, so the
 compiled-program residency that TPUs reward is preserved.
 
-Layout: a pool of B slots shares one static
-[layers, B, max_seq, kv_heads * head_dim] KV cache (init_kv_cache), the
-carry of decode_forward's layer loop, donated and updated in place.
+Layout: a pool of B slots shares one static cache (init_kv_cache), a
+tree of the pools the model's kinds of layer declare, each [layers of
+the kind, B] + its shape a slot: K and V [.., max_seq, kv_heads *
+head_dim] of the attention layers (or of the ONE full-attention layer
+that a model's later layers read again), K and V of window layers in a
+ring of the window and the widest prefill row, recurrent states (below).
+It is the carry of decode_forward's layer loop, donated and updated in
+place.
 Each slot holds at most one in-flight request and carries host-side
 state (pos, sampling knobs, per-token rng keys). Three compiled programs
 cover everything:
@@ -34,14 +39,22 @@ cover everything:
     top-k/top-p as traced per-slot arrays, so one program serves every
     sampling-config mix)
   - first-token: sample, for every row of a prefill program that ends
-    its prompt, the token its logits imply; fetched in one wait
+    its prompt, the token its logits imply; fetched in one wait. Where
+    the config marks a tail layer (nothing past that layer's K and V at
+    a position is read by a later one), the prefill program runs that
+    layer's attention, the layers after it and the head for each row's
+    last real position alone, and its logits are [R, 1, vocab]
 
 Slots never wait for each other: a finished slot is released and can be
 refilled while its neighbors keep decoding. Free/prefilling slots ride
 through the fused decode step as masked lanes — their writes land at
 their own cursor and are overwritten (prefill rewrites the range, decode
 overwrites pad garbage exactly one position before it would become
-visible), so no flag tensor is needed inside the compiled program.
+visible), so no flag tensor is needed inside the compiled program. A
+window layer's ring keeps that invariant with no mask and no reset
+(inference/decode.py, `_write_layer`), as long as no row is wider than
+the ring allows: `prefill_row`, two chunks, the scheduler's default
+budget.
 
 A model with recurrent layers keeps a RECURRENT-STATE POOL in the same
 cache tree, whatever pools its kinds of layer declare
@@ -83,12 +96,14 @@ from ..inference.decode import (
     DECODE_CHUNK,
     POOLS,
     bucket_length,
+    cache_pools,
     decode_forward,
     family,
     init_kv_cache,
     is_recurrent,
     layer_kinds,
     recurrent_pools,
+    ring_pools,
 )
 from ..ops.attention import NEG_INF
 
@@ -191,8 +206,15 @@ class SlotEngine(object):
         self.attn_impl = attn_impl
         self._vocab = cfg.vocab_size
 
+        # the widest row a prefill program may carry (the scheduler's
+        # default budget): a window layer's ring is this much deeper than
+        # its window
+        self.prefill_row = 2 * self.prefill_chunk
         self._cache = init_kv_cache(cfg, self.max_slots, self.max_seq_len,
-                                    dtype=cache_dtype)
+                                    dtype=cache_dtype, row=self.prefill_row)
+        # a config with a tail layer: a prefill program's logits are of
+        # each row's last real position alone
+        self._tail = getattr(cfg, "tail_layer", None) is not None
         if "k" not in self._cache and self.max_seq_len > cfg.max_seq_len:
             # no pool is as deep as max_seq_len: rope's table, which is
             # the config's, is all that bounds a position
@@ -236,9 +258,15 @@ class SlotEngine(object):
             valid = None if n_real is None else (
                 jnp.arange(tokens.shape[1])[None]
                 < jnp.reshape(n_real, (-1, 1)))
+            last = None
+            if self._tail:
+                last = jnp.full(slots.shape, tokens.shape[1] - 1) \
+                    if n_real is None else jnp.maximum(
+                        jnp.reshape(n_real, (-1,)) - 1, 0)
             return decode_forward(
                 params, tokens, cache, start, cfg, mesh=mesh,
-                attn_impl=self.attn_impl, valid=valid, slots=slots)
+                attn_impl=self.attn_impl, valid=valid, slots=slots,
+                last=last)
 
         def _advance(nxt, tok, pos, mask):
             # decoding lanes take the new token and move their cursor;
@@ -270,7 +298,8 @@ class SlotEngine(object):
 
         def _first_token(logits, idx, keys, temp, top_k, top_p):
             # row r's token off position idx[r] of a prefill program's
-            # logits [R, W, vocab], every row with its own key and knobs
+            # logits [R, W, vocab] (a config with a tail layer: [R, 1,
+            # vocab], idx 0), every row with its own key and knobs
             last = logits[jnp.arange(logits.shape[0]), idx]
             return sample_slots(last, keys, temp, top_k, top_p)
 
@@ -345,10 +374,25 @@ class SlotEngine(object):
         """The recurrent-state pools' bytes on the device, in all and a
         slot (0 for a model that carries none): what a slot costs
         whatever its position, beside the KV pool's bytes a position."""
-        total = sum(self._cache[name].nbytes
-                    for name in self._recurrent_pools)
-        return {"bytes": int(total),
-                "bytes_per_slot": int(total // self.max_slots)}
+        return self.pool_stats()["state"]
+
+    def pool_stats(self):
+        """Every pool's bytes on the device by what it holds, in all and
+        a slot: `global` (K and V as deep as `max_seq_len`), `ring` (K
+        and V of window layers, as deep as the window and the widest
+        prefill row, whatever `max_seq_len`), `state` (recurrent state:
+        the same at every position). Zeros for what the model has none
+        of."""
+        out = {what: {"bytes": 0, "bytes_per_slot": 0}
+               for what in ("global", "ring", "state")}
+        rings = ring_pools(self.cfg)
+        for name, (pool, _) in cache_pools(self.cfg).items():
+            what = ("state" if pool.recurrent else
+                    "ring" if name in rings else "global")
+            out[what]["bytes"] += int(self._cache[name].nbytes)
+        for entry in out.values():
+            entry["bytes_per_slot"] = entry["bytes"] // self.max_slots
+        return out
 
     def compile_counts(self):
         """jit cache entries per program — each decode variant must stay
@@ -538,6 +582,13 @@ class SlotEngine(object):
         default budget of two chunks: [1, chunk], [1, 2 chunk],
         [2, chunk]."""
         k = max(1, int(budget) // self.prefill_chunk)
+        if k * self.prefill_chunk > self.prefill_row and \
+                ring_pools(self.cfg):
+            raise ValueError(
+                "a prefill budget of %d tokens makes rows of %d, wider than "
+                "the %d that the window layers' ring leaves room for "
+                "(prefill_row: two chunks)"
+                % (budget, k * self.prefill_chunk, self.prefill_row))
         return [(rows, chunks * self.prefill_chunk)
                 for rows in range(1, min(k, self.max_slots) + 1)
                 for chunks in range(1, k // rows + 1)]
@@ -615,7 +666,9 @@ class SlotEngine(object):
             # the rows that do not end sample too, greedily, and are not
             # read: one program whatever the rows that end
             first = self._first_fn(
-                logits, jnp.asarray(n_real - 1),
+                logits,
+                jnp.asarray(np.zeros_like(n_real) if self._tail
+                            else n_real - 1),
                 jnp.asarray(np.stack([
                     self._keys_for(s) if e else np.zeros(2, np.uint32)
                     for s, e in zip(slots, ends)])),

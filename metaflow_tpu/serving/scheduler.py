@@ -887,6 +887,7 @@ class Scheduler(object):
             "prefix_cache": self.prefix_stats(),
             "kv_pages": self.kv_pages_stats(),
             "state_pool": self.state_pool_stats(),
+            "cache_pools": self.cache_pool_stats(),
             "speculative": (self.engine.spec_stats() if self._paged
                             else {"enabled": False}),
             "goodput": self.goodput_stats(),
@@ -943,6 +944,15 @@ class Scheduler(object):
         stats = getattr(self.engine, "state_pool_stats", None)
         return stats() if stats is not None else {
             "bytes": 0, "bytes_per_slot": 0}
+
+    def cache_pool_stats(self):
+        """The engine's pools by what they hold (`SlotEngine.pool_stats`:
+        `global`, `ring`, `state`; bytes, bytes a slot); zeros for an
+        engine that does not say."""
+        stats = getattr(self.engine, "pool_stats", None)
+        return stats() if stats is not None else {
+            what: {"bytes": 0, "bytes_per_slot": 0}
+            for what in ("global", "ring", "state")}
 
     def kv_pages_stats(self):
         """Page-pool health for /v1/stats and /healthz; {"enabled":

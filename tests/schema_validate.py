@@ -1812,6 +1812,8 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_max_context_tokens": "gauge",
     "tpuflow_serve_state_pool_bytes": "gauge",
     "tpuflow_serve_state_pool_bytes_per_slot": "gauge",
+    "tpuflow_serve_cache_pool_bytes": "gauge",
+    "tpuflow_serve_cache_pool_bytes_per_slot": "gauge",
     "tpuflow_serve_requests": "counter",
     "tpuflow_serve_decode_steps": "counter",
     "tpuflow_serve_prefill": "counter",

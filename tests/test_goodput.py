@@ -329,6 +329,10 @@ def _scheduler_stats():
                      "pages_free": 16, "occupancy": 0.75,
                      "shared_pages": 8, "cow_pages": 2, "exhausted": 1},
         "speculative": {"enabled": True, "k": 2, "accept_rate": 0.9},
+        "cache_pools": {
+            "global": {"bytes": 4096, "bytes_per_slot": 1024},
+            "ring": {"bytes": 2048, "bytes_per_slot": 512},
+            "state": {"bytes": 512, "bytes_per_slot": 128}},
         "goodput": {"serve_prefill_s": 1.5, "serve_decode_s": 4.0,
                     "serve_idle_s": 2.5, "elapsed_s": 8.0},
     }
@@ -376,6 +380,9 @@ class TestMetricFamilies:
         assert sample("tpuflow_serve_requests", outcome="served") == 11
         assert sample("tpuflow_serve_ttft_ms", quantile="0.99") == 30.0
         assert sample("tpuflow_serve_kv_pages", state="used") == 48
+        assert sample("tpuflow_serve_cache_pool_bytes", kind="ring") == 2048
+        assert sample("tpuflow_serve_cache_pool_bytes_per_slot",
+                      kind="global") == 1024
         assert sample("tpuflow_serve_goodput_seconds",
                       category="serve_decode") == 4.0
 

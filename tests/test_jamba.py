@@ -1,14 +1,17 @@
 """The families that carry recurrent state through the slot engine,
-Jamba (Mamba-1 layers with attention layers among them) and Brumby
-(power-retention layers alone: a state pool and no KV pool): parity with
-the plain reference, and the five properties a recurrent-state pool
+Jamba (Mamba-1 layers with attention layers among them), Brumby
+(power-retention layers alone: a state pool and no KV pool) and
+Phi-4-mini-flash (Mamba-1 layers beside window attention in rings, one
+global K and V pool that several layers read, gated memory units): parity
+with the plain reference, and the five properties a recurrent-state pool
 needs that a KV pool got for free: a padded chunk leaves the state after
 its last real token, masked lanes hold their state, a new occupant
 starts from an empty state, the state carries from chunk to chunk, and
 what treats a KV range as a prefix refuses the model by name. Every such
-case runs for both families (`fam`); what only Jamba has (the layer
-pattern, ops/ssm.py) follows, and what only Brumby has is
-tests/test_brumby.py. Tiny sizes, float32 unless said."""
+case runs for every such family (`fam`); what only Jamba has (the layer
+pattern, ops/ssm.py) follows, what only Brumby has is
+tests/test_brumby.py and what only Phi-4-mini-flash has
+tests/test_phi4flash.py. Tiny sizes, float32 unless said."""
 
 import os
 
@@ -25,8 +28,8 @@ from metaflow_tpu.cmd.serve import build_config, build_engine, \
 from metaflow_tpu.exception import TpuFlowException
 from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
 from metaflow_tpu.inference.decode import family, is_recurrent, \
-    layer_kinds, recurrent_pools
-from metaflow_tpu.models import jamba, llama, mixtral
+    layer_kinds, recurrent_pools, ring_pools
+from metaflow_tpu.models import jamba, llama, mixtral, phi4flash
 from metaflow_tpu.ops import ssm
 from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \
     Scheduler, SlotEngine
@@ -52,7 +55,7 @@ class Fam(object):
 
     def init_params(self):
         p = self.module.init_params(jax.random.PRNGKey(0), self.cfg)
-        if self.name == "jamba":
+        if "mamba_layers" in p:
             # a drawn convolution bias, so that an empty tail is not a
             # fixed point
             p["mamba_layers"]["conv_b"] = 0.5 * jax.random.normal(
@@ -61,7 +64,8 @@ class Fam(object):
 
 
 FAMS = {"jamba": Fam("jamba", jamba, jamba.JambaConfig),
-        "brumby": Fam("brumby", brumby, brumby.BrumbyConfig)}
+        "brumby": Fam("brumby", brumby, brumby.BrumbyConfig),
+        "phi4flash": Fam("phi4flash", phi4flash, phi4flash.Phi4FlashConfig)}
 DIMS = FAMS["jamba"].dims
 LENGTHS = (5, 16, 37, 50)   # a padded chunk, a whole one, 2 + a padded, 3 + 2
 
@@ -73,6 +77,15 @@ def prompt(n, salt=0):
 @pytest.fixture(scope="module", params=sorted(FAMS))
 def fam(request):
     return FAMS[request.param]
+
+
+# the cases that say of Phi-4-mini-flash what tests/test_phi4flash.py
+# says already (its parity runs the forward, chunks, rows of two slots, a
+# padded row, masked lanes and steps against the reference at once, its
+# served requests go through the scheduler, a new occupant reuses a slot)
+# run for the other two: tier-1 has little time to spare
+only_state_families = pytest.mark.parametrize(
+    "fam", ["brumby", "jamba"], indirect=True)
 
 
 @pytest.fixture(scope="module")
@@ -127,6 +140,7 @@ def serve(eng, slot, p, n=NEW):
 
 # ---- parity with the plain reference ----
 
+@only_state_families
 def test_forward_matches_the_reference(fam, params):
     tokens = prompt(48)
     want = reference.logits(params, tokens, fam.dims)
@@ -135,6 +149,7 @@ def test_forward_matches_the_reference(fam, params):
     assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
 
 
+@only_state_families
 @pytest.mark.parametrize("chunks", [(16, 16, 8), (7, 33)])
 def test_chunks_then_steps_through_the_cache_match_the_reference(
         fam, params, chunks):
@@ -159,6 +174,7 @@ def test_chunks_then_steps_through_the_cache_match_the_reference(
     assert float(jnp.abs(want - got).max()) < 1e-4 * float(jnp.abs(want).max())
 
 
+@only_state_families
 @pytest.mark.parametrize("dtype,limit", [
     # rounding only: a served token is the reference's best, or ties it
     ("float32", 1e-4),
@@ -184,11 +200,13 @@ def test_engine_serves_the_references_tokens(fam, params, dtype, limit):
 
 # ---- (a) a padded chunk, (d) the state carried from chunk to chunk ----
 
+@only_state_families
 @pytest.mark.parametrize("n", LENGTHS)
 def test_padded_last_chunk_gives_the_unpadded_tokens(engine, alone, n):
     assert serve(engine, 0, prompt(n)) == alone(prompt(n))
 
 
+@only_state_families
 def test_state_after_a_padded_chunk_is_the_state_after_its_last_token(
         fam, params, engine):
     p = prompt(37)   # chunks of 16, 16 and 5 padded to 16
@@ -205,6 +223,7 @@ def test_state_after_a_padded_chunk_is_the_state_after_its_last_token(
 
 # ---- (b) masked lanes, (c) the state reset ----
 
+@only_state_families
 def test_a_request_admitted_while_another_decodes(engine, alone):
     """The second rides through decode steps as a masked lane between
     its prefill chunks; the first decodes beside the second's chunks."""
@@ -240,6 +259,7 @@ def test_a_free_slots_state_is_held_through_decode_steps(fam, engine):
                               before[name]), name
 
 
+@only_state_families
 def test_a_request_that_reuses_a_released_slot(fam, engine, alone):
     serve(engine, 1, prompt(50, salt=4))
     for name in fam.state:
@@ -247,6 +267,7 @@ def test_a_request_that_reuses_a_released_slot(fam, engine, alone):
     assert serve(engine, 1, prompt(5, salt=1)) == alone(prompt(5, salt=1))
 
 
+@only_state_families
 def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
         fam, params, alone):
     eng = build_engine(params, fam.cfg, slots=2, max_seq_len=128,
@@ -262,6 +283,7 @@ def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
     assert eng.compile_counts()["reset_state"] == 1
 
 
+@only_state_families
 def test_chunk_sizes_16_and_64_agree(fam, params, engine, alone):
     wide = SlotEngine(params, fam.cfg, max_slots=1, max_seq_len=128,
                       prefill_chunk=64)
@@ -272,6 +294,7 @@ def test_chunk_sizes_16_and_64_agree(fam, params, engine, alone):
 
 # ---- one prefill program an iteration: rows of several slots (PR 30) ----
 
+@only_state_families
 @pytest.mark.parametrize("lengths,rows", [
     # two slots a program: 16 ends on the chunk's edge, 37 mid-chunk
     ((37, 16), [(16, 16), (21,)]),
@@ -297,6 +320,7 @@ def test_uneven_prompts_admitted_together_emit_what_they_emit_alone(
     assert sched.prefill_tokens == sum(lengths)
 
 
+@only_state_families
 def test_state_after_rows_of_several_slots_is_the_one_slot_paths(
         fam, params, engine):
     """Two prompts prefilled as rows of one program (a padded row beside
@@ -356,7 +380,10 @@ def test_twenty_prompt_lengths_compile_nothing(fam, params):
                      prefill_chunk=16)
     sched = Scheduler(eng)
     built = eng.compile_counts()
-    assert built["prefill"] == built["first_token"] == 3
+    # a first-token program a shape of the logits: [rows, width, vocab],
+    # or with a tail layer [rows, 1, vocab] whatever the width
+    assert built["prefill"] == 3 and built["first_token"] == (
+        2 if getattr(fam.cfg, "tail_layer", None) is not None else 3)
     lengths = [1, 2, 5, 15, 16, 17, 20, 31, 32, 33, 40, 47, 48, 49, 63, 64,
                65, 80, 96, 100]
     reqs = [sched.submit(Request(prompt(n, salt=n).tolist(),
@@ -416,7 +443,8 @@ def test_no_budget_builds_no_prefix_cache_and_refuses_nothing(engine,
     (llama.LlamaConfig.tiny(), "llama", False),
     (mixtral.MixtralConfig.tiny(), "mixtral", False),
     (CFG, "jamba", True),
-    (brumby.BrumbyConfig.tiny(), "brumby", True)])
+    (brumby.BrumbyConfig.tiny(), "brumby", True),
+    (phi4flash.Phi4FlashConfig.tiny(), "phi4flash", True)])
 def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
     fam = family(cfg)
     assert fam.name == name and fam.module.__name__.endswith(name)
@@ -427,8 +455,12 @@ def test_family_is_picked_by_the_configs_class(cfg, name, recurrent):
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, 32))
     assert set(cache) == {"llama": {"k", "v"}, "mixtral": {"k", "v"},
                           "jamba": {"k", "v", "conv", "ssm"},
-                          "brumby": {"ret_s", "ret_z"}}[name]
-    assert set(recurrent_pools(cfg)) == set(cache) - {"k", "v"}
+                          "brumby": {"ret_s", "ret_z"},
+                          "phi4flash": {"k", "v", "win_k", "win_v", "conv",
+                                        "ssm"}}[name]
+    assert set(ring_pools(cfg)) == set(cache) & {"win_k", "win_v"}
+    assert set(recurrent_pools(cfg)) == \
+        set(cache) - {"k", "v", "win_k", "win_v"}
     assert all(leaf.shape[1] == 2 for leaf in cache.values())
     axes = fam.module.logical_axes(cfg)
     shapes = jax.eval_shape(lambda: fam.module.init_params(
@@ -449,9 +481,6 @@ def test_the_layer_pattern_comes_from_period_and_offset():
     big = jamba.JambaConfig()
     kinds = big.layer_kinds
     assert [i for i, k in enumerate(kinds) if k == "attention"] == [7, 21]
-    assert jamba.layer_plan(kinds)[:2] == (
-        2, [("mamba", 0, 7), ("attention", 0, 1), ("mamba", 7, 6)])
-    assert jamba.layer_plan(("mamba", "attention", "mamba"))[0] == 1
     order = []
     jamba.scan_layers(CFG.layer_kinds,
                       lambda kind, i, c: order.append(kind) or c, 0)
@@ -463,6 +492,65 @@ def test_the_layer_pattern_comes_from_period_and_offset():
         configs.dims(published)) < n
     assert configs.program_config(published, 2560)[1] == jamba.JambaConfig(
         max_seq_len=2560)
+
+
+PLANS = {
+    # the four patterns that are one period over and over: one segment
+    "llama": (("attention",) * 32, [(32, [("attention", 0, 1)])]),
+    "brumby": (("retention",) * 8, [(8, [("retention", 0, 1)])]),
+    "jamba2-3b": (jamba.JambaConfig().layer_kinds, [
+        (2, [("mamba", 0, 7), ("attention", 0, 1), ("mamba", 7, 6)])]),
+    "jamba-tiny": (CFG.layer_kinds, [
+        (2, [("mamba", 0, 2), ("attention", 0, 1), ("mamba", 2, 1)])]),
+    "no-repeat": (("mamba", "attention", "mamba"), [
+        (1, [("mamba", 0, 1), ("attention", 0, 1), ("mamba", 1, 1)])]),
+    # patterns with no period of their own: repeated segments
+    "phi4-mini-flash": (phi4flash.Phi4FlashConfig().layer_kinds, [
+        (8, [("mamba", 0, 1), ("window", 0, 1)]),
+        (1, [("mamba", 0, 1), ("full", 0, 1)]),
+        (7, [("gmu", 0, 1), ("cross", 0, 1)])]),
+    "phi4flash-tiny": (phi4flash.Phi4FlashConfig.tiny().layer_kinds, [
+        (2, [("mamba", 0, 1), ("window", 0, 1)]),
+        (1, [("mamba", 0, 1), ("full", 0, 1), ("gmu", 0, 1),
+             ("cross", 0, 1)])]),
+    "runs-then-period": (("a",) * 5 + ("b", "c") * 3 + ("a",), [
+        (5, [("a", 0, 1)]), (3, [("b", 0, 1), ("c", 0, 1)]),
+        (1, [("a", 0, 1)])]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PLANS))
+def test_layer_plan_cuts_a_pattern_into_repeated_segments(name):
+    kinds, want = PLANS[name]
+    plan = jamba.layer_plan(tuple(kinds))
+    assert [(repeats, runs) for repeats, runs, _ in plan] == want
+    # walked, every layer comes once, in order, at its index in its kind
+    walked = []
+    jamba.scan_layers(
+        kinds, lambda kind, i, c: walked.append(kind) or c + 1, 0)
+    assert walked == [kind for _, runs, _ in plan for kind, _, _ in runs]
+    seen, count = [], {}
+    for kind in kinds:
+        seen.append((kind, count.get(kind, 0)))
+        count[kind] = count.get(kind, 0) + 1
+    steps = []
+
+    def body(kind, i, carry):
+        jax.debug.callback(lambda i, kind=kind: steps.append((kind, int(i))),
+                           i, ordered=True)
+        return carry
+
+    jax.block_until_ready(jamba.scan_layers(kinds, body, jnp.zeros(())))
+    jax.effects_barrier()
+    assert steps == seen
+    # a part of the model's layers counts on from what came before it
+    steps.clear()
+    cut = len(kinds) // 2
+    jax.block_until_ready(jamba.scan_layers(
+        kinds[cut:], body, jnp.zeros(()),
+        start={k: kinds[:cut].count(k) for k in set(kinds)}))
+    jax.effects_barrier()
+    assert steps == seen[cut:]
 
 
 # ---- Llama and Mixtral emit what they emitted before the family table ----
@@ -580,6 +668,11 @@ SCOPES = {
               {"decode": "ssm_state_update", "prefill": "ssm_scan"}),
     "brumby": (("decode_layers", "retention_qkvg", "retention_out", "ffn"),
                {"decode": "retention_update", "prefill": "retention_chunk"}),
+    "phi4flash": (("decode_layers", "attn_qkv", "kv_cache_update",
+                   "window_attention", "decode_attention", "cross_attention",
+                   "diff_combine", "gmu", "attn_out", "ffn", "ssm_in_proj",
+                   "ssm_conv", "ssm_x_proj", "ssm_out_proj"),
+                  {"decode": "ssm_state_update", "prefill": "ssm_scan"}),
 }
 
 

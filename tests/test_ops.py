@@ -70,6 +70,29 @@ class TestFlashAttention:
         fl = flash_attention(q, k, v, causal=False, interpret=True)
         np.testing.assert_allclose(ref, fl, atol=2e-5, rtol=2e-4)
 
+    @pytest.mark.parametrize("window", [1, 7, 64, 500])
+    def test_a_window_is_a_dense_mask(self, window):
+        """`attention(..., window=w)`: a query sees itself and the w - 1
+        positions before it, against the same softmax under a mask
+        written out; V's heads fewer and wider than K's ride along. The
+        flash kernels have no window, so asking for them is refused."""
+        q, k, _ = _qkv(S=96, H=8, KV=4, D=16)
+        v = jax.random.normal(jax.random.PRNGKey(9), (2, 96, 2, 32))
+        got = attention(q, k, v, causal=True, window=window)
+        at = np.arange(96)
+        mask = (at[None] <= at[:, None]) & (at[None] > at[:, None] - window)
+        logits = np.einsum("bqhd,bkhd->bhqk", q, np.repeat(k, 2, 2)) / 4.0
+        probs = np.asarray(jax.nn.softmax(
+            jnp.where(mask[None, None], logits, -np.inf), -1))
+        want = np.einsum("bhqk,bkhd->bqhd", probs, np.repeat(v, 4, 2))
+        assert got.shape == (2, 96, 8, 32)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-4)
+        if window >= 96:   # wider than the sequence: plain causal
+            np.testing.assert_allclose(
+                got, reference_attention(q, k, v, causal=True), atol=1e-6)
+        with pytest.raises(ValueError, match="a window needs causal"):
+            attention(q, k, v, causal=True, window=window, impl="flash")
+
 
     @pytest.mark.parametrize("causal", [True, False],
                              ids=["causal", "full"])

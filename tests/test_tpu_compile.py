@@ -345,6 +345,47 @@ def test_brumby_programs_hold_no_second_state_pool(one_chip):
         assert device_bytes(compiled) < HBM_BYTES
 
 
+def test_phi4flash_programs_at_the_benchmarks_size(one_chip):
+    """The benchmark's Phi-4-mini-flash configuration whole (32 layers at
+    published widths, 200,064 rows, 64 slots x 4,096 positions): window
+    pools 640 positions deep, one global K and V layer. The prefill
+    shapes (the widest row and the two rows; [1, 64] is [1, 128]'s
+    program at half the width) hold no pool among their temporaries. The
+    decode step holds ONE relaid copy of the global K and V (1.34e9 B)
+    and nothing else: the chip's compiler lays a pool that its loops only
+    read out anew, positions minor, once a step for the eight layers
+    that read it (PERF.md section 7); no ring and no state pool is
+    copied, and the whole program stays under 12.5e9 B."""
+    from metaflow_tpu.models import phi4flash
+    from metaflow_tpu.serving import SlotEngine
+
+    cfg = phi4flash.Phi4FlashConfig.phi4_mini_flash(max_seq_len=4096)
+    params = on(jax.eval_shape(
+        lambda: phi4flash.init_params(jax.random.PRNGKey(0), cfg)), one_chip)
+    engine = SlotEngine(params, cfg, max_slots=64, max_seq_len=4096,
+                        prefill_chunk=64)
+    cache = on(jax.eval_shape(lambda: engine._cache), one_chip)
+    nbytes = {name: math.prod(a.shape) * a.dtype.itemsize
+              for name, a in cache.items()}
+    assert cache["win_k"].shape == (8, 64, 640, 1280)
+    assert cache["k"].shape == cache["v"].shape == (1, 64, 4096, 1280)
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    decode = engine._decode_greedy_fn.lower(
+        params, cache, i32(64), i32(64), sds((64,), jnp.bool_, one_chip)
+    ).compile()
+    temporaries = decode.memory_analysis().temp_size_in_bytes
+    assert temporaries < nbytes["k"] + nbytes["v"] + nbytes["ssm"] / 4
+    assert device_bytes(decode) < 12.5e9
+    assert engine.prefill_shapes(2 * 64)[1:] == [(1, 128), (2, 64)]
+    for rows, width in engine.prefill_shapes(2 * 64)[1:]:
+        compiled = engine._prefill_fn.lower(
+            params, cache, i32(rows, width), i32(rows), i32(rows),
+            i32(rows)).compile()
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < nbytes["k"] / 16, (rows, width)
+        assert device_bytes(compiled) < 12.5e9
+
+
 def test_paged_engine_steps_llama3_8b_widths(one_chip):
     from metaflow_tpu.serving import PagedEngine
 
