@@ -640,6 +640,14 @@ def scheduler_metric_families(stats):
         .add(stats["prefill_programs"], {"count": "programs"})
         .add(stats["prefill_rows"], {"count": "rows"})
         .add(stats["prefill_tokens"], {"count": "tokens"}))
+    fams.append(
+        Family("tpuflow_serve_attention_positions", "counter",
+               "K and V positions over the decode steps run and all "
+               "reading layers: those the decoding lanes' queries saw, "
+               "and those the program fetched for them")
+        .add(stats.get("attention_positions_needed", 0), {"count": "needed"})
+        .add(stats.get("attention_positions_fetched", 0),
+             {"count": "fetched"}))
     fams.append(Family("tpuflow_serve_iterations", "counter",
                        "Scheduler loop iterations")
                 .add(stats["iterations"]))

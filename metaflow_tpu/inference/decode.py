@@ -19,8 +19,15 @@ one matrix product against that head's keys and values, in the cache's
 own dtype with float32 accumulation. K and V are never repeated to H
 heads and never widened; with a bfloat16 cache the probabilities of a
 block are rounded to bfloat16 for the product with V, as the training
-kernel rounds them (ops/attention.py). On a v5e the chunked path reads
-its KV chunks at about 70 % of the chip's HBM bandwidth (PERF.md, PR 25).
+kernel rounds them (ops/attention.py). Two forms of the same
+arithmetic read the pools (`_pool_attention` picks by what it can
+observe): the decode step of a whole pool on a TPU is ONE Pallas call a
+layer (ops/decode_attention.py) whose operands are the pools as stored,
+each lane read to its own depth and no lane that does not decode; every
+other program (a prefill program's rows, `generate()`, the paged
+engine, a process held to the CPU) is the chunk loop
+`_streamed_attention`, whose one trip count runs to the deepest row's
+depth.
 
 Model families: `family(cfg)` is the ONE place a family is picked, a
 table keyed by the config's class (the feed-forward half of a block,
@@ -94,12 +101,20 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import knobs
 from ..exception import TpuFlowException
 from ..models import brumby, jamba, llama, mixtral, phi4flash
-from ..ops import diff_attention, layer_norm, retention, rms_norm
+from ..ops import (
+    decode_attention,
+    diff_attention,
+    layer_norm,
+    retention,
+    rms_norm,
+)
 from ..ops.attention import NEG_INF
+from ..ops.decode_attention import visible as _visible
 from ..ops.moe import moe_ffn
 from ..ops.rope import apply_rope, rope_frequencies
 
@@ -360,28 +375,6 @@ def _ungroup(out, dtype):
     return out.astype(dtype)
 
 
-def _visible(key_idx, q_pos, window=None, ring=None):
-    """Which keys a query sees. key_idx: [S] indices into a layer's
-    pool; q_pos: the queries' absolute positions, `_mask_positions`'
-    shape. Causal: index i holds position i, seen iff i <= q. With
-    `window`, only where it also lies after q - window. With `ring`
-    (the pool's depth R; position p is held at index p % R), index r
-    holds, as far as query q is concerned, the one position of (q - R, q]
-    that falls on it, q - (q - r) % R: a later one cannot be meant, an
-    earlier one has been overwritten. What was never written (a
-    position before 0: a new occupant's ring still holds the last one's
-    keys) is not seen either."""
-    if ring is None:
-        key_pos = key_idx
-        seen = key_pos <= q_pos
-    else:
-        key_pos = q_pos - (q_pos - key_idx) % ring
-        seen = key_pos >= 0
-    if window is not None:
-        seen &= key_pos > q_pos - window
-    return seen
-
-
 def _value_groups(a, kv_heads):
     """[B, KV, G, ...] -> [B, kv_heads, KV // kv_heads * G, ...]: the
     groups of consecutive key heads that share one value head, side by
@@ -430,9 +423,10 @@ def _default_decode_chunk():
     return max(1, knobs.get_int("TPUFLOW_DECODE_CHUNK"))
 
 
-# KV-chunk size of the flash-decode path, and the pivot of the
-# attn_impl="auto" switchover (see generate()). Override with
-# TPUFLOW_DECODE_CHUNK=<n> (read once at import).
+# KV-chunk size of the chunk loop (`_streamed_attention`), and the pivot
+# of the attn_impl="auto" switchover (see generate()). Override with
+# TPUFLOW_DECODE_CHUNK=<n> (read once at import). The decode step's
+# kernel takes no notice of it: its block is `decode_block`'s answer.
 DECODE_CHUNK = _default_decode_chunk()
 
 
@@ -538,6 +532,99 @@ def _chunked_cached_attention(q, cache_k, cache_v, pos, layer,
                                ring=Smax if ring else None, **kw)
 
 
+def _pool_attention(q, cache_k, cache_v, pos, layer, lanes=None,
+                    v_head_dim=None, window=None, ring=False,
+                    scope="decode_attention", dtype=None):
+    """The attention of one block over layer `layer` of its pools, by
+    what the call can observe. One new position a lane of the whole pool
+    (`lanes`: `_decode_lanes`' answer, None for any other program) at
+    shapes the kernel takes (`ops/decode_attention.py`, `applies`): on a
+    TPU one Pallas call that reads the pools as stored, each lane to its
+    own depth, and no lane that does not decode; the choice is the
+    lowering platform's (`jax.lax.platform_dependent`), so a compile for
+    a described chip holds the kernel and XLA:CPU the loop. Everything
+    else (a prefill program's rows, `generate()`'s lockstep batch, sizes
+    with no whole tiles) is `_chunked_cached_attention`, which
+    `TPUFLOW_DECODE_CHUNK` governs and the kernel ignores. Under
+    `scope` either way."""
+    kw = dict(v_head_dim=v_head_dim, window=window, ring=ring, dtype=dtype)
+    loop = functools.partial(_chunked_cached_attention, scope=scope, **kw)
+    if lanes is None or not decode_attention.applies(q, cache_k, cache_v,
+                                                     v_head_dim):
+        return loop(q, cache_k, cache_v, pos, layer)
+    with jax.named_scope(scope):
+        return jax.lax.platform_dependent(
+            q, cache_k, cache_v, pos, jnp.asarray(layer, jnp.int32), *lanes,
+            tpu=functools.partial(decode_attention.attend, **kw),
+            default=lambda q, k, v, pos, layer, *lanes: loop(
+                q, k, v, pos, layer))
+
+
+def _decode_lanes(pos, valid, T, mesh):
+    """What the decode step's attention kernel prefetches, once a
+    program: (the lanes that decode first, how many they are, which they
+    are), or None for a program that is no decode step of a whole pool
+    on one chip (several new positions a row, one position for the whole
+    batch, a mesh)."""
+    if T != 1 or jnp.ndim(pos) != 1 or mesh is not None:
+        return None
+    valid = jnp.ones(pos.shape, bool) if valid is None else valid[:, 0]
+    return decode_attention.live_lanes(valid) + (valid,)
+
+
+def attention_reads(cfg, cache, attn_impl="chunked", kernel=True):
+    """What a decode step's attention reads, by the shapes alone: for
+    every kind of layer that reads a pool (reading layers, the pool's
+    depth, the most positions a query sees there, how many positions are
+    fetched at a time, how). cache: the pools (their shapes alone are
+    read). How: "kernel" where `kernel` (the decode step runs on a TPU
+    with no mesh) and the shapes are the kernel's
+    (`ops/decode_attention.py`): each decoding lane in blocks of
+    `decode_block`; "loop": every lane of the pool to the deepest one's
+    depth in chunks of `DECODE_CHUNK`; "dense": every lane's whole
+    pool."""
+    kinds = layer_kinds(cfg)
+    reads = []
+    for kind in sorted(set(kinds) & set(ATTENTION)):
+        a = ATTENTION[kind]
+        pool_k, pool_v = cache[a.k], cache[a.v]
+        B, S, width = pool_k.shape[1:]
+        q = jax.ShapeDtypeStruct((B, 1, cfg.n_heads, cfg.head_dim),
+                                 llama.param_dtype(cfg))
+        if attn_impl != "chunked":
+            unit, how = S, "dense"
+        elif kernel and decode_attention.applies(q, pool_k, pool_v,
+                                                 _v_head_dim(cfg)):
+            unit, how = decode_attention.decode_block(
+                S, width, pool_k.dtype), "kernel"
+        else:
+            unit, how = min(DECODE_CHUNK, S), "loop"
+        reads.append((kinds.count(kind), S,
+                      cfg.sliding_window if a.window else S, unit, how))
+    return reads
+
+
+def attention_positions(reads, depth):
+    """(needed, fetched) of one decode step: how many K and V positions
+    its queries see, over every layer of `reads` (`attention_reads`),
+    and how many the program fetches for them. depth: [B] numpy, a
+    decoding lane's new position + 1 and 0 for a lane that does not
+    decode. The kernel fetches each lane's depth in whole blocks, a ring
+    capped at its depth, and nothing for a lane that does not decode."""
+    needed = fetched = 0
+    for layers, S, sees, unit, how in reads:
+        needed += layers * int(np.minimum(depth, sees).sum())
+        held = np.minimum(depth, S)
+        if how == "kernel":
+            fetched += layers * int(
+                decode_attention.fetched_positions(held, unit, S).sum())
+        else:   # every lane, to the deepest one's depth or the pool's
+            deepest = S if how == "dense" else int(held.max())
+            fetched += layers * len(depth) * min(
+                -(-deepest // unit) * unit, S)
+    return needed, fetched
+
+
 def _norm(cfg, x, lp, name):
     """The norm `name` of a layer's (or the model's) leaves: an RMS norm,
     or where a bias `<name>_b` stands beside the weight a LayerNorm."""
@@ -612,7 +699,8 @@ def _at(a, last):
 
 
 def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
-                  mesh=None, attn_impl="dense", slots=None, last=None):
+                  mesh=None, attn_impl="dense", slots=None, last=None,
+                  lanes=None):
     """One attention block of `kind` (`ATTENTION`) over T new tokens,
     reading and (a kind that writes) extending layer `layer` (a traced
     index) of its pools [layers, B, S, KV * Hd], written and read in
@@ -621,7 +709,8 @@ def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
     feed-forward half is the family's. With `last` ([B]) K and V of all
     T positions are written and the rest of the block, from the queries
     on, runs for position last[b] of each row alone: x comes back
-    [B, 1, dim]. Returns (x, cache)."""
+    [B, 1, dim]. `lanes`: `_decode_lanes`' answer for the program.
+    Returns (x, cache)."""
     lp, a = layer_params, ATTENTION[kind]
     cache_k, cache_v = cache[a.k], cache[a.v]
     if cache_pools(cfg)[a.k][0].view:
@@ -655,8 +744,9 @@ def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
     kw = dict(window=cfg.sliding_window if a.window else None, ring=a.window,
               scope=a.scope, dtype=jnp.float32 if differential else None)
     if attn_impl == "chunked":
-        attn = _chunked_cached_attention(
-            q, read_k, read_v, pos, at, v_head_dim=_v_head_dim(cfg), **kw)
+        attn = _pool_attention(
+            q, read_k, read_v, pos, at, lanes, v_head_dim=_v_head_dim(cfg),
+            **kw)
     else:
         view = lambda pool, hd: pool[at].reshape(pool.shape[1:3] + (-1, hd))
         attn = _cached_attention(
@@ -820,6 +910,10 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
         cfg.rope_theta, dtype=llama.param_dtype(cfg),
         llama3_scaling=getattr(cfg, "rope_llama3_scaling", False),
     ) if fam.rope else (None, None)
+    # a decode step of the whole pool: what its attention kernel
+    # prefetches, once a program and not once a layer
+    lanes = None if slots is not None or last is not None else \
+        _decode_lanes(pos, valid, x.shape[1], mesh)
 
     def layers(pos, valid, last=None):
         """The loop's body for new tokens at `pos`, of which `valid`
@@ -831,7 +925,8 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
             if kind in ATTENTION:
                 x, cache = _decode_layer(
                     cfg, kind, cos, sin, pos, x, lp, cache, i, mesh=mesh,
-                    attn_impl=attn_impl, slots=slots, last=last)
+                    attn_impl=attn_impl, slots=slots, last=last,
+                    lanes=lanes)
                 if last is not None and memory is not None:
                     memory = _at(memory, last)
                 return x, cache, memory

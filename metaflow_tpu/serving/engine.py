@@ -37,7 +37,10 @@ cover everything:
     positions (vector-pos decode_forward), per-slot dynamic_update_slice
     cache writes, per-slot slot-masked sampling (greedy/temperature/
     top-k/top-p as traced per-slot arrays, so one program serves every
-    sampling-config mix)
+    sampling-config mix); on a TPU its attention reads the pools as
+    stored, each decoding lane to its own depth and no other lane
+    (ops/decode_attention.py), and `attention_positions()` says from the
+    host's cursors how much of what it fetches the queries see
   - first-token: sample, for every row of a prefill program that ends
     its prompt, the token its logits imply; fetched in one wait. Where
     the config marks a tail layer (nothing past that layer's K and V at
@@ -90,11 +93,13 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .. import telemetry
+from .. import device, telemetry
 from ..exception import TpuFlowException
 from ..inference.decode import (
     DECODE_CHUNK,
     POOLS,
+    attention_positions,
+    attention_reads,
     bucket_length,
     cache_pools,
     decode_forward,
@@ -221,6 +226,7 @@ class SlotEngine(object):
             raise ValueError(
                 "max_seq_len %d passes the config's %d, where rope's table "
                 "ends" % (self.max_seq_len, cfg.max_seq_len))
+        self._attention_reads = None   # attention_positions() fills it
         self._recurrent_pools = recurrent_pools(cfg)
         self.recurrent = bool(self._recurrent_pools)
         B = self.max_slots
@@ -393,6 +399,18 @@ class SlotEngine(object):
         for entry in out.values():
             entry["bytes_per_slot"] = entry["bytes"] // self.max_slots
         return out
+
+    def attention_positions(self):
+        """(needed, fetched) of the decode step the cursors stand before
+        (inference/decode.py, `attention_positions`): the K and V
+        positions its decoding lanes' queries see over all reading
+        layers, and those the program fetches for them."""
+        if self._attention_reads is None:   # by the shapes: once
+            self._attention_reads = attention_reads(
+                self.cfg, self._cache, self.attn_impl,
+                kernel=self.mesh is None and device.on_tpu())
+        return attention_positions(
+            self._attention_reads, np.where(self.decoding, self.pos + 1, 0))
 
     def compile_counts(self):
         """jit cache entries per program — each decode variant must stay

@@ -230,6 +230,11 @@ class Scheduler(object):
         self.prefill_programs = 0
         self.prefill_rows = 0
         self.prefill_tokens = 0
+        # over the decode steps run: the K and V positions the decoding
+        # lanes' queries saw, over all reading layers, and those the
+        # program fetched for them (engine.attention_positions)
+        self.attention_positions_needed = 0
+        self.attention_positions_fetched = 0
         self.peak_in_flight = 0
         self._occupancy_sum = 0.0
         # goodput accounting: device-busy seconds split prefill/decode;
@@ -737,6 +742,11 @@ class Scheduler(object):
         active = [r for r in self._slots.values() if r.state == "decode"]
         if not active:
             return False
+        positions = getattr(self.engine, "attention_positions", None)
+        if positions is not None:   # from the cursors, before they move
+            needed, fetched = positions()
+            self.attention_positions_needed += needed
+            self.attention_positions_fetched += fetched
         with telemetry.timer("serve.decode_step") as step:
             tokens = self.engine.decode_step()
             step.set(active=len(tokens))
@@ -888,6 +898,8 @@ class Scheduler(object):
             "kv_pages": self.kv_pages_stats(),
             "state_pool": self.state_pool_stats(),
             "cache_pools": self.cache_pool_stats(),
+            "attention_positions_needed": self.attention_positions_needed,
+            "attention_positions_fetched": self.attention_positions_fetched,
             "speculative": (self.engine.spec_stats() if self._paged
                             else {"enabled": False}),
             "goodput": self.goodput_stats(),

@@ -1817,6 +1817,7 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_requests": "counter",
     "tpuflow_serve_decode_steps": "counter",
     "tpuflow_serve_prefill": "counter",
+    "tpuflow_serve_attention_positions": "counter",
     "tpuflow_serve_iterations": "counter",
     "tpuflow_serve_ttft_ms": "summary",
     "tpuflow_serve_itl_ms": "summary",

@@ -318,6 +318,8 @@ def _scheduler_stats():
         "mean_batch_occupancy": 0.6, "served": 11, "cancelled": 1,
         "decode_steps": 40, "prefill_programs": 9, "prefill_rows": 14,
         "prefill_tokens": 500, "iterations": 55, "draining": False,
+        "attention_positions_needed": 700,
+        "attention_positions_fetched": 1024,
         "p50_ttft_ms": 12.0, "p99_ttft_ms": 30.0,
         "p50_itl_ms": 3.0, "p99_itl_ms": 8.0,
         "peak_in_flight": 4, "max_context_tokens": 96,
@@ -381,6 +383,8 @@ class TestMetricFamilies:
         assert sample("tpuflow_serve_ttft_ms", quantile="0.99") == 30.0
         assert sample("tpuflow_serve_kv_pages", state="used") == 48
         assert sample("tpuflow_serve_cache_pool_bytes", kind="ring") == 2048
+        assert sample("tpuflow_serve_attention_positions",
+                      count="fetched") == 1024
         assert sample("tpuflow_serve_cache_pool_bytes_per_slot",
                       kind="global") == 1024
         assert sample("tpuflow_serve_goodput_seconds",

@@ -511,6 +511,81 @@ class TestPoolCarriedThroughTheLoop:
             assert stats.temp_size_in_bytes < one_pool, stats
 
 
+class TestDecodeAttentionKernel:
+    """The decode step's attention as one Pallas call over the pools as
+    stored (ops/decode_attention.py), interpreted on XLA:CPU, against
+    the chunk loop it takes the place of on a TPU and the dense form."""
+
+    BLOCK, S = 32, 128
+    # a block's edges, the pool's full depth, and two lanes that do not
+    # decode among those that do
+    DEPTH = np.array([1, 31, 32, 33, 128, 50, 77, 9])
+    VALID = np.array([True, True, True, True, True, False, True, False])
+
+    @pytest.mark.parametrize("name,H,KV,dtype,tol", [
+        ("groups_of_4", 8, 2, "float32", 2e-5),
+        ("one_kv_head", 5, 1, "float32", 2e-5),
+        ("bfloat16", 8, 2, "bfloat16", 2e-2),
+    ])
+    def test_kernel_is_the_loop_and_fetches_each_lane_to_its_depth(
+            self, name, H, KV, dtype, tol):
+        """Heads of 128, layer 2 of three by a traced index, every lane
+        at its own depth. What the kernel may not fetch is NaN: the other
+        layers, the lanes that do not decode, and a decoding lane's
+        positions past its last needed block; what lies between a lane's
+        depth and that block's edge is finite and masked. The counter's
+        `fetched` is those blocks and no more."""
+        from metaflow_tpu.inference.decode import (_cached_attention,
+                                                   _chunked_cached_attention)
+        from metaflow_tpu.ops import decode_attention as da
+
+        B, Hd, S, block = len(self.DEPTH), 128, self.S, self.BLOCK
+        ks = jax.random.split(jax.random.PRNGKey(H), 3)
+        q = jax.random.normal(ks[0], (B, 1, H, Hd)).astype(dtype)
+        ck = jax.random.normal(ks[1], (B, S, KV, Hd)).astype(dtype)
+        cv = jax.random.normal(ks[2], (B, S, KV, Hd)).astype(dtype)
+        pos, valid = jnp.asarray(self.DEPTH - 1), jnp.asarray(self.VALID)
+        pk, pv = _as_layer(ck, 2), _as_layer(cv, 2)
+        assert da.applies(q, pk, pv)
+        assert not da.applies(q, pk[..., :64], pv[..., :64])    # half a lane
+        assert not da.applies(q, pk[:, :, :100], pv[:, :, :100])  # no divisor
+
+        depth = np.where(self.VALID, self.DEPTH, 0)
+        fetched = np.asarray(da.fetched_positions(depth, block, S))
+        assert fetched.tolist() == [32, 32, 32, 64, 128, 0, 96, 0]
+        keep = (np.arange(S)[None] < fetched[:, None])[None, :, :, None]
+        keep = keep & (np.arange(3) == 2)[:, None, None, None]
+        poison = lambda pool: jnp.where(keep, pool, jnp.nan)
+
+        kernel = jax.jit(lambda q, pk, pv, layer: da.attend(
+            q, pk, pv, pos, layer, *da.live_lanes(valid), valid,
+            block=block, interpret=True))
+        got = np.asarray(kernel(q, poison(pk), poison(pv), jnp.int32(2)),
+                         np.float32)
+        assert got.shape == q.shape and np.isfinite(got).all()
+        assert (got[~self.VALID] == 0).all()
+        for want in (_chunked_cached_attention(q, pk, pv, pos, jnp.int32(2),
+                                               chunk=block),
+                     _cached_attention(q, ck, cv, pos)):
+            np.testing.assert_allclose(
+                got[self.VALID], np.asarray(want, np.float32)[self.VALID],
+                atol=tol, rtol=tol)
+
+    def test_block_is_a_function_of_the_shape(self):
+        """A divisor of the depth in whole tiles, at most a mebibyte of
+        K: the benchmark's four pools; no answer where no divisor is."""
+        from metaflow_tpu.ops.decode_attention import (BLOCK_BYTES,
+                                                       decode_block)
+
+        for depth, width in ((1280, 1024), (2560, 128), (4096, 1280),
+                             (640, 1280), (48, 128)):
+            block = decode_block(depth, width, "bfloat16")
+            assert depth % block == 0 and block % 16 == 0
+            assert block * width * 2 <= BLOCK_BYTES
+        assert decode_block(100, 128, "bfloat16") is None
+        assert decode_block(104, 128, "float32") == 104
+
+
 class TestShardedDecode:
     def test_generate_on_fsdp_tp_mesh_matches_single_device(self, setup):
         cfg, params, _ = setup

@@ -165,6 +165,18 @@ def test_served_through_build_engine_and_scheduler(params):
     assert pools["ring"]["bytes_per_slot"] == 2 * 2 * 40 * 32 * 4
     assert pools["state"] == eng.state_pool_stats() and \
         pools["state"]["bytes"] == 3 * 2 * (3 + 16) * 128 * 4
+    # a pool 32 wide is no shape of the kernel's and max_seq_len 128 is
+    # the dense form: every lane's whole pool is fetched, two rings of 40
+    # and the global pool twice (the full layer and the cross layer)
+    steps = stats["decode_steps"]
+    assert stats["attention_positions_fetched"] == \
+        steps * 2 * (2 * 40 + 2 * 128)
+    assert 0 < stats["attention_positions_needed"] \
+        < stats["attention_positions_fetched"]
+    assert {s[1]["count"]: s[2] for s in
+            families["tpuflow_serve_attention_positions"].samples} == {
+                "needed": stats["attention_positions_needed"],
+                "fetched": stats["attention_positions_fetched"]}
 
 
 def test_the_prefill_tail_changes_no_logit_and_no_cache(params):
@@ -281,6 +293,109 @@ def test_the_differential_loop_is_four_plain_calls(impl):
             # pair-major: (KV pair, j, query pair of the group)
             at = c * 4 + j * 2 + p % 2
             assert np.allclose(got[:, :, at], want, atol=2e-5), (p, j)
+
+
+# ---- the decode step's attention kernel (ops/decode_attention.py) ----
+
+@pytest.mark.parametrize("kind", ["global", "ring"])
+def test_the_decode_kernel_reads_a_differential_pool_as_the_loop_does(kind):
+    """Key heads of 64 under value heads of 128, float32 out for the
+    subtraction, interpreted on XLA:CPU against the chunk loop on the
+    same pools: the global pool read at index 0 by a traced index, and a
+    window of 24 over a ring of 48 with lanes whose ring has wrapped
+    (positions 100 and 48), is about to (47) and has not (0, 20), a lane
+    that does not decode among them. A pair's two queries lie in the two
+    halves of one row against the pair's 128 lanes of K as stored."""
+    from metaflow_tpu.inference.decode import _chunked_cached_attention
+    from metaflow_tpu.ops import decode_attention as da
+    from metaflow_tpu.ops import diff_attention
+
+    ring = kind == "ring"
+    Bn, H, KV, Hd, S, block = 6, 8, 4, 64, (48 if ring else 96), 16
+    kw = dict(v_head_dim=2 * Hd, window=24 if ring else None, ring=ring,
+              dtype=jnp.float32)
+    r = np.random.default_rng(1)
+    f = lambda *s: jnp.asarray(r.normal(size=s), jnp.bfloat16)
+    q = diff_attention.pair_major(f(Bn, 1, H, Hd), KV)
+    pk, pv = f(2, Bn, S, KV * Hd), f(2, Bn, S, KV * Hd)
+    pos = jnp.asarray([100, 0, 47, 48, 20, 7] if ring
+                      else [95, 0, 15, 16, 17, 40])
+    valid = jnp.asarray([True, True, True, True, True, False])
+    assert da.applies(q, pk, pv, 2 * Hd)
+    layer = jnp.int32(1 if ring else 0)
+    got = jax.jit(lambda q, pk, pv, layer: da.attend(
+        q, pk, pv, pos, layer, *da.live_lanes(valid), valid, block=block,
+        interpret=True, **kw))(q, pk, pv, layer)
+    want = _chunked_cached_attention(q, pk, pv, pos, layer, chunk=block,
+                                     **kw)
+    assert got.shape == (Bn, 1, H, 2 * Hd) and got.dtype == jnp.float32
+    assert np.allclose(got[:5], want[:5], atol=2e-2, rtol=2e-2)
+    assert (np.asarray(got[5]) == 0).all()
+    depth = np.where(valid, np.minimum(np.asarray(pos) + 1, S), 0)
+    assert np.asarray(da.fetched_positions(depth, block, S)).tolist() == (
+        [48, 16, 48, 48, 32, 0] if ring else [96, 16, 16, 32, 32, 0])
+
+
+def test_a_decode_step_through_the_kernel_is_the_loops(monkeypatch):
+    """One decode step of the whole stack at widths the kernel takes
+    (key heads of 64, a pool 128 lanes wide), the kernel interpreted in
+    the platform's place: window layers over rings that have wrapped and
+    one that has not, the full layer, and the cross layer that reads
+    index 0 of a pool it does not write, a masked lane among the live
+    ones. Logits and pools of the lanes that decode are the chunk
+    loop's; a prefill program's rows keep the loop."""
+    from metaflow_tpu.inference import decode
+    from metaflow_tpu.ops import decode_attention as da
+
+    cfg = phi4flash.Phi4FlashConfig.tiny(dim=256, n_heads=4, n_kv_heads=2)
+    assert (cfg.head_dim, cfg.v_head_dim) == (64, 128)
+    params = phi4flash.init_params(jax.random.PRNGKey(0), cfg)
+    cache = init_kv_cache(cfg, 4, 64, row=16)
+    assert cache["win_k"].shape == (2, 4, 24, 128)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(cache))
+    cache = {name: jax.random.normal(key, cache[name].shape,
+                                     cache[name].dtype)
+             for key, name in zip(keys, sorted(cache))}
+    tok = jnp.asarray([[3], [5], [7], [9]])
+    pos, mask = jnp.asarray([40, 63, 5, 30]), jnp.asarray(
+        [True, True, True, False])
+    step = lambda: jax.jit(lambda cache: decode_forward(
+        params, tok, cache, pos, cfg, attn_impl="chunked",
+        valid=mask[:, None]))(cache)
+    want, want_cache = step()
+
+    calls, kernel = [], da.attend
+
+    def attend(*args, **kw):
+        calls.append(kw["ring"])
+        return kernel(*args, interpret=True, **kw)
+
+    monkeypatch.setattr(decode.decode_attention, "attend", attend)
+    monkeypatch.setattr(jax.lax, "platform_dependent",
+                        lambda *args, tpu, default: tpu(*args))
+    got, got_cache = step()
+    assert sorted(calls) == [False, False, True]   # one a traced body
+    # the counter: two rings of 24 and a global pool of 64 read twice, in
+    # blocks of 24 and 64; the masked lane fetches nothing
+    depth = np.where(mask, np.asarray(pos) + 1, 0)
+    reads = decode.attention_reads(cfg, cache)
+    assert [r[3:] for r in reads] == [(64, "kernel")] * 2 + [(24, "kernel")]
+    assert decode.attention_positions(reads, depth) == (
+        2 * (8 + 8 + 6) + 2 * (41 + 64 + 6), 2 * 3 * 24 + 2 * 3 * 64)
+    assert decode.attention_positions(
+        decode.attention_reads(cfg, cache, kernel=False), depth) == (
+        2 * (8 + 8 + 6) + 2 * (41 + 64 + 6), 2 * 4 * 24 + 2 * 4 * 64)
+    assert np.allclose(got[:3], want[:3], atol=1e-4, rtol=1e-4)
+    for name in cache:   # the masked lane writes what it likes at its cursor
+        assert np.allclose(got_cache[name][:, :3], want_cache[name][:, :3],
+                           atol=1e-4, rtol=1e-4), name
+    # rows of a prefill program (`slots`) and several positions a row
+    del calls[:]
+    jax.jit(lambda cache: decode_forward(
+        params, jnp.tile(tok[:2], (1, 8)), cache, pos[:2], cfg,
+        attn_impl="chunked", slots=jnp.asarray([2, 0]),
+        last=jnp.asarray([7, 3])))(cache)
+    assert not calls
 
 
 # ---- the ring ----
