@@ -13,6 +13,7 @@ T_PROCESS = time.perf_counter()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -93,6 +94,15 @@ def run_cell(name, seed, seconds, trace, **kw):
                                "idle_gaps": reduced["idle_gaps"]}
     ctx.log("compiles %(compiles)d (%(compile_s).1f s), persistent cache "
             "hits %(cache_hits)d misses %(cache_misses)d" % ctx.compiles)
+    # every number compared beside its limit, last in the line and last
+    # on standard error: what a record of a run that is not correct keeps
+    number = lambda x: float(x) if math.isfinite(float(x)) else None
+    result["compared"] = {c[0]: {"value": number(c[1]), "limit": number(c[2]),
+                                 "ok": c[3]} for c in ctx.checks}
+    for check, value, limit, ok in ctx.checks:
+        print("[check] %s %r limit %r %s" % (
+            check, value, limit, "ok" if ok else "NOT CORRECT"),
+            file=sys.stderr, flush=True)
     return result
 
 

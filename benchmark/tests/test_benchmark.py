@@ -157,8 +157,13 @@ def test_driver_end_to_end(cell, metric):
     assert result["failed"] == 0 and result["attempted"] > 0
     assert result["metrics"][metric]["value"] > 0
     assert result["metrics"]["setup_s"]["value"] > 0
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    # the compared numbers, each with its limit, come last in the line
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
+    assert {"compilations_in_window"} < set(result["compared"])
+    assert all(c["ok"] and c["value"] <= c["limit"]
+               for c in result["compared"].values())
+    json.dumps(result)
 
 
 @pytest.mark.parametrize("cell,metric,sizes", [
