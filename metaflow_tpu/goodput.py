@@ -651,6 +651,31 @@ def scheduler_metric_families(stats):
     fams.append(Family("tpuflow_serve_iterations", "counter",
                        "Scheduler loop iterations")
                 .add(stats["iterations"]))
+    phases = (stats.get("phases") or {}).get("phase") or {}
+    if phases:
+        seconds = Family(
+            "tpuflow_serve_phase_seconds", "counter",
+            "The serving loop's seconds on the host's clock, by phase "
+            "(the spans' names; phases nest: serve.iteration holds all "
+            "but wait, the two *.fetch phases are the loop waiting for "
+            "the device)")
+        calls = Family("tpuflow_serve_phase_calls", "counter",
+                       "Times the serving loop entered each phase")
+        for name in sorted(phases):
+            seconds.add(phases[name]["seconds"], {"phase": name})
+            calls.add(phases[name]["calls"], {"phase": name})
+        fams += [seconds, calls]
+    collected = stats.get("gc") or {}
+    if collected:
+        pause = Family(
+            "tpuflow_serve_gc_pause_seconds", "counter",
+            "Seconds Python's collector held the process while the "
+            "serving loop ran, by generation (beside the phases, not "
+            "taken out of them)")
+        for generation in sorted(collected):
+            pause.add(collected[generation]["seconds"],
+                      {"generation": generation})
+        fams.append(pause)
     ttft = Family("tpuflow_serve_ttft_ms", "summary",
                   "Time to first token, rolling window")
     ttft.add(stats["p50_ttft_ms"] or 0.0, {"quantile": "0.5"})

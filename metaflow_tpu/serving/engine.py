@@ -251,6 +251,14 @@ class SlotEngine(object):
         self._dirty = True
         self._d_tok = self._d_pos = self._d_mask = None
         self._d_temp = self._d_top_k = self._d_top_p = None
+        # every engine.* span goes through this ledger (a Scheduler puts
+        # its own here, so that one ledger holds the whole iteration);
+        # `launches` numbers the prefill and decode programs as they are
+        # dispatched: the dispatch span carries `launch`, the fetch span
+        # that waits for that program's result `awaits` (the first-token
+        # program rides behind the prefill program whose logits it reads)
+        self.phases = telemetry.PhaseLedger()
+        self.launches = 0
 
         def _prefill(params, cache, tokens, slots, start, n_real=None):
             # tokens [R, W]: row r is the next tokens of slot slots[r]
@@ -459,11 +467,12 @@ class SlotEngine(object):
         self._top_k[slot] = (self._vocab if top_k is None
                              else min(int(top_k), self._vocab))
         self._top_p[slot] = 1.0 if top_p is None else float(top_p)
-        self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
+        with self.phases("engine.admit.keys"):
+            self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
         self._key_cursor[slot] = 0
         self._dirty = True
         if self.recurrent:
-            with telemetry.annotate("engine.state.reset", slot=int(slot)):
+            with self.phases("engine.state.reset", slot=int(slot)):
                 self._cache = self._reset_state_fn(self._cache,
                                                    jnp.int32(slot))
 
@@ -666,7 +675,9 @@ class SlotEngine(object):
         for r, slot in enumerate(slots):
             tokens[r, :n_real[r]] = \
                 self._prompt[slot][start[r]:start[r] + n_real[r]]
-        with telemetry.annotate("engine.prefill.dispatch"):
+        self.launches += 1
+        launch = self.launches
+        with self.phases("engine.prefill.dispatch", launch=launch):
             logits, self._cache = self._prefill_fn(
                 self.params, self._cache, jnp.asarray(tokens),
                 jnp.asarray(slots), jnp.asarray(start), jnp.asarray(n_real))
@@ -694,7 +705,7 @@ class SlotEngine(object):
                             jnp.float32),
                 jnp.asarray(self._top_k[slots]),
                 jnp.asarray(self._top_p[slots]))
-            with telemetry.annotate("engine.first_token.fetch"):
+            with self.phases("engine.first_token.fetch", awaits=launch):
                 first = np.asarray(first)   # the host waits here, once
             done = slots[ends]
             self.decoding[done] = True
@@ -730,7 +741,7 @@ class SlotEngine(object):
         if not decoding:
             return {}
         if self._dirty:
-            with telemetry.annotate("engine.decode.upload"):
+            with self.phases("engine.decode.upload"):
                 self._d_tok = jnp.asarray(self._tok)
                 self._d_pos = jnp.asarray(self.pos)
                 self._d_mask = jnp.asarray(self.decoding)
@@ -738,7 +749,8 @@ class SlotEngine(object):
                 self._d_top_k = jnp.asarray(self._top_k)
                 self._d_top_p = jnp.asarray(self._top_p)
                 self._dirty = False
-        with telemetry.annotate("engine.decode.dispatch"):
+        self.launches += 1
+        with self.phases("engine.decode.dispatch", launch=self.launches):
             if any(self._temp[i] > 0.0 for i in decoding):
                 for i in decoding:
                     self._keys[i] = self._keys_for(i)
@@ -752,7 +764,7 @@ class SlotEngine(object):
                     self._decode_greedy_fn(
                         self.params, self._cache, self._d_tok, self._d_pos,
                         self._d_mask)
-        with telemetry.annotate("engine.decode.fetch"):
+        with self.phases("engine.decode.fetch", awaits=self.launches):
             out = np.asarray(out)   # the host waits for the device here
         tokens = {}
         for i in decoding:

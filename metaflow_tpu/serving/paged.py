@@ -369,6 +369,9 @@ class PagedEngine(object):
         self._dirty = True
         self._d_tok = self._d_pos = self._d_mask = self._d_tables = None
         self._d_temp = self._d_top_k = self._d_top_p = None
+        # the engine.* spans' ledger and the launch counter, as SlotEngine
+        self.phases = telemetry.PhaseLedger()
+        self.launches = 0
         # counters
         self.kv_bytes_copied = 0   # host<->page copies (0 on zero-copy hits)
         self.cow_pages = 0         # partial tail pages privatized
@@ -585,7 +588,8 @@ class PagedEngine(object):
         self._top_k[slot] = (self._vocab if top_k is None
                              else min(int(top_k), self._vocab))
         self._top_p[slot] = 1.0 if top_p is None else float(top_p)
-        self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
+        with self.phases("engine.admit.keys"):
+            self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
         self._key_cursor[slot] = 0
         self._max_new[slot] = int(max_new_tokens)
         self._emitted[slot] = 0
@@ -780,7 +784,9 @@ class PagedEngine(object):
         if bucket > chunk.size:
             chunk = np.concatenate([
                 chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
-        with telemetry.annotate("engine.prefill.dispatch"):
+        self.launches += 1
+        launch = self.launches
+        with self.phases("engine.prefill.dispatch", launch=launch):
             logits, self.pool.kv = self._prefill_fn(
                 self.params, self.pool.kv, jnp.asarray(chunk)[None],
                 jnp.asarray(self.block_tables[slot]), jnp.int32(start))
@@ -795,7 +801,7 @@ class PagedEngine(object):
             jnp.asarray(self._keys_for(slot)),
             jnp.float32(self._temp[slot]), jnp.int32(self._top_k[slot]),
             jnp.float32(self._top_p[slot]))
-        with telemetry.annotate("engine.first_token.fetch"):
+        with self.phases("engine.first_token.fetch", awaits=launch):
             first = int(first)
         self.decoding[slot] = True
         self.pos[slot] = prompt.size
@@ -815,7 +821,7 @@ class PagedEngine(object):
 
     def _stage(self):
         if self._dirty:
-            with telemetry.annotate("engine.decode.upload"):
+            with self.phases("engine.decode.upload"):
                 self._d_tok = jnp.asarray(self._tok)
                 self._d_pos = jnp.asarray(self.pos)
                 self._d_mask = jnp.asarray(self.decoding)
@@ -837,7 +843,8 @@ class PagedEngine(object):
         if self.spec_k > 0 and not sampled:
             return self._spec_decode_step(decoding)
         self._stage()
-        with telemetry.annotate("engine.decode.dispatch"):
+        self.launches += 1
+        with self.phases("engine.decode.dispatch", launch=self.launches):
             if sampled:
                 for i in decoding:
                     self._keys[i] = self._keys_for(i)
@@ -852,7 +859,7 @@ class PagedEngine(object):
                     self._decode_greedy_fn(
                         self.params, self.pool.kv, self._d_tok,
                         self._d_pos, self._d_mask, self._d_tables)
-        with telemetry.annotate("engine.decode.fetch"):
+        with self.phases("engine.decode.fetch", awaits=self.launches):
             out = np.asarray(out)
         tokens = {}
         for i in decoding:
@@ -880,11 +887,12 @@ class PagedEngine(object):
             drafts[i] = np.asarray(d[:K], np.int32)
         toks = np.concatenate([self._tok[:, None], drafts], axis=1)
         self._stage()
-        with telemetry.annotate("engine.decode.dispatch"):
+        self.launches += 1
+        with self.phases("engine.decode.dispatch", launch=self.launches):
             out, self.pool.kv = self._spec_fn(
                 self.params, self.pool.kv, jnp.asarray(toks), self._d_pos,
                 self._d_tables)
-        with telemetry.annotate("engine.decode.fetch"):
+        with self.phases("engine.decode.fetch", awaits=self.launches):
             out = np.asarray(out)
         tokens = {}
         for i in decoding:

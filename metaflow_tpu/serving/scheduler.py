@@ -31,15 +31,23 @@ tests/schema_validate.py::SERVING_EVENT_DATA_SCHEMAS:
 plus serve.batch_occupancy + serve.queue_depth gauges and the
 serve.decode_step / serve.prefill_chunk timers.
 
-Every boundary of an iteration is also a span on the profiler's clock
-(telemetry.annotate; recorded while a profiler session is open, a flag
-check otherwise): serve.iteration around step(), inside it serve.reap,
-serve.admit, serve.prefill_chunk (one a prefill program: rows, tokens,
-and request_ids, slots, row_tokens a row) and serve.decode_step (the two
-timers), serve.deliver; the engine adds engine.* spans inside the last
-three (docs/observability.md has the table).
+Every boundary of an iteration goes through ONE phase ledger
+(telemetry.PhaseLedger, made here and shared with the engine): a phase is
+a span on the profiler's clock (recorded while a profiler session is
+open, a flag check otherwise) and, always, its seconds and one call under
+the same name on the host's clock: serve.iteration around step(), inside
+it serve.reap, serve.admit, serve.prefill_chunk (one a prefill program:
+rows, tokens, and request_ids, slots, row_tokens a row) and
+serve.decode_step (the two timers), serve.deliver; the engine's engine.*
+phases inside admit and the two timers; `wait` around the loop's sleep
+when an iteration found nothing to do (docs/observability.md has the
+table). stats()["phases"] sums them, stats()["slow_iterations"] keeps the
+slowest of the last SLOW_WINDOW iterations phase by phase, stats()["gc"]
+the collector's pauses while the loop's thread runs.
 """
 
+import gc
+import heapq
 import itertools
 import os
 import threading
@@ -53,6 +61,20 @@ from .paged import PageExhaustedError
 from .tenancy import TenancyConfig, TenantQueues, TokenBudgets
 
 _request_ids = itertools.count(1)
+
+# iterations whose phases are kept for stats()["slow_iterations"]: about a
+# minute of a loaded server (an iteration is 15-30 ms), one small tuple each
+SLOW_WINDOW = 4096
+SLOW_SHOWN = 3
+# inside these phases the loop waits for the device; the loop's sleep
+FETCH_PHASES = ("engine.decode.fetch", "engine.first_token.fetch")
+ITERATION, WAIT = "serve.iteration", "wait"
+
+
+def _grown(now, before):
+    """{key: now - before} over the keys whose value moved."""
+    return {k: v - before.get(k, 0.0) for k, v in now.items()
+            if v != before.get(k)}
 
 
 def _pctl(values, q):
@@ -235,25 +257,41 @@ class Scheduler(object):
         # program fetched for them (engine.attention_positions)
         self.attention_positions_needed = 0
         self.attention_positions_fetched = 0
+        self.delivered_tokens = 0
         self.peak_in_flight = 0
         self._occupancy_sum = 0.0
-        # goodput accounting: device-busy seconds split prefill/decode;
-        # idle = elapsed - busy (stats()["goodput"], /metrics). Both are
-        # the host's time around the engine call. A decode step waits
-        # for its tokens, so busy_decode_s includes whatever the device
-        # still had queued before the step; a prefill program returns
-        # once dispatched unless a row ends its prompt (which fetches the
-        # first tokens), so busy_prefill_s is dispatch time for every
-        # other program, not device time (PERF.md, PR 23)
-        self.busy_prefill_s = 0.0
-        self.busy_decode_s = 0.0
+        # the phase ledger (module docstring), shared with the engine so
+        # that one ledger holds the whole iteration. busy_prefill_s and
+        # busy_decode_s (stats()["goodput"], /metrics) are its
+        # serve.prefill_chunk and serve.decode_step seconds: the HOST's
+        # time around the two engine calls, not device time. A decode step
+        # waits for its tokens, so busy_decode_s includes whatever the
+        # device still had queued before the step; a prefill program
+        # returns once dispatched unless a row ends its prompt (which
+        # fetches the first tokens), so busy_prefill_s is dispatch time
+        # for every other program (PERF.md, PR 23). "Is the chip waiting
+        # for the host" is stats()["phases"]: host_work_s against
+        # device_wait_s, and no_work_s for an idle server
+        self.phases = engine.phases = telemetry.PhaseLedger()
+        self._gc = telemetry.PhaseLedger()   # pauses, by generation
+        self._gc_t0 = self._gc_span = None
+        self._recent = deque(maxlen=SLOW_WINDOW)
         self._t_started = time.perf_counter()
+        self._t_loop = None
         # rolling latency windows for /v1/stats and /healthz percentiles:
         # bounded so a long-lived server reports RECENT tail latency, not
         # an all-time blend that a morning incident pollutes forever
         window = knobs.get_int("TPUFLOW_SERVE_LATENCY_WINDOW")
         self._ttft_window = deque(maxlen=max(1, window))
         self._itl_window = deque(maxlen=max(1, window * 4))
+
+    @property
+    def busy_prefill_s(self):
+        return self.phases.seconds.get("serve.prefill_chunk", 0.0)
+
+    @property
+    def busy_decode_s(self):
+        return self.phases.seconds.get("serve.decode_step", 0.0)
 
     # ---------- intake ----------
 
@@ -461,6 +499,7 @@ class Scheduler(object):
         elif prev is not None:
             self._itl_window.append((now - prev) * 1000)
         req.out.put(token)
+        self.delivered_tokens += 1
         if req.eos_id is not None and token == req.eos_id:
             self._finish(req, "eos")
         elif len(req.generated) >= req.max_new_tokens:
@@ -669,7 +708,7 @@ class Scheduler(object):
     def _prefill(self):
         plan = self._prefill_plan()
         if not plan:
-            return False
+            return 0
         slots = [slot for slot, _ in plan]
         reqs = [self._slots[slot] for slot in slots]
         # the program's attribution comes from the ENGINE's slot binding
@@ -682,18 +721,18 @@ class Scheduler(object):
                 "request_ids": [c["request_id"] for c in ctxs]}
         if any(c.get("span") for c in ctxs):
             data["spans"] = [c.get("span") or "" for c in ctxs]
-        with telemetry.timer("serve.prefill_chunk", data=data) as chunk:
+        with self.phases("serve.prefill_chunk", record=True,
+                         **data) as chunk:
             results = self.engine.prefill(plan)
             consumed = [n for n, _ in results]
             chunk.set(tokens=sum(consumed), row_tokens=consumed)
-        self.busy_prefill_s += chunk.seconds
         self.prefill_programs += 1
         self.prefill_rows += len(plan)
         self.prefill_tokens += sum(consumed)
         for req, slot, (_, first) in zip(reqs, slots, results):
             if first is not None:
                 self._prefill_done(req, slot, first)
-        return True
+        return len(plan)
 
     def _prefill_done(self, req, slot, first):
         """The final prefill chunk landed: populate the prefix cache,
@@ -739,18 +778,22 @@ class Scheduler(object):
         self._deliver(req, first)
 
     def _decode(self):
+        """One decode step and its tokens' delivery; returns the lanes
+        that decoded (0 where no request is past its prefill)."""
         active = [r for r in self._slots.values() if r.state == "decode"]
         if not active:
-            return False
+            return 0
+        stats = {}
         positions = getattr(self.engine, "attention_positions", None)
         if positions is not None:   # from the cursors, before they move
             needed, fetched = positions()
             self.attention_positions_needed += needed
             self.attention_positions_fetched += fetched
-        with telemetry.timer("serve.decode_step") as step:
+            stats = {"positions_needed": needed,
+                     "positions_fetched": fetched}
+        with self.phases("serve.decode_step", record=True) as step:
             tokens = self.engine.decode_step()
-            step.set(active=len(tokens))
-        self.busy_decode_s += step.seconds
+            step.set(active=len(tokens), **stats)
         self.decode_steps += 1
         self._occupancy_sum += self.engine.occupancy()
         telemetry.gauge("serve.batch_occupancy", self.engine.occupancy())
@@ -762,7 +805,7 @@ class Scheduler(object):
             if ss["enabled"]:
                 telemetry.gauge("serve.spec.accept_rate",
                                 ss["accept_rate"])
-        with telemetry.annotate("serve.deliver") as span:
+        with self.phases("serve.deliver") as span:
             delivered = 0
             for slot, toks in tokens.items():
                 req = self._slots.get(slot)
@@ -777,23 +820,35 @@ class Scheduler(object):
                         break
                     self._deliver(req, token)
                     delivered += 1
-            span.set_metadata(tokens=delivered)
-        return True
+            span.set(tokens=delivered)
+        return len(tokens)
 
     # ---------- the loop ----------
 
     def step(self):
         """One scheduler iteration; returns True if any work was done."""
-        with telemetry.annotate("serve.iteration", iteration=self.iteration):
-            with telemetry.annotate("serve.reap"):
+        phases = self.phases
+        before, collected = dict(phases.seconds), dict(self._gc.seconds)
+        prefilled, delivered = self.prefill_tokens, self.delivered_tokens
+        with phases(ITERATION, iteration=self.iteration) as span:
+            with phases("serve.reap"):
                 self._reap(time.time())
-            with telemetry.annotate("serve.admit") as span:
+            with phases("serve.admit") as admit:
                 admitted = self._admit()
-                span.set_metadata(admitted=admitted)
-            prefilled = self._prefill()
-            decoded = self._decode()
+                admit.set(admitted=admitted)
+            rows = self._prefill()
+            lanes = self._decode()
+            tokens = self.prefill_tokens - prefilled
+            span.set(lanes=lanes, prefill_rows=rows, prefill_tokens=tokens,
+                     admitted=admitted,
+                     delivered=self.delivered_tokens - delivered)
+        # (iteration, seconds by the phases that ran in it, lanes, prefill
+        # rows and tokens, admitted, the collector's seconds by generation)
+        self._recent.append((
+            self.iteration, _grown(phases.seconds, before), lanes, rows,
+            tokens, admitted, _grown(self._gc.seconds, collected)))
         self.iteration += 1
-        return bool(admitted or prefilled or decoded)
+        return bool(admitted or rows or lanes)
 
     def pending(self):
         with self._cond:
@@ -812,7 +867,34 @@ class Scheduler(object):
                     % max_iterations)
         return n
 
+    def _on_gc(self, phase, info):
+        """gc.callbacks entry while the loop's thread runs: a
+        collection's pause, on whatever thread it ran, by generation;
+        one of generation 2 is also the span runtime.gc. A collection
+        overlaps the phases: it is counted beside them."""
+        generation = info["generation"]
+        if phase == "start":
+            if generation == 2:
+                self._gc_span = telemetry.annotate("runtime.gc",
+                                                   generation=2)
+                self._gc_span.__enter__()
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0 is not None:
+            self._gc.add(generation, time.perf_counter() - self._gc_t0)
+            self._gc_t0 = None
+            if self._gc_span is not None:
+                self._gc_span.__exit__(None, None, None)
+                self._gc_span = None
+
     def _loop(self):
+        self._t_loop = time.perf_counter()
+        gc.callbacks.append(self._on_gc)
+        try:
+            self._serve()
+        finally:
+            gc.callbacks.remove(self._on_gc)
+
+    def _serve(self):
         while True:
             with self._cond:
                 if self._stopped:
@@ -825,7 +907,8 @@ class Scheduler(object):
                             or (self._draining and not self._queue
                                 and not self._slots)):
                         break
-                    self._cond.wait(timeout=0.02)
+                    with self.phases(WAIT):
+                        self._cond.wait(timeout=0.02)
         # loop exit: anything still queued/in-flight dies with "shutdown"
         with self._cond:
             leftovers = list(self._queue) + list(self._slots.values())
@@ -903,7 +986,56 @@ class Scheduler(object):
             "speculative": (self.engine.spec_stats() if self._paged
                             else {"enabled": False}),
             "goodput": self.goodput_stats(),
+            "phases": self.phase_stats(),
+            "slow_iterations": self.slow_iterations(),
+            "gc": self.gc_stats(),
         }
+
+    def gc_stats(self):
+        """By generation, the seconds Python's collector held the process
+        and its collections while the loop's thread ran (`_on_gc`)."""
+        return {str(generation): {
+            "seconds": round(seconds, 6),
+            "collections": self._gc.calls.get(generation, 0)}
+            for generation, seconds in sorted(dict(self._gc.seconds).items())}
+
+    def phase_stats(self):
+        """The loop's life on the host's clock, phase by phase (the
+        ledger's seconds and calls), and cut three ways: device_wait_s,
+        inside the two fetch phases, where the loop waits for the chip;
+        host_work_s, the rest of serve.iteration, where the chip has
+        nothing queued by this loop unless an earlier program still
+        runs; no_work_s, the loop's sleep with nothing to do. The three
+        add up to serve.iteration plus wait, which is loop_s (the
+        thread's life so far) less the loop's own few lines."""
+        seconds, calls = dict(self.phases.seconds), self.phases.calls
+        fetch = sum(seconds.get(name, 0.0) for name in FETCH_PHASES)
+        return {
+            "iterations": self.iteration,
+            "phase": {name: {"seconds": round(s, 6),
+                             "calls": calls.get(name, 0)}
+                      for name, s in sorted(seconds.items())},
+            "device_wait_s": round(fetch, 6),
+            "host_work_s": round(seconds.get(ITERATION, 0.0) - fetch, 6),
+            "no_work_s": round(seconds.get(WAIT, 0.0), 6),
+            "loop_s": (None if self._t_loop is None else
+                       round(time.perf_counter() - self._t_loop, 6)),
+        }
+
+    def slow_iterations(self):
+        """The slowest of the last SLOW_WINDOW iterations, each with what
+        it held and its milliseconds phase by phase: where a stall that
+        comes once a minute lay, and whether a collection ran in it."""
+        ms = lambda d: {str(k): round(v * 1e3, 3) for k, v in d.items()}
+        slowest = heapq.nlargest(SLOW_SHOWN, list(self._recent),
+                                 key=lambda it: it[1].get(ITERATION, 0.0))
+        return [{"iteration": n, "ms": round(took.get(ITERATION, 0.0) * 1e3,
+                                             3),
+                 "phase_ms": ms(took), "lanes": lanes, "prefill_rows": rows,
+                 "prefill_tokens": tokens, "admitted": admitted,
+                 "gc_ms": ms(collected)}
+                for n, took, lanes, rows, tokens, admitted, collected
+                in slowest]
 
     def tenant_stats(self, tenant_depths=None):
         """Per-tenant admission/latency rollup for /v1/stats and the
@@ -938,9 +1070,11 @@ class Scheduler(object):
         return {"enabled": self.tenancy.enabled(), "tenants": tenants}
 
     def goodput_stats(self):
-        """Chip-second split in the goodput categories
-        (metaflow_tpu/goodput.py): device-busy prefill/decode seconds
-        plus the scheduler-lifetime remainder as idle."""
+        """The scheduler's life split in the goodput categories
+        (metaflow_tpu/goodput.py): the host's seconds around the prefill
+        and decode engine calls (busy_prefill_s, busy_decode_s: dispatch
+        and wait, not device time) and the remainder as idle. Whether
+        the chip waits for the host is phase_stats()'s to say."""
         elapsed = max(0.0, time.perf_counter() - self._t_started)
         busy = self.busy_prefill_s + self.busy_decode_s
         return {

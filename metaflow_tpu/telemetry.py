@@ -29,6 +29,8 @@ profiler's clock under the name its record has. There is no switch: a
 span is recorded while a profiler session is open (ProfileTrigger below,
 or anyone's `jax.profiler.start_trace`) and costs one flag check when
 none is. A process that has not imported JAX is never made to.
+`PhaseLedger` is the same timed block with its seconds also summed by
+name on the host's clock, always on (the serving loop's phases).
 
 Crash safety: records flush in numbered part files
 (`_telemetry/<step>.<task>.<attempt>.<part>.jsonl`) — a task that dies
@@ -105,7 +107,9 @@ def _span_attrs(step_num, data):
     program) goes on joined by "|" (a comma would end the stat: the
     profiler packs a span's stats as `name#k=v,k=v#`)."""
     attrs = {} if step_num is None else {"step_num": int(step_num)}
-    for k, v in (data or {}).items():
+    if not data:
+        return attrs
+    for k, v in data.items():
         if isinstance(v, (str, int, float, bool)):
             attrs[k] = v
         elif isinstance(v, (list, tuple)) and all(
@@ -120,15 +124,17 @@ class _Timer(object):
     lands even when the block raises (ok: false) and the exception
     propagates. GeneratorExit is NOT a failure: it is how a consumer
     closes a generator-shaped span early (e.g. a single-artifact load).
-    `seconds` holds the block's time once it has ended."""
+    `seconds` holds the block's time once it has ended, and a `ledger`
+    (PhaseLedger) is given those seconds under the block's name."""
 
-    __slots__ = ("recorder", "name", "step_num", "data", "seconds", "_t0",
-                 "_span")
+    __slots__ = ("recorder", "name", "step_num", "data", "seconds", "ledger",
+                 "_t0", "_span")
 
-    def __init__(self, recorder, name, step_num=None, data=None):
+    def __init__(self, recorder, name, step_num=None, data=None,
+                 ledger=None):
         self.recorder, self.name = recorder, name
         self.step_num, self.data = step_num, data
-        self.seconds = None
+        self.seconds, self.ledger = None, ledger
 
     def start(self):
         """For a block that no `with` can hold (a generator's time
@@ -154,12 +160,38 @@ class _Timer(object):
     def __exit__(self, exc_type, exc, tb):
         self.seconds = time.perf_counter() - self._t0
         self._span.__exit__(exc_type, exc, tb)
+        if self.ledger is not None:
+            self.ledger.add(self.name, self.seconds)
         if self.recorder is not None:
             self.recorder.emit(
                 "timer", self.name, ms=self.seconds * 1000,
                 ok=exc_type is None or issubclass(exc_type, GeneratorExit),
                 step_num=self.step_num, data=self.data)
         return False
+
+
+class PhaseLedger(object):
+    """Seconds and calls by name, on the host's clock, always on: what a
+    loop's phases took since it was made. `ledger(name, **stats)` is a
+    timed block (_Timer) that is the span `name` on the profiler's clock,
+    exactly as `annotate(name, **stats)` is, and adds its seconds and one
+    call here when it ends; with `record=True` it is also the timer
+    record that `timer(name, data=stats)` writes. One thread's loop owns
+    a ledger; another thread may add under names of its own (a
+    collector's callback), and readers take `dict(ledger.seconds)`."""
+
+    __slots__ = ("seconds", "calls")
+
+    def __init__(self):
+        self.seconds, self.calls = {}, {}
+
+    def __call__(self, name, record=False, **stats):
+        return _Timer(_current if record else None, name,
+                      data=stats or None, ledger=self)
+
+    def add(self, name, seconds):
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        self.calls[name] = self.calls.get(name, 0) + 1
 
 
 def _rank_from_env():

@@ -1819,6 +1819,9 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_prefill": "counter",
     "tpuflow_serve_attention_positions": "counter",
     "tpuflow_serve_iterations": "counter",
+    "tpuflow_serve_phase_seconds": "counter",
+    "tpuflow_serve_phase_calls": "counter",
+    "tpuflow_serve_gc_pause_seconds": "counter",
     "tpuflow_serve_ttft_ms": "summary",
     "tpuflow_serve_itl_ms": "summary",
     "tpuflow_serve_prefix_lookups": "counter",

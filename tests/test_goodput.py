@@ -337,6 +337,14 @@ def _scheduler_stats():
             "state": {"bytes": 512, "bytes_per_slot": 128}},
         "goodput": {"serve_prefill_s": 1.5, "serve_decode_s": 4.0,
                     "serve_idle_s": 2.5, "elapsed_s": 8.0},
+        "phases": {"iterations": 55, "phase": {
+            "serve.iteration": {"seconds": 6.0, "calls": 55},
+            "engine.decode.fetch": {"seconds": 3.5, "calls": 40},
+            "wait": {"seconds": 1.9, "calls": 90}},
+            "device_wait_s": 3.5, "host_work_s": 2.5, "no_work_s": 1.9,
+            "loop_s": 8.0},
+        "gc": {"0": {"seconds": 0.004, "collections": 30},
+               "2": {"seconds": 0.11, "collections": 1}},
     }
 
 
@@ -389,6 +397,11 @@ class TestMetricFamilies:
                       kind="global") == 1024
         assert sample("tpuflow_serve_goodput_seconds",
                       category="serve_decode") == 4.0
+        assert sample("tpuflow_serve_phase_seconds",
+                      phase="engine.decode.fetch") == 3.5
+        assert sample("tpuflow_serve_phase_calls", phase="wait") == 90
+        assert sample("tpuflow_serve_gc_pause_seconds",
+                      generation="2") == 0.11
 
     def test_scheduler_conditional_families_absent(self):
         stats = _scheduler_stats()

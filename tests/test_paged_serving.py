@@ -412,6 +412,34 @@ class TestPagedSharedTelemetry:
         _assert_pool_free(eng)
 
 
+class TestPagedPhases:
+    def test_the_paged_engine_goes_through_the_schedulers_ledger(
+            self, engine):
+        """One ledger holds the whole iteration whichever engine runs
+        it: the paged engine's engine.* phases and its launch count."""
+        before = engine.launches
+        sched = Scheduler(engine)
+        assert engine.phases is sched.phases
+        reqs = [sched.submit(Request(list(range(1, 12 + 9 * i)),
+                                     max_new_tokens=5, rng=i))
+                for i in range(3)]
+        sched.run_until_idle(10_000)
+        assert all(r.reason == "length" for r in reqs)
+        calls, took = sched.phases.calls, sched.phases.seconds
+        assert calls["engine.decode.dispatch"] \
+            == calls["engine.decode.fetch"] == sched.decode_steps
+        # one slot and one chunk an execution: a program of the
+        # scheduler's is one or more of this engine's
+        assert calls["engine.prefill.dispatch"] >= sched.prefill_programs
+        assert calls["engine.first_token.fetch"] \
+            == calls["engine.admit.keys"] == len(reqs)
+        assert engine.launches - before == calls["engine.decode.dispatch"] \
+            + calls["engine.prefill.dispatch"]
+        assert took["engine.decode.dispatch"] + took["engine.decode.fetch"] \
+            <= took["serve.decode_step"] == sched.busy_decode_s
+        _assert_pool_free(engine)
+
+
 class TestPagedHTTP:
     def test_capacity_413_and_kv_healthz(self, setup, engine):
         """The paged capacity check surfaces as HTTP 413 + Retry-After,

@@ -9,6 +9,7 @@ work runs once more with no session open, for the comparison.
 """
 
 import dataclasses
+import gc
 import glob
 import os
 import re
@@ -47,7 +48,8 @@ def _events(profile):
         for n, line in enumerate(plane.lines):
             for e in line.events:
                 if e.name.startswith(("serve.", "engine.", "unit.",
-                                      "train.", "data.")):
+                                      "train.", "data.", "runtime.",
+                                      "wait")):
                     out.append((n, e.name, e.start_ns,
                                 e.start_ns + e.duration_ns, dict(e.stats)))
     return out
@@ -83,6 +85,8 @@ def captured(tmp_path_factory):
         jax.profiler.start_trace(str(tmp / "trace"))
         try:
             out["requests"], out["tokens"] = _serve(sched)
+            gc.collect()   # one of generation 2, while the loop runs
+            out["gc"] = sched.stats()["gc"]
             with telemetry.timer("unit.timed", step_num=3,
                                  data={"k": "v"}) as timed:
                 pass
@@ -143,6 +147,80 @@ class TestSchedulerSpans:
         assert all(d[4]["tokens"] >= 1 for d in deliver)
         assert sum(e[4]["admitted"] for e in line
                    if e[1] == "serve.admit") == len(PROMPTS)
+        parents("engine.admit.keys", "serve.admit")
+        # nothing of the loop's or the engine's lies between iterations:
+        # a reader takes every such span for the host at work
+        for e in line:
+            if e[1].startswith(("serve.", "engine.")) \
+                    and e[1] != "serve.iteration":
+                assert any(_inside(i, e) for i in iterations), e[1]
+        assert any(e[1] == "wait" for e in line)
+
+    def test_the_capture_holds_the_spans_the_docs_table_names(
+            self, captured):
+        """docs/observability.md's span table against the capture:
+        every serving span it names is there (the state's reset needs a
+        model that has one)."""
+        docs = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "docs", "observability.md")
+        with open(docs) as f:
+            table = f.read().split("| Span | Around | Stats |")[1]
+        rows = table.split("\n\n")[0].splitlines()[2:]
+        named = {n for row in rows
+                 for n in re.findall(r"`([\w.]+)`", row.split("|")[1])
+                 if n.startswith(("serve.", "engine.", "runtime."))
+                 or n == "wait"}
+        assert {"engine.admit.keys", "runtime.gc", "wait",
+                "serve.iteration"} <= named
+        assert named - {"engine.state.reset"} \
+            <= {e[1] for e in captured["events"]}
+        collections = [e for e in captured["events"]
+                       if e[1] == "runtime.gc"]
+        assert collections and all(e[4]["generation"] == 2
+                                   for e in collections)
+        assert captured["gc"]["2"]["collections"] >= 1
+        assert captured["gc"]["2"]["seconds"] > 0
+
+    def test_an_iteration_and_a_step_say_what_they_held(self, captured):
+        events = captured["events"]
+        iterations = [e for e in events if e[1] == "serve.iteration"]
+        assert all({"iteration", "lanes", "prefill_rows", "prefill_tokens",
+                    "admitted", "delivered"} <= set(e[4])
+                   for e in iterations)
+        assert sum(e[4]["admitted"] for e in iterations) == len(PROMPTS)
+        assert sum(e[4]["prefill_tokens"] for e in iterations) \
+            == sum(len(p) for p in PROMPTS)
+        # five tokens a request: the first from its prefill
+        assert sum(e[4]["delivered"] for e in iterations) \
+            == 5 * len(PROMPTS)
+        steps = [e for e in events if e[1] == "serve.decode_step"]
+        assert sum(e[4]["active"] for e in steps) == 4 * len(PROMPTS)
+        assert sorted(e[4]["lanes"] for e in iterations
+                      if e[4]["lanes"]) \
+            == sorted(e[4]["active"] for e in steps)
+        assert all(0 < e[4]["positions_needed"] <= e[4]["positions_fetched"]
+                   for e in steps)
+
+    def test_every_fetch_awaits_a_launch_already_made(self, captured):
+        """The engine numbers its launches: a dispatch span carries
+        `launch`, strictly increasing; a fetch span `awaits` the number
+        of a dispatch span that opened before it."""
+        line = sorted((e for e in captured["events"]
+                       if e[1].startswith("engine.")), key=lambda e: e[2])
+        made, awaited = [], 0
+        for e in line:
+            if e[1] in ("engine.prefill.dispatch",
+                        "engine.decode.dispatch"):
+                assert not made or e[4]["launch"] > made[-1]
+                made.append(e[4]["launch"])
+            elif e[1] in ("engine.first_token.fetch",
+                          "engine.decode.fetch"):
+                assert e[4]["awaits"] in made
+                awaited += 1
+            else:
+                assert "launch" not in e[4] and "awaits" not in e[4]
+        assert made == list(range(made[0], made[0] + len(made)))
+        assert awaited >= 4 * len(PROMPTS) // 2
 
     def test_every_chunk_names_its_request_and_slot(self, captured):
         """One span a prefill program: it names the request, slot and
@@ -189,6 +267,10 @@ class TestNoSession:
             "    t.set(tokens=3)\n"
             "with tracing.span('c', {'step': 1}):\n"
             "    pass\n"
+            "phases = telemetry.PhaseLedger()\n"
+            "with phases('d', record=True, n=1) as p:\n"
+            "    p.set(x=2)\n"
+            "assert phases.calls == {'d': 1} and p.seconds >= 0\n"
             "assert t.seconds >= 0\n"
             "assert 'jax' not in sys.modules, 'a span imported JAX'\n")
         env = dict(os.environ, PYTHONPATH=os.pathsep.join(

@@ -643,6 +643,138 @@ class TestServingTelemetry:
         assert all(r["data"]["ttft_ms"] >= 0 for r in firsts)
 
 
+class TestPhaseLedger:
+    """The serving loop's own account of its time: stats()["phases"],
+    ["slow_iterations"], ["gc"]."""
+
+    INNER = ("serve.reap", "serve.admit", "serve.prefill_chunk",
+             "serve.decode_step", "serve.deliver")
+    NESTED = {"serve.admit": ("engine.admit.keys",),
+              "serve.prefill_chunk": ("engine.prefill.dispatch",
+                                      "engine.first_token.fetch"),
+              "serve.decode_step": ("engine.decode.upload",
+                                    "engine.decode.dispatch",
+                                    "engine.decode.fetch")}
+
+    def test_the_ledger_accounts_for_the_loop_as_the_counters_do(
+            self, engine, tmp_path):
+        from metaflow_tpu import telemetry
+        from metaflow_tpu.datastore import FlowDataStore, LocalStorage
+
+        fds = FlowDataStore("ServePhases", LocalStorage,
+                            ds_root=str(tmp_path))
+        telemetry.init_recorder(fds, "1", "_serve", "phases-test")
+        try:
+            sched = Scheduler(engine)
+            assert engine.phases is sched.phases
+            reqs = [sched.submit(Request(list(range(1, 9 + 7 * i)),
+                                         max_new_tokens=6, rng=i))
+                    for i in range(6)]
+            sched.run_until_idle(10_000)
+            assert all(r.reason == "length" for r in reqs)
+        finally:
+            telemetry.close_recorder()
+        stats = sched.stats()
+        phases = stats["phases"]
+        took = {n: p["seconds"] for n, p in phases["phase"].items()}
+        calls = {n: p["calls"] for n, p in phases["phase"].items()}
+        # the phases nest: none sums to more than what holds it
+        assert sum(took[n] for n in self.INNER) <= took["serve.iteration"]
+        for outer, inner in self.NESTED.items():
+            assert sum(took[n] for n in inner) <= took[outer]
+        assert calls["serve.iteration"] == calls["serve.reap"] \
+            == calls["serve.admit"] == phases["iterations"] \
+            == stats["iterations"]
+        assert calls["serve.decode_step"] == calls["serve.deliver"] \
+            == calls["engine.decode.dispatch"] \
+            == calls["engine.decode.fetch"] == stats["decode_steps"]
+        assert calls["serve.prefill_chunk"] \
+            == calls["engine.prefill.dispatch"] == stats["prefill_programs"]
+        assert calls["engine.first_token.fetch"] <= len(reqs)
+        assert calls["engine.admit.keys"] == len(reqs)
+        assert engine.launches >= stats["decode_steps"] \
+            + stats["prefill_programs"]
+        # no thread ran: no sleep, no loop time, no collector's callback
+        assert "wait" not in took and phases["loop_s"] is None
+        assert phases["no_work_s"] == 0 and stats["gc"] == {}
+        assert phases["device_wait_s"] == pytest.approx(
+            took["engine.decode.fetch"] + took["engine.first_token.fetch"],
+            abs=1e-5)
+        assert phases["device_wait_s"] + phases["host_work_s"] \
+            == pytest.approx(took["serve.iteration"], abs=1e-5)
+        # what the parent computed: the two timers' seconds, summed, and
+        # the steps counted (a record rounds its milliseconds to three
+        # places)
+        records = telemetry.read_run_records(fds, "1")
+        for name, busy, count in (
+                ("serve.decode_step", sched.busy_decode_s,
+                 sched.decode_steps),
+                ("serve.prefill_chunk", sched.busy_prefill_s,
+                 sched.prefill_programs)):
+            mine = [r for r in records if r["name"] == name]
+            assert len(mine) == count > 0
+            assert busy * 1e3 == pytest.approx(
+                sum(r["ms"] for r in mine), abs=1e-3 * count)
+        assert stats["goodput"]["serve_decode_s"] == round(
+            sched.busy_decode_s, 3)
+        steps = [r["data"] for r in records
+                 if r["name"] == "serve.decode_step"]
+        assert sum(d["positions_needed"] for d in steps) \
+            == stats["attention_positions_needed"]
+        assert sum(d["positions_fetched"] for d in steps) \
+            == stats["attention_positions_fetched"]
+
+    def test_the_slowest_iterations_phase_by_phase(self, engine):
+        sched = Scheduler(engine)
+        reqs = [sched.submit(Request(list(range(1, 30)), max_new_tokens=4,
+                                     rng=i)) for i in range(3)]
+        n = sched.run_until_idle(10_000)
+        slow = sched.stats()["slow_iterations"]
+        assert len(slow) == min(3, n)
+        assert [s["ms"] for s in slow] \
+            == sorted((s["ms"] for s in slow), reverse=True)
+        recent = list(sched._recent)
+        assert [r[0] for r in recent] == list(range(n))
+        assert slow[0]["ms"] == pytest.approx(max(
+            r[1]["serve.iteration"] for r in recent) * 1e3, abs=1e-3)
+        for s in slow:
+            assert s["phase_ms"]["serve.iteration"] == s["ms"]
+            assert sum(s["phase_ms"].get(p, 0.0) for p in self.INNER) \
+                <= s["ms"] + 1e-3
+            assert 0 <= s["lanes"] <= engine.max_slots and s["gc_ms"] == {}
+        assert sum(r[2] for r in recent) == 3 * len(reqs)   # lanes
+        assert sum(r[4] for r in recent) == 29 * len(reqs)  # prompt tokens
+        assert sum(r[5] for r in recent) == len(reqs)       # admitted
+
+    @pytest.mark.parametrize("end", ["stop", "drain"])
+    def test_the_collector_is_watched_while_the_loop_runs_only(
+            self, engine, end):
+        import gc
+
+        alone = Scheduler(engine)
+        want = alone.submit(Request(list(range(1, 20)), max_new_tokens=6))
+        alone.run_until_idle(10_000)
+        found = list(gc.callbacks)
+        sched = Scheduler(engine).start()
+        try:
+            assert gc.callbacks == found + [sched._on_gc]
+            req = sched.submit(Request(list(range(1, 20)),
+                                       max_new_tokens=6))
+            # the same tokens with the callback installed as without
+            assert req.result(timeout=120) == want.generated
+            gc.collect()
+            stats = sched.stats()
+        finally:
+            assert getattr(sched, end)() in (None, True)
+        assert gc.callbacks == found
+        assert stats["gc"]["2"]["collections"] >= 1
+        assert stats["gc"]["2"]["seconds"] > 0
+        phases = stats["phases"]
+        assert phases["phase"]["wait"]["calls"] >= 1
+        assert phases["device_wait_s"] + phases["host_work_s"] \
+            + phases["no_work_s"] <= phases["loop_s"]
+
+
 class TestServeCommand:
     def test_train_checkpoint_serve_end_to_end(self, run_flow,
                                                tpuflow_root, tmp_path):
