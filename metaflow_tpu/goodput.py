@@ -635,11 +635,13 @@ def scheduler_metric_families(stats):
                 .add(stats["decode_steps"]))
     fams.append(
         Family("tpuflow_serve_prefill", "counter",
-               "Prefill programs run, the rows (slots) they carried and "
-               "the prompt tokens in those rows")
+               "Prefill programs run, the rows (slots) they carried, the "
+               "prompt tokens in those rows, and how many of the programs "
+               "were a decode step that took the rows along")
         .add(stats["prefill_programs"], {"count": "programs"})
         .add(stats["prefill_rows"], {"count": "rows"})
-        .add(stats["prefill_tokens"], {"count": "tokens"}))
+        .add(stats["prefill_tokens"], {"count": "tokens"})
+        .add(stats.get("merged_steps", 0), {"count": "merged_steps"}))
     fams.append(
         Family("tpuflow_serve_attention_positions", "counter",
                "K and V positions over the decode steps run and all "

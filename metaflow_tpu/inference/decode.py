@@ -65,7 +65,13 @@ recurrent state (Mamba's: 0.3 MB a layer and slot) is cut out of its
 pools before the loop and put back after it, a large one (power
 retention's: 34 MB a layer and slot) is read and written in its pool by
 row index a layer at a time, and its one-token update touches only the
-lanes that decode; no other row is touched and no pool is copied.
+lanes that decode; no other row is touched and no pool is copied. For a
+stack of attention layers the batch may be both at once (`rows`,
+`merges`): a decode step's lanes and a prefill program's rows as one
+batch of tokens, each at its own position, for everything that
+multiplies by a weight, taken apart only for what mixes positions
+(`_merged_layer`): one execution then reads every weight once where the
+slot engine's two programs of an iteration read it twice.
 
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
@@ -761,6 +767,56 @@ def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
     return x, cache
 
 
+def _merged_layer(cfg, cos, sin, pos, x, layer_params, cache, layer, lanes,
+                  rows, mesh=None):
+    """One block of a stack of `attention` layers over a decode step's
+    lanes AND a prefill program's rows as one batch, so that the layer's
+    weights are read once for both: x [B + R * W, 1, dim] holds the
+    lanes' one new token each, at their cursors `pos` [B], and after them
+    the rows' tokens row by row; rows = (slots [R] distinct, start [R],
+    W): row r is W tokens of slot slots[r] from position start[r].
+    Everything that multiplies by a weight (the norms, q, k and v with
+    rope at each token's own position, the output projection, the
+    feed-forward) runs once over all of them. What mixes positions takes
+    them apart, each part on the path it has in a program of its own: the
+    lanes write K and V at their cursors and read through
+    `_pool_attention` with `lanes` (on a TPU the kernel that reads the
+    pool as stored), the rows write at their slots and read their slots'
+    views in the chunk loop, all in place in the carried pool.
+
+    The rows' writes come AFTER the lanes'. A slot that is mid-prefill is
+    also a masked lane of the step, whose write lands at its cursor: past
+    everything real, so overwritten before it is seen, but in a merged
+    program its row may be writing that very position in the same
+    execution, and the row's K and V are the real ones.
+    Returns (x, cache)."""
+    lp, a = layer_params, ATTENTION["attention"]
+    slots, start, W = rows
+    B, R = pos.shape[0], slots.shape[0]
+    at = jnp.concatenate([pos, _query_positions(start, W).reshape(-1)])
+    q, k, v = _attn_qkv(cfg, cos, sin, at, x, lp)
+    of_lanes = lambda t: t[:B]
+    of_rows = lambda t: t[B:].reshape((R, W) + t.shape[2:])
+    cache_k, cache_v = cache[a.k], cache[a.v]
+    with jax.named_scope("kv_cache_update"):
+        write = lambda pool, new: _write_layer(
+            _write_layer(pool, of_lanes(new), pos, layer),
+            of_rows(new), start, layer, slots)
+        cache_k = write(cache_k, k.astype(cache_k.dtype))
+        cache_v = write(cache_v, v.astype(cache_v.dtype))
+    kw = dict(v_head_dim=_v_head_dim(cfg), scope=a.scope)
+    attn = _pool_attention(of_lanes(q), cache_k, cache_v, pos, layer, lanes,
+                           **kw)
+    with jax.named_scope(a.scope):   # as `_decode_layer` reads its rows
+        read_k = _slot_rows(cache_k, slots, layer)
+        read_v = _slot_rows(cache_v, slots, layer)
+    attn_rows = _pool_attention(of_rows(q), read_k, read_v, start, 0, **kw)
+    attn = jnp.concatenate(
+        [attn, attn_rows.reshape((R * W, 1) + attn_rows.shape[2:])])
+    x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
+    return x, dict(cache, **{a.k: cache_k, a.v: cache_v})
+
+
 def _write_layer(pool, new, pos, layer, slots=None, ring=False):
     """new [B, T, KV, Hd] into pool [layers, B, S, KV * Hd] at `layer`,
     every batch row at its own cursor (or all at a scalar `pos`); with
@@ -890,7 +946,7 @@ def _retention_layer(cfg, cos, sin, pos, x, lp, cache, layer, valid, slots):
 
 
 def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
-            last=None):
+            last=None, rows=None):
     """The layer loop of every family: the activations, the whole cache
     and (a model with gated memory units) the memory are its carry, and
     layer i of a kind reads its weights out of that kind's stack and
@@ -899,7 +955,9 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
     before the config's `tail_layer` run over every position, that layer
     writes K and V of every position, and from its queries on the loop
     runs for position last[b] of each row alone (scope `cross_decoder`):
-    x comes back [B, 1, dim]."""
+    x comes back [B, 1, dim]. With `rows` (slots, start, W) the batch is a
+    decode step's lanes followed by a prefill program's rows, and every
+    layer is a `_merged_layer` (`merges` says for which stacks)."""
     fam = family(cfg)
     kinds = layer_kinds(cfg)
     # rope's table is as long as the KV pool is deep; a stack that caches
@@ -922,6 +980,9 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
         def body(kind, i, carry):
             x, cache, memory = carry
             lp = jamba.layer_at(params[fam.stacks[kind]], i)
+            if rows is not None:
+                return _merged_layer(cfg, cos, sin, pos, x, lp, cache, i,
+                                     lanes, rows, mesh) + (memory,)
             if kind in ATTENTION:
                 x, cache = _decode_layer(
                     cfg, kind, cos, sin, pos, x, lp, cache, i, mesh=mesh,
@@ -1002,8 +1063,21 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
     return x, cache
 
 
+def merges(cfg, mesh=None, attn_impl="chunked"):
+    """Whether a prefill program's rows can ride in the decode step of
+    this stack (`decode_forward`'s `rows`): every layer caches K and V
+    and nothing else (`attention`), so that a row's tokens need nothing
+    of each other but their K and V in the pool; one chip; attention in
+    the chunk loop. A recurrent layer (a scan or a chunk form over a
+    row's positions, and a state to hold), a ring, a tail layer or a
+    gated memory keeps the two programs."""
+    return (mesh is None and attn_impl == "chunked"
+            and set(layer_kinds(cfg)) == {"attention"})
+
+
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
-                   attn_impl="dense", valid=None, slots=None, last=None):
+                   attn_impl="dense", valid=None, slots=None, last=None,
+                   rows=None):
     """Forward over T new tokens at absolute position `pos` (a traced
     scalar, or a traced [B] vector when every batch row decodes at its
     own offset — the continuous-batching engine), reading and extending
@@ -1022,11 +1096,29 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
     vector: only position last[b] of row b is read, so the layers from
     the tail layer's attention on, and the head, run for that position
     alone (the cache is extended by all T all the same).
-    Returns (logits [B, T, vocab] fp32, or [B, 1, vocab] with `last`;
-    updated cache)."""
-    x = params["embed"][tokens].astype(llama.param_dtype(cfg))
+    rows: None, or for a stack that `merges`, beside a decode step of the
+    whole pool (tokens [B, 1], `pos` [B], no `slots`), a prefill
+    program's rows to take along: (tokens [R, W], slots [R] distinct,
+    start [R], last [R]). Row r extends row slots[r] of the cache by its
+    W tokens from position start[r], as a program of its own with
+    `slots` would, in the same pass over the weights (`_merged_layer`),
+    and the head runs for position last[r] of it alone.
+    Returns (logits [B, T, vocab] fp32, or [B, 1, vocab] with `last`, or
+    [B + R, 1, vocab] with `rows`: the lanes', then the rows'; updated
+    cache)."""
+    dtype = llama.param_dtype(cfg)
+    x = params["embed"][tokens].astype(dtype)
+    if rows is not None:
+        row_tokens, row_slots, row_start, row_last = rows
+        x = jnp.concatenate(
+            [x, params["embed"][row_tokens.reshape(-1, 1)].astype(dtype)])
+        rows = (row_slots, row_start, row_tokens.shape[1])
     x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl,
-                       slots, last)
+                       slots, last, rows)
+    if rows is not None:   # the lanes, and each row's one position
+        B = tokens.shape[0]
+        x = jnp.concatenate([x[:B], _at(
+            x[B:].reshape(row_tokens.shape + x.shape[2:]), row_last)])
     x = _norm(cfg, x, params, "final_norm")
     if "lm_head" in params:
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],

@@ -48,6 +48,22 @@ cover everything:
     layer's attention, the layers after it and the head for each row's
     last real position alone, and its logits are [R, 1, vocab]
 
+Where every layer of the stack is an attention layer (Llama, Mistral,
+Mixtral), on one chip and with the chunk loop (`merges`,
+inference/decode.py), an iteration that prefills is ONE execution, of
+the decode program: `stage_rows(plan)` builds the rows that `prefill`
+would have run, and the next `decode_step` takes them along, the lanes
+and the rows' tokens one batch for everything that multiplies by a
+weight, so that every weight is read once where two programs read it
+twice. The rows' first tokens come back with the lanes' tokens in the
+one fetch (`row_results`); with no lane decoding the same program runs
+for the rows alone, and the prefill and first-token programs are never
+compiled (`warm_prefill` warms the decode step's three row shapes in
+their place). A stack with a recurrent layer, a ring, a tail layer or a
+gated memory keeps prefill then decode, two programs an iteration: its
+rows have more to take apart than K and V, and its kinds join one at a
+time (ROADMAP S9b).
+
 Slots never wait for each other: a finished slot is released and can be
 refilled while its neighbors keep decoding. Free/prefilling slots ride
 through the fused decode step as masked lanes — their writes land at
@@ -107,6 +123,7 @@ from ..inference.decode import (
     init_kv_cache,
     is_recurrent,
     layer_kinds,
+    merges,
     recurrent_pools,
     ring_pools,
 )
@@ -229,6 +246,12 @@ class SlotEngine(object):
         self._attention_reads = None   # attention_positions() fills it
         self._recurrent_pools = recurrent_pools(cfg)
         self.recurrent = bool(self._recurrent_pools)
+        # a stack of attention layers on one chip: an iteration's prefill
+        # rows ride in its decode step (`stage_rows`), and one execution
+        # reads every weight once
+        self.merges = merges(cfg, mesh, self.attn_impl)
+        self._staged = None      # the rows the next decode step takes
+        self.row_results = []    # what the last decode step's rows gave
         B = self.max_slots
         # host-side per-slot state
         self.pos = np.zeros(B, np.int32)          # next cache write index
@@ -290,24 +313,38 @@ class SlotEngine(object):
             pos = pos + mask.astype(jnp.int32)
             return tok, pos
 
-        def _decode_sampled(params, cache, tok, pos, mask, keys, temp,
-                            top_k, top_p):
+        def _step_logits(params, cache, tok, pos, mask, rows):
+            # the decode step's logits, [B, vocab]; with the rows of a
+            # prefill program riding along (`stage_rows`: tokens [R, W],
+            # slots, start, n_real), each row's logits at its last real
+            # position after them, [B + R, vocab]
+            if rows is not None:
+                rows = (rows["tokens"], rows["slots"], rows["start"],
+                        jnp.maximum(rows["n_real"] - 1, 0))
             logits, cache = decode_forward(
                 params, tok[:, None], cache, pos, cfg, mesh=mesh,
-                attn_impl=self.attn_impl, valid=mask[:, None])
-            nxt = sample_slots(logits[:, 0], keys, temp, top_k, top_p)
-            tok, pos = _advance(nxt, tok, pos, mask)
+                attn_impl=self.attn_impl, valid=mask[:, None], rows=rows)
+            return logits[:, 0], cache
+
+        def _decode_sampled(params, cache, tok, pos, mask, keys, temp,
+                            top_k, top_p, rows=None):
+            logits, cache = _step_logits(params, cache, tok, pos, mask, rows)
+            if rows is not None:   # the rows' keys and knobs, as staged
+                keys, temp, top_k, top_p = (
+                    jnp.concatenate([lanes, rows[name]])
+                    for lanes, name in ((keys, "keys"), (temp, "temp"),
+                                        (top_k, "top_k"), (top_p, "top_p")))
+            nxt = sample_slots(logits, keys, temp, top_k, top_p)
+            tok, pos = _advance(nxt[:tok.shape[0]], tok, pos, mask)
             return nxt, tok, pos, cache
 
-        def _decode_greedy(params, cache, tok, pos, mask):
+        def _decode_greedy(params, cache, tok, pos, mask, rows=None):
             # static fast path when every active slot is greedy: the full
             # per-slot sampler (two sorts + scatter per step) costs ~2x a
             # tiny forward on CPU; greedy traffic must not pay it
-            logits, cache = decode_forward(
-                params, tok[:, None], cache, pos, cfg, mesh=mesh,
-                attn_impl=self.attn_impl, valid=mask[:, None])
-            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            tok, pos = _advance(nxt, tok, pos, mask)
+            logits, cache = _step_logits(params, cache, tok, pos, mask, rows)
+            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            tok, pos = _advance(nxt[:tok.shape[0]], tok, pos, mask)
             return nxt, tok, pos, cache
 
         def _first_token(logits, idx, keys, temp, top_k, top_p):
@@ -423,7 +460,11 @@ class SlotEngine(object):
     def compile_counts(self):
         """jit cache entries per program — each decode variant must stay
         at <= 1, prefill and first_token at the programs of
-        `prefill_shapes` (all compiled by `warm_prefill`)."""
+        `prefill_shapes` (all compiled by `warm_prefill`); where the rows
+        ride in the decode step (`merges`) each decode variant has one
+        more entry for every shape of `prefill_shapes` (the greedy ones
+        compiled by `warm_prefill`) and prefill and first_token stay at
+        0."""
         return {
             "prefill": self._prefill_fn._cache_size(),
             "decode_greedy": self._decode_greedy_fn._cache_size(),
@@ -626,10 +667,24 @@ class SlotEngine(object):
         nothing real, so that no request's prefill is the first call of
         a shape. Safe with requests in flight: a row with nothing real
         writes only past its slot's cursor (overwritten before it is
-        seen) and holds a recurrent state."""
+        seen) and holds a recurrent state. Where the rows ride in the
+        decode step (`merges`) the programs warmed are that step's, one
+        a shape with no lane decoding, and the prefill and first-token
+        programs are not compiled at all (the sampled step's shapes
+        compile when a sampled request first meets them, as its
+        decode-only program does)."""
         for rows, width in self.prefill_shapes(budget):
             slots = np.arange(rows, dtype=np.int32)
             none = np.zeros(rows, np.int32)
+            if self.merges:
+                _, _, _, self._cache = self._decode_greedy_fn(
+                    self.params, self._cache, jnp.asarray(self._tok),
+                    jnp.asarray(self.pos),
+                    jnp.asarray(np.zeros(self.max_slots, bool)),
+                    {"tokens": np.full((rows, width), self.pad_id, np.int32),
+                     "slots": slots, "start": self.pos[slots],
+                     "n_real": none})
+                continue
             logits, self._cache = self._prefill_fn(
                 self.params, self._cache,
                 jnp.asarray(np.full((rows, width), self.pad_id, np.int32)),
@@ -656,6 +711,35 @@ class SlotEngine(object):
         long prompt spreads over several iterations, so that decode
         steps for the other slots interleave). The first tokens of all
         rows are fetched in one wait."""
+        slots, start, n_real, tokens, ends = self._rows_of(plan)
+        self.launches += 1
+        launch = self.launches
+        with self.phases("engine.prefill.dispatch", launch=launch):
+            logits, self._cache = self._prefill_fn(
+                self.params, self._cache, jnp.asarray(tokens),
+                jnp.asarray(slots), jnp.asarray(start), jnp.asarray(n_real))
+        self._advance_rows(slots, start + n_real)
+        first = None
+        if ends.any():
+            # the rows that do not end sample too, greedily, and are not
+            # read: one program whatever the rows that end
+            first = self._first_fn(
+                logits,
+                jnp.asarray(np.zeros_like(n_real) if self._tail
+                            else n_real - 1),
+                *map(jnp.asarray, self._row_sampling(slots, ends)))
+            with self.phases("engine.first_token.fetch", awaits=launch):
+                first = np.asarray(first)   # the host waits here, once
+            self._rows_done(slots[ends], first[ends])
+        return [(int(n), int(first[r]) if ends[r] else None)
+                for r, n in enumerate(n_real)]
+
+    def _rows_of(self, plan):
+        """(slots [R], start [R], n_real [R], tokens [R, W], ends [R]) of
+        the rows `plan` asks for (`prefill`): row r is the next n_real[r]
+        prompt tokens of slots[r] from its cursor start[r], padded to the
+        width, the longest row's tokens to a whole number of chunks;
+        ends[r]: the row ends its prompt."""
         slots = np.asarray([slot for slot, _ in plan], np.int32)
         if len(set(slots.tolist())) != len(plan) or not len(plan):
             # a recurrent state and causal attention make a slot's second
@@ -675,13 +759,10 @@ class SlotEngine(object):
         for r, slot in enumerate(slots):
             tokens[r, :n_real[r]] = \
                 self._prompt[slot][start[r]:start[r] + n_real[r]]
-        self.launches += 1
-        launch = self.launches
-        with self.phases("engine.prefill.dispatch", launch=launch):
-            logits, self._cache = self._prefill_fn(
-                self.params, self._cache, jnp.asarray(tokens),
-                jnp.asarray(slots), jnp.asarray(start), jnp.asarray(n_real))
-        end = start + n_real
+        return slots, start, n_real, tokens, start + n_real == sizes
+
+    def _advance_rows(self, slots, end):
+        """Move the rows' cursors to `end`."""
         self._prefill_cursor[slots] = end
         # keep pos at the prefill cursor: a mid-prefill slot rides
         # through fused decode steps as a masked lane whose write lands
@@ -689,30 +770,43 @@ class SlotEngine(object):
         # on already-written positions
         self.pos[slots] = end
         self._dirty = True
-        ends = end == sizes
-        first = None
-        if ends.any():
-            # the rows that do not end sample too, greedily, and are not
-            # read: one program whatever the rows that end
-            first = self._first_fn(
-                logits,
-                jnp.asarray(np.zeros_like(n_real) if self._tail
-                            else n_real - 1),
-                jnp.asarray(np.stack([
-                    self._keys_for(s) if e else np.zeros(2, np.uint32)
-                    for s, e in zip(slots, ends)])),
-                jnp.asarray(np.where(ends, self._temp[slots], 0.0),
-                            jnp.float32),
-                jnp.asarray(self._top_k[slots]),
-                jnp.asarray(self._top_p[slots]))
-            with self.phases("engine.first_token.fetch", awaits=launch):
-                first = np.asarray(first)   # the host waits here, once
-            done = slots[ends]
-            self.decoding[done] = True
-            self._tok[done] = first[ends]
-            self._key_cursor[done] += 1
-        return [(int(n), int(first[r]) if ends[r] else None)
-                for r, n in enumerate(n_real)]
+
+    def _row_sampling(self, slots, ends):
+        """(keys [R, 2], temperature, top_k, top_p [R]) for the rows'
+        first tokens: a row that ends its prompt samples with its
+        request's first key and knobs; the others sample too, greedily,
+        and are not read."""
+        return (np.stack([self._keys_for(s) if e else np.zeros(2, np.uint32)
+                          for s, e in zip(slots, ends)]),
+                np.where(ends, self._temp[slots], 0.0).astype(np.float32),
+                self._top_k[slots], self._top_p[slots])
+
+    def _rows_done(self, done, first):
+        """Slots `done` ended their prompt with the tokens `first`: they
+        decode from the next step on."""
+        self.decoding[done] = True
+        self._tok[done] = first
+        self._key_cursor[done] += 1
+        self._dirty = True
+
+    def stage_rows(self, plan):
+        """`prefill(plan)` for a stack whose rows ride in the decode step
+        (`merges`): the rows are built (host arrays: they go to the
+        device with the step's call, not one upload each), their slots'
+        cursors moved, and the NEXT `decode_step` carries them, one
+        execution that reads every weight once; `row_results` then holds
+        what `prefill` would have returned, and a row that ends its
+        prompt decodes from the step after. Returns the tokens each row
+        takes."""
+        if not self.merges:
+            raise ValueError("this engine's rows take a program of their "
+                             "own (prefill); its stack does not merge")
+        slots, start, n_real, tokens, ends = self._rows_of(plan)
+        self._advance_rows(slots, start + n_real)
+        self._staged = (slots, n_real, ends, {
+            "tokens": tokens, "slots": slots, "start": start,
+            "n_real": n_real})
+        return n_real.tolist()
 
     def prefill_step(self, slot):
         """The one-row case of `prefill`: the next chunk of `slot`.
@@ -733,12 +827,25 @@ class SlotEngine(object):
         becoming visible, their recurrent state is held). Advances
         pos/key cursors for decoding slots only.
 
+        With rows staged (`stage_rows`) the same program takes them
+        along, under the same name, dispatch and fetch spans (with no
+        lane decoding it runs for the rows alone): the rows' K and V are
+        written at their slots, each row's token after its last real
+        position comes back with the lanes' in the one fetch, and
+        `row_results` holds [(tokens_consumed, first_token_or_None), ...]
+        in the plan's order. The sampled program runs if a decoding lane
+        or a row that ends its prompt is sampled.
+
         Steady state stays on device: tok/pos flow out of one jitted call
         and back into the next; only the per-step sampling keys upload
         (and only when a sampled slot is active). Host mirrors replay the
         same masked advance, so they stay exact without a download."""
+        staged, self._staged, self.row_results = self._staged, None, []
+        rows = None
+        if staged is not None:
+            row_slots, n_real, ends, rows = staged
         decoding = [i for i in range(self.max_slots) if self.decoding[i]]
-        if not decoding:
+        if not decoding and rows is None:
             return {}
         if self._dirty:
             with self.phases("engine.decode.upload"):
@@ -751,19 +858,26 @@ class SlotEngine(object):
                 self._dirty = False
         self.launches += 1
         with self.phases("engine.decode.dispatch", launch=self.launches):
-            if any(self._temp[i] > 0.0 for i in decoding):
+            if any(self._temp[i] > 0.0 for i in decoding) or (
+                    rows is not None
+                    and (self._temp[row_slots[ends]] > 0.0).any()):
                 for i in decoding:
                     self._keys[i] = self._keys_for(i)
+                if rows is not None:
+                    keys, temp, top_k, top_p = self._row_sampling(
+                        row_slots, ends)
+                    rows = dict(rows, keys=keys, temp=temp, top_k=top_k,
+                                top_p=top_p)
                 out, self._d_tok, self._d_pos, self._cache = \
                     self._decode_sampled_fn(
                         self.params, self._cache, self._d_tok, self._d_pos,
                         self._d_mask, jnp.asarray(self._keys), self._d_temp,
-                        self._d_top_k, self._d_top_p)
+                        self._d_top_k, self._d_top_p, rows)
             else:
                 out, self._d_tok, self._d_pos, self._cache = \
                     self._decode_greedy_fn(
                         self.params, self._cache, self._d_tok, self._d_pos,
-                        self._d_mask)
+                        self._d_mask, rows)
         with self.phases("engine.decode.fetch", awaits=self.launches):
             out = np.asarray(out)   # the host waits for the device here
         tokens = {}
@@ -772,4 +886,10 @@ class SlotEngine(object):
             self._tok[i] = out[i]
             self.pos[i] += 1
             self._key_cursor[i] += 1
+        if rows is not None:   # the rows' tokens lie after the lanes'
+            first = out[self.max_slots:]
+            if ends.any():
+                self._rows_done(row_slots[ends], first[ends])
+            self.row_results = [(int(n), int(first[r]) if ends[r] else None)
+                                for r, n in enumerate(n_real)]
         return tokens

@@ -22,6 +22,20 @@ One scheduler iteration (`step()`):
      max_new_tokens finishes a request and releases its slot immediately
      (the next iteration's admit refills it — no lockstep)
 
+Where the engine's stack merges (SlotEngine.merges: attention layers
+throughout, one chip, the chunk loop: Llama, Mistral, Mixtral), 3 only
+STAGES the plan's rows (engine.stage_rows, still under
+serve.prefill_chunk) and 4's decode step takes them along: ONE execution
+an iteration reads every weight once, where the two programs of an
+iteration that prefills each read them all. The step then runs with no
+lane decoding too, the rows' first tokens are delivered after the lanes'
+tokens, and a request whose prompt ends in the step decodes from the
+next iteration on (on the two-program path it decodes in the same one).
+merged_steps counts the decode steps that carried rows; each is one of
+decode_steps and one of prefill_programs. A stack with recurrent layers,
+rings or a tail layer (Jamba, Brumby, Phi-4-mini-flash), the paged
+engine and a mesh keep the two programs.
+
 Telemetry rides the module-level flight-recorder helpers (no-ops
 outside a run context). The request lifecycle event schema is pinned in
 tests/schema_validate.py::SERVING_EVENT_DATA_SCHEMAS:
@@ -38,7 +52,8 @@ open, a flag check otherwise) and, always, its seconds and one call under
 the same name on the host's clock: serve.iteration around step(), inside
 it serve.reap, serve.admit, serve.prefill_chunk (one a prefill program:
 rows, tokens, and request_ids, slots, row_tokens a row) and
-serve.decode_step (the two timers), serve.deliver; the engine's engine.*
+serve.decode_step (the two timers; the step says the prefill_rows and
+prefill_tokens that rode in it), serve.deliver; the engine's engine.*
 phases inside admit and the two timers; `wait` around the loop's sleep
 when an iteration found nothing to do (docs/observability.md has the
 table). stats()["phases"] sums them, stats()["slow_iterations"] keeps the
@@ -252,6 +267,11 @@ class Scheduler(object):
         self.prefill_programs = 0
         self.prefill_rows = 0
         self.prefill_tokens = 0
+        # the decode steps that carried a prefill program's rows (an
+        # engine whose stack merges, engine.stage_rows): each is one of
+        # decode_steps AND one of prefill_programs, ONE execution
+        self.merged_steps = 0
+        self._rows_staged = None   # (requests, slots, tokens) of them
         # over the decode steps run: the K and V positions the decoding
         # lanes' queries saw, over all reading layers, and those the
         # program fetched for them (engine.attention_positions)
@@ -721,18 +741,28 @@ class Scheduler(object):
                 "request_ids": [c["request_id"] for c in ctxs]}
         if any(c.get("span") for c in ctxs):
             data["spans"] = [c.get("span") or "" for c in ctxs]
+        merged = getattr(self.engine, "merges", False)
         with self.phases("serve.prefill_chunk", record=True,
                          **data) as chunk:
-            results = self.engine.prefill(plan)
-            consumed = [n for n, _ in results]
+            if merged:   # staged: this iteration's decode step takes them
+                consumed = self.engine.stage_rows(plan)
+                self._rows_staged = (reqs, slots, sum(consumed))
+            else:
+                results = self.engine.prefill(plan)
+                consumed = [n for n, _ in results]
             chunk.set(tokens=sum(consumed), row_tokens=consumed)
         self.prefill_programs += 1
         self.prefill_rows += len(plan)
         self.prefill_tokens += sum(consumed)
+        if not merged:
+            self._first_tokens(reqs, slots, results)
+        return len(plan)
+
+    def _first_tokens(self, reqs, slots, results):
+        """Hand out the first token of every row that ended its prompt."""
         for req, slot, (_, first) in zip(reqs, slots, results):
             if first is not None:
                 self._prefill_done(req, slot, first)
-        return len(plan)
 
     def _prefill_done(self, req, slot, first):
         """The final prefill chunk landed: populate the prefix cache,
@@ -779,22 +809,28 @@ class Scheduler(object):
 
     def _decode(self):
         """One decode step and its tokens' delivery; returns the lanes
-        that decoded (0 where no request is past its prefill)."""
+        that decoded (0 where no request is past its prefill). The rows
+        `_prefill` staged ride in it (with no lane decoding it runs for
+        them alone), and their requests' first tokens are delivered
+        after the lanes' tokens: a request whose prompt ends here decodes
+        from the next iteration on."""
+        staged, self._rows_staged = self._rows_staged, None
         active = [r for r in self._slots.values() if r.state == "decode"]
-        if not active:
+        if not active and staged is None:
             return 0
-        stats = {}
+        reqs, slots, row_tokens = staged or ((), (), 0)
+        stats = {"prefill_rows": len(reqs), "prefill_tokens": row_tokens}
         positions = getattr(self.engine, "attention_positions", None)
         if positions is not None:   # from the cursors, before they move
             needed, fetched = positions()
             self.attention_positions_needed += needed
             self.attention_positions_fetched += fetched
-            stats = {"positions_needed": needed,
-                     "positions_fetched": fetched}
+            stats.update(positions_needed=needed, positions_fetched=fetched)
         with self.phases("serve.decode_step", record=True) as step:
             tokens = self.engine.decode_step()
             step.set(active=len(tokens), **stats)
         self.decode_steps += 1
+        self.merged_steps += staged is not None
         self._occupancy_sum += self.engine.occupancy()
         telemetry.gauge("serve.batch_occupancy", self.engine.occupancy())
         if self._paged:
@@ -821,6 +857,8 @@ class Scheduler(object):
                     self._deliver(req, token)
                     delivered += 1
             span.set(tokens=delivered)
+        if staged is not None:
+            self._first_tokens(reqs, slots, self.engine.row_results)
         return len(tokens)
 
     # ---------- the loop ----------
@@ -968,6 +1006,7 @@ class Scheduler(object):
             "prefill_programs": self.prefill_programs,
             "prefill_rows": self.prefill_rows,
             "prefill_tokens": self.prefill_tokens,
+            "merged_steps": self.merged_steps,
             "iterations": self.iteration,
             "draining": self._draining,
             # rolling-window tail latency (the SLO monitor's poll surface)

@@ -244,6 +244,119 @@ class TestSchedulerSpans:
             assert {r[1] for r in mine} == {req.slot}
 
 
+@pytest.fixture(scope="module")
+def captured_merged(tmp_path_factory):
+    """The events of a session over a scheduler whose engine merges (a
+    stack of attention layers, the chunk loop): the prefill rows ride in
+    the decode step."""
+    tmp = tmp_path_factory.mktemp("merged")
+    cfg = llama.LlamaConfig.tiny()
+    params = llama.init_params(jax.random.PRNGKey(0), cfg)
+    engine = SlotEngine(params, cfg, max_slots=2, max_seq_len=64,
+                        prefill_chunk=8, attn_impl="chunked")
+    assert engine.merges
+    sched = Scheduler(engine).start()
+    out = {}
+    try:
+        _, out["plain_tokens"] = _serve(sched)   # compiles every program
+        before = sched.stats()
+        jax.profiler.start_trace(str(tmp / "trace"))
+        try:
+            out["requests"], out["tokens"] = _serve(sched)
+        finally:
+            jax.profiler.stop_trace()
+        after = sched.stats()
+    finally:
+        sched.stop()
+    out["stats"] = {k: after[k] - before[k] for k in (
+        "decode_steps", "merged_steps", "prefill_programs", "prefill_rows",
+        "prefill_tokens")}
+    path = sorted(glob.glob(os.path.join(
+        str(tmp / "trace"), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out["events"] = _events(jax.profiler.ProfileData.from_file(path))
+    return out
+
+
+class TestMergedIteration:
+    """An iteration whose prefill rows ride in its decode step: the
+    spans, what ties a launch to its fetch, and the stats that say so."""
+
+    def test_one_launch_an_iteration_and_the_step_says_its_rows(
+            self, captured_merged):
+        events, stats = captured_merged["events"], captured_merged["stats"]
+        names = {e[1] for e in events}
+        # no program of the rows' own: nothing dispatches or awaits one
+        assert not names & {"engine.prefill.dispatch",
+                            "engine.first_token.fetch"}
+        iterations = [e for e in events if e[1] == "serve.iteration"]
+        chunks = [e for e in events if e[1] == "serve.prefill_chunk"]
+        steps = [e for e in events if e[1] == "serve.decode_step"]
+        assert chunks and all(
+            {"prefill_rows", "prefill_tokens", "active", "positions_needed",
+             "positions_fetched"} <= set(e[4]) for e in steps)
+        merged = [e for e in steps if e[4]["prefill_rows"]]
+        assert len(merged) == len(chunks) == stats["merged_steps"] \
+            == stats["prefill_programs"]
+        assert len(steps) == stats["decode_steps"]
+        assert sum(e[4]["prefill_rows"] for e in steps) \
+            == sum(e[4]["rows"] for e in chunks) == stats["prefill_rows"]
+        assert sum(e[4]["prefill_tokens"] for e in steps) \
+            == sum(e[4]["tokens"] for e in chunks) \
+            == sum(len(p) for p in PROMPTS) == stats["prefill_tokens"]
+        # a step with rows and no lane is a step all the same
+        assert any(e[4]["active"] == 0 for e in merged)
+        for it in iterations:
+            inside = [e for e in events if e is not it and _inside(it, e)]
+            chunk = [e for e in inside if e[1] == "serve.prefill_chunk"]
+            step = [e for e in inside if e[1] == "serve.decode_step"]
+            assert len(chunk) <= 1 and len(step) <= 1
+            launches = [e for e in inside
+                        if e[1] == "engine.decode.dispatch"]
+            assert len(launches) == len(step)
+            if chunk:   # the rows are staged, then ONE program carries them
+                assert chunk[0][3] <= step[0][2]
+                assert step[0][4]["prefill_rows"] == chunk[0][4]["rows"] \
+                    == it[4]["prefill_rows"]
+                assert step[0][4]["prefill_tokens"] \
+                    == chunk[0][4]["tokens"] == it[4]["prefill_tokens"]
+                assert _inside(step[0], launches[0])
+            elif step:
+                assert step[0][4]["prefill_rows"] == 0
+
+    def test_the_fetch_awaits_the_one_launch(self, captured_merged):
+        line = sorted((e for e in captured_merged["events"]
+                       if e[1] in ("engine.decode.dispatch",
+                                   "engine.decode.fetch")),
+                      key=lambda e: e[2])
+        assert line and len(line) % 2 == 0
+        for dispatch, fetch in zip(line[::2], line[1::2]):
+            assert dispatch[1] == "engine.decode.dispatch"
+            assert fetch[1] == "engine.decode.fetch"
+            assert fetch[4]["awaits"] == dispatch[4]["launch"]
+        made = [e[4]["launch"] for e in line[::2]]
+        assert made == list(range(made[0], made[0] + len(made)))
+
+    def test_same_tokens_with_and_without_a_session(self, captured_merged):
+        assert captured_merged["plain_tokens"] == captured_merged["tokens"]
+        assert all(len(t) == 5 for t in captured_merged["tokens"])
+
+    def test_the_merged_program_is_the_decode_program(self):
+        """Its executions are `jit__decode_greedy` (the readers find the
+        decode program by that name), and it holds every scope the
+        decode-only program and the prefill program hold."""
+        engine = _engine(llama, "chunked")
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        B = engine.max_slots
+        text = engine._decode_greedy_fn.lower(
+            engine.params, jax.eval_shape(lambda: engine._cache), i32(B),
+            i32(B), jax.ShapeDtypeStruct((B,), jnp.bool_),
+            {"tokens": i32(2, 8), "slots": i32(2), "start": i32(2),
+             "n_real": i32(2)}).as_text(debug_info=True)
+        assert "module @jit__decode_greedy" in text
+        for scope in DECODE_SCOPES:
+            assert _has(text, scope), scope
+
+
 class TestNoSession:
     def test_same_run_without_a_session_and_same_tokens(self, captured):
         """Generated tokens are bit-identical with and without a session

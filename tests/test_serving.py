@@ -280,6 +280,254 @@ class TestOnePrefillProgramAnIteration:
         assert eng.prefill_shapes(1) == [(1, CHUNK)]
 
 
+# ---- the prefill rows ride in the decode step (PR 39) ----
+
+PROGRAMS = ("_decode_greedy_fn", "_decode_sampled_fn", "_prefill_fn",
+            "_first_fn")
+
+
+@pytest.fixture(scope="module", params=sorted(FAMILIES))
+def merging_engine(request):
+    """(cfg, params, engine, calls) of a tiny model of each family whose
+    stack merges (attention layers, one chip, the chunk loop): three
+    slots, chunks of 16, float32; `calls[name][0]` counts the executions
+    of each of the engine's programs."""
+    mod, config = FAMILIES[request.param]
+    cfg = config.tiny()
+    params = mod.init_params(jax.random.PRNGKey(0), cfg)
+    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
+                     prefill_chunk=CHUNK, attn_impl="chunked")
+    assert eng.merges
+    return cfg, params, eng, {name: _count_calls(eng, name)
+                              for name in PROGRAMS}
+
+
+def _ran(calls):
+    return {name: count[0] for name, count in calls.items()}
+
+
+def _pool_at_real_positions(eng):
+    """{(pool, slot): its K or V at every position before the slot's
+    cursor}, of the slots that hold a request."""
+    return {(name, slot): np.asarray(eng._cache[name])[:, slot,
+                                                       :eng.pos[slot]]
+            for name in ("k", "v") for slot in range(eng.max_slots)
+            if eng.active[slot]}
+
+
+class TestRowsRideInTheDecodeStep:
+    def test_admitted_while_others_decode_emit_generates_tokens(
+            self, merging_engine):
+        """Requests that arrive while others decode prefill inside those
+        others' decode steps, ONE program an iteration, and every request
+        emits generate()'s tokens; no prefill or first-token program runs
+        at all."""
+        cfg, params, eng, calls = merging_engine
+        sched = Scheduler(eng)
+        before = _ran(calls)
+        prompts = _prompts(cfg, (40, 21, 70, 9, 33, 16), seed=4)
+        reqs = [sched.submit(Request(p, max_new_tokens=9, rng=i))
+                for i, p in enumerate(prompts[:2])]
+        merged_with_lanes = 0
+        while sched.pending():
+            if sched.iteration in (3, 5, 8, 9) and len(reqs) < len(prompts):
+                reqs.append(sched.submit(Request(
+                    prompts[len(reqs)], max_new_tokens=9, rng=len(reqs))))
+            ran, steps = _ran(calls), sched.merged_steps
+            decoding = int(eng.decoding.sum())
+            sched.step()
+            assert sum(_ran(calls).values()) - sum(ran.values()) <= 1
+            merged_with_lanes += bool(
+                sched.merged_steps - steps and decoding)
+        assert len(reqs) == len(prompts) and merged_with_lanes >= 4
+        for req in reqs:
+            assert req.reason == "length"
+            assert req.generated == _ref_tokens(params, cfg, req)
+        after = _ran(calls)
+        assert after["_prefill_fn"] == before["_prefill_fn"]
+        assert after["_first_fn"] == before["_first_fn"]
+        stats = sched.stats()
+        assert stats["merged_steps"] == stats["prefill_programs"] > 0
+        assert stats["decode_steps"] == after["_decode_greedy_fn"] \
+            - before["_decode_greedy_fn"] == stats["iterations"]
+        assert stats["prefill_tokens"] == sum(map(len, prompts))
+
+    def test_the_pool_is_the_two_program_paths_at_every_real_position(
+            self, merging_engine):
+        """The same admissions through merged steps and, on an engine of
+        the same weights, through a prefill program and a decode step an
+        iteration: once both have made the same tokens, K and V of every
+        slot agree at every real position."""
+        cfg, params, eng, _ = merging_engine
+        two = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
+                         prefill_chunk=CHUNK, attn_impl="chunked")
+        prompts = _prompts(cfg, (21, 50, 37), seed=5)
+        made = {e: {s: [] for s in range(3)} for e in (eng, two)}
+
+        def iterate(e, plan):
+            """One iteration: a request's first token comes before its
+            decode step's on the two-program path, where a row that ends
+            decodes in the same iteration."""
+            def firsts(results):
+                for (slot, _), (_, tok) in zip(plan, results):
+                    if tok is not None:
+                        made[e][slot].append(tok)
+
+            if plan and e is two:
+                firsts(e.prefill(plan))
+            elif plan:
+                e.stage_rows(plan)
+            for slot, tok in e.decode_step().items():
+                made[e][slot].append(tok)
+            if e is eng:
+                firsts(e.row_results)
+
+        for e in (eng, two):
+            e.admit(0, prompts[0], 12)
+            iterate(e, [(0, 2 * CHUNK)])            # a row alone, no lane
+            e.admit(1, prompts[1], 12)
+            e.admit(2, prompts[2], 12)
+            while not (e.decoding[1] and e.decoding[2]):
+                iterate(e, [(s, CHUNK) for s in (1, 2)
+                            if not e.decoding[s]])
+            while min(len(made[e][s]) for s in range(3)) < 6:
+                iterate(e, [])
+        assert not two.merges or two._decode_greedy_fn._cache_size() == 1
+        for slot in range(3):
+            n = min(len(made[eng][slot]), len(made[two][slot]))
+            assert n >= 6
+            assert made[eng][slot][:n] == made[two][slot][:n]
+        mine, theirs = (_pool_at_real_positions(e) for e in (eng, two))
+        for key in mine:
+            n = min(mine[key].shape[1], theirs[key].shape[1])
+            assert n >= len(prompts[key[1]]) + 5
+            np.testing.assert_allclose(mine[key][:, :n], theirs[key][:, :n],
+                                       rtol=1e-5, atol=1e-5)
+        for e in (eng, two):
+            for slot in range(3):
+                e.release(slot)
+
+    def test_a_rows_write_wins_over_its_slots_masked_lane(
+            self, merging_engine):
+        """A slot mid-prefill is also a masked lane of the step, and its
+        lane writes K and V at its cursor: the position its row's first
+        token is written to in the same execution. The row's must be what
+        stays."""
+        from metaflow_tpu.inference.decode import (decode_forward,
+                                                   init_kv_cache)
+
+        cfg, params, _, _ = merging_engine
+        cache = init_kv_cache(cfg, 3, 64)
+        start, W = 5, CHUNK
+        row = jnp.asarray(_prompts(cfg, (W,), seed=6))
+        # lane 1 is the row's slot, masked, with its cursor at the row's
+        # start and a token that is not the row's first
+        tok = jnp.asarray([[7], [int(row[0, 0]) + 1], [9]])
+        pos = jnp.asarray([3, start, 0])
+        valid = jnp.asarray([[True], [False], [False]])
+        slots, starts = jnp.asarray([1]), jnp.asarray([start])
+        kw = dict(attn_impl="chunked")
+        logits, merged = decode_forward(
+            params, tok, cache, pos, cfg, valid=valid,
+            rows=(row, slots, starts, jnp.asarray([W - 1])), **kw)
+        lane_logits, two = decode_forward(params, tok, cache, pos, cfg,
+                                          valid=valid, **kw)
+        row_logits, two = decode_forward(params, row, two, starts, cfg,
+                                         slots=slots, **kw)
+        assert logits.shape == (3 + 1, 1, cfg.vocab_size)
+        np.testing.assert_allclose(logits[0], lane_logits[0], rtol=1e-4,
+                                   atol=1e-4)
+        np.testing.assert_allclose(logits[3, 0], row_logits[0, -1],
+                                   rtol=1e-4, atol=1e-4)
+        for name in ("k", "v"):
+            for slot, upto in ((0, 4), (1, start + W)):
+                np.testing.assert_allclose(
+                    merged[name][:, slot, :upto], two[name][:, slot, :upto],
+                    rtol=1e-5, atol=1e-5)
+            # and not the lane's: the two differ at that position
+            lanes_only = decode_forward(params, tok, cache, pos, cfg,
+                                        valid=valid, **kw)[1]
+            assert not np.allclose(lanes_only[name][:, 1, start],
+                                   merged[name][:, 1, start])
+
+    def test_twenty_prompt_lengths_compile_nothing(self, merging_engine):
+        """The merged step's shapes are compiled when the scheduler is
+        built, in place of the prefill and first-token programs, none by
+        a request."""
+        cfg, params, eng, _ = merging_engine
+        sched = Scheduler(eng)
+        built = eng.compile_counts()
+        # the three shapes of `prefill_shapes`, and the decode-only step
+        # that earlier tests of the module ran
+        assert built["decode_greedy"] == 4
+        assert built["prefill"] == built["first_token"] == 0
+        lengths = [1, 2, 5, 15, 16, 17, 20, 31, 32, 33, 40, 47, 48, 49,
+                   63, 64, 65, 80, 96, 100]
+        reqs = [sched.submit(Request(p, max_new_tokens=2, rng=i))
+                for i, p in enumerate(_prompts(cfg, lengths, seed=3))]
+        sched.run_until_idle(10_000)
+        assert all(r.reason == "length" for r in reqs)
+        assert eng.compile_counts() == built
+        for req in reqs[3::10]:
+            assert req.generated == _ref_tokens(params, cfg, req)
+
+    def test_a_row_alone_with_no_lane_decoding(self, merging_engine):
+        cfg, params, eng, calls = merging_engine
+        sched = Scheduler(eng)
+        before = _ran(calls)
+        req = sched.submit(Request(_prompts(cfg, (37,), seed=7)[0],
+                                   max_new_tokens=4))
+        held = []
+        while sched.pending():
+            sched.step()
+            held.append(sched._recent[-1][2:4])   # (lanes, rows)
+        # 32 + 5 tokens with no lane decoding, then three decode steps:
+        # the first token came from the second program's row
+        assert held == [(0, 1), (0, 1), (1, 0), (1, 0), (1, 0)]
+        assert req.generated == _ref_tokens(params, cfg, req)
+        assert _ran(calls)["_decode_greedy_fn"] \
+            - before["_decode_greedy_fn"] == len(held) \
+            == sched.stats()["decode_steps"]
+        assert sched.stats()["merged_steps"] == 2
+
+    @pytest.mark.parametrize("first,then", [(0.8, 0.0), (0.0, 0.8)],
+                             ids=["sampled_lanes_greedy_rows",
+                                  "greedy_lanes_sampled_rows"])
+    def test_sampled_and_greedy_requests_side_by_side(
+            self, merging_engine, first, then):
+        """A sampled lane or a sampled row's first token takes the
+        sampled step for that execution; each request's tokens are
+        generate()'s with its own knobs and keys."""
+        cfg, params, eng, calls = merging_engine
+        sched = Scheduler(eng)
+        prompts = _prompts(cfg, (12, 40), seed=8)
+        early = sched.submit(Request(prompts[0], max_new_tokens=14,
+                                     temperature=first, top_k=20, rng=11))
+        for _ in range(3):
+            sched.step()
+        assert early.state == "decode"
+        before = _ran(calls)
+        late = [sched.submit(Request(p, max_new_tokens=5, temperature=then,
+                                     top_p=0.9, rng=12 + i))
+                for i, p in enumerate(prompts[1:])]
+        sched.run_until_idle(10_000)
+        for req in [early] + late:
+            assert req.generated == _ref_tokens(params, cfg, req)
+        after = _ran(calls)
+        assert after["_decode_sampled_fn"] > before["_decode_sampled_fn"]
+        assert after["_first_fn"] == before["_first_fn"]
+        assert eng.compile_counts()["decode_sampled"] <= 4
+
+    def test_a_stack_that_does_not_merge_keeps_its_two_programs(
+            self, family_engine):
+        cfg, params, eng, _ = family_engine   # dense attention: no merge
+        assert not eng.merges
+        eng.admit(0, list(range(1, 20)), 2)
+        with pytest.raises(ValueError, match="does not merge"):
+            eng.stage_rows([(0, CHUNK)])
+        eng.release(0)
+
+
 class TestContinuousBatching:
     def test_mid_flight_admission_no_lockstep(self, setup, engine):
         """More requests than slots, mixed lengths: later requests must

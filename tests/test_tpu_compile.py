@@ -389,14 +389,10 @@ def test_phi4flash_programs_at_the_benchmarks_size(one_chip):
         assert device_bytes(compiled) < 12.5e9
 
 
-@pytest.mark.parametrize("cell", ["mistral-7b.chat-steady",
-                                  "mixtral-8x7b.batch-offline"])
-def test_decode_step_reads_its_pool_as_stored(one_chip, cell):
-    """The chat and batch cells' decode steps at the benchmark's widths,
-    slots and depth (two layers: the loop's body is traced once whatever
-    the depth): attention is the kernel, and no temporary is of the size
-    of one layer of the K pool, whose chunks the loop it replaces sliced
-    and copied."""
+def cell_engine(cell, one_chip):
+    """(engine, the decode step's abstract arguments) of a serving cell
+    at the benchmark's widths, slots and depth, two layers deep (the
+    loop's body is traced once whatever the depth)."""
     from benchmark import configs, weights
     from metaflow_tpu.serving import SlotEngine
 
@@ -412,12 +408,51 @@ def test_decode_step_reads_its_pool_as_stored(one_chip, cell):
     cache = on(jax.eval_shape(lambda: engine._cache), one_chip)
     assert cache["k"].shape == (2, B, 1280, 1024)
     i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
-    decode = engine._decode_greedy_fn.lower(
-        params, cache, i32(B), i32(B), sds((B,), jnp.bool_, one_chip)
-    ).compile()
+    return engine, (params, cache, i32(B), i32(B),
+                    sds((B,), jnp.bool_, one_chip))
+
+
+CELLS_THAT_MERGE = ["mistral-7b.chat-steady", "mixtral-8x7b.batch-offline"]
+
+
+@pytest.mark.parametrize("cell", CELLS_THAT_MERGE)
+def test_decode_step_reads_its_pool_as_stored(one_chip, cell):
+    """The chat and batch cells' decode steps: attention is the kernel,
+    and no temporary is of the size of one layer of the K pool, whose
+    chunks the loop it replaces sliced and copied."""
+    engine, args = cell_engine(cell, one_chip)
+    decode = engine._decode_greedy_fn.lower(*args).compile()
     assert "tpu_custom_call" in decode.as_text()
-    layer = math.prod(cache["k"].shape[1:]) * 2
+    layer = math.prod(args[1]["k"].shape[1:]) * 2
     assert decode.memory_analysis().temp_size_in_bytes < layer / 16
+
+
+@pytest.mark.parametrize("cell", CELLS_THAT_MERGE)
+def test_merged_step_holds_no_second_pool_and_holds_the_kernel(one_chip,
+                                                               cell):
+    """The same two cells' decode step with a prefill program's rows
+    riding in it (the widest row and the two rows; [1, 64] is [1, 128]'s
+    program at half the width, and `benchmark/describe_compile.py` has no
+    part in it: PERF.md section 4 has all three): the lanes' attention is
+    still the kernel that reads the pool as stored, and the rows' writes,
+    views and chunk loop beside it make the compiler copy no pool (the
+    expert layer's buffers at 192 tokens are the batch cell's 47e6 B;
+    one layer of its K pool is 168e6)."""
+    engine, args = cell_engine(cell, one_chip)
+    assert engine.merges
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    layer = math.prod(args[1]["k"].shape[1:]) * 2
+    shapes = engine.prefill_shapes(2 * engine.prefill_chunk)
+    assert shapes == [(1, 64), (1, 128), (2, 64)]
+    for rows, width in shapes[1:]:
+        merged = engine._decode_greedy_fn.lower(*args, {
+            "tokens": i32(rows, width), "slots": i32(rows),
+            "start": i32(rows), "n_real": i32(rows)}).compile()
+        text = merged.as_text()
+        assert "tpu_custom_call" in text and "pool_attention" in text
+        assert merged.memory_analysis().temp_size_in_bytes < layer / 2, \
+            (rows, width)
+        assert device_bytes(merged) < HBM_BYTES
 
 
 def test_paged_engine_steps_llama3_8b_widths(one_chip):
