@@ -633,6 +633,11 @@ def scheduler_metric_families(stats):
     fams.append(Family("tpuflow_serve_decode_steps", "counter",
                        "Batched decode steps executed")
                 .add(stats["decode_steps"]))
+    fams.append(Family("tpuflow_serve_weight_passes", "counter",
+                       "Passes through the stack's weights over the decode "
+                       "steps run: the steps times the model's passes (a "
+                       "looped model runs its stack several times a token)")
+                .add(stats.get("weight_passes", stats["decode_steps"])))
     fams.append(
         Family("tpuflow_serve_prefill", "counter",
                "Prefill programs run, the rows (slots) they carried, the "

@@ -73,6 +73,18 @@ multiplies by a weight, taken apart only for what mixes positions
 (`_merged_layer`): one execution then reads every weight once where the
 slot engine's two programs of an iteration read it twice.
 
+A stack may be run several times over the same weights (a looped model:
+the config declares `passes`, models/ouro.py). "Layer i" is then an
+index into the weights only: a pool has `passes x layers` indices, pass
+t of layer i reads and writes index t * layers + i (each pass attends to
+its own K and V), the layer loop goes round once a pass inside one
+traced body, and the model's norm closes every pass. Where a layer's
+leaves hold `attn_post_norm` / `ffn_post_norm` the sublayer's output is
+normed before it joins the residual (sandwich norms: `_sandwich`).
+`stack_passes`, `cache_pools` and `attention_reads` are where the
+config's `passes` is read; every other config has one pass and traces
+what it traced.
+
 Scope names (jax.named_scope: metadata only, stable across recompiles;
 benchmark/span_readings.py sums device time under them): the layer scan
 is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
@@ -87,7 +99,9 @@ norms, rope and the gate), `retention_chunk` (a chunk) or
 `decode_attention`, `diff_combine` (a differential pair's lambda,
 subtraction and sub-norm), `gmu` (a gated memory unit), and in a prefill
 program whose last layers see one position a row `cross_decoder` around
-those. The pool's write sits
+those; in a stack run several times `loop_pass` around one pass (inside
+`decode_layers`, the layers' scopes inside it), `loop_norm` around the
+norm that closes it and `exit_gate` around the gate. The pool's write sits
 under `kv_cache_update`, its chunk reads under the attention kind's
 scope, a state pool's read and write-back under the `ssm_*` or
 `retention_*` scope that needs them: what lies under `decode_layers` and under none of
@@ -95,7 +109,7 @@ those is the loop's own cost (its counter, the residual stream), and
 anything the compiler still moves without being asked.
 
 Sharding: a KV pool carries the same logical axes as activations
-([layers of its kind, batch, seq or a ring's depth, kv_heads * head_dim],
+([passes x layers of its kind, batch, seq or a ring's depth, kv_heads * head_dim],
 heads major in the folded axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
 tensor axis, so decode parallelizes with the exact rule table training
 uses (spmd/sharding.py); XLA keeps the per-step all-gathers on ICI.
@@ -111,7 +125,7 @@ import numpy as np
 
 from .. import knobs
 from ..exception import TpuFlowException
-from ..models import brumby, jamba, llama, mixtral, phi4flash
+from ..models import brumby, jamba, llama, mixtral, ouro, phi4flash
 from ..ops import (
     decode_attention,
     diff_attention,
@@ -165,6 +179,8 @@ FAMILIES = {
                                "mamba": "mamba_layers"}),
     brumby.BrumbyConfig: Family("brumby", brumby, _dense_ffn, True,
                                 {"retention": "layers"}),
+    ouro.OuroConfig: Family("ouro", ouro, _dense_ffn, True,
+                            {"attention": "layers"}),
     phi4flash.Phi4FlashConfig: Family(
         "phi4flash", phi4flash, _dense_ffn, False,
         {kind: kind + "_layers"
@@ -172,7 +188,9 @@ FAMILIES = {
 }
 
 # A pool a kind of layer carries. shape: (cfg, max_seq_len, widest row=None)
-# -> its shape a slot (the pool is [layers of the kind, slots] + that);
+# -> its shape a slot (the pool is [passes x layers of the kind, slots] +
+# that: `passes` of them where the config runs its stack several times,
+# pass t's of layer i at index t * layers + i);
 # dtype: None for the cache's; recurrent: what it holds is carried from
 # position to position (nothing there is overwritten before it is seen,
 # so it is masked by `valid`, zeroed for a new occupant, and no KV range
@@ -284,11 +302,28 @@ def layer_kinds(cfg):
     return getattr(cfg, "layer_kinds", None) or ("attention",) * cfg.n_layers
 
 
+def stack_passes(cfg):
+    """How many times a token goes through the model's stack, over the
+    same weights (the config's `passes`; 1 where it declares none). Pass
+    t of layer i has pool index t * layers + i: each pass keeps K and V
+    of its own. Only a stack of `attention` layers goes round: what a
+    recurrent state, a ring or a pool that other layers read again is
+    from pass to pass is not defined."""
+    passes = getattr(cfg, "passes", 1)
+    if passes > 1 and set(layer_kinds(cfg)) != {"attention"}:
+        raise TpuFlowException(
+            "a stack that is run %d times over the same weights is built "
+            "for attention layers alone, not for %s"
+            % (passes, sorted(set(layer_kinds(cfg)) - {"attention"})))
+    return passes
+
+
 def cache_pools(cfg):
-    """{pool name: (its Pool, how many layers carry it)} of the model's
-    cache, from what each kind of layer present declares."""
+    """{pool name: (its Pool, how many indices it has: the layers that
+    carry it, times the config's passes)} of the model's cache, from what
+    each kind of layer present declares."""
     kinds = layer_kinds(cfg)
-    return {name: (pool, kinds.count(kind))
+    return {name: (pool, stack_passes(cfg) * kinds.count(kind))
             for kind in sorted(set(kinds))
             for name, pool in POOLS[kind].items()}
 
@@ -315,10 +350,12 @@ def is_recurrent(cfg):
 
 def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None, row=None):
     """The static cache, one tree of the pools the model's kinds of
-    layer declare (`POOLS`), each [layers of the kind, batch] + its shape
-    a slot: `k` and `v` [attention layers, batch, max_seq, kv_heads *
-    head_dim] (for a model with ONE full-attention layer that others
-    read again, that layer's alone); for window layers `win_k` and
+    layer declare (`POOLS`), each [passes x layers of the kind, batch] +
+    its shape a slot (pass t of layer i at index t * layers + i; one pass
+    for every config that declares no `passes`): `k` and `v` [passes x
+    attention layers, batch, max_seq, kv_heads * head_dim] (for a model
+    with ONE full-attention layer that others read again, that layer's
+    alone); for window layers `win_k` and
     `win_v` [.., sliding_window + row, kv_heads * head_dim], a ring
     (`row`: the most positions one program writes into a row; None: no
     bound, and the pool is max_seq deep); for Mamba layers `conv` [..,
@@ -580,9 +617,10 @@ def _decode_lanes(pos, valid, T, mesh):
 
 def attention_reads(cfg, cache, attn_impl="chunked", kernel=True):
     """What a decode step's attention reads, by the shapes alone: for
-    every kind of layer that reads a pool (reading layers, the pool's
-    depth, the most positions a query sees there, how many positions are
-    fetched at a time, how). cache: the pools (their shapes alone are
+    every kind of layer that reads a pool (reads a step: the reading
+    layers, times the config's passes; the pool's depth; the most
+    positions a query sees there; how many positions are fetched at a
+    time; how). cache: the pools (their shapes alone are
     read). How: "kernel" where `kernel` (the decode step runs on a TPU
     with no mesh) and the shapes are the kernel's
     (`ops/decode_attention.py`): each decoding lane in blocks of
@@ -605,7 +643,8 @@ def attention_reads(cfg, cache, attn_impl="chunked", kernel=True):
                 S, width, pool_k.dtype), "kernel"
         else:
             unit, how = min(DECODE_CHUNK, S), "loop"
-        reads.append((kinds.count(kind), S,
+        # a stack run several times reads each layer's pool once a pass
+        reads.append((stack_passes(cfg) * kinds.count(kind), S,
                       cfg.sliding_window if a.window else S, unit, how))
     return reads
 
@@ -688,15 +727,24 @@ def _block_ffn(cfg, x, attn, lp, mesh=None):
     contiguous and paged cache paths."""
     B, T, _ = x.shape
     with jax.named_scope("attn_out"):
-        x = x + _linear(attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp,
-                        "wo", "bo")
+        x = x + _sandwich(cfg, _linear(
+            attn.reshape(B, T, cfg.n_heads * cfg.head_dim), lp, "wo", "bo"),
+            lp, "attn_post_norm")
     return _ffn(cfg, x, lp, mesh)
 
 
 @jax.named_scope("ffn")
 def _ffn(cfg, x, lp, mesh):
     h = _norm(cfg, x, lp, "ffn_norm")
-    return x + family(cfg).ffn(cfg, h, lp, mesh)
+    return x + _sandwich(cfg, family(cfg).ffn(cfg, h, lp, mesh), lp,
+                         "ffn_post_norm")
+
+
+def _sandwich(cfg, out, lp, name):
+    """A sublayer's output on its way into the residual: as it is, or
+    where the layer's leaves hold a post norm `name` (sandwich norms)
+    normed first."""
+    return _norm(cfg, out, lp, name) if name in lp else out
 
 
 def _at(a, last):
@@ -708,9 +756,10 @@ def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
                   mesh=None, attn_impl="dense", slots=None, last=None,
                   lanes=None):
     """One attention block of `kind` (`ATTENTION`) over T new tokens,
-    reading and (a kind that writes) extending layer `layer` (a traced
-    index) of its pools [layers, B, S, KV * Hd], written and read in
-    place: every row of the pool, or with `slots` the rows it names (a
+    reading and (a kind that writes) extending index `layer` (traced;
+    pass and layer: `_layers`) of its pools [passes x layers, B, S,
+    KV * Hd], written and read in place: every row of the pool, or with
+    `slots` the rows it names (a
     pool the loop holds as a view holds just the batch already). The
     feed-forward half is the family's. With `last` ([B]) K and V of all
     T positions are written and the rest of the block, from the queries
@@ -769,9 +818,10 @@ def _decode_layer(cfg, kind, cos, sin, pos, x, layer_params, cache, layer,
 
 def _merged_layer(cfg, cos, sin, pos, x, layer_params, cache, layer, lanes,
                   rows, mesh=None):
-    """One block of a stack of `attention` layers over a decode step's
-    lanes AND a prefill program's rows as one batch, so that the layer's
-    weights are read once for both: x [B + R * W, 1, dim] holds the
+    """One block of a stack of `attention` layers, whose K and V live at
+    index `layer` of the pools (pass and layer: `_layers`), over a decode
+    step's lanes AND a prefill program's rows as one batch, so that the
+    layer's weights are read once for both: x [B + R * W, 1, dim] holds the
     lanes' one new token each, at their cursors `pos` [B], and after them
     the rows' tokens row by row; rows = (slots [R] distinct, start [R],
     W): row r is W tokens of slot slots[r] from position start[r].
@@ -946,12 +996,23 @@ def _retention_layer(cfg, cos, sin, pos, x, lp, cache, layer, valid, slots):
 
 
 def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
-            last=None, rows=None):
+            last=None, rows=None, exits=False):
     """The layer loop of every family: the activations, the whole cache
     and (a model with gated memory units) the memory are its carry, and
     layer i of a kind reads its weights out of that kind's stack and
     reads and writes index i of that kind's pools (of the rows `slots`
-    names, where the batch is not the whole pool). With `last` the layers
+    names, where the batch is not the whole pool). A config that declares
+    `passes` goes round its stack of attention layers that many times
+    over the same weights (`stack_passes`; every other config once, and
+    nothing here is traced for it that was not), and pass t of layer i
+    reads and writes pool index t * layers + i: one traced body whatever
+    the passes, the model's norm
+    applied to the carry at the end of every pass (scope `loop_pass`
+    around a pass, `loop_norm` around the norm that closes it), so that x
+    comes back normed; with `exits` the exit gate reads each pass's
+    normed carry (scope `exit_gate`) and the third value returned is
+    lambda of every pass, [passes, batch, T] float32 (else None). With
+    `last` the layers
     before the config's `tail_layer` run over every position, that layer
     writes K and V of every position, and from its queries on the loop
     runs for position last[b] of each row alone (scope `cross_decoder`):
@@ -960,6 +1021,7 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
     layer is a `_merged_layer` (`merges` says for which stacks)."""
     fam = family(cfg)
     kinds = layer_kinds(cfg)
+    passes = stack_passes(cfg)
     # rope's table is as long as the KV pool is deep; a stack that caches
     # no K and V is bound by the config's positions alone
     cos, sin = rope_frequencies(
@@ -973,19 +1035,22 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
     lanes = None if slots is not None or last is not None else \
         _decode_lanes(pos, valid, x.shape[1], mesh)
 
-    def layers(pos, valid, last=None):
+    def layers(pos, valid, last=None, t=None):
         """The loop's body for new tokens at `pos`, of which `valid`
-        are real; with `last`, the body of the layer that narrows."""
+        are real; with `last`, the body of the layer that narrows; with
+        `t` (traced), the body of pass t of a stack run several times."""
 
         def body(kind, i, carry):
             x, cache, memory = carry
             lp = jamba.layer_at(params[fam.stacks[kind]], i)
+            # where the layer's K and V live in their pools
+            index = i if t is None else t * len(kinds) + i
             if rows is not None:
-                return _merged_layer(cfg, cos, sin, pos, x, lp, cache, i,
+                return _merged_layer(cfg, cos, sin, pos, x, lp, cache, index,
                                      lanes, rows, mesh) + (memory,)
             if kind in ATTENTION:
                 x, cache = _decode_layer(
-                    cfg, kind, cos, sin, pos, x, lp, cache, i, mesh=mesh,
+                    cfg, kind, cos, sin, pos, x, lp, cache, index, mesh=mesh,
                     attn_impl=attn_impl, slots=slots, last=last,
                     lanes=lanes)
                 if last is not None and memory is not None:
@@ -1044,8 +1109,26 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
     with jax.named_scope("decode_layers"):
         cache = dict(cache, **{name: _slot_rows(pool, slots)
                                for name, pool in pools.items()})
-        carry = (x, cache, memory)
-        if last is None:
+        carry, gates = (x, cache, memory), None
+        if passes > 1:
+            def one_pass(t, state):
+                (x, cache, memory), gates = state
+                with jax.named_scope("loop_pass"):
+                    x, cache, memory = jamba.scan_layers(
+                        kinds, layers(pos, valid, t=t), (x, cache, memory))
+                    with jax.named_scope("loop_norm"):
+                        x = _norm(cfg, x, params, "final_norm")
+                    if gates is not None:
+                        with jax.named_scope("exit_gate"):
+                            gates = gates.at[t].set(
+                                fam.module.exit_gate(params, x))
+                return (x, cache, memory), gates
+
+            if exits:
+                gates = jnp.zeros((passes,) + x.shape[:2], jnp.float32)
+            carry, gates = jax.lax.fori_loop(0, passes, one_pass,
+                                             (carry, gates))
+        elif last is None:
             carry = jamba.scan_layers(kinds, layers(pos, valid), carry)
         else:
             cut = cfg.tail_layer
@@ -1060,7 +1143,7 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
         x, cache, _ = carry
         for name, pool in pools.items():
             cache[name] = _put_slot_rows(pool, cache[name], slots)
-    return x, cache
+    return x, cache, gates
 
 
 def merges(cfg, mesh=None, attn_impl="chunked"):
@@ -1077,7 +1160,7 @@ def merges(cfg, mesh=None, attn_impl="chunked"):
 
 def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
                    attn_impl="dense", valid=None, slots=None, last=None,
-                   rows=None):
+                   rows=None, exits=False):
     """Forward over T new tokens at absolute position `pos` (a traced
     scalar, or a traced [B] vector when every batch row decodes at its
     own offset — the continuous-batching engine), reading and extending
@@ -1103,9 +1186,15 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
     W tokens from position start[r], as a program of its own with
     `slots` would, in the same pass over the weights (`_merged_layer`),
     and the head runs for position last[r] of it alone.
+    exits: for a config whose stack is run several times with an exit
+    gate after each pass (`passes`), also return the CDF of the exit
+    distribution after every pass, [passes, B, T] float32 (with `rows`
+    [passes, B + R * W, 1]: the lanes, then the rows' tokens); it ends at
+    1, and with the threshold of 1 that such a config must state it
+    decides nothing: every token runs every pass.
     Returns (logits [B, T, vocab] fp32, or [B, 1, vocab] with `last`, or
     [B + R, 1, vocab] with `rows`: the lanes', then the rows'; updated
-    cache)."""
+    cache), and with `exits` the CDF after them."""
     dtype = llama.param_dtype(cfg)
     x = params["embed"][tokens].astype(dtype)
     if rows is not None:
@@ -1113,19 +1202,24 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
         x = jnp.concatenate(
             [x, params["embed"][row_tokens.reshape(-1, 1)].astype(dtype)])
         rows = (row_slots, row_start, row_tokens.shape[1])
-    x, cache = _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl,
-                       slots, last, rows)
+    x, cache, gates = _layers(cfg, params, x, cache, pos, valid, mesh,
+                              attn_impl, slots, last, rows, exits)
     if rows is not None:   # the lanes, and each row's one position
         B = tokens.shape[0]
         x = jnp.concatenate([x[:B], _at(
             x[B:].reshape(row_tokens.shape + x.shape[2:]), row_last)])
-    x = _norm(cfg, x, params, "final_norm")
+    if stack_passes(cfg) == 1:   # else the last pass's norm has closed it
+        x = _norm(cfg, x, params, "final_norm")
+        if exits:
+            gates = family(cfg).module.exit_gate(params, x)[None]
     if "lm_head" in params:
         logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
                             preferred_element_type=jnp.float32)
     else:   # a head tied to the embedding
         logits = jnp.einsum("btd,vd->btv", x, params["embed"],
                             preferred_element_type=jnp.float32)
+    if exits:
+        return logits, cache, family(cfg).module.exit_cdf(gates)
     return logits, cache
 
 

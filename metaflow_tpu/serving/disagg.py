@@ -45,7 +45,10 @@ MAGIC = b"TPFKV1\n"
 
 def encode_handoff(meta, kv):
     """Frame a KV handoff: `meta` is JSON-safe metadata, `kv` is
-    {"k": [layers, T, kv_heads, head_dim], "v": ...} host arrays."""
+    {"k": [layers, T, kv_heads, head_dim], "v": ...} host arrays, as
+    SlotEngine.extract_kv gave them (`layers` is the pool's leading
+    axis: passes x layers for a stack that is run several times; the
+    header carries the shapes, so the frame takes either)."""
     k = np.ascontiguousarray(kv["k"])
     v = np.ascontiguousarray(kv["v"])
     header = dict(meta)

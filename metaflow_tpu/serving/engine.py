@@ -96,6 +96,18 @@ a prefix of such a model: seed_prefix, extract_kv, admit_prefilled and
 kv_token_bytes refuse it (`refuse_recurrent`), as do the prefix caches,
 the paged engine and the disaggregated handoff built on them.
 
+A model whose stack is run several times over the same weights (the
+config's `passes`: a looped model, models/ouro.py) keeps K and V of
+every pass: the pools' leading axis is passes x layers (pass t of layer
+i at index t * layers + i), a position costs `passes` times a layer
+stack's K and V, and a decode step reads the weights `passes` times
+(`self.passes`; the scheduler's `weight_passes`). Everything here that
+takes a slot's K and V range takes the pool's whole leading axis, so
+seed_prefix, extract_kv, admit_prefilled, kv_token_bytes, the host
+prefix cache and the handoff frame carry passes x layers as they are;
+what lays K and V out by the model's layers (the paged pool and its
+engine and index) refuses such a config (`refuse_looped`).
+
 Token identity with generate(): same forward, same sampling ops (the
 per-slot sampler reproduces decode._sample row-for-row), same rng policy
 (request_step_keys mirrors generate's split sequence), so a request
@@ -126,6 +138,7 @@ from ..inference.decode import (
     merges,
     recurrent_pools,
     ring_pools,
+    stack_passes,
 )
 from ..ops.attention import NEG_INF
 
@@ -158,6 +171,39 @@ def refuse_recurrent(cfg, what):
                              if any(p.recurrent
                                     for p in POOLS[kind].values())),
                 ", ".join(recurrent_pools(cfg))))
+
+
+def refuse_looped(cfg, what):
+    """Raise for `what`, which lays its K and V out by the model's
+    layers, where the config runs its stack several times (`passes`):
+    every pass keeps K and V of its own, so the pool has passes x layers
+    indices and `what` has no pass index."""
+    if stack_passes(cfg) > 1:
+        raise TpuFlowException(
+            "%s is not supported for a %s model whose stack is run %d "
+            "times over the same weights (`passes`): each pass keeps K and "
+            "V of its own, %d pool indices where it lays out %d layers; "
+            "serve it through the slot engine" % (
+                what, family(cfg).name, stack_passes(cfg),
+                stack_passes(cfg) * cfg.n_layers, cfg.n_layers))
+
+
+def auto_attention(cfg, cache, max_seq_len, mesh=None):
+    """What `attn_impl="auto"` picks for slots `max_seq_len` deep over
+    the pools `cache`, by the shapes: "chunked" past 2 * DECODE_CHUNK
+    positions (generate()'s threshold: a choice, no chip has timed where
+    the two cross), and at ANY depth where every attention read of the
+    decode step is the kernel's (one chip, shapes
+    `ops/decode_attention.py` takes: `attention_reads`): the kernel reads
+    each decoding lane to its own depth and no other lane, so it never
+    fetches more than the dense read of every lane's whole pool, and only
+    a chunked stack lets the prefill rows ride in the decode step
+    (`merges`). Else "dense"."""
+    if max_seq_len > 2 * DECODE_CHUNK:
+        return "chunked"
+    reads = attention_reads(cfg, cache, "chunked", kernel=mesh is None)
+    return "chunked" if reads and all(
+        how == "kernel" for *_, how in reads) else "dense"
 
 
 def sample_slots(logits, keys, temperature, top_k, top_p):
@@ -222,10 +268,6 @@ class SlotEngine(object):
         self.pad_id = int(pad_id)
         self.min_bucket = min(int(min_bucket), self.prefill_chunk)
         self.mesh = mesh
-        if attn_impl == "auto":
-            attn_impl = ("chunked" if self.max_seq_len > 2 * DECODE_CHUNK
-                         else "dense")
-        self.attn_impl = attn_impl
         self._vocab = cfg.vocab_size
 
         # the widest row a prefill program may carry (the scheduler's
@@ -234,6 +276,10 @@ class SlotEngine(object):
         self.prefill_row = 2 * self.prefill_chunk
         self._cache = init_kv_cache(cfg, self.max_slots, self.max_seq_len,
                                     dtype=cache_dtype, row=self.prefill_row)
+        if attn_impl == "auto":
+            attn_impl = auto_attention(cfg, self._cache, self.max_seq_len,
+                                       mesh)
+        self.attn_impl = attn_impl
         # a config with a tail layer: a prefill program's logits are of
         # each row's last real position alone
         self._tail = getattr(cfg, "tail_layer", None) is not None
@@ -244,6 +290,9 @@ class SlotEngine(object):
                 "max_seq_len %d passes the config's %d, where rope's table "
                 "ends" % (self.max_seq_len, cfg.max_seq_len))
         self._attention_reads = None   # attention_positions() fills it
+        # how many times a step goes through the stack's weights (a
+        # looped model; the pools then have passes x layers indices)
+        self.passes = stack_passes(cfg)
         self._recurrent_pools = recurrent_pools(cfg)
         self.recurrent = bool(self._recurrent_pools)
         # a stack of attention layers on one chip: an iteration's prefill
@@ -532,7 +581,9 @@ class SlotEngine(object):
 
     def seed_prefix(self, slot, kv):
         """Copy a cached KV range ({"k": [layers, T, kv_heads,
-        head_dim], "v": ...}, host arrays) into the slot's cache view at
+        head_dim], "v": ...}, host arrays; `layers` is the pool's leading
+        axis, passes x layers for a stack run several times: what
+        `extract_kv` gave) into the slot's cache view at
         positions [0, T) and move the prefill cursor to T, so chunked
         prefill resumes at the match boundary. Must run after admit(),
         before the slot's first prefill; T must be < the slot's prompt
@@ -572,8 +623,10 @@ class SlotEngine(object):
 
     def extract_kv(self, slot, length):
         """The first `length` cache positions of a slot as host arrays
-        ({"k": [layers, length, kv_heads, head_dim], "v": ...}) — the
-        prefix-cache insert / disaggregation handoff read path. The
+        ({"k": [layers, length, kv_heads, head_dim], "v": ...}; `layers`
+        is the pool's leading axis: every pass's K and V of a stack run
+        several times, passes x layers) — the prefix-cache insert /
+        disaggregation handoff read path. The
         device slice uses a power-of-two bucket (static shape, bounded
         compiles) and trims on host."""
         refuse_recurrent(self.cfg, "extract_kv (prefix-cache insert, "

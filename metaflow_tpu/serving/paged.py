@@ -69,7 +69,8 @@ from ..inference.decode import (
 from ..models import llama
 from ..ops import rms_norm
 from ..ops.rope import rope_frequencies
-from .engine import refuse_recurrent, request_step_keys, sample_slots
+from .engine import (refuse_looped, refuse_recurrent, request_step_keys,
+                     sample_slots)
 
 DEFAULT_PAGE_TOKENS = 16
 
@@ -107,6 +108,8 @@ class PagePool(object):
             raise ValueError("n_pages must be >= 2 (page 0 is scratch)")
         if page_tokens < 1:
             raise ValueError("page_tokens must be >= 1")
+        # pages are [layers, ...]: no pass index (serving/engine.py)
+        refuse_looped(cfg, "a paged KV pool")
         dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
         shape = (cfg.n_layers, int(n_pages), int(page_tokens),
                  cfg.n_kv_heads, cfg.head_dim)
@@ -311,6 +314,8 @@ class PagedEngine(object):
                              "'chunked', got %r" % (attn_impl,))
         # pages hold K and V only: no page table for a recurrent state
         refuse_recurrent(cfg, "the paged engine")
+        # its one scan over `params["layers"]` is one pass over the stack
+        refuse_looped(cfg, "the paged engine")
         self.params = params
         self.cfg = cfg
         self.max_slots = int(max_slots)

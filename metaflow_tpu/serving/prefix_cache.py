@@ -211,7 +211,9 @@ class RadixPrefixCache(object):
 
     def insert(self, tokens, kv):
         """Cache the KV for `tokens` (kv: {"k": [layers, T, kv_heads,
-        head_dim], "v": ...}, T == len(tokens)). Shared prefixes with
+        head_dim], "v": ...}, T == len(tokens); `layers` is whatever the
+        engine's pool leads with, passes x layers for a stack run several
+        times: ranges are cut along T alone). Shared prefixes with
         existing entries are deduplicated via node splits; only the
         novel suffix adds bytes. Evicts LRU leaves if over budget."""
         tokens = _as_tokens(tokens)

@@ -272,6 +272,9 @@ class Scheduler(object):
         # decode_steps AND one of prefill_programs, ONE execution
         self.merged_steps = 0
         self._rows_staged = None   # (requests, slots, tokens) of them
+        # how many times a decode step goes through the stack's weights
+        # (a looped model's `passes`; 1 for every other)
+        self._passes = getattr(engine, "passes", 1)
         # over the decode steps run: the K and V positions the decoding
         # lanes' queries saw, over all reading layers, and those the
         # program fetched for them (engine.attention_positions)
@@ -819,7 +822,8 @@ class Scheduler(object):
         if not active and staged is None:
             return 0
         reqs, slots, row_tokens = staged or ((), (), 0)
-        stats = {"prefill_rows": len(reqs), "prefill_tokens": row_tokens}
+        stats = {"prefill_rows": len(reqs), "prefill_tokens": row_tokens,
+                 "passes": self._passes}
         positions = getattr(self.engine, "attention_positions", None)
         if positions is not None:   # from the cursors, before they move
             needed, fetched = positions()
@@ -1003,6 +1007,7 @@ class Scheduler(object):
             "served": self.served,
             "cancelled": self.cancelled_count,
             "decode_steps": self.decode_steps,
+            "weight_passes": self.decode_steps * self._passes,
             "prefill_programs": self.prefill_programs,
             "prefill_rows": self.prefill_rows,
             "prefill_tokens": self.prefill_tokens,

@@ -293,7 +293,7 @@ class TestMergedIteration:
         steps = [e for e in events if e[1] == "serve.decode_step"]
         assert chunks and all(
             {"prefill_rows", "prefill_tokens", "active", "positions_needed",
-             "positions_fetched"} <= set(e[4]) for e in steps)
+             "positions_fetched", "passes"} <= set(e[4]) for e in steps)
         merged = [e for e in steps if e[4]["prefill_rows"]]
         assert len(merged) == len(chunks) == stats["merged_steps"] \
             == stats["prefill_programs"]

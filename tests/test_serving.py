@@ -971,6 +971,10 @@ class TestPhaseLedger:
             == stats["attention_positions_needed"]
         assert sum(d["positions_fetched"] for d in steps) \
             == stats["attention_positions_fetched"]
+        # one pass through the weights a step, for every stack that
+        # declares no `passes`
+        assert all(d["passes"] == 1 for d in steps)
+        assert stats["weight_passes"] == stats["decode_steps"]
 
     def test_the_slowest_iterations_phase_by_phase(self, engine):
         sched = Scheduler(engine)

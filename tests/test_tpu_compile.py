@@ -455,6 +455,45 @@ def test_merged_step_holds_no_second_pool_and_holds_the_kernel(one_chip,
         assert device_bytes(merged) < HBM_BYTES
 
 
+def test_a_looped_stack_carries_its_pool_through_every_pass(one_chip):
+    """The benchmark's Ouro configuration at its widths, slots and depth,
+    two layers deep and its four passes (the loop over the passes and the
+    loop over the layers are each traced once whatever they count): the
+    pool has passes x layers indices; the decode step, alone and with two
+    rows riding in it, reads it through the kernel as stored and writes
+    it in place in every pass: beside the compiler's relaid copies of the
+    `wq` and `wk` stacks (whole, once a program: PERF.md section 7) the
+    temporaries hold nothing of the size of ONE index of the K pool."""
+    from benchmark import configs, weights
+    from metaflow_tpu.serving import SlotEngine
+
+    _, _, config, _ = configs.load_cell("ouro-2.6b.chat-short")
+    config["num_hidden_layers"], serving = 2, config["serving"]
+    _, cfg = configs.program_config(config, serving["max_seq_len"])
+    params = on(jax.eval_shape(lambda: weights.init_params(
+        jax.random.PRNGKey(0), configs.dims(config))), one_chip)
+    B = serving["slots"]
+    engine = SlotEngine(params, cfg, max_slots=B,
+                        max_seq_len=serving["max_seq_len"],
+                        prefill_chunk=serving["prefill_chunk"])
+    assert engine.merges and engine.passes == 4
+    cache = on(jax.eval_shape(lambda: engine._cache), one_chip)
+    assert cache["k"].shape == (4 * 2, B, serving["max_seq_len"], 16 * 128)
+    index = math.prod(cache["k"].shape[1:]) * 2
+    relaid = 2 * math.prod(params["layers"]["wq"].shape) * 2
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    args = (params, cache, i32(B), i32(B), sds((B,), jnp.bool_, one_chip))
+    rows = {"tokens": i32(2, 64), "slots": i32(2), "start": i32(2),
+            "n_real": i32(2)}
+    for extra in ((), (rows,)):
+        step = engine._decode_greedy_fn.lower(*args, *extra).compile()
+        text = step.as_text()
+        assert "tpu_custom_call" in text and "pool_attention" in text
+        assert step.memory_analysis().temp_size_in_bytes \
+            < relaid + index / 8, extra
+        assert device_bytes(step) < HBM_BYTES
+
+
 def test_paged_engine_steps_llama3_8b_widths(one_chip):
     from metaflow_tpu.serving import PagedEngine
 
