@@ -648,6 +648,13 @@ def scheduler_metric_families(stats):
         .add(stats["prefill_tokens"], {"count": "tokens"})
         .add(stats.get("merged_steps", 0), {"count": "merged_steps"}))
     fams.append(
+        Family("tpuflow_serve_admissions", "counter",
+               "Requests bound to a slot, and how many of them drew their "
+               "sampling keys (on the device, when a sampled token was "
+               "first asked for; none under greedy traffic)")
+        .add(stats.get("admitted", 0), {"count": "admitted"})
+        .add(stats.get("key_schedules", 0), {"count": "key_schedules"}))
+    fams.append(
         Family("tpuflow_serve_attention_positions", "counter",
                "K and V positions over the decode steps run and all "
                "reading layers: those the decoding lanes' queries saw, "

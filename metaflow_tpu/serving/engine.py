@@ -17,7 +17,8 @@ ring of the window and the widest prefill row, recurrent states (below).
 It is the carry of decode_forward's layer loop, donated and updated in
 place.
 Each slot holds at most one in-flight request and carries host-side
-state (pos, sampling knobs, per-token rng keys). Three compiled programs
+state (pos, sampling knobs, per-token rng keys: drawn when a sampled
+token is first asked for, `KeySchedules`). Three compiled programs
 cover everything:
 
   - prefill: ONE execution an iteration writes the next prompt tokens of
@@ -157,6 +158,54 @@ def request_step_keys(rng, max_new_tokens):
     return np.asarray(first)[None]
 
 
+_ZERO_KEY = np.zeros(2, np.uint32)
+_ZERO_KEY.setflags(write=False)
+
+
+class KeySchedules(object):
+    """The slots' sampling keys, for SlotEngine and PagedEngine alike:
+    `admit` records the request's rng and length (`_bind_keys`) and draws
+    nothing; `_keys_for(slot)` draws the occupant's schedule
+    (`request_step_keys`: two splits and two fetches that wait for the
+    device, under the span `engine.admit.keys`, inside whatever phase
+    asks) the first time one of its keys is asked for and keeps it until
+    `release`. A key of an occupant at temperature 0 is the zero key,
+    which `sample_slots` never reads there (it returns the argmax), so a
+    greedy request's admission touches no device. `_key_cursor` counts
+    every slot's tokens, drawn or not; `key_schedules` counts the
+    schedules drawn (0 under greedy traffic, the admissions under
+    sampled). Reads the engine's `_temp` and `phases`."""
+
+    def _init_keys(self, slots):
+        self._keys = np.zeros((slots, 2), np.uint32)  # current step key
+        self._key_request = [None] * slots        # (rng, max_new_tokens)
+        self._step_keys = [None] * slots          # [max_new, 2] once drawn
+        self._key_cursor = np.zeros(slots, np.int32)
+        self.key_schedules = 0
+
+    def _bind_keys(self, slot, rng, max_new_tokens):
+        self._key_request[slot] = (rng, max_new_tokens)
+        self._step_keys[slot] = None
+        self._key_cursor[slot] = 0
+
+    def _drop_keys(self, slot):
+        self._key_request[slot] = self._step_keys[slot] = None
+
+    def _keys_for(self, slot):
+        if self._temp[slot] <= 0.0:
+            return _ZERO_KEY
+        keys = self._step_keys[slot]
+        if keys is None:
+            with self.phases("engine.admit.keys"):
+                keys = self._step_keys[slot] = request_step_keys(
+                    *self._key_request[slot])
+            self.key_schedules += 1
+        cursor = int(self._key_cursor[slot])
+        if cursor >= len(keys):
+            raise ValueError("slot %d ran past its key schedule" % slot)
+        return keys[cursor]
+
+
 def refuse_recurrent(cfg, what):
     """Raise for `what`, which treats a KV range as a prefix, where the
     model also carries recurrent state: the K and V of the positions
@@ -238,7 +287,7 @@ def sample_slots(logits, keys, temperature, top_k, top_p):
     return jnp.where(is_greedy, greedy, sampled.astype(jnp.int32))
 
 
-class SlotEngine(object):
+class SlotEngine(KeySchedules):
     """Fixed pool of decode slots over one shared static KV cache.
 
     Host-side bookkeeping (which slot holds which request, positions,
@@ -310,10 +359,8 @@ class SlotEngine(object):
         self._temp = np.zeros(B, np.float32)
         self._top_k = np.full(B, self._vocab, np.int32)
         self._top_p = np.ones(B, np.float32)
-        self._keys = np.zeros((B, 2), np.uint32)  # current step key
-        self._step_keys = [None] * B              # [max_new, 2] per slot
+        self._init_keys(B)                        # per-token rng keys
         self._slot_ctx = [None] * B               # request trace context
-        self._key_cursor = np.zeros(B, np.int32)
         self._prompt = [None] * B                 # remaining host prompt
         self._prefill_cursor = np.zeros(B, np.int32)
         # device mirrors of the decode-step inputs: steady-state decode
@@ -557,9 +604,7 @@ class SlotEngine(object):
         self._top_k[slot] = (self._vocab if top_k is None
                              else min(int(top_k), self._vocab))
         self._top_p[slot] = 1.0 if top_p is None else float(top_p)
-        with self.phases("engine.admit.keys"):
-            self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
-        self._key_cursor[slot] = 0
+        self._bind_keys(slot, rng, max_new_tokens)
         self._dirty = True
         if self.recurrent:
             with self.phases("engine.state.reset", slot=int(slot)):
@@ -686,7 +731,7 @@ class SlotEngine(object):
         self.decoding[slot] = False
         self.pos[slot] = 0  # park the masked-lane write cursor
         self._prompt[slot] = None
-        self._step_keys[slot] = None
+        self._drop_keys(slot)
         self._temp[slot] = 0.0
         self._top_k[slot] = self._vocab
         self._top_p[slot] = 1.0
@@ -765,6 +810,9 @@ class SlotEngine(object):
         steps for the other slots interleave). The first tokens of all
         rows are fetched in one wait."""
         slots, start, n_real, tokens, ends = self._rows_of(plan)
+        # before the program is queued: a sampled request's schedule is
+        # drawn here, and its fetch would wait behind the program
+        sampling = self._row_sampling(slots, ends) if ends.any() else None
         self.launches += 1
         launch = self.launches
         with self.phases("engine.prefill.dispatch", launch=launch):
@@ -780,7 +828,7 @@ class SlotEngine(object):
                 logits,
                 jnp.asarray(np.zeros_like(n_real) if self._tail
                             else n_real - 1),
-                *map(jnp.asarray, self._row_sampling(slots, ends)))
+                *map(jnp.asarray, sampling))
             with self.phases("engine.first_token.fetch", awaits=launch):
                 first = np.asarray(first)   # the host waits here, once
             self._rows_done(slots[ends], first[ends])
@@ -827,9 +875,9 @@ class SlotEngine(object):
     def _row_sampling(self, slots, ends):
         """(keys [R, 2], temperature, top_k, top_p [R]) for the rows'
         first tokens: a row that ends its prompt samples with its
-        request's first key and knobs; the others sample too, greedily,
-        and are not read."""
-        return (np.stack([self._keys_for(s) if e else np.zeros(2, np.uint32)
+        request's first key (the zero key at temperature 0) and knobs;
+        the others sample too, greedily, and are not read."""
+        return (np.stack([self._keys_for(s) if e else _ZERO_KEY
                           for s, e in zip(slots, ends)]),
                 np.where(ends, self._temp[slots], 0.0).astype(np.float32),
                 self._top_k[slots], self._top_p[slots])
@@ -865,13 +913,6 @@ class SlotEngine(object):
         """The one-row case of `prefill`: the next chunk of `slot`.
         Returns (tokens_consumed, first_token_or_None)."""
         return self.prefill([(slot, self.prefill_chunk)])[0]
-
-    def _keys_for(self, slot):
-        keys = self._step_keys[slot]
-        cursor = int(self._key_cursor[slot])
-        if cursor >= len(keys):
-            raise ValueError("slot %d ran past its key schedule" % slot)
-        return keys[cursor]
 
     def decode_step(self):
         """One fused decode step over the WHOLE pool. Returns a dict

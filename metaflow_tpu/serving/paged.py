@@ -69,7 +69,7 @@ from ..inference.decode import (
 from ..models import llama
 from ..ops import rms_norm
 from ..ops.rope import rope_frequencies
-from .engine import (refuse_looped, refuse_recurrent, request_step_keys,
+from .engine import (KeySchedules, refuse_looped, refuse_recurrent,
                      sample_slots)
 
 DEFAULT_PAGE_TOKENS = 16
@@ -292,7 +292,7 @@ def _paged_forward(params, tokens, pool_kv, tables, pos, cfg,
     return logits, {"k": new_k, "v": new_v}
 
 
-class PagedEngine(object):
+class PagedEngine(KeySchedules):
     """SlotEngine-compatible engine over a paged KV pool.
 
     Same API surface the scheduler drives (admit/prefill/
@@ -362,10 +362,8 @@ class PagedEngine(object):
         self._temp = np.zeros(B, np.float32)
         self._top_k = np.full(B, self._vocab, np.int32)
         self._top_p = np.ones(B, np.float32)
-        self._keys = np.zeros((B, 2), np.uint32)
-        self._step_keys = [None] * B
+        self._init_keys(B)
         self._slot_ctx = [None] * B
-        self._key_cursor = np.zeros(B, np.int32)
         self._prompt = [None] * B
         self._prefill_cursor = np.zeros(B, np.int32)
         self._max_new = np.zeros(B, np.int32)
@@ -593,9 +591,7 @@ class PagedEngine(object):
         self._top_k[slot] = (self._vocab if top_k is None
                              else min(int(top_k), self._vocab))
         self._top_p[slot] = 1.0 if top_p is None else float(top_p)
-        with self.phases("engine.admit.keys"):
-            self._step_keys[slot] = request_step_keys(rng, max_new_tokens)
-        self._key_cursor[slot] = 0
+        self._bind_keys(slot, rng, max_new_tokens)
         self._max_new[slot] = int(max_new_tokens)
         self._emitted[slot] = 0
         self._context[slot] = [int(t) for t in prompt]
@@ -623,7 +619,7 @@ class PagedEngine(object):
         self.decoding[slot] = False
         self.pos[slot] = 0
         self._prompt[slot] = None
-        self._step_keys[slot] = None
+        self._drop_keys(slot)
         self._context[slot] = None
         self._temp[slot] = 0.0
         self._top_k[slot] = self._vocab
@@ -789,6 +785,9 @@ class PagedEngine(object):
         if bucket > chunk.size:
             chunk = np.concatenate([
                 chunk, np.full(bucket - chunk.size, self.pad_id, np.int32)])
+        # before the program is queued: a sampled request's schedule is
+        # drawn here, and its fetch would wait behind the program
+        key = self._keys_for(slot) if end == prompt.size else None
         self.launches += 1
         launch = self.launches
         with self.phases("engine.prefill.dispatch", launch=launch):
@@ -803,7 +802,7 @@ class PagedEngine(object):
             return consumed, None
         first = self._first_fn(
             logits, jnp.int32(prompt.size - 1 - start),
-            jnp.asarray(self._keys_for(slot)),
+            jnp.asarray(key),
             jnp.float32(self._temp[slot]), jnp.int32(self._top_k[slot]),
             jnp.float32(self._top_p[slot]))
         with self.phases("engine.first_token.fetch", awaits=launch):
@@ -816,13 +815,6 @@ class PagedEngine(object):
         self._context[slot].append(first)
         self._dirty = True
         return consumed, first
-
-    def _keys_for(self, slot):
-        keys = self._step_keys[slot]
-        cursor = int(self._key_cursor[slot])
-        if cursor >= len(keys):
-            raise ValueError("slot %d ran past its key schedule" % slot)
-        return keys[cursor]
 
     def _stage(self):
         if self._dirty:

@@ -272,6 +272,10 @@ class Scheduler(object):
         # decode_steps AND one of prefill_programs, ONE execution
         self.merged_steps = 0
         self._rows_staged = None   # (requests, slots, tokens) of them
+        # the requests bound to a slot; the engine's `key_schedules`
+        # beside it says how many of them drew their sampling keys (none
+        # under greedy traffic: engine.KeySchedules)
+        self.admitted = 0
         # how many times a decode step goes through the stack's weights
         # (a looped model's `passes`; 1 for every other)
         self._passes = getattr(engine, "passes", 1)
@@ -641,6 +645,7 @@ class Scheduler(object):
             req.admit_iteration = self.iteration
             self._slots[slot] = req
             admitted += 1
+            self.admitted += 1
             self.peak_in_flight = max(self.peak_in_flight,
                                       len(self._slots))
             if self._paged:
@@ -1012,6 +1017,8 @@ class Scheduler(object):
             "prefill_rows": self.prefill_rows,
             "prefill_tokens": self.prefill_tokens,
             "merged_steps": self.merged_steps,
+            "admitted": self.admitted,
+            "key_schedules": getattr(self.engine, "key_schedules", 0),
             "iterations": self.iteration,
             "draining": self._draining,
             # rolling-window tail latency (the SLO monitor's poll surface)

@@ -133,6 +133,66 @@ class TestPagedTokenIdentity:
         _assert_pool_free(engine)
 
 
+class TestPagedKeySchedules:
+    """The paged engine takes the slot engine's key schedules
+    (engine.KeySchedules): drawn when a sampled token is first asked
+    for, never for a greedy request."""
+
+    @pytest.mark.parametrize("case", ["greedy", "sampled-beside-greedy",
+                                      "sampled-admit-prefilled"])
+    def test_keys_are_drawn_when_a_sampled_token_is_first_asked_for(
+            self, setup, engine, monkeypatch, case):
+        from metaflow_tpu.serving import engine as engine_module
+
+        cfg, params = setup
+        drawn, sched = engine.key_schedules, Scheduler(engine)
+        prompts = [list(range(2 + i, 2 + i + n))
+                   for i, n in enumerate((PTOK - 3, PTOK + 5, 2 * PTOK))]
+        if case == "greedy":
+            def refuse(*args, **kw):
+                raise AssertionError("a greedy request drew sampling keys")
+
+            monkeypatch.setattr(engine_module, "request_step_keys", refuse)
+            monkeypatch.setattr(jax.random, "split", refuse)
+            reqs = [sched.submit(Request(p, max_new_tokens=3 + 4 * i,
+                                         rng=i))
+                    for i, p in enumerate(prompts)]
+            sched.run_until_idle(10_000)
+            monkeypatch.undo()
+            sampled = []
+        else:
+            knobs = dict(max_new_tokens=6, temperature=0.8, top_p=0.9,
+                         rng=77)
+            if case == "sampled-admit-prefilled":
+                pre = sched.submit(Request(prompts[2], prefill_only=True,
+                                           **knobs))
+                sched.run_until_idle(10_000)
+                assert engine.key_schedules == drawn + 1
+                drawn, sched = drawn + 1, Scheduler(engine)
+                knobs["prefilled"] = pre.handoff
+            reqs = [sched.submit(Request(p, max_new_tokens=PTOK, rng=i))
+                    for i, p in enumerate(prompts[:2])]
+            while not all(r.state == "decode" for r in reqs):
+                sched.step()
+            assert engine.key_schedules == drawn
+            sampled = [sched.submit(Request(prompts[2], **knobs))]
+            lanes = []
+            while sampled[0].state != "finished":
+                sched.step()
+                lanes.append(sched._recent[-1][2])
+            assert max(lanes) == 3   # beside the greedy lanes, one step
+            reqs += sampled
+            sched.run_until_idle(10_000)
+        for req in reqs:
+            assert req.reason == "length"
+            assert req.generated == _ref_tokens(params, cfg, req)
+        assert engine.key_schedules - drawn == len(sampled) \
+            == sched.stats()["key_schedules"] - drawn
+        assert sched.phases.calls.get("engine.admit.keys", 0) \
+            == len(sampled)
+        _assert_pool_free(engine)
+
+
 class TestZeroCopySharing:
     @pytest.fixture()
     def shared(self, setup):
@@ -417,11 +477,13 @@ class TestPagedPhases:
             self, engine):
         """One ledger holds the whole iteration whichever engine runs
         it: the paged engine's engine.* phases and its launch count."""
-        before = engine.launches
+        before, drawn = engine.launches, engine.key_schedules
         sched = Scheduler(engine)
         assert engine.phases is sched.phases
+        # the last request sampled: it alone draws its keys
         reqs = [sched.submit(Request(list(range(1, 12 + 9 * i)),
-                                     max_new_tokens=5, rng=i))
+                                     max_new_tokens=5, rng=i,
+                                     temperature=0.7 * (i == 2)))
                 for i in range(3)]
         sched.run_until_idle(10_000)
         assert all(r.reason == "length" for r in reqs)
@@ -431,8 +493,10 @@ class TestPagedPhases:
         # one slot and one chunk an execution: a program of the
         # scheduler's is one or more of this engine's
         assert calls["engine.prefill.dispatch"] >= sched.prefill_programs
-        assert calls["engine.first_token.fetch"] \
-            == calls["engine.admit.keys"] == len(reqs)
+        assert calls["engine.first_token.fetch"] == len(reqs)
+        assert calls["engine.admit.keys"] == 1 \
+            == engine.key_schedules - drawn \
+            == sched.stats()["key_schedules"] - drawn
         assert engine.launches - before == calls["engine.decode.dispatch"] \
             + calls["engine.prefill.dispatch"]
         assert took["engine.decode.dispatch"] + took["engine.decode.fetch"] \

@@ -34,8 +34,13 @@ DECODE_SCOPES = ("decode_layers", "attn_qkv", "kv_cache_update",
 MOE_SCOPES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine")
 
 
+SAMPLED = 2   # of PROMPTS: the one request that draws sampling keys
+
+
 def _serve(sched):
-    reqs = [sched.submit(Request(p, max_new_tokens=5)) for p in PROMPTS]
+    reqs = [sched.submit(Request(p, max_new_tokens=5, rng=n,
+                                 temperature=0.8 * (n == SAMPLED)))
+            for n, p in enumerate(PROMPTS)]
     return reqs, [r.result(timeout=120) for r in reqs]
 
 
@@ -147,7 +152,14 @@ class TestSchedulerSpans:
         assert all(d[4]["tokens"] >= 1 for d in deliver)
         assert sum(e[4]["admitted"] for e in line
                    if e[1] == "serve.admit") == len(PROMPTS)
-        parents("engine.admit.keys", "serve.admit")
+        # a schedule is drawn when a sampled token is first asked for,
+        # in the program that ends the prompt: once a SAMPLED request,
+        # never inside serve.admit, and not at all for a greedy one
+        parents("engine.admit.keys", "serve.prefill_chunk")
+        keys = [e for e in line if e[1] == "engine.admit.keys"]
+        assert len(keys) == 1
+        assert not any(_inside(a, keys[0]) for a in line
+                       if a[1] == "serve.admit")
         # nothing of the loop's or the engine's lies between iterations:
         # a reader takes every such span for the host at work
         for e in line:
@@ -305,6 +317,11 @@ class TestMergedIteration:
             == sum(len(p) for p in PROMPTS) == stats["prefill_tokens"]
         # a step with rows and no lane is a step all the same
         assert any(e[4]["active"] == 0 for e in merged)
+        # the one sampled request's keys are drawn in the merged step
+        # whose row ends its prompt, before that step's program is called
+        keys = [e for e in events if e[1] == "engine.admit.keys"]
+        assert len(keys) == 1 and sum(
+            _inside(step, keys[0]) for step in merged) == 1
         for it in iterations:
             inside = [e for e in events if e is not it and _inside(it, e)]
             chunk = [e for e in inside if e[1] == "serve.prefill_chunk"]

@@ -528,6 +528,91 @@ class TestRowsRideInTheDecodeStep:
         eng.release(0)
 
 
+# ---- sampling keys drawn at first use, not at admit (PR 42) ----
+
+@pytest.fixture(scope="module")
+def chunked_engine(setup):
+    """A tiny Llama engine whose stack merges (the chunk loop)."""
+    cfg, params = setup
+    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
+                     prefill_chunk=CHUNK, attn_impl="chunked")
+    assert eng.merges
+    return eng
+
+
+def _refuse_keys(monkeypatch):
+    """Any draw of a key schedule fails, by either of its names."""
+    from metaflow_tpu.serving import engine as engine_module
+
+    def refuse(*args, **kw):
+        raise AssertionError("a greedy request drew sampling keys")
+
+    monkeypatch.setattr(engine_module, "request_step_keys", refuse)
+    monkeypatch.setattr(jax.random, "split", refuse)
+
+
+class TestKeySchedules:
+    """A slot's key schedule is drawn the first time a key of it is asked
+    for, and nothing asks for a greedy request's: `key_schedules` and the
+    span `engine.admit.keys` count the draws."""
+
+    @pytest.mark.parametrize("case", [
+        "greedy-merged", "greedy-two-programs", "sampled-row-merged",
+        "sampled-row-two-programs", "sampled-admit-prefilled-merged",
+        "sampled-admit-prefilled-two-programs"])
+    def test_keys_are_drawn_when_a_sampled_token_is_first_asked_for(
+            self, setup, engine, chunked_engine, monkeypatch, case):
+        cfg, params = setup
+        eng = chunked_engine if case.endswith("merged") else engine
+        assert eng.merges == case.endswith("merged")
+        drawn, sched = eng.key_schedules, Scheduler(eng)
+        prompts = _prompts(cfg, (12, 23, 40), seed=42)
+        if case.startswith("greedy"):
+            # admitted, prefilled and decoded to their ends with no draw
+            _refuse_keys(monkeypatch)
+            reqs = [sched.submit(Request(p, max_new_tokens=3 + 4 * i,
+                                         rng=i))
+                    for i, p in enumerate(prompts)]
+            sched.run_until_idle(10_000)
+            monkeypatch.undo()
+            sampled = []
+        else:
+            knobs = dict(max_new_tokens=6, temperature=0.8, top_k=20,
+                         rng=77)
+            if "admit-prefilled" in case:
+                # prefilled elsewhere: the handoff's own admission draws
+                # a schedule for the first token, this one for the rest
+                pre = sched.submit(Request(prompts[2], prefill_only=True,
+                                           **knobs))
+                sched.run_until_idle(10_000)
+                assert eng.key_schedules == drawn + 1
+                drawn, sched = drawn + 1, Scheduler(eng)
+                knobs["prefilled"] = pre.handoff
+            # greedy lanes decode; a sampled request joins them in a step
+            reqs = [sched.submit(Request(p, max_new_tokens=16, rng=i))
+                    for i, p in enumerate(prompts[:2])]
+            while not all(r.state == "decode" for r in reqs):
+                sched.step()
+            assert eng.key_schedules == drawn
+            sampled = [sched.submit(Request(prompts[2], **knobs))]
+            lanes = []
+            while sampled[0].state != "finished":
+                sched.step()
+                lanes.append(sched._recent[-1][2])
+            assert max(lanes) == 3   # beside the greedy lanes, one step
+            reqs += sampled
+            sched.run_until_idle(10_000)
+        for req in reqs:
+            assert req.reason == "length"
+            assert req.generated == _ref_tokens(params, cfg, req)
+        assert eng.key_schedules - drawn == len(sampled) \
+            == sched.stats()["key_schedules"] - drawn
+        assert sched.stats()["admitted"] == len(reqs)
+        # the span moves with the draw: once a SAMPLED request
+        assert sched.phases.calls.get("engine.admit.keys", 0) \
+            == len(sampled)
+
+
 class TestContinuousBatching:
     def test_mid_flight_admission_no_lockstep(self, setup, engine):
         """More requests than slots, mixed lengths: later requests must
@@ -897,8 +982,8 @@ class TestPhaseLedger:
 
     INNER = ("serve.reap", "serve.admit", "serve.prefill_chunk",
              "serve.decode_step", "serve.deliver")
-    NESTED = {"serve.admit": ("engine.admit.keys",),
-              "serve.prefill_chunk": ("engine.prefill.dispatch",
+    NESTED = {"serve.prefill_chunk": ("engine.admit.keys",
+                                      "engine.prefill.dispatch",
                                       "engine.first_token.fetch"),
               "serve.decode_step": ("engine.decode.upload",
                                     "engine.decode.dispatch",
@@ -915,8 +1000,12 @@ class TestPhaseLedger:
         try:
             sched = Scheduler(engine)
             assert engine.phases is sched.phases
+            drawn = engine.key_schedules
+            # every other request sampled: those draw their keys, once
+            # each, when their first token is asked for
             reqs = [sched.submit(Request(list(range(1, 9 + 7 * i)),
-                                         max_new_tokens=6, rng=i))
+                                         max_new_tokens=6, rng=i,
+                                         temperature=0.7 * (i % 2)))
                     for i in range(6)]
             sched.run_until_idle(10_000)
             assert all(r.reason == "length" for r in reqs)
@@ -939,7 +1028,9 @@ class TestPhaseLedger:
         assert calls["serve.prefill_chunk"] \
             == calls["engine.prefill.dispatch"] == stats["prefill_programs"]
         assert calls["engine.first_token.fetch"] <= len(reqs)
-        assert calls["engine.admit.keys"] == len(reqs)
+        assert calls["engine.admit.keys"] == len(reqs) // 2 \
+            == stats["key_schedules"] - drawn
+        assert stats["admitted"] == len(reqs)
         assert engine.launches >= stats["decode_steps"] \
             + stats["prefill_programs"]
         # no thread ran: no sleep, no loop time, no collector's callback
