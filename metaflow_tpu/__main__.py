@@ -551,7 +551,8 @@ def watch(flow_run, run_id, datastore, datastore_root, once, check,
               help="Model config as a JSON file or inline object "
                    "(default: the checkpoint's 'cfg' entry).")
 @click.option("--model", default="llama",
-              type=click.Choice(["brumby", "jamba", "llama", "mixtral"]),
+              type=click.Choice(["brumby", "jamba", "llama", "mixtral",
+                                 "nemotron_h", "ouro", "phi4flash"]),
               help="Model family of the checkpoint.")
 @click.option("--host", default="127.0.0.1")
 @click.option("--port", default=8000, type=int)

@@ -654,6 +654,15 @@ def scheduler_metric_families(stats):
                "first asked for; none under greedy traffic)")
         .add(stats.get("admitted", 0), {"count": "admitted"})
         .add(stats.get("key_schedules", 0), {"count": "key_schedules"}))
+    pairs = stats.get("expert_pairs") or {}
+    fams.append(
+        Family("tpuflow_serve_expert_pairs", "counter",
+               "(token, expert) pairs the programs of a model with latent "
+               "expert layers routed, and those that fell on the experts "
+               "this engine holds (a chip's share of each layer; equal "
+               "where it holds them all, 0 for a model with no such layer)")
+        .add(pairs.get("routed", 0), {"count": "routed"})
+        .add(pairs.get("held", 0), {"count": "held"}))
     fams.append(
         Family("tpuflow_serve_attention_positions", "counter",
                "K and V positions over the decode steps run and all "

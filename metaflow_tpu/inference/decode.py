@@ -36,7 +36,11 @@ the stack of each kind of layer). There is ONE layer loop (`_layers`):
 the stacks are walked in the model's order (models/jamba.py,
 `scan_layers`; a stack of one kind, Llama or Mixtral, is its one-run
 case, a stack of several kinds, Jamba's Mamba-1 layers with an attention
-layer every so many, holds one stack per kind). The loop's carry is the
+layer every so many, holds one stack per kind). A layer is a mixer and
+then the family's feed-forward, or where the family's `ffn` is None
+(Nemotron-H) one of the two alone: its Mamba-2 and attention layers end
+at their residual, and the kind `ffn` is a feed-forward with no mixer
+and no pool. The loop's carry is the
 activations and the WHOLE cache: the pools are never a scanned input or
 output, which would slice every layer out and write every layer back
 into a second buffer. A layer's weights are sliced out of their stack by
@@ -91,6 +95,11 @@ is `decode_layers`, and inside a layer `attn_qkv`, `kv_cache_update`,
 `decode_attention`, `attn_out` and `ffn` (ops/moe.py adds `moe_*` inside
 `ffn`); inside a Mamba layer `ssm_in_proj`, `ssm_conv`, `ssm_x_proj`,
 `ssm_scan` (a chunk) or `ssm_state_update` (one token), `ssm_out_proj`;
+inside a Mamba-2 layer `ssd_in_proj`, `ssd_conv`, `ssd_chunk` (a row's
+positions) or `ssd_state_update` (one token), `ssd_gate_norm`,
+`ssd_out_proj`; inside a latent expert layer (under `ffn`) `moe_router`,
+`moe_latent_down`, `moe_dispatch`, `moe_experts`, `moe_combine`,
+`moe_latent_up`, `moe_shared_expert`;
 inside a retention layer `retention_qkvg` (the projections, the head
 norms, rope and the gate), `retention_chunk` (a chunk) or
 `retention_update` (one token), `retention_out`; where a model has them,
@@ -103,7 +112,7 @@ those; in a stack run several times `loop_pass` around one pass (inside
 `decode_layers`, the layers' scopes inside it), `loop_norm` around the
 norm that closes it and `exit_gate` around the gate. The pool's write sits
 under `kv_cache_update`, its chunk reads under the attention kind's
-scope, a state pool's read and write-back under the `ssm_*` or
+scope, a state pool's read and write-back under the `ssm_*`, `ssd_*` or
 `retention_*` scope that needs them: what lies under `decode_layers` and under none of
 those is the loop's own cost (its counter, the residual stream), and
 anything the compiler still moves without being asked.
@@ -125,7 +134,15 @@ import numpy as np
 
 from .. import knobs
 from ..exception import TpuFlowException
-from ..models import brumby, jamba, llama, mixtral, ouro, phi4flash
+from ..models import (
+    brumby,
+    jamba,
+    llama,
+    mixtral,
+    nemotron_h,
+    ouro,
+    phi4flash,
+)
 from ..ops import (
     decode_attention,
     diff_attention,
@@ -140,7 +157,9 @@ from ..ops.rope import apply_rope, rope_frequencies
 
 # name: what `tpuflow serve --model` takes; module: init_params,
 # logical_axes, forward; ffn: the feed-forward half of a block,
-# (cfg, h, lp, mesh) -> the residual's addend; rope: whether attention
+# (cfg, h, lp, mesh) -> the residual's addend, or None where no mixer is
+# followed by one (a layer is then a mixer or, the kind `ffn`, a
+# feed-forward alone); rope: whether attention
 # rotates q and k; stacks: kind of layer -> the key of `params` that
 # holds that kind's stacked leaves. The kinds of layer come from the
 # config (`layer_kinds`; a config without it is attention throughout).
@@ -185,6 +204,8 @@ FAMILIES = {
         "phi4flash", phi4flash, _dense_ffn, False,
         {kind: kind + "_layers"
          for kind in ("mamba", "window", "full", "cross", "gmu")}),
+    nemotron_h.NemotronHConfig: Family("nemotron_h", nemotron_h, None, False,
+                                       nemotron_h.STACKS),
 }
 
 # A pool a kind of layer carries. shape: (cfg, max_seq_len, widest row=None)
@@ -235,12 +256,25 @@ POOLS = {
     "full": {"k": _shared_kv_pool, "v": _shared_kv_pool},
     "cross": {},   # reads the full layer's k and v, writes nothing
     "gmu": {},     # reads the memory the layer loop carries, nothing else
+    "ffn": {},     # a feed-forward alone: no mixer, nothing cached
     "mamba": {
         "conv": Pool(
             lambda cfg, seq, row=None: (cfg.mamba_d_conv - 1, cfg.d_inner),
             None, True, True),
         "ssm": Pool(
             lambda cfg, seq, row=None: (cfg.mamba_d_state, cfg.d_inner),
+            jnp.float32, True, True),
+    },
+    # Mamba-2: the tail over x, B and C together, the state a head
+    # ([128, 64, 128] float32 at the published sizes: 4.19 MB a layer
+    # and slot, whole tiles)
+    "mamba2": {
+        "conv": Pool(
+            lambda cfg, seq, row=None: (cfg.conv_kernel - 1, cfg.conv_dim),
+            None, True, True),
+        "ssm": Pool(
+            lambda cfg, seq, row=None: (
+                cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
             jnp.float32, True, True),
     },
     # [KV, Hd, D] with D = 8,320 at a head size of 128 (the 8,256 products
@@ -270,6 +304,24 @@ ATTENTION = {
     "cross": Attention("k", "v", False, False, "cross_attention"),
 }
 
+# A kind of layer that carries a convolution's tail and a state (the
+# pools `conv` and `ssm`). mixer: (cfg, lp, normed x, tail, state, valid)
+# -> (out, tail, state) and, Mamba-1's, the recurrence's output before
+# its gate; conv, step, chunk: the scopes that the tail's, and the
+# state's read and write-back stand under (one token; a row's positions).
+Recurrence = collections.namedtuple("Recurrence", "mixer conv step chunk")
+RECURRENCES = {
+    "mamba": Recurrence(jamba.mamba_mixer, "ssm_conv", "ssm_state_update",
+                        "ssm_scan"),
+    "mamba2": Recurrence(nemotron_h.mamba2_mixer, "ssd_conv",
+                         "ssd_state_update", "ssd_chunk"),
+}
+
+# The one leaf of the cache that is no pool: [pairs routed, pairs that
+# fell on held experts] since the cache was made, uint32 (it wraps; a
+# reader takes differences), summed over the expert layers of kind `ffn`
+MOE_PAIRS = "moe_pairs"
+
 
 def family(cfg):
     """The family of a model config: the one place it is picked."""
@@ -293,8 +345,9 @@ def family_config_class(name):
 
 def layer_kinds(cfg):
     """The kind of every layer in the model's order, a key of `POOLS`:
-    "attention" (K and V cached), "mamba" (a convolution tail and a
-    state carried), "retention" (a state and its normaliser carried),
+    "attention" (K and V cached), "mamba" and "mamba2" (a convolution
+    tail and a state carried), "retention" (a state and its normaliser
+    carried), "ffn" (a feed-forward alone, nothing cached),
     "window" (K and V of the last positions in a ring), "full" (K and V
     cached, for itself and the layers after it), "cross" (another
     layer's K and V read again) or "gmu" (an earlier layer's output of
@@ -362,8 +415,9 @@ def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None, row=None):
     d_conv-1, d_inner] (the convolution's tail) and `ssm` [.., d_state,
     d_inner] in float32; for retention layers `ret_s` [.., kv_heads,
     head_dim, D] and `ret_z` [.., kv_heads, D] in float32. A stack with
-    no attention layer has no `k` and `v`. Every leaf has the batch on
-    axis 1.
+    no attention layer has no `k` and `v`. Every pool has the batch on
+    axis 1. A model with expert layers of kind `ffn` also gets
+    `moe_pairs` (`MOE_PAIRS`), two counters and no pool.
 
     The pools are read and written a layer at a time in place
     (`_decode_layer`): heads and head size are folded into one minor
@@ -372,10 +426,13 @@ def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None, row=None):
     single KV head (multi-query) leaves no axis of 1 for the chip's
     tiling to pad or to lay out anew on the way in and out."""
     dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
-    return {name: jnp.zeros(
-                (layers, batch_size) + pool.shape(cfg, max_seq_len, row),
-                pool.dtype or dt)
-            for name, (pool, layers) in cache_pools(cfg).items()}
+    cache = {name: jnp.zeros(
+                 (layers, batch_size) + pool.shape(cfg, max_seq_len, row),
+                 pool.dtype or dt)
+             for name, (pool, layers) in cache_pools(cfg).items()}
+    if "ffn" in layer_kinds(cfg):
+        cache[MOE_PAIRS] = jnp.zeros((2,), jnp.uint32)
+    return cache
 
 
 def _query_positions(pos, T):
@@ -733,11 +790,15 @@ def _block_ffn(cfg, x, attn, lp, mesh=None):
     return _ffn(cfg, x, lp, mesh)
 
 
-@jax.named_scope("ffn")
 def _ffn(cfg, x, lp, mesh):
-    h = _norm(cfg, x, lp, "ffn_norm")
-    return x + _sandwich(cfg, family(cfg).ffn(cfg, h, lp, mesh), lp,
-                         "ffn_post_norm")
+    """x after the feed-forward that follows a mixer in the same layer:
+    the family's, or x itself where its mixers are followed by none."""
+    ffn = family(cfg).ffn
+    if ffn is None:
+        return x
+    with jax.named_scope("ffn"):
+        h = _norm(cfg, x, lp, "ffn_norm")
+        return x + _sandwich(cfg, ffn(cfg, h, lp, mesh), lp, "ffn_post_norm")
 
 
 def _sandwich(cfg, out, lp, name):
@@ -944,13 +1005,26 @@ def _put_layer_rows(pool, rows, layer, slots):
     return _put_slot_rows(pool, rows[None], slots, layer)
 
 
-def _mamba_layer(cfg, x, lp, conv, state, valid):
-    """One Mamba block over T new tokens from this layer's carried
-    (conv [B, K-1, Di], state [B, N, Di]); the last of the four returned
-    is the recurrence's output before its gate, [B, T, Di] float32."""
-    out, conv, state, y = jamba.mamba_mixer(
+def _mamba_layer(cfg, kind, x, lp, conv, state, valid):
+    """One Mamba block of `kind` (`RECURRENCES`) over T new tokens from
+    this layer's carried (conv [B, K-1, channels], state); the last of
+    the four returned is, for Mamba-1, the recurrence's output before
+    its gate, [B, T, Di] float32 (else None)."""
+    out, conv, state, *y = RECURRENCES[kind].mixer(
         cfg, lp, _norm(cfg, x, lp, "ssm_norm"), conv, state, valid)
-    return _ffn(cfg, x + out, lp, None), conv, state, y
+    return _ffn(cfg, x + out, lp, None), conv, state, y[0] if y else None
+
+
+def _ffn_layer(cfg, x, lp, cache, valid):
+    """One layer that is a feed-forward alone (a latent mixture of
+    experts, models/nemotron_h.py), and the cache with the pairs it made
+    counted where the cache counts them."""
+    with jax.named_scope("ffn"):
+        out, pairs = nemotron_h.latent_moe(
+            cfg, lp, _norm(cfg, x, lp, "ffn_norm"), valid)
+    if MOE_PAIRS in cache:
+        cache = dict(cache, **{MOE_PAIRS: cache[MOE_PAIRS] + pairs})
+    return x + out, cache
 
 
 def _gmu_layer(cfg, x, lp, memory):
@@ -1042,6 +1116,9 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
 
         def body(kind, i, carry):
             x, cache, memory = carry
+            if kind == "ffn":
+                lp = nemotron_h.moe_leaves(params[fam.stacks[kind]], i)
+                return _ffn_layer(cfg, x, lp, cache, valid) + (memory,)
             lp = jamba.layer_at(params[fam.stacks[kind]], i)
             # where the layer's K and V live in their pools
             index = i if t is None else t * len(kinds) + i
@@ -1066,16 +1143,18 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
             # that a scope's device time holds the pool's traffic that its
             # kernel needs
             cache = dict(cache)
-            conv_scope = jax.named_scope("ssm_conv")
+            scopes = RECURRENCES[kind]
+            conv_scope = jax.named_scope(scopes.conv)
             state_scope = jax.named_scope(
-                "ssm_state_update" if x.shape[1] == 1 else "ssm_scan")
+                scopes.step if x.shape[1] == 1 else scopes.chunk)
             at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
                                                         keepdims=False)
             with conv_scope:
                 conv = at(cache["conv"])
             with state_scope:
                 state = at(cache["ssm"])
-            x, conv, state, y = _mamba_layer(cfg, x, lp, conv, state, valid)
+            x, conv, state, y = _mamba_layer(cfg, kind, x, lp, conv, state,
+                                             valid)
             put = jax.lax.dynamic_update_index_in_dim
             with conv_scope:
                 cache["conv"] = put(cache["conv"], conv, i, 0)

@@ -51,6 +51,27 @@ Capacity semantics are identical in the sparse and dense paths: an
 expert accepts its first ``capacity`` tokens in token order; the rest
 are dropped (their combine weight becomes 0 and the residual stream
 passes through). The gmm path has no capacity — it is exactly dropless.
+With ``exact`` the sparse path is dropless too, at a capacity's cost:
+the step counts its own pairs an expert, and only a step in which some
+expert is sent more than its capacity runs again with buffers as deep
+as the step has tokens (`lax.cond`: one branch executes). With many
+small experts and a decode step of a hundred tokens that is the
+difference between 128 experts x 128 rows and 128 x 22.
+
+Routers (`route`): ``softmax_top_k`` (Mixtral: the top-k logits,
+softmaxed over those k) and ``sigmoid_bias`` (sigmoid scores, the top-k
+of scores plus a selection bias, the chosen scores normalised over the k
+and scaled). A caller whose router reads something other than what the
+experts read (a latent expert layer) routes itself and hands `moe_ffn`
+the ``routing``.
+
+A share of the experts (``held``): the router keeps its published width
+and picks over all of it, the leaves hold experts `first .. first +
+count - 1` only, and the picks that fall on other experts add nothing,
+here as on the chip that would hold this share: no buffer, row or
+operation stands for an absent expert. Experts are three matrices
+(SwiGLU: `act(x w_gate) * (x w_up)`, then `w_down`) or, with no
+`w_gate`, two (`act(x w_up)`, then `w_down`).
 """
 
 import math
@@ -67,6 +88,37 @@ def top_k_router(logits, num_experts, k, dtype=jnp.float32):
     gate_logits, idx = jax.lax.top_k(logits, k)
     weights = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
     return weights.astype(dtype), idx
+
+
+def relu2(x):
+    """relu(x) ** 2, the activation of a two-matrix expert."""
+    return jnp.square(jax.nn.relu(x))
+
+
+@jax.named_scope("moe_router")
+def route(x, router_w, k, form="softmax_top_k", bias=None, scale=1.0,
+          dtype=None):
+    """x [.., E] through the router [E, experts] -> (weights [.., k] in
+    `dtype` (x's), idx [.., k]); logits and scores in float32.
+
+    softmax_top_k: the top-k logits, softmaxed over those k.
+    sigmoid_bias: s = sigmoid(logits); the k largest of s + bias are
+    chosen (the bias moves the choice only); their s, normalised over
+    the k (+ 1e-20) and times `scale`, are the weights."""
+    logits = jnp.einsum("...e,en->...n", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32))
+    dtype = dtype or x.dtype
+    if form == "softmax_top_k":
+        return top_k_router(logits, router_w.shape[1], k, dtype=dtype)
+    if form != "sigmoid_bias":
+        raise ValueError("router form must be 'softmax_top_k' or "
+                         "'sigmoid_bias', got %r" % (form,))
+    scores = jax.nn.sigmoid(logits)
+    picked = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(picked, k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = scale * (w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20))
+    return w.astype(dtype), idx
 
 
 def expert_capacity(num_tokens, num_experts, k, capacity_factor):
@@ -102,12 +154,34 @@ def _constrain_expert_axis(x, mesh):
 
 def moe_ffn(x, router_w, w_gate, w_up, w_down, num_experts_per_tok=2,
             capacity_factor=None, activation=jax.nn.silu, dispatch="sparse",
-            mesh=None, ep_buffer_factor=None):
+            mesh=None, ep_buffer_factor=None, routing=None, held=None,
+            valid=None, exact=False):
     """Token-choice MoE feed-forward.
 
     x:        [B, S, E]
     router_w: [E, num_experts]
-    w_gate/w_up: [num_experts, E, F]; w_down: [num_experts, F, E]
+    w_gate/w_up: [num_experts, E, F]; w_down: [num_experts, F, E];
+              w_gate None: experts of two matrices. Each may be a
+              function that gives the array ('sparse' and 'dense'): a
+              caller whose leaves are one layer of a stack hands the
+              cutting in, so that under `exact` each branch of the
+              `cond` cuts the layer out where it multiplies by it. An
+              array cut outside is the `cond`'s operand, and the
+              compiler then copies the layer (1.4 GB for 128 experts of
+              1024 x 2688) on the way in
+    routing:  (weights [B, S, k], idx [B, S, k]) from `route`, where the
+              caller has routed (router_w is then not read, and the
+              auxiliary loss returned is 0)
+    held:     None (the leaves hold every expert), or (experts routed
+              over, first): the leaves hold experts first .. first +
+              len(w_up) - 1 of that many, and a pick outside them
+              adds nothing ('sparse' and 'dense')
+    valid:    None or [B, S] bool: a token that is not valid (a lane
+              that holds no decoding request, a row's padding) is sent
+              to no expert, and its output is 0 ('sparse' and 'dense')
+    exact:    'sparse' with a capacity_factor: no pair is dropped; a
+              step that overflows some expert's capacity runs with
+              lossless buffers instead
     mesh:     pass the device mesh explicitly so the sparse path can pin
               its expert buffers to the 'expert' axis even when the step
               is traced outside a `with mesh:` block; falls back to the
@@ -120,8 +194,15 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, num_experts_per_tok=2,
     Returns (out [B, S, E], aux_loss scalar).
     """
     B, S, E = x.shape
-    num_experts = router_w.shape[1]
+    num_experts = held[0] if held is not None else router_w.shape[1]
     k = num_experts_per_tok
+    if dispatch not in ("sparse", "dense") and (
+            routing is not None or held is not None or valid is not None
+            or w_gate is None):
+        raise ValueError(
+            "dispatch=%r routes for itself over experts of three matrices "
+            "that are all here; routing, held, valid and two-matrix "
+            "experts take 'sparse' or 'dense'" % (dispatch,))
 
     if dispatch == "gmm_ep":
         # routing happens per token-slice INSIDE the shard_map; branch
@@ -143,26 +224,34 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, num_experts_per_tok=2,
         raise ValueError("ep_buffer_factor only applies to dispatch='gmm_ep'")
     tokens = x.reshape(B * S, E)
 
-    with jax.named_scope("moe_router"):
-        router_logits = jnp.einsum(
-            "te,en->tn", tokens.astype(jnp.float32),
-            router_w.astype(jnp.float32)
-        )
-        weights, idx = top_k_router(router_logits, num_experts, k,
-                                    dtype=x.dtype)
-        one_hot = jax.nn.one_hot(idx, num_experts, dtype=x.dtype)  # [t,k,n]
-        aux = _load_balancing_loss(router_logits, one_hot)
+    if routing is not None:
+        weights, idx = (a.reshape(B * S, k) for a in routing)
+        one_hot, aux = None, jnp.zeros((), jnp.float32)
+    else:
+        with jax.named_scope("moe_router"):
+            router_logits = jnp.einsum(
+                "te,en->tn", tokens.astype(jnp.float32),
+                router_w.astype(jnp.float32)
+            )
+            weights, idx = top_k_router(router_logits, num_experts, k,
+                                        dtype=x.dtype)
+            one_hot = jax.nn.one_hot(idx, num_experts, dtype=x.dtype)  # [t,k,n]
+            aux = _load_balancing_loss(router_logits, one_hot)
+    first = held[1] if held is not None else 0
+    if valid is not None:
+        valid = valid.reshape(B * S)
 
     if dispatch == "sparse":
         out = _sparse_dispatch_ffn(
             tokens, weights, idx, w_gate, w_up, w_down, num_experts, k,
             capacity_factor, activation,
             mesh if mesh is not None else _active_mesh(),
+            first=first, valid=valid, exact=exact,
         )
     elif dispatch == "dense":
         out = _dense_dispatch_ffn(
             tokens, weights, idx, one_hot, w_gate, w_up, w_down, num_experts,
-            k, capacity_factor, activation,
+            k, capacity_factor, activation, first=first, valid=valid,
         )
     elif dispatch == "gmm":
         if capacity_factor is not None:
@@ -189,53 +278,97 @@ def moe_ffn(x, router_w, w_gate, w_up, w_down, num_experts_per_tok=2,
     return out.reshape(B, S, E), aux
 
 
+def _leaf(w):
+    """An expert leaf: the array, or what the function gives."""
+    return w() if callable(w) else w
+
+
+def _experts(x_buf, w_gate, w_up, w_down, activation, dtype):
+    """The experts' matrices over their buffers x_buf [n, rows, E]: three
+    (act(x w_gate) * (x w_up), then w_down) or, with no w_gate, two."""
+    product = lambda w: jnp.einsum("nce,nef->ncf", x_buf, _leaf(w),
+                                   preferred_element_type=jnp.float32)
+    if w_gate is None:
+        hidden = activation(product(w_up))
+    else:
+        hidden = activation(product(w_gate)) * product(w_up)
+    return jnp.einsum("ncf,nfe->nce", hidden.astype(dtype), _leaf(w_down),
+                      preferred_element_type=jnp.float32)
+
+
+def _n_experts(w_up):
+    """How many experts the leaves hold, without cutting one out."""
+    return (jax.eval_shape(w_up) if callable(w_up) else w_up).shape[0]
+
+
 def _sparse_dispatch_ffn(tokens, weights, idx, w_gate, w_up, w_down,
-                         num_experts, k, capacity_factor, activation, mesh):
+                         num_experts, k, capacity_factor, activation, mesh,
+                         first=0, valid=None, exact=False):
     """Capacity-bucketed dispatch: O(k·T·capacity_factor) expert FLOPs.
 
     Slot order is token-major (slot t·k+j precedes t'·k+j' iff t<t' or
     (t==t', j<j')); since top-k indices are distinct per token, each token
     holds at most one slot per expert, so per-expert arrival order equals
     token order — the same drop decisions as the dense oracle's
-    token-axis cumsum."""
+    token-axis cumsum.
+
+    The leaves hold N = w_up.shape[0] experts, `first` .. first + N - 1
+    of the `num_experts` routed over. A slot whose expert is not among
+    them, or whose token is not `valid`, goes nowhere: it takes no place
+    in any buffer and adds nothing."""
     T, E = tokens.shape
-    N = num_experts
-    C = expert_capacity(T, N, k, capacity_factor)
+    N = _n_experts(w_up)
+    C = expert_capacity(T, num_experts, k, capacity_factor)
 
     with jax.named_scope("moe_dispatch"):
         e_flat = idx.reshape(T * k)                  # expert id per slot
         w_flat = weights.reshape(T * k)              # combine weight per slot
+        here = None
+        if N != num_experts or valid is not None:
+            e_flat = e_flat - first
+            here = (e_flat >= 0) & (e_flat < N)
+            if valid is not None:
+                here = here & jnp.repeat(valid, k)
+            e_flat = jnp.where(here, e_flat, N)      # N: no expert here
         slot_one_hot = jax.nn.one_hot(e_flat, N, dtype=jnp.int32)  # [T*k, N]
         # 0-based arrival position of each slot within its expert
         pos = jnp.cumsum(slot_one_hot, axis=0) - 1   # [T*k, N]
-        pos_flat = jnp.take_along_axis(pos, e_flat[:, None], axis=1)[:, 0]
-        keep = pos_flat < C
-        # dropped slots scatter out of range; mode="drop" discards them
-        # with static shapes (positions are unique per expert, so add ==
-        # set)
-        safe_pos = jnp.where(keep, pos_flat, C)
-        t_flat = jnp.arange(T * k) // k              # owning token per slot
+        at = e_flat if here is None else jnp.minimum(e_flat, N - 1)
+        pos_flat = jnp.take_along_axis(pos, at[:, None], axis=1)[:, 0]
 
-        x_buf = jnp.zeros((N, C, E), tokens.dtype).at[e_flat, safe_pos].add(
-            tokens[t_flat], mode="drop"
-        )
-        x_buf = _constrain_expert_axis(x_buf, mesh)  # all-to-all boundary in
+    def run(C):
+        with jax.named_scope("moe_dispatch"):
+            keep = pos_flat < C
+            if here is not None:
+                keep = keep & here
+            # dropped slots scatter out of range; mode="drop" discards
+            # them with static shapes (positions are unique per expert,
+            # so add == set)
+            safe_pos = jnp.where(keep, pos_flat, C)
+            t_flat = jnp.arange(T * k) // k          # owning token per slot
+            x_buf = jnp.zeros((N, C, E), tokens.dtype).at[
+                e_flat, safe_pos].add(tokens[t_flat], mode="drop")
+            x_buf = _constrain_expert_axis(x_buf, mesh)  # all-to-all in
 
-    with jax.named_scope("moe_experts"):
-        gate = activation(jnp.einsum("nce,nef->ncf", x_buf, w_gate,
-                                     preferred_element_type=jnp.float32))
-        up = jnp.einsum("nce,nef->ncf", x_buf, w_up,
-                        preferred_element_type=jnp.float32)
-        y_buf = jnp.einsum("ncf,nfe->nce", (gate * up).astype(tokens.dtype),
-                           w_down, preferred_element_type=jnp.float32)
-        y_buf = _constrain_expert_axis(y_buf.astype(tokens.dtype), mesh)
+        with jax.named_scope("moe_experts"):
+            y_buf = _experts(x_buf, w_gate, w_up, w_down, activation,
+                             tokens.dtype)
+            y_buf = _constrain_expert_axis(y_buf.astype(tokens.dtype), mesh)
 
-    # combine: gather each slot's expert output back (all-to-all boundary
-    # out); out-of-range gathers clamp but are zeroed by the keep mask
-    with jax.named_scope("moe_combine"):
-        y_slots = y_buf[e_flat, safe_pos]            # [T*k, E]
-        y_slots = jnp.where(keep[:, None], y_slots, 0) * w_flat[:, None]
-        return y_slots.reshape(T, k, E).sum(axis=1)
+        # combine: gather each slot's expert output back (all-to-all
+        # boundary out); out-of-range gathers clamp but are zeroed by the
+        # keep mask
+        with jax.named_scope("moe_combine"):
+            y_slots = y_buf[e_flat, safe_pos]            # [T*k, E]
+            y_slots = jnp.where(keep[:, None], y_slots, 0) * w_flat[:, None]
+            return y_slots.reshape(T, k, E).sum(axis=1)
+
+    if not exact or C >= T:
+        return run(C)
+    # the step's own counts: only an expert that is sent more than C
+    # tokens makes the step pay for buffers as deep as its tokens
+    fits = jnp.max(jnp.sum(slot_one_hot, axis=0)) <= C
+    return jax.lax.cond(fits, lambda: run(C), lambda: run(T))
 
 
 def _gmm_dispatch_ffn(tokens, weights, idx, w_gate, w_up, w_down,
@@ -409,10 +542,19 @@ def _gmm_ep_dispatch_ffn(x, router_w, w_gate, w_up, w_down, num_experts, k,
 
 
 def _dense_dispatch_ffn(tokens, weights, idx, one_hot, w_gate, w_up, w_down,
-                        num_experts, k, capacity_factor, activation):
-    """Reference oracle: every expert sees every token (one-hot einsums)."""
+                        num_experts, k, capacity_factor, activation,
+                        first=0, valid=None):
+    """Reference oracle: every expert whose leaves are here (`first` ..
+    first + w_up.shape[0] - 1) sees every token (one-hot einsums)."""
     T, E = tokens.shape
+    N = _n_experts(w_up)
     with jax.named_scope("moe_dispatch"):
+        if one_hot is None:   # the caller routed
+            one_hot = jax.nn.one_hot(idx, num_experts, dtype=tokens.dtype)
+        if N != num_experts:
+            one_hot = one_hot[..., first:first + N]
+        if valid is not None:
+            one_hot = one_hot * valid[:, None, None].astype(one_hot.dtype)
         # combine matrix: [tokens, experts], rows sum to 1 over selected
         # experts
         combine = jnp.einsum("tkn,tk->tn", one_hot, weights)
@@ -432,13 +574,8 @@ def _dense_dispatch_ffn(tokens, weights, idx, one_hot, w_gate, w_up, w_down,
         # [n, t, E]: per-expert token batch
         h = jnp.einsum("te,tn->nte", tokens, combine != 0)
     with jax.named_scope("moe_experts"):
-        gate = activation(jnp.einsum("nte,nef->ntf", h, w_gate,
-                                     preferred_element_type=jnp.float32))
-        up = jnp.einsum("nte,nef->ntf", h, w_up,
-                        preferred_element_type=jnp.float32)
-        expert_out = jnp.einsum(
-            "ntf,nfe->nte", (gate * up).astype(tokens.dtype), w_down,
-            preferred_element_type=jnp.float32)
+        expert_out = _experts(h, w_gate, w_up, w_down, activation,
+                              tokens.dtype)
     with jax.named_scope("moe_combine"):
         return jnp.einsum("nte,tn->te", expert_out.astype(tokens.dtype),
                           combine)

@@ -86,7 +86,9 @@ since no layer caches K and V: a slot then costs the same at position 10
 and at 30,000, and `max_seq_len` bounds rope's table only). None of the
 three invariants above holds for a
 recurrence, which has no garbage that is overwritten before it is seen,
-so for such a model: (a) the prefill program is told how many of each
+so for such a model (Jamba, Brumby, Phi-4-mini-flash, and Nemotron-H,
+whose Mamba-2 layers keep a tail over 10,240 channels and a state of
+4.19 MB a layer and slot): (a) the prefill program is told how many of each
 row's positions are real, and the state after a row is the state after
 its last real token (a row with none holds the state); (b) the fused
 decode step holds the state of every lane whose mask is false; (c)
@@ -126,6 +128,7 @@ from .. import device, telemetry
 from ..exception import TpuFlowException
 from ..inference.decode import (
     DECODE_CHUNK,
+    MOE_PAIRS,
     POOLS,
     attention_positions,
     attention_reads,
@@ -378,6 +381,13 @@ class SlotEngine(KeySchedules):
         # program rides behind the prefill program whose logits it reads)
         self.phases = telemetry.PhaseLedger()
         self.launches = 0
+        # a model with latent expert layers: the (token, expert) pairs
+        # its programs routed and those that fell on the experts held
+        # here, since the engine was made. The device counts them in the
+        # cache (`MOE_PAIRS`, uint32 that wraps); every decode step's
+        # fetch brings the counters along and the host adds what changed
+        self.expert_pairs = {"routed": 0, "held": 0}
+        self._pairs_seen = np.zeros(2, np.uint32)
 
         def _prefill(params, cache, tokens, slots, start, n_real=None):
             # tokens [R, W]: row r is the next tokens of slot slots[r]
@@ -974,6 +984,12 @@ class SlotEngine(KeySchedules):
                         self._d_mask, rows)
         with self.phases("engine.decode.fetch", awaits=self.launches):
             out = np.asarray(out)   # the host waits for the device here
+            if MOE_PAIRS in self._cache:   # computed by now: 8 bytes more
+                seen = np.asarray(self._cache[MOE_PAIRS])
+                for name, n in zip(("routed", "held"),
+                                   seen - self._pairs_seen):
+                    self.expert_pairs[name] += int(n)
+                self._pairs_seen = seen
         tokens = {}
         for i in decoding:
             tokens[i] = int(out[i])

@@ -1019,6 +1019,8 @@ class Scheduler(object):
             "merged_steps": self.merged_steps,
             "admitted": self.admitted,
             "key_schedules": getattr(self.engine, "key_schedules", 0),
+            "expert_pairs": dict(getattr(self.engine, "expert_pairs", None)
+                                 or {"routed": 0, "held": 0}),
             "iterations": self.iteration,
             "draining": self._draining,
             # rolling-window tail latency (the SLO monitor's poll surface)

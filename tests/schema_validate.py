@@ -1819,6 +1819,7 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_weight_passes": "counter",
     "tpuflow_serve_prefill": "counter",
     "tpuflow_serve_admissions": "counter",
+    "tpuflow_serve_expert_pairs": "counter",
     "tpuflow_serve_attention_positions": "counter",
     "tpuflow_serve_iterations": "counter",
     "tpuflow_serve_phase_seconds": "counter",
