@@ -633,6 +633,11 @@ def scheduler_metric_families(stats):
     fams.append(Family("tpuflow_serve_decode_steps", "counter",
                        "Batched decode steps executed")
                 .add(stats["decode_steps"]))
+    fams.append(Family("tpuflow_serve_steps_ahead", "counter",
+                       "Decode steps launched while the step before was "
+                       "still uncollected: all but the first of a loaded "
+                       "loop, none on an engine that runs nothing ahead")
+                .add(stats.get("steps_ahead", 0)))
     fams.append(Family("tpuflow_serve_weight_passes", "counter",
                        "Passes through the stack's weights over the decode "
                        "steps run: the steps times the model's passes (a "

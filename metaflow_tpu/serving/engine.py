@@ -43,7 +43,8 @@ cover everything:
     (ops/decode_attention.py), and `attention_positions()` says from the
     host's cursors how much of what it fetches the queries see
   - first-token: sample, for every row of a prefill program that ends
-    its prompt, the token its logits imply; fetched in one wait. Where
+    its prompt, the token its logits imply, and put it at the row's lane
+    among the device's last tokens; fetched in one wait, later. Where
     the config marks a tail layer (nothing past that layer's K and V at
     a position is read by a later one), the prefill program runs that
     layer's attention, the layers after it and the head for each row's
@@ -53,17 +54,33 @@ Where every layer of the stack is an attention layer (Llama, Mistral,
 Mixtral), on one chip and with the chunk loop (`merges`,
 inference/decode.py), an iteration that prefills is ONE execution, of
 the decode program: `stage_rows(plan)` builds the rows that `prefill`
-would have run, and the next `decode_step` takes them along, the lanes
+would have run, and the next `launch_decode` takes them along, the lanes
 and the rows' tokens one batch for everything that multiplies by a
 weight, so that every weight is read once where two programs read it
 twice. The rows' first tokens come back with the lanes' tokens in the
-one fetch (`row_results`); with no lane decoding the same program runs
+one fetch (`row_results`), and the program leaves the token of a row that
+ends (`ends`, host-known) at its lane; with no lane decoding the same
+program runs
 for the rows alone, and the prefill and first-token programs are never
 compiled (`warm_prefill` warms the decode step's three row shapes in
 their place). A stack with a recurrent layer, a ring, a tail layer or a
 gated memory keeps prefill then decode, two programs an iteration: its
 rows have more to take apart than K and V, and its kinds join one at a
 time (ROADMAP S9b).
+
+Every program has a LAUNCH and a COLLECT (`launch_decode` /
+`decode_step`, `launch_prefill` / `collect_prefill`; `decode_step` with
+nothing in flight and `prefill` are the two in a row). A launch uploads
+what is dirty, dispatches and replays the program's masked advance on the host's mirrors (`pos`,
+the key cursors, which rows now decode, which lanes have their last token
+launched: all known without the tokens), and waits for nothing; a collect
+is the one blocking fetch of the OLDEST launch. So a scheduler may launch
+step n+1 while step n runs and fetch n's tokens after (`runs_ahead`), and
+the device always has its next program queued. What the host learns only
+from the tokens decides nothing that launches: the lanes' last tokens live
+on the device alone (`_d_tok`). `seed_prefix`, `extract_kv` and the state reset of `admit` queue
+behind the step in flight in program order; `extract_kv` then waits for
+it.
 
 Slots never wait for each other: a finished slot is released and can be
 refilled while its neighbors keep decoding. Free/prefilling slots ride
@@ -118,6 +135,8 @@ served through the engine emits exactly the tokens the lockstep path
 would give it alone — greedy case bit-exact (pinned by
 tests/test_serving.py).
 """
+
+from collections import deque
 
 import numpy as np
 
@@ -299,6 +318,10 @@ class SlotEngine(KeySchedules):
     is NOT thread-safe — exactly one scheduler loop drives it.
     """
 
+    # the loop may launch a decode step before it collects the last one's
+    # tokens: everything a launch needs is known without them
+    runs_ahead = True
+
     def __init__(self, params, cfg, max_slots=8, max_seq_len=None,
                  prefill_chunk=64, mesh=None, attn_impl="auto",
                  cache_dtype=None, pad_id=0, min_bucket=16):
@@ -352,13 +375,21 @@ class SlotEngine(KeySchedules):
         # reads every weight once
         self.merges = merges(cfg, mesh, self.attn_impl)
         self._staged = None      # the rows the next decode step takes
-        self.row_results = []    # what the last decode step's rows gave
+        self.row_results = []    # what the last collected step's rows gave
+        # launched and not yet collected, oldest first: the decode steps
+        # (`launch_decode`) and the prefill programs (`launch_prefill`)
+        self._decodes = deque()
+        self._prefills = deque()
         B = self.max_slots
         # host-side per-slot state
         self.pos = np.zeros(B, np.int32)          # next cache write index
         self.active = np.zeros(B, bool)           # slot holds a request
         self.decoding = np.zeros(B, bool)         # past prefill
-        self._tok = np.zeros(B, np.int32)         # last emitted token
+        # tokens the occupant may emit and those launched for it so far
+        # (its first among them): a lane whose last token is in flight
+        # rides the next launch masked, with no token fetched to say so
+        self._max_new = np.zeros(B, np.int32)
+        self._emitted = np.zeros(B, np.int32)
         self._temp = np.zeros(B, np.float32)
         self._top_k = np.full(B, self._vocab, np.int32)
         self._top_p = np.ones(B, np.float32)
@@ -369,9 +400,16 @@ class SlotEngine(KeySchedules):
         # device mirrors of the decode-step inputs: steady-state decode
         # re-uploads NOTHING (the jitted step advances tok/pos on device);
         # slot membership or sampling-knob changes set _dirty and the
-        # next step re-stages from the host arrays above
+        # next step re-stages from the host arrays above what the host
+        # knows ahead of the tokens (pos, the mask, the knobs). The lanes'
+        # last tokens are the DEVICE's alone: a step writes them, a row
+        # that ends its prompt leaves its first token at its slot (the
+        # merged step, the first-token program), `admit_prefilled` patches
+        # one lane, and the host keeps no mirror of them, which would be a
+        # step stale while a step is in flight
         self._dirty = True
-        self._d_tok = self._d_pos = self._d_mask = None
+        self._d_tok = jnp.zeros(B, jnp.int32)
+        self._d_pos = self._d_mask = None
         self._d_temp = self._d_top_k = self._d_top_p = None
         # every engine.* span goes through this ledger (a Scheduler puts
         # its own here, so that one ledger holds the whole iteration);
@@ -411,19 +449,30 @@ class SlotEngine(KeySchedules):
                 attn_impl=self.attn_impl, valid=valid, slots=slots,
                 last=last)
 
-        def _advance(nxt, tok, pos, mask):
+        def _put_first(tok, first, slots, ends):
+            # a row that ends its prompt leaves its token at its slot, for
+            # the lane to decode from in the next step; the other rows'
+            # land past the lanes and are dropped
+            return tok.at[jnp.where(ends, slots, tok.shape[0])].set(
+                first, mode="drop")
+
+        def _advance(nxt, tok, pos, mask, rows):
             # decoding lanes take the new token and move their cursor;
             # masked lanes (free / mid-prefill) hold still — the SAME
             # update runs on the host mirrors, so no download is needed
-            tok = jnp.where(mask, nxt, tok)
+            lanes = tok.shape[0]
+            tok = jnp.where(mask, nxt[:lanes], tok)
             pos = pos + mask.astype(jnp.int32)
+            if rows is not None:
+                tok = _put_first(tok, nxt[lanes:], rows["slots"],
+                                 rows["ends"])
             return tok, pos
 
         def _step_logits(params, cache, tok, pos, mask, rows):
             # the decode step's logits, [B, vocab]; with the rows of a
             # prefill program riding along (`stage_rows`: tokens [R, W],
-            # slots, start, n_real), each row's logits at its last real
-            # position after them, [B + R, vocab]
+            # slots, start, n_real, and ends for `_advance`), each row's
+            # logits at its last real position after them, [B + R, vocab]
             if rows is not None:
                 rows = (rows["tokens"], rows["slots"], rows["start"],
                         jnp.maximum(rows["n_real"] - 1, 0))
@@ -441,8 +490,8 @@ class SlotEngine(KeySchedules):
                     for lanes, name in ((keys, "keys"), (temp, "temp"),
                                         (top_k, "top_k"), (top_p, "top_p")))
             nxt = sample_slots(logits, keys, temp, top_k, top_p)
-            tok, pos = _advance(nxt[:tok.shape[0]], tok, pos, mask)
-            return nxt, tok, pos, cache
+            tok, pos = _advance(nxt, tok, pos, mask, rows)
+            return nxt, tok, pos, cache, cache.get(MOE_PAIRS)
 
         def _decode_greedy(params, cache, tok, pos, mask, rows=None):
             # static fast path when every active slot is greedy: the full
@@ -450,15 +499,20 @@ class SlotEngine(KeySchedules):
             # tiny forward on CPU; greedy traffic must not pay it
             logits, cache = _step_logits(params, cache, tok, pos, mask, rows)
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            tok, pos = _advance(nxt[:tok.shape[0]], tok, pos, mask)
-            return nxt, tok, pos, cache
+            tok, pos = _advance(nxt, tok, pos, mask, rows)
+            # the expert pairs' counters beside the tokens: the cache's
+            # own are donated to the next step before these are fetched
+            return nxt, tok, pos, cache, cache.get(MOE_PAIRS)
 
-        def _first_token(logits, idx, keys, temp, top_k, top_p):
+        def _first_token(logits, idx, keys, temp, top_k, top_p, tok, slots,
+                         ends):
             # row r's token off position idx[r] of a prefill program's
             # logits [R, W, vocab] (a config with a tail layer: [R, 1,
-            # vocab], idx 0), every row with its own key and knobs
+            # vocab], idx 0), every row with its own key and knobs; and
+            # the lanes' tokens with those of the rows that end put in
             last = logits[jnp.arange(logits.shape[0]), idx]
-            return sample_slots(last, keys, temp, top_k, top_p)
+            first = sample_slots(last, keys, temp, top_k, top_p)
+            return first, _put_first(tok, first, slots, ends)
 
         def _seed(cache, k, v, slot):
             # write a [layers, T, kv_heads, head_dim] KV range into one
@@ -500,6 +554,8 @@ class SlotEngine(KeySchedules):
         self._decode_greedy_fn = jax.jit(_decode_greedy,
                                          donate_argnums=(1,))
         self._first_fn = jax.jit(_first_token)
+        self._set_tok_fn = jax.jit(lambda tok, slot, token:
+                                   tok.at[slot].set(token))
         self._seed_fn = jax.jit(_seed, donate_argnums=(0,))
         self._reset_state_fn = jax.jit(_reset_state, donate_argnums=(0,))
         # no donation: the pool cache must survive an extraction
@@ -615,6 +671,8 @@ class SlotEngine(KeySchedules):
                              else min(int(top_k), self._vocab))
         self._top_p[slot] = 1.0 if top_p is None else float(top_p)
         self._bind_keys(slot, rng, max_new_tokens)
+        self._max_new[slot] = int(max_new_tokens)
+        self._emitted[slot] = 0
         self._dirty = True
         if self.recurrent:
             with self.phases("engine.state.reset", slot=int(slot)):
@@ -726,11 +784,12 @@ class SlotEngine(KeySchedules):
             self._cache, jnp.asarray(k, dtype), jnp.asarray(v, dtype),
             jnp.int32(slot))
         self._prefill_cursor[slot] = prompt.size
-        self.decoding[slot] = True
         self.pos[slot] = prompt.size
-        self._tok[slot] = int(first_token)
-        self._key_cursor[slot] = 1
-        self._dirty = True
+        # one lane of the device's tokens patched, behind whatever step
+        # is in flight
+        self._d_tok = self._set_tok_fn(self._d_tok, jnp.int32(slot),
+                                       jnp.int32(first_token))
+        self._rows_done(np.asarray([slot]))
 
     def release(self, slot):
         """Reclaim a slot immediately; the stale cache contents stay and
@@ -775,7 +834,8 @@ class SlotEngine(KeySchedules):
         nothing real, so that no request's prefill is the first call of
         a shape. Safe with requests in flight: a row with nothing real
         writes only past its slot's cursor (overwritten before it is
-        seen) and holds a recurrent state. Where the rows ride in the
+        seen), holds a recurrent state and ends no prompt, so the lanes'
+        tokens stay as they are. Where the rows ride in the
         decode step (`merges`) the programs warmed are that step's, one
         a shape with no lane decoding, and the prefill and first-token
         programs are not compiled at all (the sampled step's shapes
@@ -785,13 +845,13 @@ class SlotEngine(KeySchedules):
             slots = np.arange(rows, dtype=np.int32)
             none = np.zeros(rows, np.int32)
             if self.merges:
-                _, _, _, self._cache = self._decode_greedy_fn(
-                    self.params, self._cache, jnp.asarray(self._tok),
-                    jnp.asarray(self.pos),
+                _, _, _, self._cache, _ = self._decode_greedy_fn(
+                    self.params, self._cache, self._d_tok,
+                    jnp.asarray(self.pos.copy()),
                     jnp.asarray(np.zeros(self.max_slots, bool)),
                     {"tokens": np.full((rows, width), self.pad_id, np.int32),
                      "slots": slots, "start": self.pos[slots],
-                     "n_real": none})
+                     "n_real": none, "ends": np.zeros(rows, bool)})
                 continue
             logits, self._cache = self._prefill_fn(
                 self.params, self._cache,
@@ -803,15 +863,14 @@ class SlotEngine(KeySchedules):
                 jnp.asarray(np.zeros((rows, 2), np.uint32)),
                 jnp.asarray(self._temp[slots]),
                 jnp.asarray(self._top_k[slots]),
-                jnp.asarray(self._top_p[slots]))
+                jnp.asarray(self._top_p[slots]),
+                self._d_tok, jnp.asarray(slots),
+                jnp.asarray(np.zeros(rows, bool)))
 
     def prefill(self, plan):
         """Run one iteration's prefill, ONE execution of the prefill
-        program: `plan` is [(slot, most_tokens), ...] over distinct
-        prefilling slots, and row r of the program carries the next
-        min(most_tokens, what is left) prompt tokens of its slot, padded
-        to the program's width (the longest row's tokens, to a whole
-        number of chunks).
+        program, and wait for its first tokens: `launch_prefill(plan)`,
+        then `collect_prefill()`.
 
         Returns [(tokens_consumed, first_token_or_None), ...] in the
         plan's order: first_token is the request's first sampled token,
@@ -819,6 +878,20 @@ class SlotEngine(KeySchedules):
         long prompt spreads over several iterations, so that decode
         steps for the other slots interleave). The first tokens of all
         rows are fetched in one wait."""
+        self.launch_prefill(plan)
+        return self.collect_prefill()
+
+    def launch_prefill(self, plan):
+        """Dispatch one prefill program and wait for nothing: `plan` is
+        [(slot, most_tokens), ...] over distinct prefilling slots, and
+        row r of the program carries the next min(most_tokens, what is
+        left) prompt tokens of its slot, padded to the program's width
+        (the longest row's tokens, to a whole number of chunks). The
+        first-token program rides behind it where a row ends its prompt
+        and leaves that row's token at its lane on the device, so the
+        slot decodes from the next `launch_decode` on, whenever the
+        tokens are fetched (`collect_prefill`). Returns the tokens each
+        row takes."""
         slots, start, n_real, tokens, ends = self._rows_of(plan)
         # before the program is queued: a sampled request's schedule is
         # drawn here, and its fetch would wait behind the program
@@ -834,14 +907,24 @@ class SlotEngine(KeySchedules):
         if ends.any():
             # the rows that do not end sample too, greedily, and are not
             # read: one program whatever the rows that end
-            first = self._first_fn(
+            first, self._d_tok = self._first_fn(
                 logits,
                 jnp.asarray(np.zeros_like(n_real) if self._tail
                             else n_real - 1),
-                *map(jnp.asarray, sampling))
+                *map(jnp.asarray, sampling), self._d_tok,
+                jnp.asarray(slots), jnp.asarray(ends))
+            self._rows_done(slots[ends])
+        self._prefills.append((launch, n_real, ends, first))
+        return n_real.tolist()
+
+    def collect_prefill(self):
+        """[(tokens_consumed, first_token_or_None), ...] of the oldest
+        prefill program launched and not yet collected, in its plan's
+        order; the host waits here, once, where a row ended its prompt."""
+        launch, n_real, ends, first = self._prefills.popleft()
+        if first is not None:
             with self.phases("engine.first_token.fetch", awaits=launch):
-                first = np.asarray(first)   # the host waits here, once
-            self._rows_done(slots[ends], first[ends])
+                first = np.asarray(first)
         return [(int(n), int(first[r]) if ends[r] else None)
                 for r, n in enumerate(n_real)]
 
@@ -858,7 +941,8 @@ class SlotEngine(KeySchedules):
             raise ValueError("a prefill program takes distinct slots, "
                              "got %r" % (slots.tolist(),))
         for slot in slots:
-            if not self.active[slot] or self.decoding[slot]:
+            if not self.active[slot] or self.decoding[slot] or \
+                    self._prefill_cursor[slot] >= self._prompt[slot].size:
                 raise ValueError("slot %d is not prefilling" % slot)
         start = self._prefill_cursor[slots]
         sizes = np.asarray([self._prompt[s].size for s in slots])
@@ -892,23 +976,34 @@ class SlotEngine(KeySchedules):
                 np.where(ends, self._temp[slots], 0.0).astype(np.float32),
                 self._top_k[slots], self._top_p[slots])
 
-    def _rows_done(self, done, first):
-        """Slots `done` ended their prompt with the tokens `first`: they
-        decode from the next step on."""
+    def _rows_done(self, done):
+        """Slots `done` ended their prompt in a program just launched,
+        which leaves each one's first token at its lane on the device:
+        they decode from the next launch on."""
         self.decoding[done] = True
-        self._tok[done] = first
         self._key_cursor[done] += 1
         self._dirty = True
+        self._emitted_one(done)
+
+    def _emitted_one(self, slots):
+        """One more token of each of `slots` is launched: a lane that
+        has its last one rides the later launches masked (its slot stays
+        its request's until `release`)."""
+        self._emitted[slots] += 1
+        last = slots[self._emitted[slots] >= self._max_new[slots]]
+        if last.size:
+            self.decoding[last] = False
+            self._dirty = True
 
     def stage_rows(self, plan):
-        """`prefill(plan)` for a stack whose rows ride in the decode step
-        (`merges`): the rows are built (host arrays: they go to the
-        device with the step's call, not one upload each), their slots'
-        cursors moved, and the NEXT `decode_step` carries them, one
-        execution that reads every weight once; `row_results` then holds
-        what `prefill` would have returned, and a row that ends its
-        prompt decodes from the step after. Returns the tokens each row
-        takes."""
+        """`launch_prefill(plan)` for a stack whose rows ride in the
+        decode step (`merges`): the rows are built (host arrays: they go
+        to the device with the step's call, not one upload each), their
+        slots' cursors moved, and the NEXT `launch_decode` carries them,
+        one execution that reads every weight once; when that launch is
+        collected `row_results` holds what `prefill` would have returned,
+        and a row that ends its prompt decodes from the launch after its
+        own. Returns the tokens each row takes."""
         if not self.merges:
             raise ValueError("this engine's rows take a program of their "
                              "own (prefill); its stack does not merge")
@@ -916,7 +1011,7 @@ class SlotEngine(KeySchedules):
         self._advance_rows(slots, start + n_real)
         self._staged = (slots, n_real, ends, {
             "tokens": tokens, "slots": slots, "start": start,
-            "n_real": n_real})
+            "n_real": n_real, "ends": ends})
         return n_real.tolist()
 
     def prefill_step(self, slot):
@@ -924,45 +1019,52 @@ class SlotEngine(KeySchedules):
         Returns (tokens_consumed, first_token_or_None)."""
         return self.prefill([(slot, self.prefill_chunk)])[0]
 
-    def decode_step(self):
-        """One fused decode step over the WHOLE pool. Returns a dict
-        {slot: token} for slots in the decode state; other slots ride
-        through as masked lanes (their KV writes are overwritten before
-        becoming visible, their recurrent state is held). Advances
-        pos/key cursors for decoding slots only.
+    def launch_decode(self):
+        """Dispatch one fused decode step over the WHOLE pool and wait
+        for nothing. Returns the slots that decode in it (None, and
+        nothing is dispatched, where no slot is in the decode state and no
+        row is staged); the other slots ride through as masked lanes
+        (their KV writes are overwritten before becoming visible, their
+        recurrent state is held).
 
         With rows staged (`stage_rows`) the same program takes them
         along, under the same name, dispatch and fetch spans (with no
         lane decoding it runs for the rows alone): the rows' K and V are
         written at their slots, each row's token after its last real
-        position comes back with the lanes' in the one fetch, and
-        `row_results` holds [(tokens_consumed, first_token_or_None), ...]
-        in the plan's order. The sampled program runs if a decoding lane
-        or a row that ends its prompt is sampled.
+        position comes back with the lanes' in the one fetch, and a row
+        that ends its prompt leaves it at its lane for the next launch.
+        The sampled program runs if a decoding lane or a row that ends
+        its prompt is sampled.
 
         Steady state stays on device: tok/pos flow out of one jitted call
         and back into the next; only the per-step sampling keys upload
-        (and only when a sampled slot is active). Host mirrors replay the
-        same masked advance, so they stay exact without a download."""
-        staged, self._staged, self.row_results = self._staged, None, []
+        (and only when a sampled slot is active). The host mirrors replay
+        the same masked advance HERE, without the tokens: positions, key
+        cursors, the rows that now decode and the lanes that have their
+        last token launched are all known before anything is fetched, so
+        the next launch can be made while this one runs. `ahead` on the
+        dispatch span says whether another launch was uncollected."""
+        staged, self._staged = self._staged, None
         rows = None
         if staged is not None:
             row_slots, n_real, ends, rows = staged
-        decoding = [i for i in range(self.max_slots) if self.decoding[i]]
-        if not decoding and rows is None:
-            return {}
+        decoding = np.flatnonzero(self.decoding)
+        if not decoding.size and rows is None:
+            return None
         if self._dirty:
             with self.phases("engine.decode.upload"):
-                self._d_tok = jnp.asarray(self._tok)
-                self._d_pos = jnp.asarray(self.pos)
-                self._d_mask = jnp.asarray(self.decoding)
-                self._d_temp = jnp.asarray(self._temp)
-                self._d_top_k = jnp.asarray(self._top_k)
-                self._d_top_p = jnp.asarray(self._top_p)
+                # copies: the mirrors move on before the program that
+                # reads an upload has run, and an upload may read the
+                # host's array as late as that
+                self._d_pos, self._d_mask, self._d_temp, self._d_top_k, \
+                    self._d_top_p = (jnp.asarray(mirror.copy()) for mirror in (
+                        self.pos, self.decoding, self._temp, self._top_k,
+                        self._top_p))
                 self._dirty = False
         self.launches += 1
-        with self.phases("engine.decode.dispatch", launch=self.launches):
-            if any(self._temp[i] > 0.0 for i in decoding) or (
+        with self.phases("engine.decode.dispatch", launch=self.launches,
+                         ahead=int(bool(self._decodes))):
+            if (self._temp[decoding] > 0.0).any() or (
                     rows is not None
                     and (self._temp[row_slots[ends]] > 0.0).any()):
                 for i in decoding:
@@ -972,34 +1074,52 @@ class SlotEngine(KeySchedules):
                         row_slots, ends)
                     rows = dict(rows, keys=keys, temp=temp, top_k=top_k,
                                 top_p=top_p)
-                out, self._d_tok, self._d_pos, self._cache = \
+                out, self._d_tok, self._d_pos, self._cache, pairs = \
                     self._decode_sampled_fn(
                         self.params, self._cache, self._d_tok, self._d_pos,
-                        self._d_mask, jnp.asarray(self._keys), self._d_temp,
+                        self._d_mask, jnp.asarray(self._keys.copy()),
+                        self._d_temp,
                         self._d_top_k, self._d_top_p, rows)
             else:
-                out, self._d_tok, self._d_pos, self._cache = \
+                out, self._d_tok, self._d_pos, self._cache, pairs = \
                     self._decode_greedy_fn(
                         self.params, self._cache, self._d_tok, self._d_pos,
                         self._d_mask, rows)
-        with self.phases("engine.decode.fetch", awaits=self.launches):
+        self.pos[decoding] += 1
+        self._key_cursor[decoding] += 1
+        self._emitted_one(decoding)
+        if rows is not None and ends.any():
+            self._rows_done(row_slots[ends])
+        self._decodes.append((self.launches, decoding, out, pairs,
+                              None if staged is None else (n_real, ends)))
+        return decoding.tolist()
+
+    def decode_step(self):
+        """{slot: token} of the OLDEST decode step launched and not yet
+        collected: the one place the host waits for the device. With none
+        in flight it launches one first, so a caller that keeps nothing
+        ahead gets one whole fused step a call, as ever ({} where no slot
+        is in the decode state and no row is staged); a loop that keeps a
+        step in flight calls `launch_decode` for step n+1 and then this
+        for step n. Where the step carried rows, `row_results` then holds
+        [(tokens_consumed, first_token_or_None), ...] in the plan's
+        order (else [])."""
+        if not self._decodes and self.launch_decode() is None:
+            self.row_results = []
+            return {}
+        launch, decoding, out, pairs, rows = self._decodes.popleft()
+        with self.phases("engine.decode.fetch", awaits=launch):
             out = np.asarray(out)   # the host waits for the device here
-            if MOE_PAIRS in self._cache:   # computed by now: 8 bytes more
-                seen = np.asarray(self._cache[MOE_PAIRS])
+            if pairs is not None:   # computed by now: 8 bytes more
+                seen = np.asarray(pairs)
                 for name, n in zip(("routed", "held"),
                                    seen - self._pairs_seen):
                     self.expert_pairs[name] += int(n)
                 self._pairs_seen = seen
-        tokens = {}
-        for i in decoding:
-            tokens[i] = int(out[i])
-            self._tok[i] = out[i]
-            self.pos[i] += 1
-            self._key_cursor[i] += 1
+        self.row_results = []
         if rows is not None:   # the rows' tokens lie after the lanes'
+            n_real, ends = rows
             first = out[self.max_slots:]
-            if ends.any():
-                self._rows_done(row_slots[ends], first[ends])
             self.row_results = [(int(n), int(first[r]) if ends[r] else None)
                                 for r, n in enumerate(n_real)]
-        return tokens
+        return {int(i): int(out[i]) for i in decoding}

@@ -375,6 +375,8 @@ class PagedEngine(KeySchedules):
         # the engine.* spans' ledger and the launch counter, as SlotEngine
         self.phases = telemetry.PhaseLedger()
         self.launches = 0
+        # what the last launch_prefill / launch_decode computed
+        self._prefilled = self._stepped = None
         # counters
         self.kv_bytes_copied = 0   # host<->page copies (0 on zero-copy hits)
         self.cow_pages = 0         # partial tail pages privatized
@@ -765,6 +767,40 @@ class PagedEngine(KeySchedules):
             out.append((consumed, first))
         return out
 
+    # a speculative burst's acceptance, and so every slot's next position,
+    # is read off the fetched tokens: this engine runs nothing ahead. In
+    # the scheduler's contract (SlotEngine's launch and collect) its
+    # launch computes the program whole and its collect hands that out
+    runs_ahead = False
+
+    def launch_prefill(self, plan):
+        self._prefilled = self.prefill(plan)
+        return [n for n, _ in self._prefilled]
+
+    def collect_prefill(self):
+        out, self._prefilled = self._prefilled, None
+        return out
+
+    def launch_decode(self):
+        """The slots that decode in the step, which is computed here
+        (None where there is none); `decode_step` hands it out."""
+        decoding = np.flatnonzero(self.decoding).tolist()
+        if not decoding:
+            return None
+        self._stepped = self._step(decoding)
+        return decoding
+
+    def decode_step(self):
+        """One fused step over the whole pool. Returns {slot: token}
+        (plain path) or {slot: [tokens]} (speculative path — up to
+        spec_k+1 tokens per slot per step). The scheduler treats both
+        shapes uniformly. What `launch_decode` computed, where it was
+        called first."""
+        if self._stepped is None and self.launch_decode() is None:
+            return {}
+        out, self._stepped = self._stepped, None
+        return out
+
     def prefill_step(self, slot):
         """Write the next prompt chunk of `slot` through its block
         table: returns (tokens_consumed, first_token_or_None)."""
@@ -828,20 +864,17 @@ class PagedEngine(KeySchedules):
                 self._d_top_p = jnp.asarray(self._top_p)
                 self._dirty = False
 
-    def decode_step(self):
-        """One fused step over the whole pool. Returns {slot: token}
-        (plain path) or {slot: [tokens]} (speculative path — up to
-        spec_k+1 tokens per slot per step). The scheduler treats both
-        shapes uniformly."""
-        decoding = [i for i in range(self.max_slots) if self.decoding[i]]
-        if not decoding:
-            return {}
+    def _step(self, decoding):
+        """The fused step for the slots `decoding`, whole: dispatched and
+        fetched (`ahead=0` on its dispatch span: nothing is ever left
+        uncollected here)."""
         sampled = any(self._temp[i] > 0.0 for i in decoding)
         if self.spec_k > 0 and not sampled:
             return self._spec_decode_step(decoding)
         self._stage()
         self.launches += 1
-        with self.phases("engine.decode.dispatch", launch=self.launches):
+        with self.phases("engine.decode.dispatch", launch=self.launches,
+                         ahead=0):
             if sampled:
                 for i in decoding:
                     self._keys[i] = self._keys_for(i)
@@ -885,7 +918,8 @@ class PagedEngine(KeySchedules):
         toks = np.concatenate([self._tok[:, None], drafts], axis=1)
         self._stage()
         self.launches += 1
-        with self.phases("engine.decode.dispatch", launch=self.launches):
+        with self.phases("engine.decode.dispatch", launch=self.launches,
+                         ahead=0):
             out, self.pool.kv = self._spec_fn(
                 self.params, self.pool.kv, jnp.asarray(toks), self._d_pos,
                 self._d_tables)

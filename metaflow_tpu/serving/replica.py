@@ -38,22 +38,17 @@ from .. import knobs
 
 def _add_step_delay(engine, delay_s):
     """Each prefill program / fused decode step holds its slots for
-    `delay_s` more wall seconds (GIL released)."""
-    real_decode = engine.decode_step
-    real_prefill = engine.prefill
+    `delay_s` more wall seconds (GIL released), where the scheduler
+    launches the one and collects the other."""
+    def delayed(call):
+        def slower(*args):
+            out = call(*args)
+            time.sleep(delay_s)
+            return out
+        return slower
 
-    def decode_step():
-        out = real_decode()
-        time.sleep(delay_s)
-        return out
-
-    def prefill(plan):
-        out = real_prefill(plan)
-        time.sleep(delay_s)
-        return out
-
-    engine.decode_step = decode_step
-    engine.prefill = prefill
+    engine.decode_step = delayed(engine.decode_step)
+    engine.launch_prefill = delayed(engine.launch_prefill)
 
 
 def _warm(engine):

@@ -11,16 +11,44 @@ One scheduler iteration (`step()`):
   1. reap  — cancelled/deadline-expired requests free their slot NOW
   2. admit — free slots refill from the queue head (FIFO)
   3. prefill — up to `prefill_budget` prompt tokens in ONE engine
-     program (engine.prefill): the budget's k whole chunks go to up to
-     k prefilling slots, a row each, round-robin, or to a lone slot as
-     one row of k chunks (`_prefill_plan`); every slot whose prompt ends
-     in the program emits its first token (TTFT) and joins the decode
-     set. The program's shapes are compiled when the scheduler is built
-     (engine.warm_prefill); prefill_programs / prefill_rows /
+     program (engine.launch_prefill): the budget's k whole chunks go to
+     up to k prefilling slots, a row each, round-robin, or to a lone slot
+     as one row of k chunks (`_prefill_plan`). The program is dispatched
+     and not waited for. Its shapes are compiled when the scheduler is
+     built (engine.warm_prefill); prefill_programs / prefill_rows /
      prefill_tokens count how often it engages
-  4. decode — ONE fused jitted step advances every decoding slot; eos /
-     max_new_tokens finishes a request and releases its slot immediately
-     (the next iteration's admit refills it — no lockstep)
+  4. launch — ONE fused jitted step for every decoding slot is
+     dispatched (engine.launch_decode), the slots whose prompt ended in
+     3 among them; nothing is waited for
+  5. collect — the step launched by the iteration BEFORE is fetched
+     (engine.decode_step, the one place the loop waits for the
+     device) and its tokens delivered; eos / max_new_tokens finishes a
+     request and releases its slot (a later iteration's admit refills
+     it — no lockstep). Then the first tokens of 3's program are fetched
+     (engine.collect_prefill) and delivered (TTFT)
+
+ONE DECODE STEP IN FLIGHT. The device runs step n while the host
+delivers step n-1, reaps, admits, plans, uploads and dispatches step n+1,
+so an iteration lasts the longer of the device's work and the host's, not
+their sum. What makes it possible is that everything a launch needs is
+known without the tokens of the step in flight: positions, key cursors,
+which rows end their prompt (they decode from the very next launch, their
+first token put at their lane on the device), and which lanes have their
+last token launched (max_new_tokens; they ride the next launch masked).
+Only `eos` is learnt late: that lane runs one more step, whose token is
+dropped at delivery (the stream is token for token what it was; the
+write lies past the request's last position). A slot freed by step n's
+tokens is refilled for launch n+2, and a request that arrives while step
+n runs rides n+2 where launch n+1 is already made: up to a step later
+than before. A request that is cancelled or expires with a token in
+flight loses it. `drain`, `stop` and `run_until_idle` collect the step in
+flight before they return. steps_ahead counts the launches made while
+another was uncollected (all but the first of a loaded loop). An engine
+that cannot launch without the last step's tokens (`runs_ahead` false:
+the paged engine, whose speculative bursts accept by them) computes a
+step in its launch and hands it out in its collect, which then follows in
+the same iteration, 3's first tokens before 4: the loop is the same,
+steps_ahead stays 0.
 
 Where the engine's stack merges (SlotEngine.merges: attention layers
 throughout, one chip, the chunk loop: Llama, Mistral, Mixtral), 3 only
@@ -28,9 +56,10 @@ STAGES the plan's rows (engine.stage_rows, still under
 serve.prefill_chunk) and 4's decode step takes them along: ONE execution
 an iteration reads every weight once, where the two programs of an
 iteration that prefills each read them all. The step then runs with no
-lane decoding too, the rows' first tokens are delivered after the lanes'
-tokens, and a request whose prompt ends in the step decodes from the
-next iteration on (on the two-program path it decodes in the same one).
+lane decoding too, and when it is collected the rows' first tokens are
+delivered after the lanes' tokens; a request whose prompt ends in the
+step decodes from the next launch on (on the two-program path it decodes
+in the same iteration's).
 merged_steps counts the decode steps that carried rows; each is one of
 decode_steps and one of prefill_programs. A stack with recurrent layers,
 rings or a tail layer (Jamba, Brumby, Phi-4-mini-flash), the paged
@@ -52,9 +81,11 @@ open, a flag check otherwise) and, always, its seconds and one call under
 the same name on the host's clock: serve.iteration around step(), inside
 it serve.reap, serve.admit, serve.prefill_chunk (one a prefill program:
 rows, tokens, and request_ids, slots, row_tokens a row) and
-serve.decode_step (the two timers; the step says the prefill_rows and
-prefill_tokens that rode in it), serve.deliver; the engine's engine.*
-phases inside admit and the two timers; `wait` around the loop's sleep
+serve.decode_step (the two timers; the step holds this iteration's launch
+and the fetch of the one before, and says the lanes, prefill_rows and
+prefill_tokens of the LAUNCH), serve.deliver; the engine's engine.*
+phases inside admit and the two timers, engine.first_token.fetch after
+serve.deliver; `wait` around the loop's sleep
 when an iteration found nothing to do (docs/observability.md has the
 table). stats()["phases"] sums them, stats()["slow_iterations"] keeps the
 slowest of the last SLOW_WINDOW iterations phase by phase, stats()["gc"]
@@ -177,6 +208,10 @@ class Request(object):
         self.prefilled = prefilled
         self.handoff = None
         self._prefix_handle = None   # pinned prefix-cache match
+        # prompt tokens no program was launched for yet: at 0 the prompt
+        # has ended as far as planning goes, though the first token may
+        # still be in flight (state stays "prefill" until it is delivered)
+        self._prompt_left = len(self.tokens)
         self._cancelled = threading.Event()
 
     def cancel(self):
@@ -272,6 +307,21 @@ class Scheduler(object):
         # decode_steps AND one of prefill_programs, ONE execution
         self.merged_steps = 0
         self._rows_staged = None   # (requests, slots, tokens) of them
+        # one decode step in flight: where the engine can launch a step
+        # without the last one's tokens (`runs_ahead`), an iteration
+        # launches its step and THEN collects and delivers the one before,
+        # so that the device has its next program queued while the host
+        # reaps, admits, plans, uploads and delivers. `_in_flight` holds
+        # what each uncollected launch carried, oldest first: ({slot:
+        # request} of its lanes, the rows staged into it or None);
+        # `_prefilled` the rows of a prefill program whose first tokens
+        # are not collected yet. steps_ahead counts the launches made
+        # while another was uncollected: all but the first of a loaded
+        # loop, none for an engine that cannot run ahead
+        self._ahead = 1 if getattr(engine, "runs_ahead", False) else 0
+        self._in_flight = deque()
+        self._prefilled = None
+        self.steps_ahead = 0
         # the requests bound to a slot; the engine's `key_schedules`
         # beside it says how many of them drew their sampling keys (none
         # under greedy traffic: engine.KeySchedules)
@@ -612,8 +662,11 @@ class Scheduler(object):
                         temperature=req.temperature, top_k=req.top_k,
                         top_p=req.top_p, rng=req.rng)
                 else:
+                    # a prefill-only request ends with its first token:
+                    # its lane never decodes
                     self.engine.admit(
-                        slot, req.tokens, req.max_new_tokens,
+                        slot, req.tokens,
+                        1 if req.prefill_only else req.max_new_tokens,
                         temperature=req.temperature, top_k=req.top_k,
                         top_p=req.top_p, rng=req.rng)
             except PageExhaustedError:
@@ -707,6 +760,7 @@ class Scheduler(object):
         else:
             self.engine.seed_prefix(slot, handle.kv())
         req._prefix_handle = handle
+        req._prompt_left -= handle.length
         self.prefix_hits += 1
         self.prefix_hit_tokens += handle.length
         telemetry.event("serve.prefix.hit", data=self._tdata(req, {
@@ -722,7 +776,7 @@ class Scheduler(object):
         k // rows chunks that keep rows x width inside the budget: a
         lone prefilling slot gets one row of the whole budget."""
         slots = [s for s, r in sorted(self._slots.items())
-                 if r.state == "prefill"]
+                 if r.state == "prefill" and r._prompt_left]
         if not slots:
             return []
         chunk = self.engine.prefill_chunk
@@ -755,21 +809,32 @@ class Scheduler(object):
             if merged:   # staged: this iteration's decode step takes them
                 consumed = self.engine.stage_rows(plan)
                 self._rows_staged = (reqs, slots, sum(consumed))
-            else:
-                results = self.engine.prefill(plan)
-                consumed = [n for n, _ in results]
+            else:        # dispatched: its first tokens are collected later
+                consumed = self.engine.launch_prefill(plan)
+                self._prefilled = (reqs, slots)
             chunk.set(tokens=sum(consumed), row_tokens=consumed)
+        for req, n in zip(reqs, consumed):
+            req._prompt_left -= n
         self.prefill_programs += 1
         self.prefill_rows += len(plan)
         self.prefill_tokens += sum(consumed)
-        if not merged:
-            self._first_tokens(reqs, slots, results)
+        if not self._ahead:   # before the decode step that follows them
+            self._collect_prefill()
         return len(plan)
 
+    def _collect_prefill(self):
+        """Fetch and hand out the first tokens of the prefill program
+        launched this iteration, if there is one."""
+        launched, self._prefilled = self._prefilled, None
+        if launched is not None:
+            self._first_tokens(*launched, self.engine.collect_prefill())
+
     def _first_tokens(self, reqs, slots, results):
-        """Hand out the first token of every row that ended its prompt."""
+        """Hand out the first token of every row that ended its prompt
+        (not to a request that was cancelled or expired, and left its
+        slot, with its row in flight)."""
         for req, slot, (_, first) in zip(reqs, slots, results):
-            if first is not None:
+            if first is not None and self._slots.get(slot) is req:
                 self._prefill_done(req, slot, first)
 
     def _prefill_done(self, req, slot, first):
@@ -816,28 +881,49 @@ class Scheduler(object):
         self._deliver(req, first)
 
     def _decode(self):
-        """One decode step and its tokens' delivery; returns the lanes
-        that decoded (0 where no request is past its prefill). The rows
-        `_prefill` staged ride in it (with no lane decoding it runs for
-        them alone), and their requests' first tokens are delivered
-        after the lanes' tokens: a request whose prompt ends here decodes
-        from the next iteration on."""
+        """This iteration's decode step: launch it, then collect and
+        deliver the step in flight before it (the same step, where the
+        engine cannot run ahead). Returns (the lanes that decode in the
+        launch, whether a step was collected). The rows `_prefill` staged
+        ride in the launch (with no lane decoding it runs for them alone);
+        when it is collected their requests' first tokens are delivered
+        after the lanes' tokens, and a request whose prompt ends in it
+        decodes from the launch after its own, made before that."""
         staged, self._rows_staged = self._rows_staged, None
-        active = [r for r in self._slots.values() if r.state == "decode"]
-        if not active and staged is None:
-            return 0
+        if not (self._in_flight or staged or self.engine.decoding.any()):
+            return 0, False
+        with self.phases("serve.decode_step", record=True) as step:
+            lanes = self._launch(step, staged)
+            collected = None
+            if len(self._in_flight) > (self._ahead if lanes is not None
+                                       else 0):
+                requests, rows = self._in_flight.popleft()
+                collected = self.engine.decode_step()
+        if collected is not None:
+            self._deliver_step(requests, collected, rows)
+        return len(lanes or ()), collected is not None
+
+    def _launch(self, step, staged):
+        """Launch one decode step, under the open `serve.decode_step`
+        span `step`; returns the slots that decode in it, None where the
+        engine had nothing to launch."""
         reqs, slots, row_tokens = staged or ((), (), 0)
         stats = {"prefill_rows": len(reqs), "prefill_tokens": row_tokens,
                  "passes": self._passes}
         positions = getattr(self.engine, "attention_positions", None)
         if positions is not None:   # from the cursors, before they move
             needed, fetched = positions()
-            self.attention_positions_needed += needed
-            self.attention_positions_fetched += fetched
             stats.update(positions_needed=needed, positions_fetched=fetched)
-        with self.phases("serve.decode_step", record=True) as step:
-            tokens = self.engine.decode_step()
-            step.set(active=len(tokens), **stats)
+        lanes = self.engine.launch_decode()
+        if lanes is None:
+            return None
+        step.set(active=len(lanes), **stats)
+        self.attention_positions_needed += stats.get("positions_needed", 0)
+        self.attention_positions_fetched += stats.get("positions_fetched", 0)
+        self.steps_ahead += bool(self._in_flight)
+        self._in_flight.append((
+            {slot: self._slots[slot] for slot in lanes
+             if slot in self._slots}, staged and (reqs, slots)))
         self.decode_steps += 1
         self.merged_steps += staged is not None
         self._occupancy_sum += self.engine.occupancy()
@@ -850,11 +936,19 @@ class Scheduler(object):
             if ss["enabled"]:
                 telemetry.gauge("serve.spec.accept_rate",
                                 ss["accept_rate"])
+        return lanes
+
+    def _deliver_step(self, requests, tokens, rows):
+        """Deliver a collected step's tokens: `requests` is {slot:
+        request} as its launch bound them, `rows` the (requests, slots)
+        staged into it. The token of a lane whose request has left its
+        slot since (cancelled, expired, or ended by an `eos` that the
+        step before delivered) is dropped."""
         with self.phases("serve.deliver") as span:
             delivered = 0
             for slot, toks in tokens.items():
-                req = self._slots.get(slot)
-                if req is None:
+                req = requests.get(slot)
+                if req is None or self._slots.get(slot) is not req:
                     continue
                 # speculative decode emits up to spec_k+1 tokens per slot
                 # per step; eos/length inside the burst stops delivery of
@@ -866,9 +960,17 @@ class Scheduler(object):
                     self._deliver(req, token)
                     delivered += 1
             span.set(tokens=delivered)
-        if staged is not None:
-            self._first_tokens(reqs, slots, self.engine.row_results)
-        return len(tokens)
+        if rows is not None:
+            self._first_tokens(*rows, self.engine.row_results)
+
+    def _flush(self):
+        """Collect whatever is still in flight and deliver what has a
+        request to go to: the device is quiet and the engine's launches
+        are all collected when this returns."""
+        while self._in_flight:
+            requests, rows = self._in_flight.popleft()
+            self._deliver_step(requests, self.engine.decode_step(), rows)
+        self._collect_prefill()
 
     # ---------- the loop ----------
 
@@ -884,7 +986,8 @@ class Scheduler(object):
                 admitted = self._admit()
                 admit.set(admitted=admitted)
             rows = self._prefill()
-            lanes = self._decode()
+            lanes, fetched = self._decode()
+            self._collect_prefill()
             tokens = self.prefill_tokens - prefilled
             span.set(lanes=lanes, prefill_rows=rows, prefill_tokens=tokens,
                      admitted=admitted,
@@ -895,7 +998,7 @@ class Scheduler(object):
             self.iteration, _grown(phases.seconds, before), lanes, rows,
             tokens, admitted, _grown(self._gc.seconds, collected)))
         self.iteration += 1
-        return bool(admitted or rows or lanes)
+        return bool(admitted or rows or lanes or fetched)
 
     def pending(self):
         with self._cond:
@@ -912,6 +1015,7 @@ class Scheduler(object):
                 raise RuntimeError(
                     "scheduler did not go idle in %d iterations"
                     % max_iterations)
+        self._flush()   # an `eos` leaves one step in flight behind it
         return n
 
     def _on_gc(self, phase, info):
@@ -962,6 +1066,7 @@ class Scheduler(object):
             self._queue.clear()
         for req in leftovers:
             self._finish(req, "shutdown")
+        self._flush()   # its tokens have no request left to go to
 
     def start(self):
         if self._thread is not None:
@@ -1012,6 +1117,7 @@ class Scheduler(object):
             "served": self.served,
             "cancelled": self.cancelled_count,
             "decode_steps": self.decode_steps,
+            "steps_ahead": self.steps_ahead,
             "weight_passes": self.decode_steps * self._passes,
             "prefill_programs": self.prefill_programs,
             "prefill_rows": self.prefill_rows,

@@ -60,6 +60,9 @@ def main():
             if eng.merges:
                 rows = {"tokens": i32(R, W), "slots": i32(R),
                         "start": i32(R), "n_real": i32(R)}
+                if hasattr(eng, "launch_decode"):   # since PR 45: the
+                    # program leaves the token of a row that ends at its lane
+                    rows["ends"] = jax.ShapeDtypeStruct((R,), jnp.bool_)
                 texts["merged_%dx%d" % (R, W)] = \
                     eng._decode_greedy_fn.lower(
                         params, cache, i32(B), i32(B), mask,

@@ -1816,6 +1816,7 @@ OPENMETRICS_SERVE_METRICS = {
     "tpuflow_serve_cache_pool_bytes_per_slot": "gauge",
     "tpuflow_serve_requests": "counter",
     "tpuflow_serve_decode_steps": "counter",
+    "tpuflow_serve_steps_ahead": "counter",
     "tpuflow_serve_weight_passes": "counter",
     "tpuflow_serve_prefill": "counter",
     "tpuflow_serve_admissions": "counter",

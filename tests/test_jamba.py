@@ -283,6 +283,39 @@ def test_through_the_scheduler_each_request_emits_what_it_emits_alone(
     assert eng.compile_counts()["reset_state"] == 1
 
 
+def test_a_step_in_flight_leaves_every_state_as_it_was(fam, params, engine,
+                                                       alone):
+    """One decode step in flight (PR 45), for every family that carries
+    recurrent state: each request emits what it emits alone, every launch
+    but the first is made before the step before it is collected, and an
+    `eos`, learnt a step late, costs one more step of its lane, whose
+    update lands in a state that the slot's next occupant finds reset
+    (`engine.state.reset` queues behind that step in program order)."""
+    sched = Scheduler(engine)
+    first, second = prompt(37, salt=3), prompt(50, salt=4)
+    want = alone(first)
+    at = next(i for i in range(2, NEW - 1) if want[i] not in want[:i])
+    ends = sched.submit(Request(first.tolist(), max_new_tokens=NEW,
+                                eos_id=want[at]))
+    other = sched.submit(Request(second.tolist(), max_new_tokens=NEW))
+    for _ in range(200):
+        if ends.reason is not None:
+            break
+        sched.step()
+    assert ends.reason == "eos" and ends.generated == want[:at + 1]
+    assert ends.slot in sched._in_flight[-1][0]   # one more step of it
+    late = sched.submit(Request(prompt(16, salt=5).tolist(),
+                                max_new_tokens=NEW))
+    sched.run_until_idle(10_000)
+    assert late.slot == ends.slot and len(ends.generated) == at + 1
+    assert other.generated == alone(second)
+    assert late.generated == alone(prompt(16, salt=5))
+    stats = sched.stats()
+    assert stats["steps_ahead"] == stats["decode_steps"] - 1 > 0
+    assert not sched._in_flight and not engine._decodes
+    assert engine.free_slots() == list(range(engine.max_slots))
+
+
 @only_state_families
 def test_chunk_sizes_16_and_64_agree(fam, params, engine, alone):
     wide = SlotEngine(params, fam.cfg, max_slots=1, max_seq_len=128,
@@ -307,9 +340,9 @@ def test_uneven_prompts_admitted_together_emit_what_they_emit_alone(
         engine, alone, monkeypatch, lengths, rows):
     eng = engine   # three slots, chunks of 16, every slot released
     sched = Scheduler(eng)
-    plans, real = [], eng.prefill
+    plans, real = [], eng.collect_prefill
     monkeypatch.setattr(
-        eng, "prefill", lambda plan: plans.append(real(plan)) or plans[-1])
+        eng, "collect_prefill", lambda: plans.append(real()) or plans[-1])
     prompts = [prompt(n, salt=n) for n in lengths]
     reqs = [sched.submit(Request(p.tolist(), max_new_tokens=NEW))
             for p in prompts]

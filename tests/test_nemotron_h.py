@@ -352,7 +352,7 @@ def test_engine_serves_the_references_logits(params, dtype, limit):
         served[1].append(eng.decode_step()[1])
     eng.admit(0, second, 8)
     while len(served[0]) < 8 or len(served[1]) < 14:
-        if not eng.decoding[0]:
+        if not served[0]:   # still prefilling
             _, tok = eng.prefill_step(0)
             if tok is not None:
                 served[0].append(tok)

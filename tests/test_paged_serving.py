@@ -536,6 +536,46 @@ class TestPagedHTTP:
         _assert_pool_free(engine)
 
 
+class TestNothingAhead:
+    """The paged engine runs no step ahead (`runs_ahead` false: a burst's
+    acceptance is read off its tokens): through the same loop its launch
+    computes the step and its collect hands it out in the same iteration,
+    so it serves as it did before the loop kept a step in flight."""
+
+    @pytest.mark.parametrize("spec_k", [0, 3])
+    def test_steps_ahead_reads_zero_and_tokens_are_generates(
+            self, setup, engine, spec_k):
+        cfg, params = setup
+        eng = engine if spec_k == 0 else PagedEngine(
+            params, cfg, max_slots=4, max_seq_len=128, prefill_chunk=16,
+            page_tokens=PTOK, spec_k=spec_k)
+        assert eng.runs_ahead is False
+        sched = Scheduler(eng)
+        rng = np.random.default_rng(11)
+        reqs = [sched.submit(Request(
+            rng.integers(0, cfg.vocab_size, n).tolist(), max_new_tokens=m,
+            rng=i)) for i, (n, m) in enumerate(
+                [(5, 9), (16, 1), (33, 12), (40, 7), (17, 10), (3, 6)])]
+        delivered = []
+        while sched.pending():
+            before = sched.decode_steps
+            sched.step()
+            assert not sched._in_flight and sched._prefilled is None
+            # a step's tokens are delivered in the iteration that ran it
+            delivered.append((sched.decode_steps - before,
+                              sched._recent[-1][2]))
+        assert all((launched == 1) == (lanes > 0)
+                   for launched, lanes in delivered)
+        for req in reqs:
+            assert req.reason == "length"
+            assert req.generated == _ref_tokens(params, cfg, req)
+        stats = sched.stats()
+        assert stats["steps_ahead"] == 0 < stats["decode_steps"]
+        assert sched.phases.calls["serve.decode_step"] \
+            == sched.phases.calls["serve.deliver"] == stats["decode_steps"]
+        _assert_pool_free(eng)
+
+
 class TestSpeculativeDecoding:
     @pytest.fixture(scope="class")
     def spec_engine(self, setup):

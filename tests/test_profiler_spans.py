@@ -140,14 +140,37 @@ class TestSchedulerSpans:
                      "serve.decode_step", "serve.deliver"):
             parents(name, "serve.iteration")
         parents("engine.prefill.dispatch", "serve.prefill_chunk")
-        parents("engine.first_token.fetch", "serve.prefill_chunk")
+        # the first tokens are fetched after the decode step is launched
+        # and the step before it delivered: under the iteration alone
+        parents("engine.first_token.fetch", "serve.iteration")
+        chunks = [e for e in line if e[1] == "serve.prefill_chunk"]
+        assert not any(_inside(c, f) for c in chunks for f in line
+                       if f[1] == "engine.first_token.fetch")
         for name in ("engine.decode.upload", "engine.decode.dispatch",
                      "engine.decode.fetch"):
             parents(name, "serve.decode_step")
-        # deliver is the step's fan-out, after the engine call
-        deliver = [e for e in line if e[1] == "serve.deliver"]
+        # one decode step in flight: a step's span holds its launch and
+        # THEN the fetch of the launch before, and every launch but the
+        # first of a busy stretch is made with one uncollected
         steps = [e for e in line if e[1] == "serve.decode_step"]
-        assert len(deliver) == len(steps)
+        for step in steps:
+            inner = sorted((e for e in line if e is not step
+                            and _inside(step, e)
+                            and e[1] in ("engine.decode.dispatch",
+                                         "engine.decode.fetch")),
+                           key=lambda e: e[2])
+            assert [e[1] for e in inner] in (
+                ["engine.decode.dispatch"], ["engine.decode.fetch"],
+                ["engine.decode.dispatch", "engine.decode.fetch"])
+            if len(inner) == 2:
+                assert inner[0][4]["ahead"] == 1
+                assert inner[1][4]["awaits"] < inner[0][4]["launch"]
+        dispatched = [e[4]["ahead"] for e in line
+                      if e[1] == "engine.decode.dispatch"]
+        assert dispatched[0] == 0 and sum(dispatched) >= len(dispatched) - 2
+        # deliver is a collected step's fan-out, after the engine call
+        deliver = [e for e in line if e[1] == "serve.deliver"]
+        assert len(deliver) == len(dispatched) <= len(steps)
         assert not any(_inside(s, d) for s in steps for d in deliver)
         assert all(d[4]["tokens"] >= 1 for d in deliver)
         assert sum(e[4]["admitted"] for e in line
@@ -205,7 +228,10 @@ class TestSchedulerSpans:
         # five tokens a request: the first from its prefill
         assert sum(e[4]["delivered"] for e in iterations) \
             == 5 * len(PROMPTS)
-        steps = [e for e in events if e[1] == "serve.decode_step"]
+        # a step's span says what its LAUNCH held; the span of an
+        # iteration that only collects the step in flight says nothing
+        steps = [e for e in events if e[1] == "serve.decode_step"
+                 and "active" in e[4]]
         assert sum(e[4]["active"] for e in steps) == 4 * len(PROMPTS)
         assert sorted(e[4]["lanes"] for e in iterations
                       if e[4]["lanes"]) \
@@ -302,8 +328,11 @@ class TestMergedIteration:
                             "engine.first_token.fetch"}
         iterations = [e for e in events if e[1] == "serve.iteration"]
         chunks = [e for e in events if e[1] == "serve.prefill_chunk"]
-        steps = [e for e in events if e[1] == "serve.decode_step"]
-        assert chunks and all(
+        # a step's span says what its LAUNCH held; one that only collects
+        # the step in flight (the last of a busy stretch) says nothing
+        spans = [e for e in events if e[1] == "serve.decode_step"]
+        steps = [e for e in spans if e[4]]
+        assert chunks and len(spans) > len(steps) and all(
             {"prefill_rows", "prefill_tokens", "active", "positions_needed",
              "positions_fetched", "passes"} <= set(e[4]) for e in steps)
         merged = [e for e in steps if e[4]["prefill_rows"]]
@@ -329,7 +358,7 @@ class TestMergedIteration:
             assert len(chunk) <= 1 and len(step) <= 1
             launches = [e for e in inside
                         if e[1] == "engine.decode.dispatch"]
-            assert len(launches) == len(step)
+            assert len(launches) == len([e for e in step if e[4]])
             if chunk:   # the rows are staged, then ONE program carries them
                 assert chunk[0][3] <= step[0][2]
                 assert step[0][4]["prefill_rows"] == chunk[0][4]["rows"] \
@@ -337,21 +366,33 @@ class TestMergedIteration:
                 assert step[0][4]["prefill_tokens"] \
                     == chunk[0][4]["tokens"] == it[4]["prefill_tokens"]
                 assert _inside(step[0], launches[0])
-            elif step:
+            elif step and step[0][4]:
                 assert step[0][4]["prefill_rows"] == 0
 
-    def test_the_fetch_awaits_the_one_launch(self, captured_merged):
+    def test_the_fetch_awaits_the_oldest_launch(self, captured_merged):
+        """One step in flight: every launch is fetched once, in order,
+        and where `ahead` says so the next launch was dispatched before
+        that fetch."""
         line = sorted((e for e in captured_merged["events"]
                        if e[1] in ("engine.decode.dispatch",
                                    "engine.decode.fetch")),
                       key=lambda e: e[2])
         assert line and len(line) % 2 == 0
-        for dispatch, fetch in zip(line[::2], line[1::2]):
-            assert dispatch[1] == "engine.decode.dispatch"
-            assert fetch[1] == "engine.decode.fetch"
-            assert fetch[4]["awaits"] == dispatch[4]["launch"]
-        made = [e[4]["launch"] for e in line[::2]]
+        made = [e[4]["launch"] for e in line
+                if e[1] == "engine.decode.dispatch"]
         assert made == list(range(made[0], made[0] + len(made)))
+        assert [e[4]["awaits"] for e in line
+                if e[1] == "engine.decode.fetch"] == made
+        uncollected, ahead = 0, 0
+        for e in line:
+            if e[1] == "engine.decode.dispatch":
+                assert e[4]["ahead"] == min(uncollected, 1)
+                ahead += e[4]["ahead"]
+                uncollected += 1
+            else:
+                uncollected -= 1
+            assert 0 <= uncollected <= 2
+        assert ahead >= len(made) - 3   # all but a busy stretch's first
 
     def test_same_tokens_with_and_without_a_session(self, captured_merged):
         assert captured_merged["plain_tokens"] == captured_merged["tokens"]
@@ -368,7 +409,9 @@ class TestMergedIteration:
             engine.params, jax.eval_shape(lambda: engine._cache), i32(B),
             i32(B), jax.ShapeDtypeStruct((B,), jnp.bool_),
             {"tokens": i32(2, 8), "slots": i32(2), "start": i32(2),
-             "n_real": i32(2)}).as_text(debug_info=True)
+             "n_real": i32(2),
+             "ends": jax.ShapeDtypeStruct((2,), jnp.bool_)}
+        ).as_text(debug_info=True)
         assert "module @jit__decode_greedy" in text
         for scope in DECODE_SCOPES:
             assert _has(text, scope), scope

@@ -195,14 +195,14 @@ class TestOnePrefillProgramAnIteration:
         cfg, params, eng, programs = family_engine
         sched = Scheduler(eng)
         plans = []
-        real = eng.prefill
-        eng.prefill = lambda plan: plans.append(real(plan)) or plans[-1]
+        real = eng.collect_prefill
+        eng.collect_prefill = lambda: plans.append(real()) or plans[-1]
         try:
             reqs = [sched.submit(Request(p, max_new_tokens=6, rng=i))
                     for i, p in enumerate(_prompts(cfg, lengths))]
             sched.run_until_idle(10_000)
         finally:
-            eng.prefill = real
+            eng.collect_prefill = real
         for req in reqs:
             assert req.generated == _ref_tokens(params, cfg, req)
         # the tokens each program's rows consumed, program by program
@@ -348,8 +348,11 @@ class TestRowsRideInTheDecodeStep:
         assert after["_first_fn"] == before["_first_fn"]
         stats = sched.stats()
         assert stats["merged_steps"] == stats["prefill_programs"] > 0
+        # every iteration launches a step but the last, which collects
+        # the one in flight; every launch but the first is made ahead
         assert stats["decode_steps"] == after["_decode_greedy_fn"] \
-            - before["_decode_greedy_fn"] == stats["iterations"]
+            - before["_decode_greedy_fn"] == stats["iterations"] - 1 \
+            == stats["steps_ahead"] + 1
         assert stats["prefill_tokens"] == sum(map(len, prompts))
 
     def test_the_pool_is_the_two_program_paths_at_every_real_position(
@@ -481,12 +484,14 @@ class TestRowsRideInTheDecodeStep:
         while sched.pending():
             sched.step()
             held.append(sched._recent[-1][2:4])   # (lanes, rows)
-        # 32 + 5 tokens with no lane decoding, then three decode steps:
-        # the first token came from the second program's row
-        assert held == [(0, 1), (0, 1), (1, 0), (1, 0), (1, 0)]
+        # 32 + 5 tokens with no lane decoding, then three decode steps
+        # launched (the first token came from the second program's row,
+        # and its lane decodes in the very next launch), then the
+        # iteration that collects the last of them
+        assert held == [(0, 1), (0, 1), (1, 0), (1, 0), (1, 0), (0, 0)]
         assert req.generated == _ref_tokens(params, cfg, req)
         assert _ran(calls)["_decode_greedy_fn"] \
-            - before["_decode_greedy_fn"] == len(held) \
+            - before["_decode_greedy_fn"] == len(held) - 1 \
             == sched.stats()["decode_steps"]
         assert sched.stats()["merged_steps"] == 2
 
@@ -981,10 +986,10 @@ class TestPhaseLedger:
     ["slow_iterations"], ["gc"]."""
 
     INNER = ("serve.reap", "serve.admit", "serve.prefill_chunk",
-             "serve.decode_step", "serve.deliver")
+             "serve.decode_step", "serve.deliver",
+             "engine.first_token.fetch")
     NESTED = {"serve.prefill_chunk": ("engine.admit.keys",
-                                      "engine.prefill.dispatch",
-                                      "engine.first_token.fetch"),
+                                      "engine.prefill.dispatch"),
               "serve.decode_step": ("engine.decode.upload",
                                     "engine.decode.dispatch",
                                     "engine.decode.fetch")}
@@ -1022,9 +1027,13 @@ class TestPhaseLedger:
         assert calls["serve.iteration"] == calls["serve.reap"] \
             == calls["serve.admit"] == phases["iterations"] \
             == stats["iterations"]
-        assert calls["serve.decode_step"] == calls["serve.deliver"] \
+        # a step is launched in one iteration and collected in the next:
+        # an iteration with no lane left to launch (the last, at least)
+        # holds a span with a fetch and no launch
+        assert calls["serve.decode_step"] > calls["serve.deliver"] \
             == calls["engine.decode.dispatch"] \
-            == calls["engine.decode.fetch"] == stats["decode_steps"]
+            == calls["engine.decode.fetch"] == stats["decode_steps"] \
+            > stats["steps_ahead"] > 0
         assert calls["serve.prefill_chunk"] \
             == calls["engine.prefill.dispatch"] == stats["prefill_programs"]
         assert calls["engine.first_token.fetch"] <= len(reqs)
@@ -1045,19 +1054,18 @@ class TestPhaseLedger:
         # the steps counted (a record rounds its milliseconds to three
         # places)
         records = telemetry.read_run_records(fds, "1")
-        for name, busy, count in (
-                ("serve.decode_step", sched.busy_decode_s,
-                 sched.decode_steps),
-                ("serve.prefill_chunk", sched.busy_prefill_s,
-                 sched.prefill_programs)):
+        for name, busy in (("serve.decode_step", sched.busy_decode_s),
+                           ("serve.prefill_chunk", sched.busy_prefill_s)):
             mine = [r for r in records if r["name"] == name]
-            assert len(mine) == count > 0
+            count = len(mine)
+            assert count == calls[name] > 0
             assert busy * 1e3 == pytest.approx(
                 sum(r["ms"] for r in mine), abs=1e-3 * count)
         assert stats["goodput"]["serve_decode_s"] == round(
             sched.busy_decode_s, 3)
         steps = [r["data"] for r in records
-                 if r["name"] == "serve.decode_step"]
+                 if r["name"] == "serve.decode_step" and r.get("data")]
+        assert len(steps) == stats["decode_steps"]
         assert sum(d["positions_needed"] for d in steps) \
             == stats["attention_positions_needed"]
         assert sum(d["positions_fetched"] for d in steps) \

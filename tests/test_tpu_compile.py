@@ -433,7 +433,9 @@ def test_merged_step_holds_no_second_pool_and_holds_the_kernel(one_chip,
     """The same two cells' decode step with a prefill program's rows
     riding in it (the widest row and the two rows; [1, 64] is [1, 128]'s
     program at half the width, and `benchmark/describe_compile.py` has no
-    part in it: PERF.md section 4 has all three): the lanes' attention is
+    part in it: PERF.md section 4 has all three), `ends` among the rows'
+    arrays and the tokens of the rows that end put at their lanes (PR
+    45): the lanes' attention is
     still the kernel that reads the pool as stored, and the rows' writes,
     views and chunk loop beside it make the compiler copy no pool (the
     expert layer's buffers at 192 tokens are the batch cell's 47e6 B;
@@ -447,12 +449,52 @@ def test_merged_step_holds_no_second_pool_and_holds_the_kernel(one_chip,
     for rows, width in shapes[1:]:
         merged = engine._decode_greedy_fn.lower(*args, {
             "tokens": i32(rows, width), "slots": i32(rows),
-            "start": i32(rows), "n_real": i32(rows)}).compile()
+            "start": i32(rows), "n_real": i32(rows),
+            "ends": sds((rows,), jnp.bool_, one_chip)}).compile()
         text = merged.as_text()
         assert "tpu_custom_call" in text and "pool_attention" in text
         assert merged.memory_analysis().temp_size_in_bytes < layer / 2, \
             (rows, width)
         assert device_bytes(merged) < HBM_BYTES
+
+
+def test_first_token_program_leaves_its_tokens_at_the_lanes(one_chip):
+    """The first-token program of a stack whose rows take a program of
+    their own (the Jamba cell at the benchmark's widths and slots, two
+    layers deep): it samples the rows' tokens AND puts those of the rows
+    that end at their lanes among the device's last tokens, so that the
+    next decode step is launched with nothing fetched in between (PR 45).
+    Its temporaries are the sampler's over two rows of logits, nothing of
+    the size of a pool, and the lanes' tokens come out as they went in,
+    one int32 a slot."""
+    from benchmark import configs, weights
+    from metaflow_tpu.serving import SlotEngine
+
+    _, _, config, _ = configs.load_cell("jamba2-3b.reason-steady")
+    config["num_hidden_layers"], serving = 2, config["serving"]
+    _, cfg = configs.program_config(config, serving["max_seq_len"])
+    params = jax.eval_shape(lambda: weights.init_params(
+        jax.random.PRNGKey(0), configs.dims(config)))
+    B = serving["slots"]
+    engine = SlotEngine(params, cfg, max_slots=B,
+                        max_seq_len=serving["max_seq_len"],
+                        prefill_chunk=serving["prefill_chunk"])
+    assert not engine.merges and engine.recurrent
+    pool = min(math.prod(a.shape) * a.dtype.itemsize
+               for a in jax.tree.leaves(jax.eval_shape(
+                   lambda: engine._cache)) if a.ndim > 1)
+    i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
+    f32 = lambda *shape: sds(shape, jnp.float32, one_chip)
+    for rows, width in engine.prefill_shapes(2 * engine.prefill_chunk)[1:]:
+        args = (f32(rows, width, cfg.vocab_size), i32(rows),
+                sds((rows, 2), jnp.uint32, one_chip), f32(rows), i32(rows),
+                f32(rows), i32(B), i32(rows),
+                sds((rows,), jnp.bool_, one_chip))
+        first = engine._first_fn.lower(*args).compile()
+        assert [o.shape for o in jax.eval_shape(engine._first_fn, *args)] \
+            == [(rows,), (B,)]
+        assert first.memory_analysis().temp_size_in_bytes < pool / 4
+        assert device_bytes(first) < 16 * rows * width * cfg.vocab_size
 
 
 def test_a_looped_stack_carries_its_pool_through_every_pass(one_chip):
@@ -484,7 +526,7 @@ def test_a_looped_stack_carries_its_pool_through_every_pass(one_chip):
     i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
     args = (params, cache, i32(B), i32(B), sds((B,), jnp.bool_, one_chip))
     rows = {"tokens": i32(2, 64), "slots": i32(2), "start": i32(2),
-            "n_real": i32(2)}
+            "n_real": i32(2), "ends": sds((2,), jnp.bool_, one_chip)}
     for extra in ((), (rows,)):
         step = engine._decode_greedy_fn.lower(*args, *extra).compile()
         text = step.as_text()
