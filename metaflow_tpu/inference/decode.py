@@ -149,6 +149,7 @@ from ..ops import (
     layer_norm,
     retention,
     rms_norm,
+    ssm,
 )
 from ..ops.attention import NEG_INF
 from ..ops.decode_attention import visible as _visible
@@ -305,10 +306,13 @@ ATTENTION = {
 }
 
 # A kind of layer that carries a convolution's tail and a state (the
-# pools `conv` and `ssm`). mixer: (cfg, lp, normed x, tail, state, valid)
-# -> (out, tail, state) and, Mamba-1's, the recurrence's output before
-# its gate; conv, step, chunk: the scopes that the tail's, and the
-# state's read and write-back stand under (one token; a row's positions).
+# pools `conv` and `ssm`). mixer: (cfg, lp, normed x, tail, state, valid,
+# at) -> (out, tail, state) and, Mamba-1's, the recurrence's output
+# before its gate; with `at` (layer, lanes), in a decode step of the
+# whole pool, `state` is the pool itself, read and written at that layer
+# for the lanes that decode. conv, step, chunk: the scopes that the
+# tail's, and the state's read and write-back stand under (one token; a
+# row's positions).
 Recurrence = collections.namedtuple("Recurrence", "mixer conv step chunk")
 RECURRENCES = {
     "mamba": Recurrence(jamba.mamba_mixer, "ssm_conv", "ssm_state_update",
@@ -706,6 +710,23 @@ def attention_reads(cfg, cache, attn_impl="chunked", kernel=True):
     return reads
 
 
+def state_updates(cfg, cache, kernel=True):
+    """How a decode step of the whole pool updates each recurrent pool,
+    by the shapes alone: "kernel" where `kernel` (the step runs on a TPU
+    with no mesh) and a lane's state of one layer is whole tiles of the
+    chip: one Pallas call a layer that reads and writes the decoding
+    lanes' state where it lies and no other lane (ops/ssm.py,
+    ops/retention.py); "loop": the layer of every lane, cut out of the
+    pool, updated and put back. cache: the pools (their shapes alone are
+    read). A pool that is small beside its layer's state (a
+    convolution's tail, retention's normaliser) is always the latter."""
+    takes = {"ssm": ssm.whole_tiles, "ret_s": retention.whole_tiles}
+    return {
+        name: "kernel" if kernel and name in takes
+        and takes[name](cache[name]) else "loop"
+        for name, (pool, _) in cache_pools(cfg).items() if pool.recurrent}
+
+
 def attention_positions(reads, depth):
     """(needed, fetched) of one decode step: how many K and V positions
     its queries see, over every layer of `reads` (`attention_reads`),
@@ -1005,13 +1026,15 @@ def _put_layer_rows(pool, rows, layer, slots):
     return _put_slot_rows(pool, rows[None], slots, layer)
 
 
-def _mamba_layer(cfg, kind, x, lp, conv, state, valid):
+def _mamba_layer(cfg, kind, x, lp, conv, state, valid, at=None):
     """One Mamba block of `kind` (`RECURRENCES`) over T new tokens from
-    this layer's carried (conv [B, K-1, channels], state); the last of
-    the four returned is, for Mamba-1, the recurrence's output before
-    its gate, [B, T, Di] float32 (else None)."""
+    this layer's carried (conv [B, K-1, channels], state), or with `at`
+    (layer, lanes) from that layer of the whole pool `state`, updated in
+    place; the last of the four returned is, for Mamba-1, the
+    recurrence's output before its gate, [B, T, Di] float32 (else
+    None)."""
     out, conv, state, *y = RECURRENCES[kind].mixer(
-        cfg, lp, _norm(cfg, x, lp, "ssm_norm"), conv, state, valid)
+        cfg, lp, _norm(cfg, x, lp, "ssm_norm"), conv, state, valid, at)
     return _ffn(cfg, x + out, lp, None), conv, state, y[0] if y else None
 
 
@@ -1141,7 +1164,12 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
             # the layer's tail and state are read out of the pools and
             # written back under the scope of the op that uses them, so
             # that a scope's device time holds the pool's traffic that its
-            # kernel needs
+            # kernel needs. In a decode step of the whole pool the state
+            # is not cut out: the mixer gets the pool, the layer and the
+            # lanes that decode, and its one-token update (under the
+            # scope `step`) reads and writes those lanes' state of this
+            # layer where it lies (ops/ssm.py, `ssd_update_pool`,
+            # `selective_update_pool`)
             cache = dict(cache)
             scopes = RECURRENCES[kind]
             conv_scope = jax.named_scope(scopes.conv)
@@ -1149,17 +1177,21 @@ def _layers(cfg, params, x, cache, pos, valid, mesh, attn_impl, slots,
                 scopes.step if x.shape[1] == 1 else scopes.chunk)
             at = lambda a: jax.lax.dynamic_index_in_dim(a, i, 0,
                                                         keepdims=False)
-            with conv_scope:
-                conv = at(cache["conv"])
-            with state_scope:
-                state = at(cache["ssm"])
-            x, conv, state, y = _mamba_layer(cfg, kind, x, lp, conv, state,
-                                             valid)
             put = jax.lax.dynamic_update_index_in_dim
             with conv_scope:
+                conv = at(cache["conv"])
+            if lanes is not None:
+                x, conv, cache["ssm"], y = _mamba_layer(
+                    cfg, kind, x, lp, conv, cache["ssm"], valid, (i, lanes))
+            else:
+                with state_scope:
+                    state = at(cache["ssm"])
+                x, conv, state, y = _mamba_layer(cfg, kind, x, lp, conv,
+                                                 state, valid)
+                with state_scope:
+                    cache["ssm"] = put(cache["ssm"], state, i, 0)
+            with conv_scope:
                 cache["conv"] = put(cache["conv"], conv, i, 0)
-            with state_scope:
-                cache["ssm"] = put(cache["ssm"], state, i, 0)
             if memory is not None:
                 # the config marks which Mamba layer's output the gated
                 # memory units after it read

@@ -279,7 +279,7 @@ def layer_at(stack, i):
 
 # ---- the blocks ----
 
-def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
+def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None, at=None):
     """The Mamba-1 mixer over T new positions of normed input x
     [B, T, D], continuing from (conv_tail [B, K-1, Di], h [B, N, Di]
     float32). Matmuls in the model's dtype; convolution, softplus and
@@ -288,7 +288,10 @@ def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
     Returns (out [B, T, D], conv tail, h, y): tail and state after the
     last valid position (ops/ssm.py), and the recurrence's output y
     [B, T, Di] in float32 before the gate, which a model with gated
-    memory units hands on."""
+    memory units hands on. at: None, or (layer, lanes) in a decode step
+    of a whole pool: h is then the pool [layers, B, N, Di], whose layer
+    `layer` (traced) is updated in place for the lanes that decode
+    (`ssm.selective_update_pool`), and the pool is what comes back."""
     T = x.shape[1]
     Di, N, R = cfg.d_inner, cfg.mamba_d_state, cfg.mamba_dt_rank
     with jax.named_scope("ssm_in_proj"):
@@ -310,9 +313,12 @@ def mamba_mixer(cfg, lp, x, conv_tail, h, valid=None):
         A = -jnp.exp(lp["A_log"].astype(jnp.float32)).T
     if T == 1:
         with jax.named_scope("ssm_state_update"):
-            y, h = ssm.selective_step(
-                h, u[:, 0], delta[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"],
-                None if valid is None else valid[:, 0])
+            step = (u[:, 0], delta[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"])
+            if at is None:
+                y, h = ssm.selective_step(
+                    h, *step, None if valid is None else valid[:, 0])
+            else:
+                y, h = ssm.selective_update_pool(h, at[0], *step, at[1])
             y = y[:, None]
     else:
         with jax.named_scope("ssm_scan"):

@@ -288,14 +288,17 @@ def moe_leaves(stack, i):
 
 # ---- the blocks ----
 
-def mamba2_mixer(cfg, lp, x, conv_tail, S, valid=None):
+def mamba2_mixer(cfg, lp, x, conv_tail, S, valid=None, at=None):
     """The Mamba-2 mixer over T new positions of normed input x
     [B, T, D], continuing from (conv_tail [B, K-1, conv_dim], S
     [B, H, P, N] float32). Matmuls in the model's dtype; convolution,
     softplus, the recurrence and the gated norm in float32. One position
     is the one-token update, several the chunk form (ops/ssm.py).
     Returns (out [B, T, D], conv tail, S): tail and state after the last
-    valid position."""
+    valid position. at: None, or (layer, lanes) in a decode step of a
+    whole pool: S is then the pool [layers, B, H, P, N], whose layer
+    `layer` (traced) is updated in place for the lanes that decode
+    (`ssm.ssd_update_pool`), and the pool is what comes back."""
     B, T, _ = x.shape
     H, P, N, G = (cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state,
                   cfg.n_groups)
@@ -317,9 +320,12 @@ def mamba2_mixer(cfg, lp, x, conv_tail, S, valid=None):
         A = -jnp.exp(lp["A_log"].astype(jnp.float32))
     if T == 1:
         with jax.named_scope("ssd_state_update"):
-            y, S = ssm.ssd_step(
-                S, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"],
-                None if valid is None else valid[:, 0])
+            step = (xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"])
+            if at is None:
+                y, S = ssm.ssd_step(
+                    S, *step, None if valid is None else valid[:, 0])
+            else:
+                y, S = ssm.ssd_update_pool(S, at[0], *step, at[1])
             y = y[:, None]
     else:
         with jax.named_scope("ssd_chunk"):

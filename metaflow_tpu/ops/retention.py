@@ -293,6 +293,12 @@ def _update_state_xla(pool, layer, g, pk, pq, v, valid):
     return jax.lax.dynamic_update_index_in_dim(pool, S, layer, 0), num
 
 
+def whole_tiles(pool_s):
+    """Whether the kernel takes a pool of this shape: its blocks, a KV
+    head's [Hd, D], are whole tiles of the chip."""
+    return pool_s.shape[-2] % 8 == 0 and pool_s.shape[-1] % 128 == 0
+
+
 def update_pool(pool_s, pool_z, layer, q, k, v, log_g, eps, valid=None):
     """`step` for every slot of a pool at once, layer `layer` (traced) of
     pool_s [layers, B, KV, Hd, D] and pool_z [layers, B, KV, D] updated
@@ -306,11 +312,10 @@ def update_pool(pool_s, pool_z, layer, q, k, v, log_g, eps, valid=None):
     if valid is None:
         valid = jnp.ones(q.shape[:1], bool)
     args = (pool_s, jnp.asarray(layer, jnp.int32), g, pk, pq, v, valid)
-    if pool_s.shape[-2] % 8 or pool_s.shape[-1] % 128:
-        # the kernel's blocks are whole tiles of the chip
-        pool_s, num = _update_state_xla(*args)
-    else:
+    if whole_tiles(pool_s):
         pool_s, num = jax.lax.platform_dependent(
             *args, tpu=_update_state_kernel, default=_update_state_xla)
+    else:
+        pool_s, num = _update_state_xla(*args)
     y = num / (den[..., None] + eps)
     return y.reshape(q.shape), pool_s, pool_z

@@ -162,6 +162,7 @@ from ..inference.decode import (
     recurrent_pools,
     ring_pools,
     stack_passes,
+    state_updates,
 )
 from ..ops.attention import NEG_INF
 
@@ -606,6 +607,15 @@ class SlotEngine(KeySchedules):
         for entry in out.values():
             entry["bytes_per_slot"] = entry["bytes"] // self.max_slots
         return out
+
+    def state_updates(self):
+        """{recurrent pool: how a decode step updates it} ("kernel": the
+        decoding lanes' state alone, where it lies; "loop": the layer of
+        every lane cut out and put back), by the shapes and the platform
+        (inference/decode.py, `state_updates`); empty for a model that
+        carries no state."""
+        return state_updates(self.cfg, self._cache,
+                             kernel=self.mesh is None and device.on_tpu())
 
     def attention_positions(self):
         """(needed, fetched) of the decode step the cursors stand before

@@ -1140,6 +1140,7 @@ class Scheduler(object):
             "kv_pages": self.kv_pages_stats(),
             "state_pool": self.state_pool_stats(),
             "cache_pools": self.cache_pool_stats(),
+            "state_updates": self.state_updates(),
             "attention_positions_needed": self.attention_positions_needed,
             "attention_positions_fetched": self.attention_positions_fetched,
             "speculative": (self.engine.spec_stats() if self._paged
@@ -1249,6 +1250,13 @@ class Scheduler(object):
         stats = getattr(self.engine, "state_pool_stats", None)
         return stats() if stats is not None else {
             "bytes": 0, "bytes_per_slot": 0}
+
+    def state_updates(self):
+        """How the engine's decode step updates each recurrent pool
+        (`SlotEngine.state_updates`: "kernel" or "loop"); empty for an
+        engine that keeps none or does not say."""
+        updates = getattr(self.engine, "state_updates", None)
+        return updates() if updates is not None else {}
 
     def cache_pool_stats(self):
         """The engine's pools by what they hold (`SlotEngine.pool_stats`:

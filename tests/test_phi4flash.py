@@ -9,6 +9,7 @@ the family shares with Jamba and Brumby (the recurrent-state pool, the
 refusals by name, the scopes) is tests/test_jamba.py's, which runs for it
 too. Tiny sizes, float32."""
 
+import functools
 import os
 
 import jax
@@ -371,6 +372,10 @@ def test_a_decode_step_through_the_kernel_is_the_loops(monkeypatch):
         return kernel(*args, interpret=True, **kw)
 
     monkeypatch.setattr(decode.decode_attention, "attend", attend)
+    # the Mamba layers' state update is a kernel of the platform's too
+    monkeypatch.setattr(decode.ssm, "_selective_pool_kernel",
+                        functools.partial(decode.ssm._selective_pool_kernel,
+                                          interpret=True))
     monkeypatch.setattr(jax.lax, "platform_dependent",
                         lambda *args, tpu, default: tpu(*args))
     got, got_cache = step()

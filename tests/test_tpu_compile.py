@@ -389,14 +389,16 @@ def test_phi4flash_programs_at_the_benchmarks_size(one_chip):
         assert device_bytes(compiled) < 12.5e9
 
 
-def cell_engine(cell, one_chip):
+def cell_engine(cell, one_chip, **cut):
     """(engine, the decode step's abstract arguments) of a serving cell
     at the benchmark's widths, slots and depth, two layers deep (the
-    loop's body is traced once whatever the depth)."""
+    loop's body is traced once whatever the depth); `cut`: the other
+    keys of the configuration that say which two."""
     from benchmark import configs, weights
     from metaflow_tpu.serving import SlotEngine
 
     _, _, config, _ = configs.load_cell(cell)
+    config.update(cut)
     config["num_hidden_layers"], serving = 2, config["serving"]
     _, cfg = configs.program_config(config, serving["max_seq_len"])
     params = on(jax.eval_shape(lambda: weights.init_params(
@@ -406,7 +408,6 @@ def cell_engine(cell, one_chip):
                         max_seq_len=serving["max_seq_len"],
                         prefill_chunk=serving["prefill_chunk"])
     cache = on(jax.eval_shape(lambda: engine._cache), one_chip)
-    assert cache["k"].shape == (2, B, 1280, 1024)
     i32 = lambda *shape: sds(shape, jnp.int32, one_chip)
     return engine, (params, cache, i32(B), i32(B),
                     sds((B,), jnp.bool_, one_chip))
@@ -421,6 +422,7 @@ def test_decode_step_reads_its_pool_as_stored(one_chip, cell):
     and no temporary is of the size of one layer of the K pool, whose
     chunks the loop it replaces sliced and copied."""
     engine, args = cell_engine(cell, one_chip)
+    assert args[1]["k"].shape == (2, engine.max_slots, 1280, 1024)
     decode = engine._decode_greedy_fn.lower(*args).compile()
     assert "tpu_custom_call" in decode.as_text()
     layer = math.prod(args[1]["k"].shape[1:]) * 2
@@ -456,6 +458,39 @@ def test_merged_step_holds_no_second_pool_and_holds_the_kernel(one_chip,
         assert merged.memory_analysis().temp_size_in_bytes < layer / 2, \
             (rows, width)
         assert device_bytes(merged) < HBM_BYTES
+
+
+# cell: (the scope and name of its state update's kernel, the pool's
+# shape, what cuts the configuration to two recurrent layers)
+CELLS_WITH_STATE = {
+    "jamba2-3b.reason-steady": (
+        "ssm_state_update", (2, 128, 16, 5120), {}),
+    "nemotron-3-super.reason-steady": (
+        "ssd_state_update", (2, 128, 128, 64, 128),
+        {"hybrid_override_pattern": "MM"}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS_WITH_STATE))
+def test_decode_step_updates_its_state_pool_in_place(one_chip, cell):
+    """The reason and super cells' decode steps at the benchmark's widths
+    and 128 slots: the one-token update of a recurrent layer is the
+    Pallas call that reads and writes the decoding lanes' state where it
+    lies (ops/ssm.py), named after its scope, and no temporary is of the
+    size of ONE layer of the `ssm` pool, which the plain update cut out
+    for every lane, selected and put back (0.55e9 B a layer in the super
+    cell, 0.042e9 in the reason cell)."""
+    name, shape, cut = CELLS_WITH_STATE[cell]
+    engine, args = cell_engine(cell, one_chip, **cut)
+    assert args[1]["ssm"].shape == shape
+    assert engine.state_updates()["ssm"] == "loop"   # this process: a CPU
+    decode = engine._decode_greedy_fn.lower(*args).compile()
+    calls = [line for line in decode.as_text().splitlines()
+             if "tpu_custom_call" in line and name in line]
+    assert calls, name
+    layer = math.prod(shape[1:]) * 4
+    assert decode.memory_analysis().temp_size_in_bytes < layer / 2
+    assert device_bytes(decode) < HBM_BYTES
 
 
 def test_first_token_program_leaves_its_tokens_at_the_lanes(one_chip):
