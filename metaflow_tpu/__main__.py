@@ -574,8 +574,6 @@ def watch(flow_run, run_id, datastore, datastore_root, once, check,
 @click.option("--mesh", "mesh_spec", default=None,
               type=click.Choice(["dp", "fsdp", "fsdp_tp"]),
               help="Shard params over a device mesh (training rules).")
-@click.option("--attn-impl", default="auto",
-              type=click.Choice(["auto", "dense", "chunked"]))
 @click.option("--prefill-workers", default=0, type=int,
               help="Dedicated prefill replicas (disaggregated "
                    "prefill/decode): K workers run only chunked "
@@ -614,9 +612,8 @@ def watch(flow_run, run_id, datastore, datastore_root, once, check,
                    "API (docs/serving.md#federation).")
 def serve(flow_run, run_id, step_name, ckpt_step, params_key, config_json,
           model, host, port, replicas, slots, max_seq_len, prefill_chunk,
-          max_queue, mesh_spec, attn_impl, prefill_workers,
-          prefix_cache_mb, paged, page_tokens, spec_k,
-          reload_checkpoint, federate):
+          max_queue, mesh_spec, prefill_workers, prefix_cache_mb, paged,
+          page_tokens, spec_k, reload_checkpoint, federate):
     from . import device
     from .cmd.serve import serve as serve_impl
     from .exception import TpuFlowException
@@ -632,8 +629,7 @@ def serve(flow_run, run_id, step_name, ckpt_step, params_key, config_json,
                    port=port, replicas=replicas, slots=slots,
                    max_seq_len=max_seq_len,
                    prefill_chunk=prefill_chunk, max_queue=max_queue,
-                   mesh_spec=mesh_spec, attn_impl=attn_impl,
-                   prefill_workers=prefill_workers,
+                   mesh_spec=mesh_spec, prefill_workers=prefill_workers,
                    prefix_cache_mb=prefix_cache_mb,
                    paged=paged, page_tokens=page_tokens, spec_k=spec_k,
                    reload_checkpoint=reload_checkpoint,

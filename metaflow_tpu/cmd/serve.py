@@ -68,8 +68,8 @@ def extract_params(restored, params_key="params"):
 
 
 def build_engine(params, cfg, slots=8, max_seq_len=None, prefill_chunk=64,
-                 mesh_spec=None, attn_impl="auto", paged=False,
-                 page_tokens=None, spec_k=None):
+                 mesh_spec=None, paged=False, page_tokens=None,
+                 spec_k=None):
     """Shard params over a mesh (the training rule table) and build the
     engine: the slot engine, or (paged=True / TPUFLOW_PAGED=1) the
     paged-KV engine with optional speculative decoding. mesh_spec:
@@ -100,11 +100,10 @@ def build_engine(params, cfg, slots=8, max_seq_len=None, prefill_chunk=64,
         return PagedEngine(params, cfg, max_slots=slots,
                            max_seq_len=max_seq_len,
                            prefill_chunk=prefill_chunk, mesh=mesh,
-                           attn_impl=attn_impl, page_tokens=page_tokens,
-                           spec_k=spec_k)
+                           page_tokens=page_tokens, spec_k=spec_k)
     return SlotEngine(params, cfg, max_slots=slots,
                       max_seq_len=max_seq_len, prefill_chunk=prefill_chunk,
-                      mesh=mesh, attn_impl=attn_impl)
+                      mesh=mesh)
 
 
 def build_prefix_cache(engine, prefix_cache_mb=None):
@@ -186,9 +185,9 @@ def serve_fleet(flow_run, run_id=None, step_name=None, ckpt_step=None,
                 params_key="params", config_json=None, model="llama",
                 host="127.0.0.1", port=8000, replicas=2, slots=8,
                 max_seq_len=None, prefill_chunk=64, max_queue=64,
-                mesh_spec=None, attn_impl="auto", prefill_workers=0,
-                prefix_cache_mb=None, paged=False, page_tokens=None,
-                spec_k=None, echo=print, block=True):
+                mesh_spec=None, prefill_workers=0, prefix_cache_mb=None,
+                paged=False, page_tokens=None, spec_k=None, echo=print,
+                block=True):
     """`tpuflow serve FLOW/RUN --replicas N`: fork N replica workers
     (each loading the run's checkpoint through load_run_checkpoint) and
     front them with the health-checked failover router
@@ -212,7 +211,7 @@ def serve_fleet(flow_run, run_id=None, step_name=None, ckpt_step=None,
         "--flow", flow_name, "--run-id", str(run_id),
         "--params-key", params_key, "--model", model,
         "--slots", str(slots), "--prefill-chunk", str(prefill_chunk),
-        "--max-queue", str(max_queue), "--attn-impl", attn_impl,
+        "--max-queue", str(max_queue),
     ]
     if step_name:
         replica_args += ["--step-name", step_name]
@@ -346,10 +345,9 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
           params_key="params", config_json=None, model="llama",
           host="127.0.0.1", port=8000, replicas=1, slots=8,
           max_seq_len=None, prefill_chunk=64, max_queue=64,
-          mesh_spec=None, attn_impl="auto", prefill_workers=0,
-          prefix_cache_mb=None, paged=False, page_tokens=None,
-          spec_k=None, reload_checkpoint=False, federate=None,
-          echo=print, block=True):
+          mesh_spec=None, prefill_workers=0, prefix_cache_mb=None,
+          paged=False, page_tokens=None, spec_k=None,
+          reload_checkpoint=False, federate=None, echo=print, block=True):
     """Load FLOW/RUN's checkpoint and serve it. Returns the running
     ServingServer when block=False (tests); otherwise serves until
     SIGTERM/SIGINT, draining in-flight requests before exit. With
@@ -379,7 +377,7 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
             replicas=int(replicas), slots=slots,
             max_seq_len=max_seq_len, prefill_chunk=prefill_chunk,
             max_queue=max_queue, mesh_spec=mesh_spec,
-            attn_impl=attn_impl, prefill_workers=int(prefill_workers),
+            prefill_workers=int(prefill_workers),
             prefix_cache_mb=prefix_cache_mb, paged=paged,
             page_tokens=page_tokens, spec_k=spec_k, echo=echo,
             block=block)
@@ -397,9 +395,8 @@ def serve(flow_run, run_id=None, step_name=None, ckpt_step=None,
     engine = build_engine(params, cfg, slots=slots,
                           max_seq_len=max_seq_len,
                           prefill_chunk=prefill_chunk,
-                          mesh_spec=mesh_spec, attn_impl=attn_impl,
-                          paged=paged, page_tokens=page_tokens,
-                          spec_k=spec_k)
+                          mesh_spec=mesh_spec, paged=paged,
+                          page_tokens=page_tokens, spec_k=spec_k)
     _init_serve_telemetry(flow_name, run_id)
     cache = build_prefix_cache(engine, prefix_cache_mb)
     scheduler = Scheduler(engine, max_queue=max_queue,

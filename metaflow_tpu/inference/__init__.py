@@ -1,8 +1,8 @@
+from .cache import init_kv_cache
 from .decode import (
     bucket_length,
     decode_forward,
     generate,
-    init_kv_cache,
     make_generator,
     pad_to_bucket,
 )

@@ -13,79 +13,73 @@ The reference framework has no inference engine (it orchestrates user
 frameworks); this is part of the training/serving substrate the TPU
 rebuild provides natively (SURVEY.md §5.7).
 
-Attention over the cache (`decode_attention`) is grouped-query as
-stored: the G = H // KV query heads that share a KV head are the rows of
-one matrix product against that head's keys and values, in the cache's
-own dtype with float32 accumulation. K and V are never repeated to H
-heads and never widened; with a bfloat16 cache the probabilities of a
-block are rounded to bfloat16 for the product with V, as the training
-kernel rounds them (ops/attention.py). Two forms of the same
-arithmetic read the pools (`_pool_attention` picks by what it can
-observe): the decode step of a whole pool on a TPU is ONE Pallas call a
-layer (ops/decode_attention.py) whose operands are the pools as stored,
-each lane read to its own depth and no lane that does not decode; every
-other program (a prefill program's rows, `generate()`, the paged
-engine, a process held to the CPU) is the chunk loop
-`_streamed_attention`, whose one trip count runs to the deepest row's
-depth.
+Attention over the cache (`decode_attention`) is grouped-query as stored:
+the G = H // KV query heads that share a KV head are the rows of one
+matrix product against that head's keys and values, in the cache's own
+dtype with float32 accumulation. K and V are never repeated to H heads
+and never widened; with a bfloat16 cache the probabilities of a block are
+rounded to bfloat16 for the product with V, as the training kernel rounds
+them (ops/attention.py). Two forms of the same arithmetic read the pools
+(`_pool_attention` picks by what it can observe): the decode step of a
+whole pool on a TPU is ONE Pallas call a layer (ops/decode_attention.py)
+whose operands are the pools as stored, each lane read to its own depth
+and no lane that does not decode; every other program (a prefill
+program's rows, `generate()`, the paged engine, a process held to the
+CPU) is the chunk loop `_streamed_attention`, whose one trip count runs
+to the deepest row's depth. These, or for a shallow pool the kernel does
+not take every lane's whole pool at once ("dense"): `pool_read` decides
+once, from the shapes, and no option reaches it.
 
 Model families: `family(cfg)` is the ONE place a family is picked, a
 table keyed by the config's class (the feed-forward half of a block,
-whether attention rotates its queries and keys, where `params` holds
-the stack of each kind of layer). There is ONE layer loop (`_layers`):
-the stacks are walked in the model's order (models/jamba.py,
-`scan_layers`; a stack of one kind, Llama or Mixtral, is its one-run
-case, a stack of several kinds, Jamba's Mamba-1 layers with an attention
-layer every so many, holds one stack per kind). A layer is a mixer and
-then the family's feed-forward, or where the family's `ffn` is None
-(Nemotron-H) one of the two alone: its Mamba-2 and attention layers end
-at their residual, and the kind `ffn` is a feed-forward with no mixer
-and no pool. The loop's carry is the
-activations and the WHOLE cache: the pools are never a scanned input or
-output, which would slice every layer out and write every layer back
-into a second buffer. A layer's weights are sliced out of their stack by
-the loop's index (`layer_at`), and the layer writes its new positions
-into, and reads its chunks out of, its own index of the donated pools
-in place: K and V for the attention layers, and for the Mamba layers
-the convolution's tail `conv` and the float32 state `ssm` (ops/ssm.py),
-for the power-retention layers (Brumby: every layer, so the cache holds
-no K and V at all) the float32 state `ret_s` and its normaliser `ret_z`
-(ops/retention.py); for window layers (Phi-4-mini-flash) K and V of
-the last positions in a ring, `win_k` and `win_v`, as deep as the window
-and the widest row one program writes; `k` and `v` may also be ONE
-layer's, which the layers after it read again and never write, and a
-gated memory unit has no pool at all: what it reads rides the loop's
-carry. Which pools a kind of layer carries, their shape a slot and their
-dtype are that kind's declaration (`POOLS`; an attention kind also says
-which pools it reads, whether it writes them and whether its queries see
-a window, `ATTENTION`), and `init_kv_cache`, this loop, the engine's
-reset of a new occupant and `is_recurrent` read it. Unlike a KV position, a recurrent state has no
-garbage that is overwritten before it is seen: `valid` tells those
-layers which of the new positions are real. The batch is the whole pool
-(a decode step) or, with `slots`, a few distinct rows of it (the slot
-engine's prefill program): K and V of those rows are then written in
-the pool by index and a layer's views of them read out of it; a small
-recurrent state (Mamba's: 0.3 MB a layer and slot) is cut out of its
-pools before the loop and put back after it, a large one (power
-retention's: 34 MB a layer and slot) is read and written in its pool by
-row index a layer at a time, and its one-token update touches only the
-lanes that decode; no other row is touched and no pool is copied. For a
-stack of attention layers the batch may be both at once (`rows`,
-`merges`): a decode step's lanes and a prefill program's rows as one
-batch of tokens, each at its own position, for everything that
-multiplies by a weight, taken apart only for what mixes positions
-(`_merged_layer`): one execution then reads every weight once where the
-slot engine's two programs of an iteration read it twice.
+whether attention rotates its queries and keys, where `params` holds the
+stack of each kind of layer). There is ONE layer loop (`_layers`): the
+stacks are walked in the model's order (models/jamba.py, `scan_layers`; a
+stack of one kind, Llama or Mixtral, is its one-run case, a stack of
+several kinds, Jamba's Mamba-1 layers with an attention layer every so
+many, holds one stack per kind). A layer is a mixer and then the family's
+feed-forward, or where the family's `ffn` is None (Nemotron-H) one of the
+two alone: its Mamba-2 and attention layers end at their residual, and
+the kind `ffn` is a feed-forward with no mixer and no pool. The loop's
+carry is the activations and the WHOLE cache: the pools are never a
+scanned input or output, which would slice every layer out and write
+every layer back into a second buffer. A layer's weights are sliced out
+of their stack by the loop's index (`layer_at`), and the layer writes its
+new positions into, and reads its chunks out of, its own index of the
+donated pools in place. What those pools are for each kind of layer (K
+and V, a ring of the last positions, a convolution's tail and a state,
+one layer's K and V that later layers read again, nothing at all), and
+how a pool is written and cut, is the cache's own business
+(inference/cache.py: `POOLS`, `init_kv_cache`, `_write_layer`,
+`_slot_rows`; this module knows no pool's axes but through them and the
+attention reads); an attention kind says here which pools it reads,
+whether it writes them and whether its queries see a window
+(`ATTENTION`). Unlike a KV position, a recurrent state has no garbage
+that is overwritten before it is seen: `valid` tells those layers which
+of the new positions are real. The batch is the whole pool (a decode
+step) or, with `slots`, a few distinct rows of it (the slot engine's
+prefill program): K and V of those rows are then written in the pool by
+index and a layer's views of them read out of it; a small recurrent state
+(Mamba's: 0.3 MB a layer and slot) is cut out of its pools before the
+loop and put back after it, a large one (power retention's: 34 MB a layer
+and slot) is read and written in its pool by row index a layer at a time,
+and its one-token update touches only the lanes that decode; no other row
+is touched and no pool is copied. For a stack of attention layers the
+batch may be both at once (`rows`, `merges`): a decode step's lanes and a
+prefill program's rows as one batch of tokens, each at its own position,
+for everything that multiplies by a weight, taken apart only for what
+mixes positions (`_merged_layer`): one execution then reads every weight
+once where the slot engine's two programs of an iteration read it twice.
 
 A stack may be run several times over the same weights (a looped model:
-the config declares `passes`, models/ouro.py). "Layer i" is then an
-index into the weights only: a pool has `passes x layers` indices, pass
-t of layer i reads and writes index t * layers + i (each pass attends to
-its own K and V), the layer loop goes round once a pass inside one
-traced body, and the model's norm closes every pass. Where a layer's
-leaves hold `attn_post_norm` / `ffn_post_norm` the sublayer's output is
-normed before it joins the residual (sandwich norms: `_sandwich`).
-`stack_passes`, `cache_pools` and `attention_reads` are where the
+the config declares `passes`, models/ouro.py). "Layer i" is then an index
+into the weights only: a pool has `passes x layers` indices, pass t of
+layer i reads and writes index t * layers + i (each pass attends to its
+own K and V), the layer loop goes round once a pass inside one traced
+body, and the model's norm closes every pass. Where a layer's leaves hold
+`attn_post_norm` / `ffn_post_norm` the sublayer's output is normed before
+it joins the residual (sandwich norms: `_sandwich`). `stack_passes`,
+`cache_pools` (inference/cache.py) and `attention_reads` are where the
 config's `passes` is read; every other config has one pass and traces
 what it traced.
 
@@ -99,29 +93,23 @@ inside a Mamba-2 layer `ssd_in_proj`, `ssd_conv`, `ssd_chunk` (a row's
 positions) or `ssd_state_update` (one token), `ssd_gate_norm`,
 `ssd_out_proj`; inside a latent expert layer (under `ffn`) `moe_router`,
 `moe_latent_down`, `moe_dispatch`, `moe_experts`, `moe_combine`,
-`moe_latent_up`, `moe_shared_expert`;
-inside a retention layer `retention_qkvg` (the projections, the head
-norms, rope and the gate), `retention_chunk` (a chunk) or
-`retention_update` (one token), `retention_out`; where a model has them,
-`window_attention` (a window layer's ring reads and attention) and
-`cross_attention` (a layer's read of another layer's K and V) beside
-`decode_attention`, `diff_combine` (a differential pair's lambda,
-subtraction and sub-norm), `gmu` (a gated memory unit), and in a prefill
-program whose last layers see one position a row `cross_decoder` around
-those; in a stack run several times `loop_pass` around one pass (inside
-`decode_layers`, the layers' scopes inside it), `loop_norm` around the
-norm that closes it and `exit_gate` around the gate. The pool's write sits
-under `kv_cache_update`, its chunk reads under the attention kind's
-scope, a state pool's read and write-back under the `ssm_*`, `ssd_*` or
-`retention_*` scope that needs them: what lies under `decode_layers` and under none of
-those is the loop's own cost (its counter, the residual stream), and
-anything the compiler still moves without being asked.
-
-Sharding: a KV pool carries the same logical axes as activations
-([passes x layers of its kind, batch, seq or a ring's depth, kv_heads * head_dim],
-heads major in the folded axis) — under a mesh, batch rides the data/fsdp axes and kv_heads the
-tensor axis, so decode parallelizes with the exact rule table training
-uses (spmd/sharding.py); XLA keeps the per-step all-gathers on ICI.
+`moe_latent_up`, `moe_shared_expert`; inside a retention layer
+`retention_qkvg` (the projections, the head norms, rope and the gate),
+`retention_chunk` (a chunk) or `retention_update` (one token),
+`retention_out`; where a model has them, `window_attention` (a window
+layer's ring reads and attention) and `cross_attention` (a layer's read
+of another layer's K and V) beside `decode_attention`, `diff_combine` (a
+differential pair's lambda, subtraction and sub-norm), `gmu` (a gated
+memory unit), and in a prefill program whose last layers see one position
+a row `cross_decoder` around those; in a stack run several times
+`loop_pass` around one pass (inside `decode_layers`, the layers' scopes
+inside it), `loop_norm` around the norm that closes it and `exit_gate`
+around the gate. The pool's write sits under `kv_cache_update`, its chunk
+reads under the attention kind's scope, a state pool's read and
+write-back under the `ssm_*`, `ssd_*` or `retention_*` scope that needs
+them: what lies under `decode_layers` and under none of those is the
+loop's own cost (its counter, the residual stream), and anything the
+compiler still moves without being asked.
 """
 
 import collections
@@ -132,7 +120,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import knobs
 from ..exception import TpuFlowException
 from ..models import (
     brumby,
@@ -155,6 +142,9 @@ from ..ops.attention import NEG_INF
 from ..ops.decode_attention import visible as _visible
 from ..ops.moe import moe_ffn
 from ..ops.rope import apply_rope, rope_frequencies
+from .cache import (MOE_PAIRS, _layer_rows, _put_layer_rows, _put_slot_rows,
+                    _slot_rows, _v_head_dim, _write_layer, cache_pools,
+                    init_kv_cache, layer_kinds, stack_passes)
 
 # name: what `tpuflow serve --model` takes; module: init_params,
 # logical_axes, forward; ffn: the feed-forward half of a block,
@@ -209,87 +199,6 @@ FAMILIES = {
                                        nemotron_h.STACKS),
 }
 
-# A pool a kind of layer carries. shape: (cfg, max_seq_len, widest row=None)
-# -> its shape a slot (the pool is [passes x layers of the kind, slots] +
-# that: `passes` of them where the config runs its stack several times,
-# pass t's of layer i at index t * layers + i);
-# dtype: None for the cache's; recurrent: what it holds is carried from
-# position to position (nothing there is overwritten before it is seen,
-# so it is masked by `valid`, zeroed for a new occupant, and no KV range
-# stands for it); view: a prefill program cuts its rows out once and puts
-# them back (small states, and K and V of one layer that several read),
-# else a layer reads and writes its rows in place.
-Pool = collections.namedtuple("Pool", "shape dtype recurrent view")
-
-
-def _kv_width(cfg):
-    """K and V of a position, heads folded: V's heads may be fewer and
-    wider than K's (`v_head_dim`), never of another width in all."""
-    return cfg.n_kv_heads * cfg.head_dim
-
-
-def _v_head_dim(cfg):
-    """How wide a head of V is: a key head's size, or what the config
-    says (differential attention: a pair's two key heads share one value
-    head of twice the size)."""
-    return getattr(cfg, "v_head_dim", cfg.head_dim)
-
-
-def _ring_depth(cfg, seq, row):
-    """How deep a window layer's pool is: the window and the widest row
-    one program writes (`_write_layer` has the derivation), or without a
-    bound on the row the whole sequence, where nothing ever wraps."""
-    return seq if row is None else cfg.sliding_window + row
-
-
-_kv_pool = Pool(lambda cfg, seq, row=None: (seq, _kv_width(cfg)),
-                None, False, False)
-_ring_pool = Pool(
-    lambda cfg, seq, row=None: (_ring_depth(cfg, seq, row), _kv_width(cfg)),
-    None, False, False)
-# one layer's K and V that the layers after it read again: a row's view
-# of it (a few MB at 4,096 positions) is cut out once a program, not once
-# a reading layer
-_shared_kv_pool = _kv_pool._replace(view=True)
-POOLS = {
-    "attention": {"k": _kv_pool, "v": _kv_pool},
-    "window": {"win_k": _ring_pool, "win_v": _ring_pool},
-    "full": {"k": _shared_kv_pool, "v": _shared_kv_pool},
-    "cross": {},   # reads the full layer's k and v, writes nothing
-    "gmu": {},     # reads the memory the layer loop carries, nothing else
-    "ffn": {},     # a feed-forward alone: no mixer, nothing cached
-    "mamba": {
-        "conv": Pool(
-            lambda cfg, seq, row=None: (cfg.mamba_d_conv - 1, cfg.d_inner),
-            None, True, True),
-        "ssm": Pool(
-            lambda cfg, seq, row=None: (cfg.mamba_d_state, cfg.d_inner),
-            jnp.float32, True, True),
-    },
-    # Mamba-2: the tail over x, B and C together, the state a head
-    # ([128, 64, 128] float32 at the published sizes: 4.19 MB a layer
-    # and slot, whole tiles)
-    "mamba2": {
-        "conv": Pool(
-            lambda cfg, seq, row=None: (cfg.conv_kernel - 1, cfg.conv_dim),
-            None, True, True),
-        "ssm": Pool(
-            lambda cfg, seq, row=None: (
-                cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state),
-            jnp.float32, True, True),
-    },
-    # [KV, Hd, D] with D = 8,320 at a head size of 128 (the 8,256 products
-    # of a symmetric square and 64 zeros: whole lanes, ops/retention.py)
-    "retention": {
-        "ret_s": Pool(lambda cfg, seq, row=None: (
-            cfg.n_kv_heads, cfg.head_dim, retention.state_dim(cfg.head_dim)),
-            jnp.float32, True, False),
-        "ret_z": Pool(lambda cfg, seq, row=None: (
-            cfg.n_kv_heads, retention.state_dim(cfg.head_dim)),
-            jnp.float32, True, False),
-    },
-}
-
 # An attention kind of layer. k, v: the pools it reads; writes: whether
 # it first writes its own K and V of the new positions there, at its own
 # index (a layer that does not projects no K and V and reads index 0 of
@@ -321,11 +230,6 @@ RECURRENCES = {
                          "ssd_state_update", "ssd_chunk"),
 }
 
-# The one leaf of the cache that is no pool: [pairs routed, pairs that
-# fell on held experts] since the cache was made, uint32 (it wraps; a
-# reader takes differences), summed over the expert layers of kind `ffn`
-MOE_PAIRS = "moe_pairs"
-
 
 def family(cfg):
     """The family of a model config: the one place it is picked."""
@@ -345,98 +249,6 @@ def family_config_class(name):
             return config_cls
     raise TpuFlowException("unknown model family %r (families: %s)" % (
         name, ", ".join(sorted(f.name for f in FAMILIES.values()))))
-
-
-def layer_kinds(cfg):
-    """The kind of every layer in the model's order, a key of `POOLS`:
-    "attention" (K and V cached), "mamba" and "mamba2" (a convolution
-    tail and a state carried), "retention" (a state and its normaliser
-    carried), "ffn" (a feed-forward alone, nothing cached),
-    "window" (K and V of the last positions in a ring), "full" (K and V
-    cached, for itself and the layers after it), "cross" (another
-    layer's K and V read again) or "gmu" (an earlier layer's output of
-    the same program, nothing cached)."""
-    return getattr(cfg, "layer_kinds", None) or ("attention",) * cfg.n_layers
-
-
-def stack_passes(cfg):
-    """How many times a token goes through the model's stack, over the
-    same weights (the config's `passes`; 1 where it declares none). Pass
-    t of layer i has pool index t * layers + i: each pass keeps K and V
-    of its own. Only a stack of `attention` layers goes round: what a
-    recurrent state, a ring or a pool that other layers read again is
-    from pass to pass is not defined."""
-    passes = getattr(cfg, "passes", 1)
-    if passes > 1 and set(layer_kinds(cfg)) != {"attention"}:
-        raise TpuFlowException(
-            "a stack that is run %d times over the same weights is built "
-            "for attention layers alone, not for %s"
-            % (passes, sorted(set(layer_kinds(cfg)) - {"attention"})))
-    return passes
-
-
-def cache_pools(cfg):
-    """{pool name: (its Pool, how many indices it has: the layers that
-    carry it, times the config's passes)} of the model's cache, from what
-    each kind of layer present declares."""
-    kinds = layer_kinds(cfg)
-    return {name: (pool, stack_passes(cfg) * kinds.count(kind))
-            for kind in sorted(set(kinds))
-            for name, pool in POOLS[kind].items()}
-
-
-def recurrent_pools(cfg):
-    """The names of the pools that hold recurrent state."""
-    return sorted(name for name, (pool, _) in cache_pools(cfg).items()
-                  if pool.recurrent)
-
-
-def ring_pools(cfg):
-    """The names of the pools that are rings: K and V of the kinds of
-    layer whose queries see a window."""
-    return sorted(name for kind in set(layer_kinds(cfg))
-                  if kind in ATTENTION and ATTENTION[kind].window
-                  for name in (ATTENTION[kind].k, ATTENTION[kind].v))
-
-
-def is_recurrent(cfg):
-    """Whether some layer carries a state that a KV range does not
-    hold: such a model's prefix is not its cached K and V."""
-    return bool(recurrent_pools(cfg))
-
-
-def init_kv_cache(cfg, batch_size, max_seq_len, dtype=None, row=None):
-    """The static cache, one tree of the pools the model's kinds of
-    layer declare (`POOLS`), each [passes x layers of the kind, batch] +
-    its shape a slot (pass t of layer i at index t * layers + i; one pass
-    for every config that declares no `passes`): `k` and `v` [passes x
-    attention layers, batch, max_seq, kv_heads * head_dim] (for a model
-    with ONE full-attention layer that others read again, that layer's
-    alone); for window layers `win_k` and
-    `win_v` [.., sliding_window + row, kv_heads * head_dim], a ring
-    (`row`: the most positions one program writes into a row; None: no
-    bound, and the pool is max_seq deep); for Mamba layers `conv` [..,
-    d_conv-1, d_inner] (the convolution's tail) and `ssm` [.., d_state,
-    d_inner] in float32; for retention layers `ret_s` [.., kv_heads,
-    head_dim, D] and `ret_z` [.., kv_heads, D] in float32. A stack with
-    no attention layer has no `k` and `v`. Every pool has the batch on
-    axis 1. A model with expert layers of kind `ffn` also gets
-    `moe_pairs` (`MOE_PAIRS`), two counters and no pool.
-
-    The pools are read and written a layer at a time in place
-    (`_decode_layer`): heads and head size are folded into one minor
-    axis (heads major), so that the indexed write and the chunk reads
-    meet rows of whole lanes whatever the number of KV heads, and a
-    single KV head (multi-query) leaves no axis of 1 for the chip's
-    tiling to pad or to lay out anew on the way in and out."""
-    dt = jnp.dtype(dtype) if dtype is not None else llama.param_dtype(cfg)
-    cache = {name: jnp.zeros(
-                 (layers, batch_size) + pool.shape(cfg, max_seq_len, row),
-                 pool.dtype or dt)
-             for name, (pool, layers) in cache_pools(cfg).items()}
-    if "ffn" in layer_kinds(cfg):
-        cache[MOE_PAIRS] = jnp.zeros((2,), jnp.uint32)
-    return cache
 
 
 def _query_positions(pos, T):
@@ -523,15 +335,12 @@ def _cached_attention(q, cache_k, cache_v, pos, window=None, ring=False,
         return _ungroup(out, dtype or q.dtype)
 
 
-def _default_decode_chunk():
-    return max(1, knobs.get_int("TPUFLOW_DECODE_CHUNK"))
-
-
 # KV-chunk size of the chunk loop (`_streamed_attention`), and the pivot
-# of the attn_impl="auto" switchover (see generate()). Override with
-# TPUFLOW_DECODE_CHUNK=<n> (read once at import). The decode step's
+# of `pool_read`. Why 256: a choice, and a constant until a chip has
+# swept it on the reads that use it (a prefill program's rows,
+# `generate()`, the paged engine, every run on a CPU). The decode step's
 # kernel takes no notice of it: its block is `decode_block`'s answer.
-DECODE_CHUNK = _default_decode_chunk()
+DECODE_CHUNK = 256
 
 
 def _streamed_attention(q, pos, chunk, n_chunks, fetch, window=None,
@@ -648,9 +457,9 @@ def _pool_attention(q, cache_k, cache_v, pos, layer, lanes=None,
     lowering platform's (`jax.lax.platform_dependent`), so a compile for
     a described chip holds the kernel and XLA:CPU the loop. Everything
     else (a prefill program's rows, `generate()`'s lockstep batch, sizes
-    with no whole tiles) is `_chunked_cached_attention`, which
-    `TPUFLOW_DECODE_CHUNK` governs and the kernel ignores. Under
-    `scope` either way."""
+    with no whole tiles) is `_chunked_cached_attention`, in chunks of
+    `DECODE_CHUNK`, which the kernel ignores. Under `scope` either
+    way."""
     kw = dict(v_head_dim=v_head_dim, window=window, ring=ring, dtype=dtype)
     loop = functools.partial(_chunked_cached_attention, scope=scope, **kw)
     if lanes is None or not decode_attention.applies(q, cache_k, cache_v,
@@ -708,6 +517,27 @@ def attention_reads(cfg, cache, attn_impl="chunked", kernel=True):
         reads.append((stack_passes(cfg) * kinds.count(kind), S,
                       cfg.sliding_window if a.window else S, unit, how))
     return reads
+
+
+def pool_read(depth, cfg=None, cache=None, mesh=None):
+    """How a program reads K and V pools `depth` positions deep, the ONE
+    rule, by the shapes: "chunked" past twice `DECODE_CHUNK` positions (a
+    choice: no chip has timed where the two cross), and, for a decode
+    step of the whole pools `cache` (the slot engine's; `generate()` and
+    the paged engine give a depth alone, their programs are never the
+    kernel's), at ANY depth where every attention read of the step is
+    the kernel's (one chip, shapes `ops/decode_attention.py` takes): it
+    reads each decoding lane to its own depth and no other lane, never
+    more than the dense read of every lane's whole pool, and only a
+    chunked stack lets the prefill rows ride in the step (`merges`).
+    Else "dense"."""
+    if depth > 2 * DECODE_CHUNK:
+        return "chunked"
+    if cache is None:
+        return "dense"
+    reads = attention_reads(cfg, cache, "chunked", kernel=mesh is None)
+    return "chunked" if reads and all(
+        how == "kernel" for *_, how in reads) else "dense"
 
 
 def state_updates(cfg, cache, kernel=True):
@@ -947,83 +777,6 @@ def _merged_layer(cfg, cos, sin, pos, x, layer_params, cache, layer, lanes,
         [attn, attn_rows.reshape((R * W, 1) + attn_rows.shape[2:])])
     x = _block_ffn(cfg, x, attn, lp, mesh=mesh)
     return x, dict(cache, **{a.k: cache_k, a.v: cache_v})
-
-
-def _write_layer(pool, new, pos, layer, slots=None, ring=False):
-    """new [B, T, KV, Hd] into pool [layers, B, S, KV * Hd] at `layer`,
-    every batch row at its own cursor (or all at a scalar `pos`); with
-    `slots` ([B] distinct), row b of `new` into row slots[b] of the pool.
-
-    A row of a prefill program is padded to the program's width, so its
-    last positions may lie past the pool's edge: those land on the last
-    position, which is past the row's cursor like every padded position
-    and so overwritten before it is seen (a clamped block write would
-    shift the real positions instead).
-
-    With `ring` the pool is a window layer's, S = window + the widest
-    row a program writes, and position p lands on index p % S. The
-    engine's invariant, "garbage is overwritten before it is seen",
-    holds there too. A program that writes positions c .. c + T - 1 of a
-    row (T <= S - window) overwrites what stood at c + t - S, and the
-    earliest position any query from c on still sees is c - window + 1 >
-    c + t - S: nothing a live query needs is lost, whether position c + t
-    is real or pads the row. What a padded position (or a masked lane's
-    write at its cursor c) leaves at index (c' + x) % S, x < T, for the
-    row's next cursor c', a later query q >= c' takes for position c' +
-    x - S (`_visible`: the one position of (q - S, q] on that index)
-    until position c' + x itself is written over it, and c' + x - S <= q
-    - window: outside the window. So a ring needs no mask on its writes
-    and no reset for a new occupant, whose queries at q < S take every
-    index past q for a position before 0."""
-    new = new.reshape(new.shape[:2] + (-1,))
-    B, T = new.shape[:2]
-    if jnp.ndim(pos) == 0:
-        if not ring:
-            return jax.lax.dynamic_update_slice(
-                pool, new[None], (layer, 0, pos, 0))
-        pos = jnp.full((B,), pos)
-    rows = jnp.arange(B) if slots is None else slots
-    at = pos[:, None] + jnp.arange(T)[None]
-    at = at % pool.shape[2] if ring else jnp.minimum(at, pool.shape[2] - 1)
-    return pool.at[layer, rows[:, None], at].set(
-        new, mode="promise_in_bounds")
-
-
-def _slot_rows(pool, slots, layer=None):
-    """Rows `slots` ([R], traced) of a pool [layers, B, ...], each cut
-    out of its own slot, of every layer or of `layer` alone: a pool
-    [layers or 1, R, ...] that holds just those rows."""
-    size = (pool.shape[0] if layer is None else 1, 1) + pool.shape[2:]
-    rest = (0,) * (pool.ndim - 2)
-    return jnp.concatenate([
-        jax.lax.dynamic_slice(
-            pool, (0 if layer is None else layer, slots[r]) + rest, size)
-        for r in range(slots.shape[0])], axis=1)
-
-
-def _put_slot_rows(pool, rows, slots, layer=None):
-    """`_slot_rows(pool, slots, layer)` back into the pool, in place."""
-    rest = (0,) * (pool.ndim - 2)
-    for r in range(slots.shape[0]):
-        pool = jax.lax.dynamic_update_slice(
-            pool, rows[:, r:r + 1],
-            (0 if layer is None else layer, slots[r]) + rest)
-    return pool
-
-
-def _layer_rows(pool, layer, slots):
-    """Layer `layer` of a pool [layers, B, ...]: the whole batch, or the
-    rows `slots` names, [R, ...]."""
-    if slots is None:
-        return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
-    return _slot_rows(pool, slots, layer)[0]
-
-
-def _put_layer_rows(pool, rows, layer, slots):
-    """`_layer_rows(pool, layer, slots)` back into the pool, in place."""
-    if slots is None:
-        return jax.lax.dynamic_update_index_in_dim(pool, rows, layer, 0)
-    return _put_slot_rows(pool, rows[None], slots, layer)
 
 
 def _mamba_layer(cfg, kind, x, lp, conv, state, valid, at=None):
@@ -1279,9 +1032,10 @@ def decode_forward(params, tokens, cache, pos, cfg, mesh=None,
 
     tokens: [B, T] (T static: the prompt length for prefill, 1 per decode
     step). valid: None (every position is real) or a [B, T] mask whose
-    true positions lead each row: a recurrent state (`recurrent_pools`)
-    passes through the positions that are not valid. K and V need no
-    mask (what is written there is overwritten before it is seen).
+    true positions lead each row: a recurrent state (inference/cache.py,
+    `recurrent_pools`) passes through the positions that are not valid.
+    K and V need no mask (what is written there is overwritten before it
+    is seen).
     slots: None (row b of the batch is row b of the cache) or a traced
     [B] vector of DISTINCT rows of a cache that holds more: row b of the
     batch reads and extends row slots[b] in place and no other row is
@@ -1372,15 +1126,11 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
 
     attn_impl: 'dense' (whole-cache masked attention), 'chunked'
     (flash-decode: online softmax over only the filled prefix — the
-    long-context serving path), or 'auto'. The auto switchover picks
-    'chunked' once the KV cache is deeper than 2 * DECODE_CHUNK
-    positions (512 with the default chunk of 256): the dense path reads
-    the whole [Smax] cache every step whatever is filled, the chunked
-    path only the filled prefix, a chunk at a time. Where the two cross
-    has not been measured on a chip: the threshold is a choice, and only
-    the chunked path has a chip time (the module docstring; PERF.md,
-    PR 25). DECODE_CHUNK — and therefore this threshold — is
-    overridable via TPUFLOW_DECODE_CHUNK (read once at import).
+    long-context serving path), or 'auto', what `pool_read` answers for
+    the cache's depth. This selector is the reference's: generate() is
+    what both engines are tested against and 'dense' is the tests'
+    oracle; no server takes it (an engine reads as `pool_read` says of
+    its shapes).
 
     prompt_len: None when prompt_tokens is exactly the prompt. A TRACED
     scalar when prompt_tokens is right-PADDED to a longer static shape
@@ -1411,13 +1161,11 @@ def generate(params, prompt_tokens, cfg, max_new_tokens, temperature=0.0,
             "max_seq_len (%d), where rope's table ends"
             % (P, max_new_tokens, cfg.max_seq_len))
     if attn_impl not in ("auto", "dense", "chunked"):
-        # a typo'd impl must not silently select dense (and then be
-        # recorded verbatim in benchmark results)
+        # a typo'd impl must not silently select dense
         raise ValueError("attn_impl must be 'auto', 'dense' or "
                          "'chunked', got %r" % (attn_impl,))
     if attn_impl == "auto":
-        attn_impl = ("chunked" if (max_seq_len or total) > 2 * DECODE_CHUNK
-                     else "dense")
+        attn_impl = pool_read(max_seq_len or total)
 
     valid = None if prompt_len is None else jnp.broadcast_to(
         jnp.arange(P) < prompt_len, (B, P))

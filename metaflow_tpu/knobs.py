@@ -182,9 +182,6 @@ _k("TPUFLOW_DATA_WORKERS", "int", 8, "count", "data",
 # --- training --------------------------------------------------------------
 _k("TPUFLOW_PEAK_TFLOPS", "float", None, "TFLOP/s", "training",
    "per-chip peak TFLOPs override for MFU accounting")
-_k("TPUFLOW_DECODE_CHUNK", "int", 256, "tokens", "training",
-   "KV chunk of the chunk-loop attention and the attn_impl=auto "
-   "switchover; the decode kernel's block is not its to set")
 _k("TPUFLOW_ZERO", "bool", False, "", "training",
    "ZeRO-style optimizer-state sharding over the data axis")
 

@@ -77,19 +77,6 @@ class LlamaConfig:
             **kw,
         )
 
-    @staticmethod
-    def bench_1b(**kw):
-        """~1.2B params: fits one v5e chip in bf16 with Adam state offloaded
-        sharding-free; used by bench.py."""
-        return replace(
-            LlamaConfig(
-                vocab_size=32_000, dim=2048, n_layers=16, n_heads=16,
-                n_kv_heads=8, ffn_dim=5632, max_seq_len=2048,
-                rope_llama3_scaling=False,
-            ),
-            **kw,
-        )
-
 
 def param_dtype(cfg):
     return jnp.dtype(cfg.dtype)

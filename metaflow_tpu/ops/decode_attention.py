@@ -1,7 +1,7 @@
 """One new position a lane attending over a K and V pool as stored: the
 decode step's attention as ONE Pallas call.
 
-The pools are `[layers, B, S, KV * Hd]` (inference/decode.py,
+The pools are `[layers, B, S, KV * Hd]` (inference/cache.py,
 `init_kv_cache`: heads folded into the minor axis, heads major), and the
 kernel's operands are those pools whole, read only: no slice, reshape or
 transpose of a pool happens outside it, so there is nothing for the

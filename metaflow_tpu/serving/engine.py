@@ -8,14 +8,12 @@ Orca/vLLM-lineage fix, shaped for TPUs: scheduling happens in Python,
 but every device step is one of a FIXED set of jitted programs, so the
 compiled-program residency that TPUs reward is preserved.
 
-Layout: a pool of B slots shares one static cache (init_kv_cache), a
-tree of the pools the model's kinds of layer declare, each [layers of
-the kind, B] + its shape a slot: K and V [.., max_seq, kv_heads *
-head_dim] of the attention layers (or of the ONE full-attention layer
-that a model's later layers read again), K and V of window layers in a
-ring of the window and the widest prefill row, recurrent states (below).
-It is the carry of decode_forward's layer loop, donated and updated in
-place.
+Layout: a pool of B slots shares one static cache, a tree of the pools
+the model's kinds of layer declare, each [layers of the kind, B] + its
+shape a slot (inference/cache.py, `init_kv_cache`: the one module that
+knows the format, and whose `seed`, `extract` and `reset` this engine
+jits). It is the carry of decode_forward's layer loop, donated and
+updated in place.
 Each slot holds at most one in-flight request and carries host-side
 state (pos, sampling knobs, per-token rng keys: drawn when a sampled
 token is first asked for, `KeySchedules`). Three compiled programs
@@ -35,8 +33,8 @@ cover everything:
     so there are no narrower buckets), and `warm_prefill` compiles all
     of them before a request is admitted
   - decode: advance ALL slots one token in one fused call — per-slot
-    positions (vector-pos decode_forward), per-slot dynamic_update_slice
-    cache writes, per-slot slot-masked sampling (greedy/temperature/
+    positions (vector-pos decode_forward), cache writes at each lane's
+    own cursor, per-slot slot-masked sampling (greedy/temperature/
     top-k/top-p as traced per-slot arrays, so one program serves every
     sampling-config mix); on a TPU its attention reads the pools as
     stored, each decoding lane to its own depth and no other lane
@@ -52,21 +50,21 @@ cover everything:
 
 Where every layer of the stack is an attention layer (Llama, Mistral,
 Mixtral), on one chip and with the chunk loop (`merges`,
-inference/decode.py), an iteration that prefills is ONE execution, of
-the decode program: `stage_rows(plan)` builds the rows that `prefill`
-would have run, and the next `launch_decode` takes them along, the lanes
-and the rows' tokens one batch for everything that multiplies by a
-weight, so that every weight is read once where two programs read it
-twice. The rows' first tokens come back with the lanes' tokens in the
-one fetch (`row_results`), and the program leaves the token of a row that
-ends (`ends`, host-known) at its lane; with no lane decoding the same
-program runs
-for the rows alone, and the prefill and first-token programs are never
-compiled (`warm_prefill` warms the decode step's three row shapes in
-their place). A stack with a recurrent layer, a ring, a tail layer or a
-gated memory keeps prefill then decode, two programs an iteration: its
-rows have more to take apart than K and V, and its kinds join one at a
-time (ROADMAP S9b).
+inference/decode.py; `attn_impl`, what `pool_read` there answers for
+this engine's shapes: a fact, no option), an iteration that prefills is
+ONE execution, of the decode program: `stage_rows(plan)` builds the rows
+that `prefill` would have run, and the next `launch_decode` takes them
+along, the lanes and the rows' tokens one batch for everything that
+multiplies by a weight, so that every weight is read once where two
+programs read it twice. The rows' first tokens come back with the lanes'
+tokens in the one fetch (`row_results`), and the program leaves the
+token of a row that ends (`ends`, host-known) at its lane; with no lane
+decoding the same program runs for the rows alone, and the prefill and
+first-token programs are never compiled (`warm_prefill` warms the decode
+step's three row shapes in their place). A stack with a recurrent layer,
+a ring, a tail layer or a gated memory keeps prefill then decode, two
+programs an iteration: its rows have more to take apart than K and V,
+and its kinds join one at a time (ROADMAP S9b).
 
 Every program has a LAUNCH and a COLLECT (`launch_decode` /
 `decode_step`, `launch_prefill` / `collect_prefill`; `decode_step` with
@@ -89,29 +87,24 @@ their own cursor and are overwritten (prefill rewrites the range, decode
 overwrites pad garbage exactly one position before it would become
 visible), so no flag tensor is needed inside the compiled program. A
 window layer's ring keeps that invariant with no mask and no reset
-(inference/decode.py, `_write_layer`), as long as no row is wider than
+(inference/cache.py, `_write_layer`), as long as no row is wider than
 the ring allows: `prefill_row`, two chunks, the scheduler's default
 budget.
 
 A model with recurrent layers keeps a RECURRENT-STATE POOL in the same
 cache tree, whatever pools its kinds of layer declare
-(inference/decode.py, `POOLS`): per Mamba layer and slot the
-convolution's tail and the float32 state (Jamba, beside the KV pool of
-its attention layers); per power-retention layer and slot the float32
-state and its normaliser (Brumby: 34 MB a layer and slot, and NO KV pool,
-since no layer caches K and V: a slot then costs the same at position 10
-and at 30,000, and `max_seq_len` bounds rope's table only). None of the
-three invariants above holds for a
+(inference/cache.py, `POOLS`, says what each holds and how large;
+Brumby's is 34 MB a layer and slot and it has NO KV pool: a slot then
+costs the same at position 10 and at 30,000, and `max_seq_len` bounds
+rope's table only). None of the three invariants above holds for a
 recurrence, which has no garbage that is overwritten before it is seen,
-so for such a model (Jamba, Brumby, Phi-4-mini-flash, and Nemotron-H,
-whose Mamba-2 layers keep a tail over 10,240 channels and a state of
-4.19 MB a layer and slot): (a) the prefill program is told how many of each
-row's positions are real, and the state after a row is the state after
-its last real token (a row with none holds the state); (b) the fused
-decode step holds the state of every lane whose mask is false; (c)
-admit zeroes the slot's state (one small jitted program, host span
-`engine.state.reset`); (d) the state carries from chunk to chunk of one
-prompt in the pool. A KV range is not
+so for such a model (Jamba, Brumby, Phi-4-mini-flash, Nemotron-H): (a)
+the prefill program is told how many of each row's positions are real,
+and the state after a row is the state after its last real token (a row
+with none holds the state); (b) the fused decode step holds the state of
+every lane whose mask is false; (c) admit zeroes the slot's state (one
+small jitted program, host span `engine.state.reset`); (d) the state
+carries from chunk to chunk of one prompt in the pool. A KV range is not
 a prefix of such a model: seed_prefix, extract_kv, admit_prefilled and
 kv_token_bytes refuse it (`refuse_recurrent`), as do the prefix caches,
 the paged engine and the disaggregated handoff built on them.
@@ -136,6 +129,7 @@ would give it alone — greedy case bit-exact (pinned by
 tests/test_serving.py).
 """
 
+import functools
 from collections import deque
 
 import numpy as np
@@ -145,25 +139,13 @@ import jax.numpy as jnp
 
 from .. import device, telemetry
 from ..exception import TpuFlowException
-from ..inference.decode import (
-    DECODE_CHUNK,
-    MOE_PAIRS,
-    POOLS,
-    attention_positions,
-    attention_reads,
-    bucket_length,
-    cache_pools,
-    decode_forward,
-    family,
-    init_kv_cache,
-    is_recurrent,
-    layer_kinds,
-    merges,
-    recurrent_pools,
-    ring_pools,
-    stack_passes,
-    state_updates,
-)
+from ..inference import cache as kv_cache
+from ..inference.cache import (MOE_PAIRS, POOLS, cache_pools, init_kv_cache,
+                               is_recurrent, layer_kinds, recurrent_pools,
+                               ring_pools, stack_passes)
+from ..inference.decode import (attention_positions, attention_reads,
+                                bucket_length, decode_forward, family, merges,
+                                pool_read, state_updates)
 from ..ops.attention import NEG_INF
 
 
@@ -260,24 +242,6 @@ def refuse_looped(cfg, what):
                 stack_passes(cfg) * cfg.n_layers, cfg.n_layers))
 
 
-def auto_attention(cfg, cache, max_seq_len, mesh=None):
-    """What `attn_impl="auto"` picks for slots `max_seq_len` deep over
-    the pools `cache`, by the shapes: "chunked" past 2 * DECODE_CHUNK
-    positions (generate()'s threshold: a choice, no chip has timed where
-    the two cross), and at ANY depth where every attention read of the
-    decode step is the kernel's (one chip, shapes
-    `ops/decode_attention.py` takes: `attention_reads`): the kernel reads
-    each decoding lane to its own depth and no other lane, so it never
-    fetches more than the dense read of every lane's whole pool, and only
-    a chunked stack lets the prefill rows ride in the decode step
-    (`merges`). Else "dense"."""
-    if max_seq_len > 2 * DECODE_CHUNK:
-        return "chunked"
-    reads = attention_reads(cfg, cache, "chunked", kernel=mesh is None)
-    return "chunked" if reads and all(
-        how == "kernel" for *_, how in reads) else "dense"
-
-
 def sample_slots(logits, keys, temperature, top_k, top_p):
     """Per-slot sampling: [B, vocab] fp32 logits -> [B] int32, with
     TRACED per-slot knobs (temperature[B], top_k[B] int32 — vocab size
@@ -324,11 +288,8 @@ class SlotEngine(KeySchedules):
     runs_ahead = True
 
     def __init__(self, params, cfg, max_slots=8, max_seq_len=None,
-                 prefill_chunk=64, mesh=None, attn_impl="auto",
-                 cache_dtype=None, pad_id=0, min_bucket=16):
-        if attn_impl not in ("auto", "dense", "chunked"):
-            raise ValueError("attn_impl must be 'auto', 'dense' or "
-                             "'chunked', got %r" % (attn_impl,))
+                 prefill_chunk=64, mesh=None, cache_dtype=None, pad_id=0,
+                 min_bucket=16):
         self.params = params
         self.cfg = cfg
         self.max_slots = int(max_slots)
@@ -352,10 +313,8 @@ class SlotEngine(KeySchedules):
         self.prefill_row = 2 * self.prefill_chunk
         self._cache = init_kv_cache(cfg, self.max_slots, self.max_seq_len,
                                     dtype=cache_dtype, row=self.prefill_row)
-        if attn_impl == "auto":
-            attn_impl = auto_attention(cfg, self._cache, self.max_seq_len,
-                                       mesh)
-        self.attn_impl = attn_impl
+        # how the programs read the pools: the shapes' answer, no option
+        self.attn_impl = pool_read(self.max_seq_len, cfg, self._cache, mesh)
         # a config with a tail layer: a prefill program's logits are of
         # each row's last real position alone
         self._tail = getattr(cfg, "tail_layer", None) is not None
@@ -515,38 +474,6 @@ class SlotEngine(KeySchedules):
             first = sample_slots(last, keys, temp, top_k, top_p)
             return first, _put_first(tok, first, slots, ends)
 
-        def _seed(cache, k, v, slot):
-            # write a [layers, T, kv_heads, head_dim] KV range into one
-            # slot's cache view starting at position 0; slot is TRACED
-            # so compiles are bounded by the T bucket, not the pool size.
-            # The pools fold heads and head size into one axis
-            # (init_kv_cache); the host's contract keeps them apart
-            fold = lambda a: a.reshape(a.shape[0], 1, a.shape[1], -1)
-            return {name: jax.lax.dynamic_update_slice(
-                        cache[name], fold(new), (0, slot, 0, 0))
-                    for name, new in (("k", k), ("v", v))}
-
-        def _extract(cache, slot, T):
-            # read the first T positions of one slot's view; T is STATIC
-            # (callers pass a power-of-two bucket and trim on host)
-            L, width = cache["k"].shape[0], cache["k"].shape[3]
-            return tuple(
-                jax.lax.dynamic_slice(
-                    cache[name], (0, slot, 0, 0), (L, 1, T, width)
-                ).reshape(L, T, cfg.n_kv_heads, cfg.head_dim)
-                for name in ("k", "v"))
-
-        def _reset_state(cache, slot):
-            # a new occupant starts from an empty recurrent state; its K
-            # and V need no reset (overwritten before they are seen)
-            cache = dict(cache)
-            for name in self._recurrent_pools:
-                arr = cache[name]
-                cache[name] = jax.lax.dynamic_update_slice_in_dim(
-                    arr, jnp.zeros(arr.shape[:1] + (1,) + arr.shape[2:],
-                                   arr.dtype), slot, axis=1)
-            return cache
-
         # the cache is donated: the pool's KV state is the single largest
         # buffer and every call replaces it wholesale
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(1,))
@@ -557,10 +484,15 @@ class SlotEngine(KeySchedules):
         self._first_fn = jax.jit(_first_token)
         self._set_tok_fn = jax.jit(lambda tok, slot, token:
                                    tok.at[slot].set(token))
-        self._seed_fn = jax.jit(_seed, donate_argnums=(0,))
-        self._reset_state_fn = jax.jit(_reset_state, donate_argnums=(0,))
+        # the cache's own programs (inference/cache.py)
+        self._seed_fn = jax.jit(kv_cache.seed, donate_argnums=(0,))
+        self._reset_state_fn = jax.jit(
+            functools.partial(kv_cache.reset,
+                              names=tuple(self._recurrent_pools)),
+            donate_argnums=(0,))
         # no donation: the pool cache must survive an extraction
-        self._extract_fn = jax.jit(_extract, static_argnums=(2,))
+        self._extract_fn = jax.jit(functools.partial(kv_cache.extract, cfg),
+                                   static_argnums=(2,))
 
     # ---------- pool state ----------
 
@@ -651,9 +583,7 @@ class SlotEngine(KeySchedules):
         """Host bytes one cached token costs (k + v across layers) —
         the unit the prefix-cache byte budget is denominated in."""
         refuse_recurrent(self.cfg, "kv_token_bytes (a prefix cache's unit)")
-        k = self._cache["k"]
-        layers, _, _, width = k.shape   # width: kv_heads * head_dim
-        return 2 * layers * width * k.dtype.itemsize
+        return kv_cache.kv_position_bytes(self._cache)
 
     # ---------- slot lifecycle ----------
 

@@ -59,12 +59,12 @@ import jax.numpy as jnp
 
 from .. import knobs, telemetry
 from ..inference.decode import (
-    DECODE_CHUNK,
     _attn_qkv,
     _block_ffn,
     _cached_attention,
     _streamed_attention,
     bucket_length,
+    pool_read,
 )
 from ..models import llama
 from ..ops import rms_norm
@@ -346,9 +346,7 @@ class PagedEngine(KeySchedules):
             total_pages = self.max_slots * self.n_blocks + 1
         self.pool = PagePool(cfg, total_pages, ptok, dtype=cache_dtype)
         if attn_impl == "auto":
-            attn_impl = ("chunked"
-                         if self.n_blocks * ptok > 2 * DECODE_CHUNK
-                         else "dense")
+            attn_impl = pool_read(self.n_blocks * ptok)
         self.attn_impl = attn_impl
 
         B = self.max_slots

@@ -85,10 +85,8 @@ def _build_synthetic(args):
     return build_engine(params, cfg, slots=args.slots,
                         max_seq_len=args.max_seq_len,
                         prefill_chunk=args.prefill_chunk,
-                        mesh_spec=args.mesh or None,
-                        attn_impl=args.attn_impl, paged=args.paged,
-                        page_tokens=args.page_tokens,
-                        spec_k=args.spec_k)
+                        mesh_spec=args.mesh or None, paged=args.paged,
+                        page_tokens=args.page_tokens, spec_k=args.spec_k)
 
 
 def _build_from_checkpoint(args):
@@ -104,10 +102,8 @@ def _build_from_checkpoint(args):
     return build_engine(params, cfg, slots=args.slots,
                         max_seq_len=args.max_seq_len,
                         prefill_chunk=args.prefill_chunk,
-                        mesh_spec=args.mesh or None,
-                        attn_impl=args.attn_impl, paged=args.paged,
-                        page_tokens=args.page_tokens,
-                        spec_k=args.spec_k)
+                        mesh_spec=args.mesh or None, paged=args.paged,
+                        page_tokens=args.page_tokens, spec_k=args.spec_k)
 
 
 def _init_replica_telemetry(flow_name, run_id, index):
@@ -158,7 +154,6 @@ def build_parser():
     p.add_argument("--prefill-chunk", type=int, default=64)
     p.add_argument("--max-queue", type=int, default=64)
     p.add_argument("--mesh", default=None)
-    p.add_argument("--attn-impl", default="auto")
     p.add_argument("--no-warmup", action="store_true")
     p.add_argument("--step-delay-ms", type=float, default=0.0)
     p.add_argument("--role", default="unified",

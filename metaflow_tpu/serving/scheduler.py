@@ -51,7 +51,8 @@ the same iteration, 3's first tokens before 4: the loop is the same,
 steps_ahead stays 0.
 
 Where the engine's stack merges (SlotEngine.merges: attention layers
-throughout, one chip, the chunk loop: Llama, Mistral, Mixtral), 3 only
+throughout, one chip, pools that the engine's shapes say are read in the
+chunk loop, `SlotEngine.attn_impl`: Llama, Mistral, Mixtral), 3 only
 STAGES the plan's rows (engine.stage_rows, still under
 serve.prefill_chunk) and 4's decode step takes them along: ONE execution
 an iteration reads every weight once, where the two programs of an

@@ -23,7 +23,8 @@ from benchmark.families import brumby as ref_family
 from metaflow_tpu import goodput
 from metaflow_tpu.cmd.serve import build_config, build_engine
 from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
-from metaflow_tpu.inference.decode import cache_pools, family_config_class
+from metaflow_tpu.inference.cache import cache_pools
+from metaflow_tpu.inference.decode import family_config_class
 from metaflow_tpu.models import brumby
 from metaflow_tpu.ops import retention
 from metaflow_tpu.serving import Request, Scheduler, SlotEngine

@@ -308,6 +308,39 @@ def test_registry_entries_are_complete():
         assert knob.doc, name
 
 
+def test_the_decode_chunk_is_a_constant_and_no_knob():
+    """`TPUFLOW_DECODE_CHUNK` set both the chunk loop's chunk and where an
+    engine switched reads, at import; no cell and no chip run ever passed
+    another value. It is a constant of inference/decode.py."""
+    from metaflow_tpu.inference import decode
+
+    assert "TPUFLOW_DECODE_CHUNK" not in knobs.KNOBS
+    assert decode.DECODE_CHUNK == 256
+    assert len(knobs.KNOBS) <= 155   # and no knob came in its place
+
+
+def test_the_cache_module_imports_nothing_of_the_block_or_the_engines():
+    """inference/cache.py is what inference/decode.py and serving/ read
+    the cache's format from: the arrows point one way."""
+    import ast
+
+    path = os.path.join(LIBRARY, "inference", "cache.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    imported = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported += [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            module = "." * node.level + (node.module or "")
+            imported += ["%s.%s" % (module, alias.name)
+                         for alias in node.names]
+    assert imported, path
+    for name in imported:
+        assert "decode" not in name.split(".") \
+            and "serving" not in name.split("."), name
+
+
 # ---------------------------------------------------------------------------
 # regression: defaults that used to drift between call sites
 # ---------------------------------------------------------------------------

@@ -27,8 +27,9 @@ from metaflow_tpu.cmd.serve import build_config, build_engine, \
     build_prefix_cache
 from metaflow_tpu.exception import TpuFlowException
 from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
-from metaflow_tpu.inference.decode import family, is_recurrent, \
-    layer_kinds, recurrent_pools, ring_pools
+from metaflow_tpu.inference.cache import is_recurrent, layer_kinds, \
+    recurrent_pools, ring_pools
+from metaflow_tpu.inference.decode import family
 from metaflow_tpu.models import jamba, llama, mixtral, phi4flash
 from metaflow_tpu.ops import ssm
 from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \

@@ -21,8 +21,9 @@ from metaflow_tpu.cmd.serve import build_config, build_engine, \
     build_prefix_cache
 from metaflow_tpu.exception import TpuFlowException
 from metaflow_tpu.inference import decode_forward, init_kv_cache
-from metaflow_tpu.inference.decode import MOE_PAIRS, family, is_recurrent, \
-    layer_kinds, merges, recurrent_pools
+from metaflow_tpu.inference.cache import MOE_PAIRS, is_recurrent, \
+    layer_kinds, recurrent_pools
+from metaflow_tpu.inference.decode import family, merges
 from metaflow_tpu.models import jamba, mixtral, nemotron_h
 from metaflow_tpu.ops import moe, ssm
 from metaflow_tpu.serving import PagedEngine, RadixPrefixCache, Request, \

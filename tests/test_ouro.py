@@ -22,9 +22,10 @@ from metaflow_tpu import goodput
 from metaflow_tpu.cmd.serve import build_config
 from metaflow_tpu.exception import TpuFlowException
 from metaflow_tpu.inference import decode_forward, generate, init_kv_cache
-from metaflow_tpu.inference.decode import (attention_reads, cache_pools,
-                                           family, family_config_class,
-                                           merges, stack_passes)
+from metaflow_tpu.inference.cache import cache_pools, stack_passes
+from metaflow_tpu.inference.decode import (attention_reads, family,
+                                           family_config_class, merges,
+                                           pool_read)
 from metaflow_tpu.models import llama, ouro
 from metaflow_tpu.serving import (PagedEngine, PagePool, Request, Scheduler,
                                   SlotEngine)
@@ -39,6 +40,9 @@ DIMS = dict(configs.dims(dict(configs.read_json(os.path.join(
 PUBLISHED = configs.read_json(os.path.join(
     ROOT, "benchmark", "configs", "ouro-2.6b-serve.json"))
 CHUNK = 16
+# past 2 * DECODE_CHUNK positions: an engine that deep reads its pools in
+# the chunk loop and its stack merges (`pool_read`); no argument says so
+DEEP = 640
 # float32 on both sides, the program's products at the backend's default
 # precision and the reference's at `highest`, through passes x layers = 6
 # blocks whose sandwich norms rescale every sublayer's output to unit
@@ -131,8 +135,8 @@ def test_rows_of_two_slots_ride_beside_a_decoding_lane(params):
     its steps, a masked lane writing at each row's first position at
     every pass's pool index (PR 39's trap); every request's logits are
     the reference's and the pool is the two-program path's."""
-    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=96,
-                     prefill_chunk=CHUNK, attn_impl="chunked")
+    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=DEEP,
+                     prefill_chunk=CHUNK)
     assert eng.merges and eng.passes == PASSES
     prompts = [prompt(21), prompt(37, 1), prompt(30, 2)]
     made = {s: [] for s in range(3)}
@@ -183,8 +187,8 @@ def test_the_scheduler_serves_generates_tokens_and_counts_passes(
     from metaflow_tpu import telemetry
     from metaflow_tpu.datastore import FlowDataStore, LocalStorage
 
-    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=96,
-                     prefill_chunk=CHUNK, attn_impl="chunked")
+    eng = SlotEngine(params, CFG, max_slots=3, max_seq_len=DEEP,
+                     prefill_chunk=CHUNK)
     fds = FlowDataStore("ServeLoop", LocalStorage, ds_root=str(tmp_path))
     telemetry.init_recorder(fds, "1", "_serve", "loop-test")
     try:
@@ -220,8 +224,8 @@ def test_a_kv_range_carries_every_pass(params):
     """extract_kv, the handoff frame, seed_prefix and admit_prefilled take
     the pool's whole leading axis: a prefix seeded from another slot's
     range decodes the tokens a local prefill gives."""
-    eng = SlotEngine(params, CFG, max_slots=2, max_seq_len=96,
-                     prefill_chunk=CHUNK, attn_impl="chunked")
+    eng = SlotEngine(params, CFG, max_slots=2, max_seq_len=DEEP,
+                     prefill_chunk=CHUNK)
     p = prompt(40)
     eng.admit(0, p, 6)
     first = None
@@ -282,25 +286,30 @@ def test_only_a_stack_of_attention_layers_goes_round():
     assert stack_passes(jamba.JambaConfig.tiny()) == 1
 
 
-# ---- what `auto` picks: a pool the decode kernel can block is chunked ----
+# ---- how a pool is read: a pool the decode kernel can block is chunked ----
 
-@pytest.mark.parametrize("head_dim, depth, mesh, picks", [
-    (128, 512, None, "chunked"),    # the published head, the cell's depth
-    (128, 64, None, "chunked"),
-    (32, 512, None, "dense"),       # no whole lanes: the kernel refuses
-    (32, 640, None, "chunked"),     # past 2 * DECODE_CHUNK, as before
-    (128, 512, "a mesh", "dense"),  # the kernel is one chip's
+@pytest.mark.parametrize("head_dim, depth, mesh, whole_pool, picks", [
+    (128, 512, None, True, "chunked"),    # the published head and depth
+    (128, 64, None, True, "chunked"),
+    (32, 512, None, True, "dense"),       # no whole lanes: the kernel refuses
+    (32, 640, None, True, "chunked"),     # past 2 * DECODE_CHUNK, as before
+    (128, 512, "a mesh", True, "dense"),  # the kernel is one chip's
+    (128, 512, None, False, "dense"),     # a depth alone, as generate() asks
 ])
 def test_auto_attention_follows_what_the_kernel_takes(head_dim, depth, mesh,
-                                                      picks):
+                                                      whole_pool, picks):
     """A pool no deeper than 2 * DECODE_CHUNK was served dense whatever
     its shapes, so a 512-deep pool had no merged step and no kernel: the
-    rule now asks what the decode step's attention would be."""
-    from metaflow_tpu.serving.engine import auto_attention
+    rule asks what the decode step's attention would be, where the caller
+    is a decode step of whole pools (the slot engine; `generate()` and
+    the paged engine, whose programs are never the kernel's, give a depth
+    alone)."""
     cfg = ouro.OuroConfig.tiny(head_dim=head_dim, max_seq_len=1024,
                                dtype="bfloat16")
     cache = jax.eval_shape(lambda: init_kv_cache(cfg, 2, depth))
-    assert auto_attention(cfg, cache, depth, mesh) == picks
+    picked = pool_read(depth, cfg, cache, mesh) if whole_pool \
+        else pool_read(depth)
+    assert picked == picks
     assert merges(cfg, mesh, picks) == (picks == "chunked")
 
 

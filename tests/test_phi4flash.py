@@ -22,7 +22,8 @@ from benchmark.families import phi4flash as ref
 from metaflow_tpu import goodput
 from metaflow_tpu.cmd.serve import build_config, build_engine
 from metaflow_tpu.inference import decode_forward, init_kv_cache
-from metaflow_tpu.inference.decode import _visible, _write_layer
+from metaflow_tpu.inference.cache import _write_layer
+from metaflow_tpu.inference.decode import _visible
 from metaflow_tpu.models import phi4flash
 from metaflow_tpu.serving import Request, Scheduler, SlotEngine
 

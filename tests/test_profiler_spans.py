@@ -290,8 +290,9 @@ def captured_merged(tmp_path_factory):
     tmp = tmp_path_factory.mktemp("merged")
     cfg = llama.LlamaConfig.tiny()
     params = llama.init_params(jax.random.PRNGKey(0), cfg)
-    engine = SlotEngine(params, cfg, max_slots=2, max_seq_len=64,
-                        prefill_chunk=8, attn_impl="chunked")
+    # past 2 * DECODE_CHUNK positions: the chunk loop, by the shapes
+    engine = SlotEngine(params, cfg, max_slots=2, max_seq_len=640,
+                        prefill_chunk=8)
     assert engine.merges
     sched = Scheduler(engine).start()
     out = {}
@@ -489,6 +490,10 @@ def _has(text, scope):
 
 
 def _engine(model, attn_impl, paged=False):
+    """An engine of abstract weights that reads its pools as `attn_impl`
+    says: the slot engine's by its depth (`pool_read`: the chunk loop
+    past 2 * DECODE_CHUNK positions), the paged engine's by its
+    argument."""
     cfg = model.LlamaConfig.tiny() if model is llama \
         else model.MixtralConfig.tiny()
     params = jax.eval_shape(
@@ -497,8 +502,10 @@ def _engine(model, attn_impl, paged=False):
         return PagedEngine(params, cfg, max_slots=2, max_seq_len=64,
                            prefill_chunk=8, page_tokens=8,
                            attn_impl=attn_impl)
-    return SlotEngine(params, cfg, max_slots=2, max_seq_len=64,
-                      prefill_chunk=8, attn_impl=attn_impl)
+    engine = SlotEngine(params, cfg, max_slots=2, prefill_chunk=8,
+                        max_seq_len=640 if attn_impl == "chunked" else 64)
+    assert engine.attn_impl == attn_impl
+    return engine
 
 
 def _decode_text(engine):

@@ -26,6 +26,12 @@ from metaflow_tpu.serving import (
 )
 
 
+# past 2 * DECODE_CHUNK positions: an engine that deep reads its pools in
+# the chunk loop, as a deployment's does, and a stack of attention layers
+# merges (inference/decode.py, `pool_read`); no argument says so
+DEEP = 640
+
+
 @pytest.fixture(scope="module")
 def setup():
     cfg = llama.LlamaConfig.tiny()
@@ -101,8 +107,9 @@ class TestTokenIdentity:
         traced trip count runs to the deepest slot; shallower slots mask
         the extra chunks) — token-identical to dense lockstep."""
         cfg, params = setup
-        eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
-                         prefill_chunk=16, attn_impl="chunked")
+        eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=DEEP,
+                         prefill_chunk=16)
+        assert eng.attn_impl == "chunked"
         sched = Scheduler(eng)
         rng = np.random.default_rng(2)
         reqs = []
@@ -295,8 +302,8 @@ def merging_engine(request):
     mod, config = FAMILIES[request.param]
     cfg = config.tiny()
     params = mod.init_params(jax.random.PRNGKey(0), cfg)
-    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
-                     prefill_chunk=CHUNK, attn_impl="chunked")
+    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=DEEP,
+                     prefill_chunk=CHUNK)
     assert eng.merges
     return cfg, params, eng, {name: _count_calls(eng, name)
                               for name in PROGRAMS}
@@ -362,8 +369,8 @@ class TestRowsRideInTheDecodeStep:
         iteration: once both have made the same tokens, K and V of every
         slot agree at every real position."""
         cfg, params, eng, _ = merging_engine
-        two = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
-                         prefill_chunk=CHUNK, attn_impl="chunked")
+        two = SlotEngine(params, cfg, max_slots=3, max_seq_len=DEEP,
+                         prefill_chunk=CHUNK)
         prompts = _prompts(cfg, (21, 50, 37), seed=5)
         made = {e: {s: [] for s in range(3)} for e in (eng, two)}
 
@@ -416,8 +423,7 @@ class TestRowsRideInTheDecodeStep:
         lane writes K and V at its cursor: the position its row's first
         token is written to in the same execution. The row's must be what
         stays."""
-        from metaflow_tpu.inference.decode import (decode_forward,
-                                                   init_kv_cache)
+        from metaflow_tpu.inference import decode_forward, init_kv_cache
 
         cfg, params, _, _ = merging_engine
         cache = init_kv_cache(cfg, 3, 64)
@@ -539,8 +545,8 @@ class TestRowsRideInTheDecodeStep:
 def chunked_engine(setup):
     """A tiny Llama engine whose stack merges (the chunk loop)."""
     cfg, params = setup
-    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
-                     prefill_chunk=CHUNK, attn_impl="chunked")
+    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=DEEP,
+                     prefill_chunk=CHUNK)
     assert eng.merges
     return eng
 
@@ -1201,6 +1207,38 @@ class TestServeCommand:
         params = {"embed": 1}
         assert extract_params({"params": params}) is params
         assert extract_params(params) is params
+
+    @pytest.mark.parametrize("entry", ["tpuflow serve", "the replica's"])
+    def test_attn_impl_is_no_option_of_a_server(self, entry, capsys):
+        """`--attn-impl dense` made a server run two programs an
+        iteration where the shapes ask for one; how an engine reads its
+        pools is `pool_read`'s answer, and neither entry point, nor
+        `build_engine`, `serve`, `serve_fleet` or `SlotEngine`, takes the
+        word."""
+        import inspect
+
+        from metaflow_tpu.cmd import serve as cmd
+
+        if entry == "tpuflow serve":
+            from click.testing import CliRunner
+
+            from metaflow_tpu.__main__ import main as cli
+
+            result = CliRunner().invoke(
+                cli, ["serve", "SomeFlow/1", "--attn-impl", "dense"])
+            assert result.exit_code == 2
+            assert "No such option" in result.output
+            assert "--attn-impl" in result.output
+        else:
+            from metaflow_tpu.serving import replica
+
+            with pytest.raises(SystemExit) as refused:
+                replica.build_parser().parse_args(["--attn-impl", "dense"])
+            assert refused.value.code == 2
+            assert "--attn-impl" in capsys.readouterr().err
+        for fn in (cmd.build_engine, cmd.serve, cmd.serve_fleet,
+                   SlotEngine.__init__):
+            assert "attn_impl" not in inspect.signature(fn).parameters
 
     def test_build_engine_shards_by_model_family(self):
         """--mesh with a Mixtral checkpoint must use the Mixtral rule

@@ -39,10 +39,11 @@ def ahead_engine(request, setup):
         params = jamba.init_params(jax.random.PRNGKey(0), cfg)
     else:
         cfg, params = setup
-    eng = SlotEngine(params, cfg, max_slots=3, max_seq_len=128,
-                     prefill_chunk=CHUNK,
-                     **(dict(attn_impl="chunked") if kind == "merging"
-                        else {}))
+    # the read is the shapes' to say: past 2 * DECODE_CHUNK positions the
+    # chunk loop, and a stack of attention layers then merges
+    eng = SlotEngine(params, cfg, max_slots=3,
+                     max_seq_len=640 if kind == "merging" else 128,
+                     prefill_chunk=CHUNK)
     assert eng.runs_ahead and eng.merges == (kind == "merging") \
         and eng.recurrent == (kind == "recurrent")
     return cfg, params, eng
